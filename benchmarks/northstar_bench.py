@@ -280,15 +280,10 @@ def run_all() -> dict:
     shuffle_mb = int(os.environ.get("TPU3FS_NS_SHUFFLE_MB", "512"))
     kv_reads = int(os.environ.get("TPU3FS_NS_KV_READS", "1024"))
     rebuild_mb = int(os.environ.get("TPU3FS_NS_REBUILD_MB", "1024"))
-    for name, fn in (
-        ("graysort", lambda: graysort_shuffle(total_mb=shuffle_mb)),
-        ("kvcache", lambda: kvcache_random_read(reads=kv_reads)),
-        ("rebuild", lambda: failed_target_rebuild(file_mb=rebuild_mb)),
-    ):
-        try:
-            out.update(fn())
-        except Exception as e:  # a broken workload must not hide the others
-            out[f"northstar_error_{name}"] = repr(e)[:200]
+    # a workload that fails raises: the caller's run fails with it
+    out.update(graysort_shuffle(total_mb=shuffle_mb))
+    out.update(kvcache_random_read(reads=kv_reads))
+    out.update(failed_target_rebuild(file_mb=rebuild_mb))
     return out
 
 

@@ -38,6 +38,25 @@ class TestFabricBatchedIo:
         for r, (_, _, _, data) in zip(got, writes):
             assert r.ok and r.data == data
 
+    def test_batch_larger_than_the_channel_pool(self):
+        """One exactly-once channel per op, 1024 in the pool: a batch past
+        that (a 1 GiB checkpoint save on the chip, chip_smoke's ckpt leg)
+        died CLIENT_NO_CHANNEL before batch_write ran it as rounds."""
+        from tpu3fs.client.storage_client import CHANNEL_POOL
+
+        fab = Fabric(SystemSetupConfig(num_chains=4, chunk_size=4096))
+        client = fab.storage_client()
+        n = CHANNEL_POOL + 77
+        writes = [(fab.chain_ids[i % 4], ChunkId(60, i), 0,
+                   i.to_bytes(4, "little") * 8) for i in range(n)]
+        replies = client.batch_write(writes, chunk_size=4096)
+        assert len(replies) == n and all(r.ok for r in replies)
+        got = client.batch_read(
+            [ReadReq(c, cid, 0, -1) for c, cid, _, _ in writes])
+        assert [bytes(r.data) for r in got] == [w[3] for w in writes]
+        # every channel came back
+        assert len(client._channels._free) == CHANNEL_POOL
+
     def test_batch_write_falls_back_per_op_on_errors(self):
         fab = Fabric(SystemSetupConfig(num_chains=2, chunk_size=4096))
         client = fab.storage_client()

@@ -536,3 +536,26 @@ class TestMonitorRecorders:
         names = {s.name for s in sink.samples}
         assert {"ckpt.save_ms", "ckpt.restore_ms", "ckpt.save_bytes",
                 "ckpt.gc_removed"} <= names
+
+
+class TestExtensionDtypes:
+    def test_bfloat16_leaves_roundtrip(self):
+        """A chip-resident train state is bfloat16: numpy's .str for it is
+        an opaque "<V2" and it has no buffer-protocol format, so the
+        manifest names it and the shard bytes go out through a uint8
+        view."""
+        import jax.numpy as jnp
+
+        fab = _fabric()
+        mgr = _manager(fab)
+        w = jnp.arange(96, dtype=jnp.float32).reshape(8, 12).astype(
+            jnp.bfloat16)
+        manifest = mgr.save({"w": w, "n": np.int32(3)}, 5)
+        assert manifest.leaves[0].dtype == "bfloat16"
+        out = mgr.restore(5)
+        assert out["w"].dtype == jnp.bfloat16
+        assert out["w"].tobytes() == np.asarray(w).tobytes()
+        like = {"w": jax.ShapeDtypeStruct((8, 12), jnp.bfloat16),
+                "n": jax.ShapeDtypeStruct((), np.int32)}
+        assert mgr.restore(5, like=like)["w"].tobytes() == \
+            np.asarray(w).tobytes()

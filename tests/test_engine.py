@@ -48,7 +48,7 @@ class TestEngineContract:
         engine.commit(cid(1), 2, 1)
         assert engine.read(cid(1)) == b"A" * 25 + b"B" * 50 + b"A" * 25
 
-    def test_version_taxonomy(self, engine):
+    def test_version_classification(self, engine):
         engine.update(cid(1), 1, 1, b"x", 0, chunk_size=CS)
         engine.commit(cid(1), 1, 1)
         with pytest.raises(FsError) as ei:

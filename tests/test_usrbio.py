@@ -816,3 +816,35 @@ class TestRingCrossProcessFork:
         for n in names:
             assert not os.path.exists(os.path.join(SHM_DIR, n)), \
                 f"reaper left {n}"
+
+
+class TestDeadAgentDetected:
+    def test_waiter_stops_when_the_agent_process_is_gone(self):
+        """A SIGKILLed storage process completes nothing: the waiter must
+        notice the dead pid on its first wake-up, not sit out the 30 s
+        call timeout (chip_smoke's kill-and-restart leg stalled 30 s per
+        stale ring, client side and chain-forward side, before this)."""
+        import subprocess
+        import sys
+        import time
+
+        from tpu3fs.rpc.services import (
+            STORAGE_SERVICE_ID,
+            BatchReadReq,
+            BatchReadRsp,
+        )
+        from tpu3fs.usrbio.transport import RingClient
+
+        gone = subprocess.Popen([sys.executable, "-c", "pass"])
+        gone.wait()
+        ring = RingClient(entries=8, iov_bytes=1 << 20,
+                          agent_pid=gone.pid)
+        try:
+            t0 = time.monotonic()
+            with pytest.raises(FsError) as ei:
+                ring.call(STORAGE_SERVICE_ID, 11, BatchReadReq([]),
+                          BatchReadRsp, bulk_iovs=())
+            assert ei.value.code == Code.USRBIO_AGENT_GONE
+            assert time.monotonic() - t0 < 5.0
+        finally:
+            ring.close()

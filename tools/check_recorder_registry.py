@@ -175,30 +175,24 @@ def slo_spec_sources() -> List[Tuple[str, str]]:
 
     out.append(("tpu3fs.monitor.slo.DEFAULT_CLUSTER_SPEC",
                 DEFAULT_CLUSTER_SPEC))
-    try:
-        import tomllib  # py311+
-    except ImportError:
-        try:
-            import tomli as tomllib  # py310 backport
-        except ImportError:
-            tomllib = None
-    if tomllib is not None:
-        for dirpath, dirnames, filenames in os.walk(REPO):
-            dirnames[:] = [d for d in dirnames
-                           if d not in (".git", "__pycache__",
-                                        ".claude", "node_modules")]
-            for fn in sorted(filenames):
-                if not fn.endswith(".toml"):
-                    continue
-                path = os.path.join(dirpath, fn)
-                try:
-                    with open(path, "rb") as f:
-                        data = tomllib.load(f)
-                except Exception:
-                    continue
-                spec = (data.get("slo") or {}).get("spec", "")
-                if spec:
-                    out.append((os.path.relpath(path, REPO), spec))
+    import tomllib
+
+    for dirpath, dirnames, filenames in os.walk(REPO):
+        dirnames[:] = [d for d in dirnames
+                       if d not in (".git", "__pycache__",
+                                    ".claude", "node_modules")]
+        for fn in sorted(filenames):
+            if not fn.endswith(".toml"):
+                continue
+            path = os.path.join(dirpath, fn)
+            try:
+                with open(path, "rb") as f:
+                    data = tomllib.load(f)
+            except Exception:
+                continue
+            spec = (data.get("slo") or {}).get("spec", "")
+            if spec:
+                out.append((os.path.relpath(path, REPO), spec))
     return out
 
 

@@ -514,10 +514,10 @@ class TwoPhaseApplication(ApplicationBase):
 
     def _apply_config_push(self, version: int, content: str) -> None:
         if version > self._config_version and content:
-            from tpu3fs.rpc.services import _flatten
-            from tpu3fs.utils.config import tomllib
+            import tomllib
 
             from tpu3fs.monitor.flight import flight
+            from tpu3fs.rpc.services import _flatten
 
             try:
                 self.config.hot_update(_flatten(tomllib.loads(content)))

@@ -53,11 +53,12 @@ def _refresh_quota_table(fabric, *, out=sys.stdout) -> None:
     registry (best-effort: a cluster without a pushed table keeps the
     daemon's current — default-permissive — state)."""
     try:
+        import tomllib
+
         from tpu3fs.mgmtd.types import NodeType
-        from tpu3fs.utils.config import tomllib
 
         blob = fabric.mgmtd.get_config(NodeType.STORAGE)
-        if blob is None or not blob.content or tomllib is None:
+        if blob is None or not blob.content:
             return
         data = tomllib.loads(blob.content)
         sec = data.get("tenants")

@@ -35,6 +35,7 @@ from tpu3fs.ckpt.manifest import (
     Manifest,
     contiguous_runs,
     overlap_box,
+    parse_dtype,
     step_dir,
     unflatten_tree,
 )
@@ -163,7 +164,8 @@ class CheckpointLoader:
                            f"leaf {spec.key}: template shape {tshape} != "
                            f"saved {tuple(spec.shape)}")
             tdtype = getattr(tmpl, "dtype", None)
-            if tdtype is not None and np.dtype(tdtype) != np.dtype(spec.dtype):
+            if tdtype is not None and \
+                    np.dtype(tdtype) != parse_dtype(spec.dtype):
                 raise _err(Code.INVALID_ARG,
                            f"leaf {spec.key}: template dtype {tdtype} != "
                            f"saved {spec.dtype}")
@@ -234,7 +236,7 @@ class CheckpointLoader:
             for bi, parts in enumerate(plans):
                 for pi, (si, ooff, oshape) in enumerate(parts):
                     sh = manifest.shards[si]
-                    itemsize = np.dtype(
+                    itemsize = parse_dtype(
                         manifest.leaves[sh.leaf].dtype).itemsize
                     part_runs[(bi, pi)] = contiguous_runs(
                         ooff, oshape, sh.offset, sh.shape, itemsize)
@@ -275,7 +277,7 @@ class CheckpointLoader:
 
         out: List[np.ndarray] = []
         for bi, ((li, off, shape), parts) in enumerate(zip(boxes, plans)):
-            dtype = np.dtype(manifest.leaves[li].dtype)
+            dtype = parse_dtype(manifest.leaves[li].dtype)
             buf = np.empty(shape, dtype=dtype)
             for pi, (si, ooff, oshape) in enumerate(parts):
                 piece = np.frombuffer(

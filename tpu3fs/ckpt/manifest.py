@@ -25,6 +25,8 @@ import json
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from tpu3fs.rpc.serde import deserialize, serialize
 from tpu3fs.utils.result import Code
 from tpu3fs.utils.result import err as _err
@@ -35,13 +37,30 @@ ARC_SUFFIX = ".arc"
 FORMAT_VERSION = 1
 
 
+def dtype_tag(dtype) -> str:
+    """The manifest's name for a dtype: numpy's ``.str`` ("<f4"), except
+    for the extension types a chip-resident tree is made of (bfloat16, the
+    float8s), whose ``.str`` is an opaque "<V2" — those go by name."""
+    dt = np.dtype(dtype)
+    return dt.name if dt.kind == "V" else dt.str
+
+
+def parse_dtype(tag: str) -> np.dtype:
+    try:
+        return np.dtype(tag)
+    except TypeError:
+        import ml_dtypes  # noqa: F401  (registers the extension names)
+
+        return np.dtype(tag)
+
+
 @dataclass
 class LeafSpec:
     """One pytree array leaf."""
 
     key: str                 # "/"-joined keypath (diagnostics; tree is
     #                          authoritative for structure)
-    dtype: str               # numpy dtype .str, e.g. "<f4"
+    dtype: str               # dtype_tag(), e.g. "<f4" or "bfloat16"
     shape: List[int] = field(default_factory=list)   # global shape
     # mesh axis name per dim ("" = unsharded dim) as saved — informational
     # for inspect; restore computes overlap boxes from ShardSpec directly

@@ -40,6 +40,7 @@ from tpu3fs.ckpt.manifest import (
     Manifest,
     LeafSpec,
     ShardSpec,
+    dtype_tag,
     flatten_tree,
     leaf_keypaths,
     shard_file_name,
@@ -267,7 +268,8 @@ class CheckpointSaver:
         for i, leaf in enumerate(leaves):
             dtype, gshape, spec, shards = self._leaf_arrays(leaf)
             manifest.leaves.append(LeafSpec(
-                key=keys[i], dtype=dtype.str, shape=list(gshape), spec=spec))
+                key=keys[i], dtype=dtype_tag(dtype), shape=list(gshape),
+                spec=spec))
             for off, shape, fetch in shards:
                 data = np.ascontiguousarray(fetch(), dtype=dtype)
                 j = len([s for s in manifest.shards if s.leaf == i])
@@ -402,7 +404,9 @@ class CheckpointSaver:
             # path's own checksum pass (ONE pooled content pass per save)
             items: List[Tuple[str, object]] = [
                 (f"{tpath}/{spec.file}",
-                 memoryview(np.ascontiguousarray(shard.data)).cast("B"))
+                 # via uint8: bfloat16 has no buffer-protocol format
+                 memoryview(np.ascontiguousarray(shard.data)
+                            .reshape(-1).view(np.uint8)))
                 for spec, shard in zip(manifest.shards, planned)]
             mpath = f"{tpath}/{MANIFEST_NAME}"
             try:

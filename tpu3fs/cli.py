@@ -1001,7 +1001,7 @@ class AdminCli:
                             items: Dict[str, object]) -> str:
         """Merge one [section] of scalar items into a pushed-config blob,
         preserving every other section (faults/tenants share this)."""
-        from tpu3fs.utils.config import tomllib
+        import tomllib
 
         data = tomllib.loads(content) if content else {}
         data.setdefault(section, {})

@@ -81,10 +81,14 @@ class TargetSelectionMode(enum.Enum):
     TAIL = "tail"                   # strongest freshness (already committed)
 
 
+CHANNEL_POOL = 1024
+MAX_BATCH_WRITE_OPS = CHANNEL_POOL // 2
+
+
 class UpdateChannelAllocator:
     """Exclusive channel ids; a channel+seqnum names one logical update."""
 
-    def __init__(self, capacity: int = 1024):
+    def __init__(self, capacity: int = CHANNEL_POOL):
         self._free = list(range(1, capacity + 1))
         self._seq: Dict[int, int] = defaultdict(int)
         self._lock = threading.Lock()
@@ -773,9 +777,18 @@ class StorageClient:
         with _spans.root_span(
                 "client.batch_write",
                 nbytes=sum(len(w[3]) for w in writes)), self._op_scope():
-            return self._batch_write_op(writes, chunk_size=chunk_size,
-                                        op_crcs=op_crcs,
-                                        full_replace=full_replace)
+            # every op holds an exactly-once channel until its round
+            # returns and the pool is finite, so a batch larger than it
+            # (a 1 GiB checkpoint save is > 1024 chunk ops) runs as rounds;
+            # half the pool leaves room for this client's other threads
+            out: List[UpdateReply] = []
+            for lo in range(0, len(writes), MAX_BATCH_WRITE_OPS):
+                hi = lo + MAX_BATCH_WRITE_OPS
+                out += self._batch_write_op(
+                    writes[lo:hi], chunk_size=chunk_size,
+                    op_crcs=None if op_crcs is None else op_crcs[lo:hi],
+                    full_replace=full_replace)
+            return out
 
     def _batch_write_op(
         self,

@@ -17,24 +17,8 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported to the kernels)
 from jax.sharding import Mesh
-
-try:  # JAX >= 0.5 exports shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# kwarg compat: newer JAX renamed check_rep -> check_vma; our call sites
-# use the new name, so map it back on older installs (this image: 0.4.x)
-import inspect as _inspect  # noqa: E402
-
-if "check_vma" not in _inspect.signature(shard_map).parameters:
-    _raw_shard_map = shard_map
-
-    def shard_map(*args, check_vma=None, **kw):  # type: ignore[no-redef]
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _raw_shard_map(*args, **kw)
 
 
 def make_storage_mesh(

@@ -4,7 +4,7 @@ The reference gets crude isolation from per-disk worker pools and RDMA
 transmission limits (SURVEY §2.3 UpdateWorker/AioReadWorker, IBSocket); a
 multi-tenant tpu3fs makes it a first-class, hot-configurable layer:
 
-- ``core``: the traffic-class taxonomy, context-local tagging, token
+- ``core``: the traffic-class classification, context-local tagging, token
   buckets + concurrency gates, the declarative ``QosConfig`` tree and the
   ``AdmissionController`` enforced in RPC dispatch (tpu3fs/rpc/net.py and,
   as a cheap ceiling, native/rpc_net.cpp).

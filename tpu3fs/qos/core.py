@@ -37,7 +37,7 @@ from tpu3fs.utils.config import Config, ConfigItem
 
 
 class TrafficClass(enum.IntEnum):
-    """The traffic-class taxonomy (foreground first, background after).
+    """The traffic-class classification (foreground first, background after).
 
     Mirrors the reference's implicit split of 32 foreground vs 8
     background update threads per disk (UpdateWorker.h:11-46) as an

@@ -500,7 +500,8 @@ class RpcMessenger:
         except OSError:
             return None  # cannot read the server's shm: different host
         ring = _ut.RingClient(entries=self._usrbio_entries,
-                              iov_bytes=self._usrbio_iov_bytes)
+                              iov_bytes=self._usrbio_iov_bytes,
+                              agent_pid=rsp.pid)
         try:
             reg = self._client.call(
                 addr, _ut.USRBIO_SERVICE_ID, 2,
@@ -2177,8 +2178,8 @@ def bind_core_service(server: RpcServer, *, config=None, on_shutdown=None) -> No
         import time as _time
 
         if config is not None:
-            # config.py's shim: stdlib tomllib on 3.11+, tomli on 3.10
-            from tpu3fs.utils.config import tomllib
+            import tomllib
+
             from tpu3fs.monitor.flight import flight
 
             last_update["seq"] += 1

@@ -430,7 +430,8 @@ class ServingPeerClient:
         except OSError:
             return None  # different host: peerRead stays on sockets
         ring = _ut.RingClient(entries=self._entries,
-                              iov_bytes=self._iov_bytes)
+                              iov_bytes=self._iov_bytes,
+                              agent_pid=rsp.pid)
         try:
             reg = self._client.call(
                 addr, _ut.USRBIO_SERVICE_ID, 2,

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Tuple
 
 from tpu3fs.rpc.net import pack_bulk_header, split_bulk
 from tpu3fs.rpc.serde import deserialize, serialize
-from tpu3fs.usrbio.ring import RSP_HDR, TOKEN_CAP, Iov, IoRing
+from tpu3fs.usrbio.ring import RSP_HDR, TOKEN_CAP, Iov, IoRing, _pid_alive
 from tpu3fs.utils.result import Code, FsError, Status
 
 #: control-plane service the storage binary binds for ring registration
@@ -363,7 +363,13 @@ class RingClient:
     application errors, exactly like RpcClient."""
 
     def __init__(self, entries: int = 128, iov_bytes: int = 64 << 20,
-                 call_timeout: float = 30.0):
+                 call_timeout: float = 30.0, agent_pid: int = 0):
+        """``agent_pid`` is the serving process from the handshake: a
+        waiter that finds it dead stops at once (USRBIO_AGENT_GONE ->
+        sockets) instead of sitting out the call timeout on a ring
+        nobody will ever complete — the mirror of the server reaping the
+        rings of dead owners."""
+        self._agent_pid = agent_pid
         self.iov = Iov(iov_bytes)
         self.ring = IoRing(entries, for_read=True)
         self._arena = _ShmArena(self.iov)
@@ -562,6 +568,11 @@ class RingClient:
                         code, f"no completion in {timeout}s"))
                 self.ring.complete_sem.wait(timeout=min(0.2, remaining))
                 cqes = self.ring.reap()
+                if not cqes and self._agent_pid \
+                        and not _pid_alive(self._agent_pid):
+                    raise FsError(Status(
+                        Code.USRBIO_AGENT_GONE,
+                        f"agent process {self._agent_pid} is gone"))
             except (FsError, ValueError, OSError) as e:
                 # _reaping MUST clear on ANY reaper failure — a ValueError
                 # from the mmap closing under us (close() racing in-flight

@@ -21,15 +21,8 @@ the configured value, shadowing the class-level declarations).
 from __future__ import annotations
 
 import threading
+import tomllib
 from typing import Any, Callable, Dict, List
-
-try:  # py311+: stdlib toml reader
-    import tomllib
-except ImportError:  # pragma: no cover
-    try:  # py310: the tomli backport has the identical API
-        import tomli as tomllib
-    except ImportError:
-        tomllib = None
 
 
 class ConfigItem:
@@ -177,8 +170,6 @@ class Config:
                 self.set(key, val)
 
     def load_toml(self, text: str) -> None:
-        if tomllib is None:  # pragma: no cover
-            raise NotImplementedError("tomllib unavailable")
         self.load_dict(tomllib.loads(text))
 
     def apply_flag_overrides(self, argv: List[str]) -> List[str]:

@@ -1,7 +1,7 @@
 """Result/Status error model.
 
 Re-expresses the reference's ``Result<T> = Expected<T, Status>`` and the
-per-subsystem error taxonomy (ref: src/common/utils/Result.h,
+per-subsystem error classification (ref: src/common/utils/Result.h,
 src/common/utils/StatusCode.h) as a small Python type. Services return
 ``Result`` values instead of raising, so RPC layers can serialize failures and
 clients can drive retry ladders off the code class.
@@ -17,7 +17,7 @@ T = TypeVar("T")
 
 
 class Code(enum.IntEnum):
-    """Error taxonomy, grouped by subsystem in disjoint ranges.
+    """Error classification, grouped by subsystem in disjoint ranges.
 
     Mirrors the reference's StatusCode/MetaCode/StorageCode/RPCCode split
     (src/common/utils/StatusCode.h); numbering is our own.
@@ -84,7 +84,7 @@ class Code(enum.IntEnum):
     #                          deadline passed (the resolver may already
     #                          be aborting it) or it was never written
 
-    # storage 5xx (update-code taxonomy, ref StorageOperator.cc:401-434)
+    # storage 5xx (update-code classification, ref StorageOperator.cc:401-434)
     CHUNK_NOT_FOUND = 500
     CHUNK_NOT_COMMIT = 501        # read saw an uncommitted head version
     CHUNK_STALE_UPDATE = 502      # update ver <= committed ver (duplicate)
