@@ -24,13 +24,15 @@ What a bare run does, in order:
   fourchip  the mesh kernels on real devices; NOT RUN with fewer than four
 
 Any failure is a non-zero exit and no result line. Without a TPU the run
-stops at once ("no TPU"). The last line of a passing run is one JSON
-object: {"ok": true, "device": {...}, ...}.
+stops at once ("no TPU"). The last line of a whole, passing run is one JSON
+object with exactly these keys:
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}.
+The per-section outcome and the seed are on the lines before it.
 
 Rehearsal and bring-up options are explicit and never the default:
 --rehearse-cpu runs everything tiny on the CPU backend to check the script
 itself, says so, prints no result line; --sections and --size-mib cut a
-chip run down and mark its result "partial".
+chip run down, say so, and print no result line either.
 """
 
 from __future__ import annotations
@@ -96,6 +98,15 @@ def child_env() -> dict:
         [HERE] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
                   if p])
     return env
+
+
+def result_line(device: dict) -> str:
+    """The last line of a whole, passing chip run: exactly these keys, the
+    device as JAX reports it. Sections, seed and timings are printed on
+    the lines before it, never inside it."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
 
 
 def free_port() -> int:
@@ -998,8 +1009,7 @@ def run(args) -> int:
     full_mib = 8 if ctx.rehearse else 1024
     size_mib = args.size_mib or full_mib
     ctx.leg_bytes = size_mib * MIB
-    partial = (sections != list(SECTIONS) or size_mib < full_mib
-               or ctx.rehearse)
+    cut = sections != list(SECTIONS) or size_mib < full_mib
     if ctx.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
         say("REHEARSAL on the cpu backend: NOT a chip run. It checks this "
@@ -1009,7 +1019,7 @@ def run(args) -> int:
     ctx.meter = meter
     ctx.dev_tag = (f"[{device['platform']} {device['kind']}]"
                    if not ctx.rehearse else "[cpu REHEARSAL]")
-    if size_mib < full_mib or args.sections:
+    if cut:
         say(f"CUT RUN: sections={','.join(sections)} "
             f"size={size_mib} MiB per leg (full: {full_mib})")
 
@@ -1086,18 +1096,18 @@ def run(args) -> int:
         if not args.run_dir:
             shutil.rmtree(run_dir, ignore_errors=True)
 
-    say("sections: " + ", ".join(f"{s}={status[s]}" for s in SECTIONS))
+    say(f"seed {ctx.seed}; sections: "
+        + ", ".join(f"{s}={status[s]}" for s in SECTIONS))
     say(f"total {time.time() - t_start:.0f}s; "
         f"{meter.since((0, 0.0, 0, 0, 0))}")
     if ctx.rehearse:
         say("REHEARSAL finished on the cpu backend: not a chip run, "
             "no result.")
         return 0
-    result = {"ok": True, "device": device, "sections": status,
-              "seed": ctx.seed}
-    if partial:
-        result["partial"] = True
-    print(json.dumps(result), flush=True)
+    if cut:
+        say("CUT RUN finished on the chip: not the whole smoke, no result.")
+        return 0
+    print(result_line(device), flush=True)
     return 0
 
 
@@ -1109,11 +1119,11 @@ def main(argv=None) -> int:
                    help="tiny run on the cpu backend; not a chip run, "
                         "prints no result line")
     p.add_argument("--sections", type=lambda s: s.split(","),
-                   help=f"subset of {','.join(SECTIONS)}; marks the result "
-                        "partial")
+                   help=f"subset of {','.join(SECTIONS)}; a cut run prints "
+                        "no result line")
     p.add_argument("--size-mib", type=int, default=0,
-                   help="MiB per leg instead of 1024; marks the result "
-                        "partial")
+                   help="MiB per leg instead of 1024; a cut run prints no "
+                        "result line")
     p.add_argument("--run-dir", default="",
                    help="where the cluster keeps its disks and logs "
                         "(default: a fresh temporary directory, removed)")

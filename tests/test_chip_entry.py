@@ -50,6 +50,23 @@ def test_smoke_children_are_pinned_to_the_cpu(monkeypatch):
     assert env["PYTHONPATH"].split(os.pathsep)[0] == REPO
 
 
+def test_smoke_result_line_has_exactly_the_contract_keys():
+    """The driver refuses a last line with any key beyond ok and device
+    (platform, kind, count); sections and seed go on the lines before."""
+    import json
+
+    import chip_smoke
+
+    line = chip_smoke.result_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    assert "\n" not in line
+    got = json.loads(line)
+    assert list(got) == ["ok", "device"] and got["ok"] is True
+    assert got["device"] == {"platform": "tpu", "kind": "TPU v5 lite",
+                             "count": 1}
+    assert type(got["device"]["count"]) is int
+
+
 def test_compile_cache_dir_is_placeable_and_otherwise_fixed(
         monkeypatch, tmp_path):
     from tpu3fs.utils import compile_cache as cc
