@@ -1,0 +1,11 @@
+"""perfbench's own tests run on the CPU backend (python -m pytest
+perfbench/tests). They are not part of tests/, the repo's tier-1."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
