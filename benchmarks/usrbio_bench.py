@@ -5,9 +5,9 @@ StorageClient drives the SAME storage service twice — once over the
 USRBIO shared-memory ring transport (TPU3FS_USRBIO on, the default) and
 once over the pipelined bulk-framed sockets (TPU3FS_USRBIO=0) — and
 reports read + write, batch + single-op, with per-op latency. Modes run
-INTERLEAVED with rotated order (trace_bench discipline: this host's
-numbers swing ~2x run-to-run; fixed order shows phantom wins from
-position bias alone) and medians are compared.
+INTERLEAVED with rotated order (this host's numbers swing ~2x run-to-run;
+fixed order shows phantom wins from position bias alone) and medians are
+compared.
 
 Default shape: mgmtd + 1 storage booted as REAL subprocesses — the
 co-located-client deployment the ring targets (client and server own

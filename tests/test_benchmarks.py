@@ -187,23 +187,6 @@ class TestWriteBench:
             assert "note" in ab  # core-bound caveat travels with the row
 
 
-class TestTraceBench:
-    """benchmarks/trace_bench fast-mode smoke: all four tracer modes run
-    over real sockets, sampled spans actually land in span files."""
-
-    def test_small_run(self, tmp_path):
-        from benchmarks.trace_bench import run as trace_bench
-
-        res = trace_bench(chunks=8, size=32 << 10, batch=4, rounds=1,
-                          out=str(tmp_path / "bt.json"))
-        by = {r["metric"]: r for r in res["rows"]}
-        for m in ("trace_write_off", "trace_write_sample_0",
-                  "trace_write_sample_0.01", "trace_write_sample_1.0"):
-            assert by[m]["value"] > 0, by
-        # full sampling wrote spans through the columnar sink
-        assert by["trace_span_files"]["value"] >= 1
-
-
 class TestSloBench:
     """benchmarks/slo_bench fast-mode smoke: both collector modes run
     over real sockets, samples actually reach the aggregator, and the

@@ -609,3 +609,430 @@ class TestQueueWaitSpan:
             w.stop()
         rows = _rows(tracer)
         assert any(r["stage"] == "queue_wait" for r in rows)
+
+
+# -- profiled capture: the span tree under a jax.profiler session -------------
+#
+# One in-process socket cluster and ONE profiler session for the module:
+# every test below reads what that session left (a session costs seconds).
+
+EC_K, EC_M, SPAN_CHUNK = 2, 1, 1 << 14
+
+
+@pytest.fixture(scope="module")
+def span_cluster():
+    """mgmtd + 3 storage nodes (one EC(2,1) chain, one CR-3 chain) + a meta
+    server a chain table, over real TCP sockets in this process: RPC hops
+    are real, their replies carry the server's stamps."""
+    from tpu3fs.kv import MemKVEngine
+    from tpu3fs.meta.store import ChainAllocator, MetaStore
+    from tpu3fs.mgmtd.service import Mgmtd
+    from tpu3fs.mgmtd.types import LocalTargetState, NodeType
+    from tpu3fs.ops.stripe import shard_size_of
+    from tpu3fs.rpc.services import (
+        MgmtdRpcClient,
+        RpcMessenger,
+        bind_core_service,
+        bind_meta_service,
+        bind_mgmtd_service,
+        bind_storage_service,
+    )
+    from tpu3fs.storage.craq import StorageService
+    from tpu3fs.storage.target import StorageTarget
+
+    mgmtd = Mgmtd(1, MemKVEngine())
+    mgmtd.extend_lease()
+    mserver = RpcServer()
+    bind_mgmtd_service(mserver, mgmtd)
+    mserver.start()
+    servers = [mserver]
+    shared = RpcClient()
+    ec_chain, cr_chain = 900_002, 900_001
+    shard = shard_size_of(SPAN_CHUNK, EC_K)
+    beats = {}
+    for i, node in enumerate((10, 11, 12)):
+        mcli = MgmtdRpcClient(mserver.address, shared)
+        svc = StorageService(node, mcli.refresh_routing)
+        svc.set_messenger(RpcMessenger(mcli.refresh_routing, shared))
+        svc.add_target(StorageTarget(2000 + i, ec_chain, chunk_size=shard))
+        svc.add_target(StorageTarget(1000 + i, cr_chain,
+                                     chunk_size=SPAN_CHUNK))
+        server = RpcServer()
+        bind_storage_service(server, svc)
+        server.start()
+        mgmtd.register_node(node, NodeType.STORAGE, host=server.host,
+                            port=server.port)
+        for tid in (2000 + i, 1000 + i):
+            mgmtd.create_target(tid, node_id=node)
+        beats[node] = {2000 + i: LocalTargetState.UPTODATE,
+                       1000 + i: LocalTargetState.UPTODATE}
+        servers.append(server)
+    mgmtd.upload_chain(cr_chain, [1000, 1001, 1002])
+    mgmtd.upload_chain(ec_chain, [2000, 2001, 2002], ec_k=EC_K, ec_m=EC_M)
+    mgmtd.upload_chain_table(1, [cr_chain])
+    mgmtd.upload_chain_table(2, [ec_chain])
+    for node, states in beats.items():
+        mgmtd.heartbeat(node, 1, states)
+    metas = {}
+    for kind, table, chain in (("ec", 2, ec_chain), ("cr", 1, cr_chain)):
+        meta = MetaStore(MemKVEngine(), ChainAllocator(table, [chain]),
+                         default_chunk_size=SPAN_CHUNK)
+        server = RpcServer()
+        bind_meta_service(server, meta)
+        bind_core_service(server)
+        server.start()
+        servers.append(server)
+        metas[kind] = server.address
+
+    def client(kind):
+        """-> (meta, fio) of a fresh client on the EC or the CR table."""
+        from tpu3fs.client.file_io import FileIoClient
+        from tpu3fs.client.storage_client import StorageClient
+        from tpu3fs.rpc.services import MetaRpcClient
+
+        rpc = RpcClient()
+        mcli = MgmtdRpcClient(mserver.address, rpc)
+        storage = StorageClient(
+            f"span-{kind}-{time.time_ns()}", mcli.refresh_routing,
+            RpcMessenger(mcli.refresh_routing, rpc))
+        return (MetaRpcClient([metas[kind]], rpc, client_id=f"span-{kind}"),
+                FileIoClient(storage))
+
+    yield client
+    for s in servers:
+        s.stop()
+
+
+def _kv_store(client):
+    from tpu3fs.kvcache import KVCacheClient, PrefixBlockStore
+
+    meta, fio = client("ec")
+    return PrefixBlockStore(KVCacheClient(meta, fio, root="/kv"),
+                            block_tokens=4)
+
+
+def _kv_blocks(base, n=3):
+    import numpy as np
+
+    return [np.full((9, 64), base + i, dtype=np.uint16) for i in range(n)]
+
+
+@pytest.fixture(scope="module")
+def profiled(span_cluster, tmp_path_factory):
+    """Everything the cells do, three times each, under ONE profiler
+    session -> {"rows": captured dict rows, "trees", "anchor", "marks":
+    [(name, start_ns)] of the trace's t3: annotations, "dropped"}."""
+    import jax
+    import numpy as np
+
+    from tpu3fs.ckpt import CheckpointLoader, CheckpointSaver
+    from tpu3fs.dataload import (
+        DataLoader,
+        LoaderConfig,
+        PackedDataset,
+        pack_records,
+    )
+
+    dev = jax.devices()[0]
+    store = _kv_store(span_cluster)
+    meta, fio = span_cluster("cr")
+    saver = CheckpointSaver(meta, fio, root="/ckpt")
+    loader = CheckpointLoader(meta, fio, root="/ckpt")
+    state = {"a": jax.device_put(np.arange(6000, dtype=np.float32), dev),
+             "b": jax.device_put(np.ones((64, 65), dtype=np.float32), dev)}
+    meta.mkdirs("/data", recursive=True)
+    rng = np.random.default_rng(0)
+    pack_records(meta, fio, "/data/ds.rec", [
+        rng.integers(0, 1 << 30, 2048, dtype=np.int32).tobytes()
+        for _ in range(96)])
+    dataset = PackedDataset(meta, fio, ["/data/ds.rec"])
+    mesh = jax.sharding.Mesh(np.array([dev]), ("dp",))
+    # warm every path once, untraced: compiles and first connections
+    store.append_blocks(list(range(100, 112)), _kv_blocks(100))
+    jax.block_until_ready(store.get_blocks(list(range(100, 112)),
+                                           device=dev))
+    saver.save(state, 1)
+    jax.block_until_ready(loader.restore(1, like=state))
+
+    tracer = spans.tracer()
+    tracer.reset_captured()
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        for rep in range(3):
+            tokens = list(range(1000 * rep, 1000 * rep + 12))
+            assert store.append_blocks(tokens, _kv_blocks(10 * rep)) == 3
+            assert store.match_prefix(tokens).blocks == 3
+            jax.block_until_ready(store.get_blocks(tokens, device=dev))
+            saver.save(state, 10 + rep)
+            jax.block_until_ready(loader.restore(10 + rep, like=state))
+        with DataLoader(dataset, LoaderConfig(
+                global_batch=16, dtype="int32", sample_shape=(2048,),
+                epochs=1), mesh=mesh) as batches:
+            assert sum(1 for _ in batches) == 6
+    finally:
+        jax.profiler.stop_trace()
+    rows = assemble.rows_of_captured(tracer.captured())
+    import glob
+
+    (xplane,) = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")
+    marks = [(ev.name, float(ev.start_ns))
+             for plane in jax.profiler.ProfileData.from_file(xplane).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(spans.ANNOTATION_PREFIX)]
+    out = {"rows": rows, "trees": assemble.assemble_traces(rows),
+           "anchor": tracer.anchor(), "marks": marks,
+           "dropped": tracer.captured_dropped()}
+    tracer.reset_captured()
+    return out
+
+
+def _names(tree, below):
+    """Names (op, or op.stage) of every span beneath `below`."""
+    out, todo = [], list(tree.children.get(below["span_id"], []))
+    while todo:
+        r = todo.pop()
+        out.append(f"{r['op']}.{r['stage']}" if r["stage"] else r["op"])
+        todo.extend(tree.children.get(r["span_id"], []))
+    return out
+
+
+def _trees_of(profiled, op):
+    return [t for t in profiled["trees"].values()
+            if t.root is not None and t.root["op"] == op]
+
+
+SPAN_TREES = {
+    "kvcache.append_blocks": [
+        "kvcache.append_blocks.probe", "kvcache.append_blocks.encode_array",
+        "meta.*", "fio.batch_write_files", "fio.write_ec_chunk.rmw_probe",
+        "fio.write_ec_chunk.stripe_read", "client.write_stripe",
+        "client.write_stripe.encode", "client.write_stripe.stage_shards",
+        "client.write_stripe.commit_shards", "codec.encode"],
+    "kvcache.get_blocks": [
+        "meta.*", "fio.batch_read_files", "fio.batch_read_files.plan",
+        "fio.batch_read_files.assemble", "client.batch_read",
+        "rpc.client.server_run", "kvcache.get_blocks.decode",
+        "kvcache.get_blocks.device_put"],
+    "ckpt.save": [
+        "ckpt.save.snapshot", "ckpt.save.frame", "ckpt.save.write",
+        "ckpt.save.commit", "meta.create", "meta.rename",
+        "fio.batch_write_files", "client.batch_write"],
+    "ckpt.restore": [
+        "ckpt.restore.manifest", "ckpt.restore.read",
+        "ckpt.restore.device_put", "meta.*", "fio.read",
+        "fio.batch_read_files", "client.batch_read"],
+    "dataload.fetch": [
+        "dataload.fetch.read", "dataload.fetch.assemble",
+        "dataload.fetch.device_put", "dataload.fetch.push_wait",
+        "fio.batch_read_files", "client.batch_read",
+        "rpc.client.server_wait"],
+}
+
+
+class TestProfiledCapture:
+    def test_nothing_is_retained_without_a_profiler_session(self,
+                                                            span_cluster):
+        """(a) no profiler session, no trace.dir: a put and a get leave the
+        sink empty and start no trace at all."""
+        import jax
+
+        tracer = spans.tracer()
+        tracer.reset_captured()
+        assert not tracer.enabled and not spans.profiler_active()
+        store = _kv_store(span_cluster)
+        tokens = list(range(5000, 5012))
+        with spans.root_span("outer") as ctx:
+            assert ctx is None
+            store.append_blocks(tokens, _kv_blocks(50))
+            got = store.get_blocks(tokens, device=jax.devices()[0])
+        assert all(b is not None for b in got)
+        assert tracer.captured() == [] and tracer.captured_dropped() == 0
+
+    @pytest.mark.parametrize("op", sorted(SPAN_TREES))
+    def test_the_tree_of_each_client_api_op(self, profiled, op):
+        """(b) each client API op leaves one tree per call that holds the
+        layers beneath it, and its leaves cover 0.8 of it."""
+        from fnmatch import fnmatchcase
+
+        trees = _trees_of(profiled, op)
+        assert len(trees) == (6 if op == "dataload.fetch" else 3)
+        for tree in trees:
+            names = _names(tree, tree.root)
+            for want in SPAN_TREES[op]:
+                assert any(fnmatchcase(n, want) for n in names), (op, want)
+        # ops of a few milliseconds here, where the interpreter between
+        # two spans weighs most: the best tree reaches the 0.8 the chip's
+        # median tree is held to (PERF.md), none falls far below
+        cov = sorted(t.coverage() for t in trees)
+        assert cov[-1] >= 0.8 and cov[0] >= 0.6, cov
+
+    def test_the_put_ladder_down_to_the_shard_rpcs(self, profiled):
+        """(b) under each client.write_stripe: k+m stage hops and k+m
+        commit hops, each with the server's wait and run beside issue and
+        collect, and one codec.encode with a dispatch or the host's code."""
+        for tree in _trees_of(profiled, "kvcache.append_blocks"):
+            stripes = [r for r in tree.rows if r["op"] == "client.write_stripe"
+                       and not r["stage"]]
+            assert len(stripes) == 3    # a block is one partial stripe
+            for stripe in stripes:
+                for stage in ("stage_shards", "commit_shards"):
+                    (st,) = [r for r in tree.children[stripe["span_id"]]
+                             if r["stage"] == stage]
+                    hops = [r for r in tree.children[st["span_id"]]
+                            if r["op"].startswith("rpc.client.3.")]
+                    assert len(hops) == EC_K + EC_M
+                    for hop in hops:
+                        stages = {r["stage"] for r in
+                                  tree.children[hop["span_id"]]}
+                        assert {"issue", "collect", "server_wait",
+                                "server_run"} <= stages
+                (enc,) = [r for r in tree.children[stripe["span_id"]]
+                          if r["op"] == "codec.encode" and not r["stage"]]
+                assert enc["nbytes"] == EC_K * 8192 and enc["code"] == 1
+
+    def test_annotations_lie_on_the_spans_by_the_anchor(self, profiled):
+        """(c) every t3: annotation of the trace, converted by the anchor,
+        starts within 1 ms of an in-memory span of its name."""
+        import bisect
+
+        anchors = [s for n, s in profiled["marks"] if n == spans.ANCHOR_NAME]
+        assert len(anchors) == 1 and profiled["anchor"] is not None
+        to_trace, to_perf = assemble.spans_to_trace_clock(
+            anchors[0], profiled["anchor"][0])
+        assert abs(to_perf(to_trace(12.5)) - 12.5) < 1e-6
+        starts = {}
+        for r in profiled["rows"]:
+            name = spans.ANNOTATION_PREFIX + r["op"] + (
+                "." + r["stage"] if r["stage"] else "")
+            starts.setdefault(name, []).append(to_trace(r["t_perf"]))
+        seen = 0
+        for name, s in profiled["marks"]:
+            if name == spans.ANCHOR_NAME:
+                continue
+            mine = sorted(starts[name])
+            i = bisect.bisect_left(mine, s)
+            near = min(abs(mine[j] - s) for j in (i - 1, i)
+                       if 0 <= j < len(mine))
+            assert near < 1e6, (name, near)
+            seen += 1
+        # live spans only: the hop's stages are rows, not annotations
+        assert seen > 100
+        assert not any(n.startswith("t3:rpc.client")
+                       for n, _ in profiled["marks"])
+
+    def test_rows_carry_the_perf_clock_and_the_thread(self, profiled):
+        assert profiled["dropped"] == 0
+        for r in profiled["rows"]:
+            assert r["t_perf"] > 0 and r["tid"] > 0
+            assert abs(spans.wall_of_perf(r["t_perf"]) - r["ts"]) < 0.05
+        nexts = [r for r in profiled["rows"] if r["op"] == "dataload.next"]
+        assert len(nexts) == 6 and all(r["nbytes"] == 16 * 8192
+                                       for r in nexts)
+
+    def test_the_sink_is_bounded_and_counts_what_it_drops(self, monkeypatch):
+        """(d) the in-memory sink never exceeds its bound."""
+        import collections
+
+        tracer = spans.Tracer()
+        monkeypatch.setattr(tracer, "_captured",
+                            collections.deque(maxlen=10))
+        for i in range(7):
+            ctx = spans.TraceContext("t", f"s{i}", profiled=True)
+            spans.add_span(ctx, "op", "a", 1.0, 0.1)
+            spans.add_span(ctx, "op", "b", 1.0, 0.1)
+            tracer.finish_op(ctx, "op", 1.0, 0.2)
+        assert len(tracer.captured()) == 10
+        assert tracer.captured_dropped() == 7 * 3 - 10
+        assert tracer.captured()[-1][spans.CAPTURED_FIELDS.index(
+            "span_id")] == "s6"
+        tracer.reset_captured()
+        assert tracer.captured() == [] and tracer.captured_dropped() == 0
+        assert spans.CAPTURE_MAX_ROWS >= 1 << 19
+
+    def test_the_file_sink_keeps_its_schema(self, tracer, tmp_path):
+        """The span files carry no per-process clock or thread column."""
+        tracer.configure(service="t", node=1, directory=str(tmp_path),
+                         sample_rate=0.0, slow_op_ms=10_000)
+        with spans.root_span("client.op", force=True):
+            with spans.span("client.op", "stage"):
+                pass
+        rows = _rows(tracer)
+        assert len(rows) == 2
+        assert not {"t_perf", "tid"} & set(rows[0])
+        (stage,) = [r for r in rows if r["stage"]]
+        (op,) = [r for r in rows if not r["stage"]]
+        assert stage["parent_id"] == op["span_id"]
+
+    def test_nested_ops_and_stages_parent_what_they_call(self, tracer,
+                                                         tmp_path):
+        tracer.configure(service="t", node=1, directory=str(tmp_path),
+                         sample_rate=1.0)
+        with spans.root_span("outer"):
+            with spans.root_span("inner") as inner:
+                inner.nbytes = 7
+                with spans.span("inner", "stage"):
+                    spans.add_span(spans.current_trace(), "leaf", "x",
+                                   time.time(), 0.001)
+        rows = {(r["op"], r["stage"]): r for r in _rows(tracer)}
+        assert rows[("inner", "")]["parent_id"] == \
+            rows[("outer", "")]["span_id"]
+        assert rows[("inner", "")]["nbytes"] == 7
+        assert rows[("inner", "stage")]["parent_id"] == \
+            rows[("inner", "")]["span_id"]
+        assert rows[("leaf", "x")]["parent_id"] == \
+            rows[("inner", "stage")]["span_id"]
+        tree = assemble.TraceTree("t", list(rows.values()))
+        assert [r["op"] for r in tree.leaf_rows()] == ["leaf"]
+
+
+class TestPins:
+    def test_the_encode_program_keeps_its_name(self):
+        """(e) the benchmark's roofline reader finds the fused encode+CRC
+        program by `encode_device` in its name: a refactor that renames it
+        fails here, not as a metric gone silent."""
+        import numpy as np
+
+        from tpu3fs.ops.stripe import get_codec
+
+        codec = get_codec(2, 1, 512)
+        text = codec._encode_dev.lower(
+            np.zeros((1, 2, 512), np.uint8)).as_text()
+        assert "module @jit__encode_device" in text.splitlines()[0]
+
+    def test_the_profiler_switch_is_where_the_tracer_looks(self):
+        """(f) a jaxlib that moves TraceMe.is_enabled fails here and does
+        not silently end all capture."""
+        from jaxlib._profiler import TraceMe
+
+        assert callable(TraceMe.is_enabled)
+        is_enabled, trace_me = spans._resolve_profiler()
+        assert trace_me is TraceMe and is_enabled() is False
+
+    def test_meta_span_names_are_the_registry_s(self):
+        from tpu3fs.kv import MemKVEngine
+        from tpu3fs.meta.store import ChainAllocator, MetaStore
+        from tpu3fs.rpc.services import (
+            META_METHOD_NAMES,
+            META_SERVICE_ID,
+            bind_meta_service,
+        )
+
+        server = RpcServer()
+        bind_meta_service(server, MetaStore(
+            MemKVEngine(), ChainAllocator(1, [1]), default_chunk_size=4096))
+        bound = {mid: m.name for mid, m in
+                 server._services[META_SERVICE_ID].methods.items()}
+        assert {k: v for k, v in META_METHOD_NAMES.items()
+                if k in bound} == bound
+
+    def test_ring_stamps_round_trip(self):
+        from tpu3fs.usrbio.ring import pack_stamps, unpack_stamps
+
+        assert unpack_stamps(0) is None
+        assert unpack_stamps(pack_stamps(0.0, 0.0)) == (0.0, 0.0)
+        wait_s, run_s = unpack_stamps(pack_stamps(0.001234, 2.5))
+        assert abs(wait_s - 0.001234) < 2e-6 and abs(run_s - 2.5) < 2e-6
+        assert unpack_stamps(pack_stamps(1e9, 1e9)) is not None
+
+

@@ -610,6 +610,33 @@ class TestRingTransport:
             del _os.environ["TPU3FS_USRBIO"]
         sc.close()
 
+    def test_a_traced_ring_hop_carries_the_server_s_stamps(self,
+                                                           ring_cluster):
+        """The CQE's third word brings the serving side's wait and run
+        (what a socket reply's Timestamps bring): a traced ring hop has
+        server_wait and server_run beside issue and collect."""
+        from tpu3fs.analytics import spans
+        from tpu3fs.client.storage_client import ReadReq
+        from tpu3fs.storage.types import ChunkId
+
+        sc, messenger = _mk_client(ring_cluster, "rc-span")
+        chain = ring_cluster["chain_id"]
+        assert all(r.ok for r in sc.batch_write(
+            [(chain, ChunkId(7, 0), 0, b"s" * 900)], chunk_size=4096))
+        assert any(v is not None for v in messenger._usrbio_rings.values())
+        ctx = spans.TraceContext("t" * 16, "s" * 16)
+        with spans.trace_scope(ctx):
+            got = sc.batch_read([ReadReq(chain, ChunkId(7, 0), 0, -1)])
+        assert bytes(got[0].data) == b"s" * 900
+        (hop,) = [e for e in ctx.events if e.op == "rpc.client.ring"]
+        stages = {e.stage: e for e in ctx.events
+                  if e.parent_id == hop.span_id}
+        assert {"issue", "collect", "server_wait", "server_run"} <= \
+            set(stages)
+        assert 0 < stages["server_run"].dur_us < hop.dur_us
+        assert stages["server_wait"].t_perf >= stages["issue"].t_perf
+        sc.close()
+
     def test_large_payload_and_single_ops(self, ring_cluster):
         from tpu3fs.storage.types import ChunkId
 

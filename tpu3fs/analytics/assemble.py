@@ -9,12 +9,16 @@ envelope), and derives the two operator views:
 
 - ``format_trace``: one trace as an indented tree with per-span wall
   times and a STAGE COVERAGE line — the fraction of the root
-  (client-observed) latency that attributed stage spans account for.
-  Coverage sums additive stages only: container stages (``collect``,
-  ``forward``) hold their callee's whole pipeline and would double
-  count.
+  (client-observed) latency that attributed spans account for.
+  Coverage takes the tree's LEAVES only: a span with spans beneath it,
+  and the container stages (``collect``, ``forward``: they hold another
+  process's whole pipeline), would double count.
 - ``top_traces`` / ``stage_percentiles``: slowest ops and per-stage
   p50/p90/p99 across every loaded trace — the trace-top view.
+
+``spans_to_trace_clock`` puts the rows of a profiled capture
+(``spans.tracer().captured()``) on the clock of the profiler's own trace,
+from the one ``t3:anchor`` annotation both hold.
 """
 
 from __future__ import annotations
@@ -23,6 +27,7 @@ import glob
 import os
 from typing import Dict, Iterable, List, Optional, Sequence
 
+from tpu3fs.analytics.spans import CAPTURED_FIELDS
 from tpu3fs.analytics.trace import read_records
 
 # stages whose duration CONTAINS downstream work (excluded from the
@@ -78,11 +83,21 @@ class TraceTree:
     def stage_rows(self) -> List[dict]:
         return [r for r in self.rows if r.get("stage")]
 
+    def leaf_rows(self) -> List[dict]:
+        """Spans with nothing beneath them, the root and the container
+        stages apart: what the tree ATTRIBUTES time to. A live stage
+        (spans.span) parents what its block calls, so a stage that holds
+        an RPC hop is no leaf; the hop's own stages are."""
+        root = self.root
+        return [r for r in self.rows
+                if r is not root and r["span_id"] not in self.children
+                and r.get("stage") not in CONTAINER_STAGES]
+
     def coverage(self) -> float:
         """Fraction of the root (client-observed) wall during which at
-        least one ATTRIBUTED stage was active: the interval UNION of
-        additive stage spans clipped to the root window, over the root
-        duration. Union, not sum — pipelined fan-outs run stages
+        least one ATTRIBUTED span was active: the interval UNION of the
+        tree's leaves (leaf_rows) clipped to the root window, over the
+        root duration. Union, not sum — pipelined fan-outs run stages
         concurrently, and a plain sum would exceed 100% without meaning
         the breakdown explains the latency. Cross-process span clocks
         are wall time on (assumed loosely synced) hosts; sub-ms skew
@@ -93,9 +108,7 @@ class TraceTree:
         r0 = root.get("ts", 0.0)
         r1 = r0 + root["dur_us"] / 1e6
         ivals = []
-        for r in self.stage_rows():
-            if r["stage"] in CONTAINER_STAGES:
-                continue
+        for r in self.leaf_rows():
             a = max(r0, r.get("ts", 0.0))
             b = min(r1, r.get("ts", 0.0) + r.get("dur_us", 0.0) / 1e6)
             if b > a:
@@ -123,6 +136,30 @@ class TraceTree:
         empty for pre-tenancy span files."""
         return sorted({r.get("tenant", "") for r in self.rows
                        if r.get("tenant")})
+
+
+def rows_of_captured(captured: Sequence[tuple]) -> List[dict]:
+    """The in-memory sink's tuples (spans.tracer().captured()) as the
+    dict rows this module works on."""
+    return [dict(zip(CAPTURED_FIELDS, row)) for row in captured]
+
+
+def spans_to_trace_clock(anchor_event_start_ns: float,
+                         anchor_perf_ns: int):
+    """The two directions between this process's perf_counter and the
+    clock of a loaded profiler trace, from the session's one anchor: the
+    ``t3:anchor`` event's ``start_ns`` as the trace has it, and the
+    perf_counter_ns the tracer read inside it (``tracer().anchor()[0]``).
+    -> (to_trace_ns, to_perf_s): ``to_trace_ns(row["t_perf"])`` is where a
+    span starts on the trace's clock, ``to_perf_s(event.start_ns)`` where
+    a trace event lies on the spans' clock."""
+    def to_trace_ns(t_perf_s: float) -> float:
+        return anchor_event_start_ns + (t_perf_s * 1e9 - anchor_perf_ns)
+
+    def to_perf_s(trace_ns: float) -> float:
+        return (trace_ns - anchor_event_start_ns + anchor_perf_ns) / 1e9
+
+    return to_trace_ns, to_perf_s
 
 
 def assemble_traces(rows: Sequence[dict]) -> Dict[str, TraceTree]:

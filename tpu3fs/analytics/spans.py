@@ -24,23 +24,35 @@ flushes or drops the whole accumulation:
   UNCONDITIONALLY, sampling rate 0 included — the ops an operator most
   needs are never the ones sampling dropped;
 - FORCED capture: the wire slow bit (set via ``start_trace(force=True)``)
-  makes every hop flush, for targeted debugging.
+  makes every hop flush, for targeted debugging;
+- PROFILED capture: while a ``jax.profiler`` session is active in this
+  process every root op is captured, ``trace.dir`` or not, into a
+  bounded in-memory sink (``tracer().captured()``), and every live span
+  is also a profiler annotation ``t3:<op>[.<stage>]`` — whoever profiles
+  the chip gets the host's spans with the profile, on one clock (the
+  ``t3:anchor`` mark; ``assemble.spans_to_trace_clock``); nobody else
+  pays more than one ``TraceMe.is_enabled()`` call a root op.
 
 Flushed spans stream through ``analytics.trace.StructuredTraceLog`` —
 the same columnar sink the storage event trace uses — one file set per
 process; ``analytics.assemble`` joins the files of N processes back into
-per-trace trees. Overhead discipline: with no tracer configured the only
-cost on any hot path is one ContextVar read returning None.
+per-trace trees. Overhead discipline: with no tracer configured and no
+profiler session the only cost on any hot path is one ContextVar read
+returning None (nested sites) or that plus one ``is_enabled()`` call (root
+op sites).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import operator
 import os
+import random
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import List, Optional, Sequence, Tuple
 
 from tpu3fs.utils.config import Config, ConfigItem
@@ -75,6 +87,51 @@ class SpanEvent:
     tenant: str = ""       # owning tenant (op spans; tpu3fs/tenant)
     sampled: bool = False
     slow: bool = False     # flushed by the slow-op/forced path
+    # in-memory only (the profiled sink; never written to the span files):
+    t_perf: float = 0.0    # start on time.perf_counter (this process's)
+    tid: int = 0           # emitting thread's ident
+
+
+# the two fields the span files do not carry (a per-process clock and a
+# per-process thread id mean nothing to another process's reader)
+_MEMORY_ONLY = ("t_perf", "tid")
+# a captured row is a plain tuple in this field order: a window of a
+# traced cell holds some hundred thousand rows, and a tuple of shared
+# strings and small numbers costs about a third of a dataclass instance
+CAPTURED_FIELDS = tuple(f.name for f in fields(SpanEvent))
+_row_of = operator.attrgetter(*CAPTURED_FIELDS)
+# most rows the in-memory sink holds; the oldest are dropped and counted.
+# Reckoned from the largest window measured (kvcache_sessions: about
+# 300 000 rows, 110 MB): three windows' worth, under 400 MB
+CAPTURE_MAX_ROWS = 1 << 20
+
+ANNOTATION_PREFIX = "t3:"
+ANCHOR_NAME = ANNOTATION_PREFIX + "anchor"
+
+
+def _resolve_profiler():
+    """-> (is_enabled, TraceMe) of this process's jaxlib, looked up once.
+    No jaxlib, or a jaxlib that moved the symbol, means "never profiled"
+    (tests/test_trace.py pins the import so that an upgrade fails a test
+    instead of silently ending all capture)."""
+    try:
+        from jaxlib._profiler import TraceMe
+
+        return TraceMe.is_enabled, TraceMe
+    except (ImportError, AttributeError):
+        return (lambda: False), None
+
+
+_is_profiling = None   # resolved at the first root op
+_TraceMe = None
+
+
+def profiler_active() -> bool:
+    """Whether a jax.profiler session is active in this process."""
+    global _is_profiling, _TraceMe
+    if _is_profiling is None:
+        _is_profiling, _TraceMe = _resolve_profiler()
+    return _is_profiling()
 
 
 class TraceContext:
@@ -88,16 +145,24 @@ class TraceContext:
     """
 
     __slots__ = ("trace_id", "span_id", "parent_id", "sampled", "slow",
-                 "events")
+                 "events", "profiled", "root", "nbytes", "ts", "mark")
 
     def __init__(self, trace_id: str, span_id: str, parent_id: str = "",
                  sampled: bool = False, slow: bool = False,
-                 events: Optional[list] = None):
+                 events: Optional[list] = None, profiled: bool = False):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
         self.sampled = sampled
         self.slow = slow
+        # captured because a profiler session is active: rows go to the
+        # tracer's in-memory sink, live spans are profiler annotations
+        self.profiled = profiled
+        # open_op()'s bookkeeping for the op this context is the span of
+        self.root = False      # owns the accumulator: flushes at close
+        self.nbytes = 0        # payload bytes, where known only at the end
+        self.ts = 0.0          # wall-clock start
+        self.mark = None       # the open profiler annotation
         # list.append is GIL-atomic: overlap-forward helper threads and
         # worker threads may append concurrently with the op thread
         self.events: List[SpanEvent] = events if events is not None else []
@@ -106,7 +171,8 @@ class TraceContext:
         """Nested context for a sub-op in THIS process (shared
         accumulator: one flush decision covers the whole op)."""
         return TraceContext(self.trace_id, _new_id(), self.span_id,
-                            self.sampled, self.slow, self.events)
+                            self.sampled, self.slow, self.events,
+                            self.profiled)
 
     # -- envelope carriage -------------------------------------------------
     def to_wire(self) -> str:
@@ -138,8 +204,17 @@ def decode_wire(message: str) -> Optional[TraceContext]:
                         slow=bool(flags & FLAG_SLOW))
 
 
+# Span and trace ids: 64 random bits from a generator of this process's own
+# (seeded from the OS, again in a forked child), not os.urandom per id — a
+# traced RPC hop makes six ids, and where getrandom() is a slow system call
+# (the chip host: about 20 us) that alone took a quarter of a traced cell's
+# requests (PERF.md, PR 25).
+_id_source = random.Random()
+os.register_at_fork(after_in_child=_id_source.seed)
+
+
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _id_source.getrandbits(64)
 
 
 def sampled_of(trace_id: str, rate: float) -> bool:
@@ -196,6 +271,16 @@ class Tracer:
         # with the op's accumulated events whenever an op crosses the
         # slow threshold, independent of the sampling decision
         self._slow_hooks: List = []
+        # the profiled sink: rows of ops that finished under a profiler
+        # session (module doc); bounded, oldest dropped and counted
+        self._captured: collections.deque = collections.deque(
+            maxlen=CAPTURE_MAX_ROWS)
+        self._captured_total = 0
+        self._cap_lock = threading.Lock()
+        # the clock tie of the current profiler session: perf_counter_ns
+        # and time_ns read inside its one t3:anchor annotation
+        self._anchor: Optional[Tuple[int, int]] = None
+        self._in_session = False
 
     def configure(self, *, service: Optional[str] = None,
                   node: Optional[int] = None,
@@ -259,16 +344,56 @@ class Tracer:
             return []
         return list(log.paths)
 
+    # -- the profiled sink ---------------------------------------------------
+    def captured(self) -> List[tuple]:
+        """Rows (CAPTURED_FIELDS order) of the ops that finished under a
+        profiler session since the last reset, oldest first."""
+        with self._cap_lock:
+            return list(self._captured)
+
+    def captured_dropped(self) -> int:
+        """Rows the bounded sink dropped since the last reset."""
+        with self._cap_lock:
+            return self._captured_total - len(self._captured)
+
+    def reset_captured(self) -> None:
+        with self._cap_lock:
+            self._captured.clear()
+            self._captured_total = 0
+
+    def anchor(self) -> Optional[Tuple[int, int]]:
+        """(perf_counter_ns, time_ns) read inside the newest session's
+        t3:anchor annotation; None before the first profiled op."""
+        return self._anchor
+
+    def _profiled(self) -> bool:
+        """Whether a profiler session is active; its first root op drops
+        the anchor that ties this process's clock to the trace's."""
+        if not profiler_active():
+            self._in_session = False
+            return False
+        if not self._in_session:
+            with self._cap_lock:
+                if not self._in_session:
+                    with _TraceMe(ANCHOR_NAME):
+                        self._anchor = (time.perf_counter_ns(),
+                                        time.time_ns())
+                    self._in_session = True
+        return True
+
     # -- emission ----------------------------------------------------------
     def start_trace(self, force: bool = False) -> Optional[TraceContext]:
         """Head decision for an op with no inbound context. Returns None
-        when tracing is off for this process (the zero-overhead path)."""
-        if not self.enabled:
+        when tracing is off for this process and no profiler session is
+        active (the zero-overhead path: one is_enabled() call)."""
+        profiled = self._profiled()
+        if not (self.enabled or profiled):
             return None
         tid = _new_id()
         return TraceContext(tid, _new_id(),
-                            sampled=sampled_of(tid, self.sample_rate),
-                            slow=force)
+                            sampled=(self.enabled
+                                     and sampled_of(tid, self.sample_rate)),
+                            slow=force, profiled=profiled)
 
     def _flush_events(self, events: Sequence[SpanEvent],
                       slow: bool) -> None:
@@ -280,33 +405,44 @@ class Tracer:
                 ev.slow = True
             # SpanEvent is flat: its __dict__ IS the columnar row (skips
             # the per-event reflection walk on the flush path)
-            log.append_row(dict(ev.__dict__))
+            row = dict(ev.__dict__)
+            for name in _MEMORY_ONLY:
+                del row[name]
+            log.append_row(row)
+
+    def _capture(self, events: Sequence[SpanEvent]) -> None:
+        rows = [_row_of(ev) for ev in events]
+        with self._cap_lock:
+            self._captured_total += len(rows)
+            self._captured.extend(rows)
 
     def end_op(self, ctx: TraceContext, op: str, ts: float, dur_s: float,
                *, code: int = 0, nbytes: int = 0,
-               tclass: str = "", tenant: str = "") -> None:
+               tclass: str = "", tenant: str = "",
+               t_perf: Optional[float] = None) -> None:
         """Append the op span for a NESTED op (the flush decision belongs
         to whichever op owns the accumulator — the process root). An
         empty tenant resolves from the ambient scope, so every op span
         carries its owner without each call site threading it."""
         if not tenant:
-            from tpu3fs.tenant.identity import current_tenant
-
-            tenant = current_tenant() or ""
+            tenant = _ambient_tenant()
         ctx.events.append(SpanEvent(
             trace_id=ctx.trace_id, span_id=ctx.span_id,
             parent_id=ctx.parent_id, service=self.service, node=self.node,
             op=op, stage="", ts=ts, dur_us=dur_s * 1e6, code=code,
             nbytes=nbytes, tclass=tclass, tenant=tenant,
-            sampled=ctx.sampled))
+            sampled=ctx.sampled,
+            t_perf=perf_of_wall(ts) if t_perf is None else t_perf,
+            tid=threading.get_ident()))
 
     def finish_op(self, ctx: TraceContext, op: str, ts: float,
                   dur_s: float, *, code: int = 0, nbytes: int = 0,
-                  tclass: str = "", tenant: str = "") -> None:
+                  tclass: str = "", tenant: str = "",
+                  t_perf: Optional[float] = None) -> None:
         """Emit the op span and make the flush-or-drop decision for every
         event the op accumulated in this process."""
         self.end_op(ctx, op, ts, dur_s, code=code, nbytes=nbytes,
-                    tclass=tclass, tenant=tenant)
+                    tclass=tclass, tenant=tenant, t_perf=t_perf)
         is_slow = ctx.slow or dur_s * 1e6 >= self.slow_op_us
         if is_slow and self._slow_hooks:
             for hook in self._slow_hooks:
@@ -314,12 +450,40 @@ class Tracer:
                     hook(list(ctx.events))
                 except Exception:
                     pass  # a black-box feed must never fail the op
+        if ctx.profiled:
+            self._capture(ctx.events)
         if ctx.sampled or is_slow:
             self._flush_events(ctx.events, is_slow and not ctx.sampled)
         ctx.events.clear()
 
 
 _TRACER = Tracer()
+
+_current_tenant = None   # tpu3fs.tenant.identity's, resolved once
+
+
+def _ambient_tenant() -> str:
+    global _current_tenant
+    if _current_tenant is None:
+        from tpu3fs.tenant.identity import current_tenant
+
+        _current_tenant = current_tenant
+    return _current_tenant() or ""
+
+
+# A row's start on perf_counter, for emitters that took only a wall-clock
+# start (server-side stages, older call sites): the two clocks' distance
+# when this module was loaded. Live spans and the RPC hop read both clocks
+# at the start instead, which is what the anchor conversion wants.
+_WALL_MINUS_PERF = time.time() - time.perf_counter()
+
+
+def perf_of_wall(ts: float) -> float:
+    return ts - _WALL_MINUS_PERF
+
+
+def wall_of_perf(t_perf: float) -> float:
+    return t_perf + _WALL_MINUS_PERF
 
 
 def tracer() -> Tracer:
@@ -379,18 +543,32 @@ def round_traces() -> Tuple[TraceContext, ...]:
 
 
 def add_span(ctx: Optional[TraceContext], op: str, stage: str, ts: float,
-             dur_s: float, *, code: int = 0, nbytes: int = 0) -> None:
+             dur_s: float, *, code: int = 0, nbytes: int = 0,
+             t_perf: Optional[float] = None,
+             span_id: Optional[str] = None) -> None:
     """Append one already-measured stage span to a context (no-op on
     None): the storage pipeline measures its stage/forward/commit walls
-    anyway — tracing reuses those numbers instead of re-clocking."""
+    anyway — tracing reuses those numbers instead of re-clocking. Stages
+    measured after the fact are rows only, never profiler annotations."""
     if ctx is None:
         return
     t = _TRACER
     ctx.events.append(SpanEvent(
-        trace_id=ctx.trace_id, span_id=_new_id(), parent_id=ctx.span_id,
-        service=t.service, node=t.node, op=op, stage=stage, ts=ts,
-        dur_us=dur_s * 1e6, code=code, nbytes=nbytes,
-        sampled=ctx.sampled))
+        trace_id=ctx.trace_id, span_id=span_id or _new_id(),
+        parent_id=ctx.span_id, service=t.service, node=t.node, op=op,
+        stage=stage, ts=ts, dur_us=dur_s * 1e6, code=code, nbytes=nbytes,
+        sampled=ctx.sampled,
+        t_perf=perf_of_wall(ts) if t_perf is None else t_perf,
+        tid=threading.get_ident()))
+
+
+def add_span_at(ctx: Optional[TraceContext], op: str, stage: str,
+                t_perf: float, dur_s: float, *, nbytes: int = 0) -> None:
+    """add_span for a stage the caller clocked on perf_counter (a monitor
+    recorder's two reads): the wall-clock start is derived."""
+    if ctx is not None:
+        add_span(ctx, op, stage, wall_of_perf(t_perf), dur_s, nbytes=nbytes,
+                 t_perf=t_perf)
 
 
 def add_span_multi(ctxs: Sequence[TraceContext], op: str, stage: str,
@@ -400,42 +578,171 @@ def add_span_multi(ctxs: Sequence[TraceContext], op: str, stage: str,
         add_span(ctx, op, stage, ts, dur_s, code=code, nbytes=nbytes)
 
 
+class Hop:
+    """The client side of one traced wire hop, whatever the transport
+    (sockets, native sockets, USRBIO ring): a child context that rides the
+    envelope, and the "rpc.client" stages under it — ``issue`` (serialize
+    and put on the wire), ``collect`` (the wait for the reply; a
+    container), and, where the reply carries the server's stamps,
+    ``server_wait`` (receive to handler start: queue, admission, decode),
+    ``server_run`` (the handler), ``wire`` (the collect wait minus the
+    server's window: frames in flight, reply serialize and parse) and
+    ``decode`` (the reply's payload back into objects, this side). Each
+    start is read from the clocks when the stage starts, never
+    reconstructed as "now minus duration": the anchor conversion needs
+    starts that are exact. The server's two stages tile from the end of
+    ``issue``, where the request left this side; ``wire`` ends where the
+    reply is in."""
+
+    __slots__ = ("ctx", "ts", "t0", "t_issued")
+
+    def __init__(self, parent: TraceContext):
+        self.ctx = parent.child()
+        self.ts = time.time()
+        self.t0 = time.perf_counter()
+        self.t_issued = self.t0
+
+    @classmethod
+    def start(cls) -> Optional["Hop"]:
+        """A hop under the calling context's trace; None when untraced
+        (one ContextVar read and nothing else)."""
+        parent = _trace_var.get()
+        return cls(parent) if parent is not None else None
+
+    def _add(self, stage: str, t_perf: float, dur_s: float,
+             nbytes: int = 0) -> None:
+        add_span(self.ctx, "rpc.client", stage,
+                 self.ts + (t_perf - self.t0), dur_s, nbytes=nbytes,
+                 t_perf=t_perf)
+
+    def issued(self, nbytes: int = 0) -> None:
+        self.t_issued = time.perf_counter()
+        self._add("issue", self.t0, self.t_issued - self.t0, nbytes)
+
+    def collected(self, op: str, t_wait: float, *, code: int = 0,
+                  server: Optional[Tuple[float, float]] = None,
+                  t_decode: Optional[float] = None) -> None:
+        """The reply is in and, where ``t_decode`` says when that began,
+        decoded: ``t_wait`` is perf_counter when the wait for the reply
+        began, ``server`` the (wait, run) seconds of the server's own
+        stamps where the reply carried them. Closes the hop."""
+        now = time.perf_counter()
+        got = now if t_decode is None else t_decode
+        self._add("collect", t_wait, got - t_wait)
+        if server is not None:
+            wait_s, run_s = server
+            self._add("server_wait", self.t_issued, wait_s)
+            self._add("server_run", self.t_issued + wait_s, run_s)
+            wire = (got - t_wait) - (wait_s + run_s)
+            if wire > 0:
+                self._add("wire", got - wire, wire)
+        if t_decode is not None:
+            self._add("decode", t_decode, now - t_decode)
+        _TRACER.end_op(self.ctx, op, self.ts, now - self.t0, code=code,
+                       t_perf=self.t0)
+
+
+def _mark(ctx: TraceContext, op: str, stage: str = ""):
+    """Open the profiler annotation of a live span (None unless the trace
+    is a profiled one): the span then lies on the profiler's own timeline
+    beside the device's operations."""
+    if not ctx.profiled:
+        return None
+    mark = _TraceMe(f"{ANNOTATION_PREFIX}{op}.{stage}" if stage
+                    else ANNOTATION_PREFIX + op)
+    mark.__enter__()
+    return mark
+
+
 @contextlib.contextmanager
 def span(op: str, stage: str, *, nbytes: int = 0):
     """Clock a block as a stage span under the current context (no-op —
-    not even a clock read — when untraced)."""
+    not even a clock read — when untraced). What the block calls parents
+    to the stage, so a tree's leaves are what is attributed and a stage
+    that holds other spans is seen to (``TraceTree.coverage``)."""
     ctx = _trace_var.get()
     if ctx is None:
         yield None
         return
+    inner = ctx.child()
+    mark = _mark(ctx, op, stage)
     ts = time.time()
     t0 = time.perf_counter()
+    token = _trace_var.set(inner)
     try:
         yield ctx
     finally:
-        add_span(ctx, op, stage, ts, time.perf_counter() - t0,
-                 nbytes=nbytes)
+        dur = time.perf_counter() - t0
+        _trace_var.reset(token)
+        if mark is not None:
+            mark.__exit__(None, None, None)
+        add_span(ctx, op, stage, ts, dur, nbytes=nbytes, t_perf=t0,
+                 span_id=inner.span_id)
+
+
+def open_op(op: str, *, force: bool = False,
+            live: bool = True) -> Optional[TraceContext]:
+    """Open an op span whose body the caller scopes (``trace_scope``) and
+    whose clock the caller reads — a monitor recorder's two reads serve
+    the span too (``close_op``). Joins the current trace as a child op
+    when one is active, otherwise head-starts a trace (sampling or a
+    profiler session decide; None when neither captures). ``live`` False
+    is for an op that is already over: it gets no profiler annotation."""
+    outer = _trace_var.get()
+    if outer is not None:
+        ctx = outer.child()
+    else:
+        ctx = _TRACER.start_trace(force=force)
+        if ctx is None:
+            return None
+        ctx.root = True
+    if live:
+        ctx.mark = _mark(ctx, op)
+    ctx.ts = time.time()
+    return ctx
+
+
+def close_op(ctx: Optional[TraceContext], op: str, t_perf: float,
+             dur_s: float, *, code: int = 0, nbytes: int = 0) -> None:
+    """Emit the op span of an ``open_op`` context from the caller's clock
+    reads (start on perf_counter, seconds); the root op flushes or drops
+    everything the op accumulated (incl. slow-op capture)."""
+    if ctx is None:
+        return
+    if ctx.mark is not None:
+        ctx.mark.__exit__(None, None, None)
+        ctx.mark = None
+    emit = _TRACER.finish_op if ctx.root else _TRACER.end_op
+    emit(ctx, op, ctx.ts, dur_s, code=code, nbytes=nbytes or ctx.nbytes,
+         t_perf=t_perf)
+
+
+def add_op(op: str, t_perf: float, dur_s: float, *, nbytes: int = 0) -> None:
+    """An already-measured op with nothing beneath it (a consumer's wait a
+    recorder clocked): one row under the current trace, or a trace of its
+    own. No annotation: it is over when it is known."""
+    ctx = open_op(op, live=False)
+    if ctx is not None:
+        ctx.ts = wall_of_perf(t_perf)
+        close_op(ctx, op, t_perf, dur_s, nbytes=nbytes)
 
 
 @contextlib.contextmanager
-def root_span(op: str, *, nbytes: int = 0, force: bool = False):
+def root_span(op: str, *, nbytes: int = 0, force: bool = False,
+              code: int = 0):
     """Client-side op boundary: joins the current trace when one is
-    active (nested client ops emit a plain span), otherwise head-starts a
-    trace — sampling decision, envelope stamping downstream, flush-or-
-    drop at exit (incl. slow-op capture). Yields the context or None."""
-    outer = _trace_var.get()
-    if outer is not None:
-        with span(op, "", nbytes=nbytes):
-            yield outer
-        return
-    ctx = _TRACER.start_trace(force=force)
+    active (a nested client op is a child op span, and what it calls
+    parents to it), otherwise head-starts a trace — sampling decision,
+    envelope stamping downstream, flush-or-drop at exit (incl. slow-op
+    capture). Yields the op's context or None; a caller that learns the
+    payload size only at the end sets ``ctx.nbytes`` before leaving.
+    ``code`` is what a block that does not raise records (-1 if it does)."""
+    ctx = open_op(op, force=force)
     if ctx is None:
         yield None
         return
-    ts = time.time()
     t0 = time.perf_counter()
     token = _trace_var.set(ctx)
-    code = 0
     try:
         yield ctx
     except BaseException:
@@ -443,5 +750,5 @@ def root_span(op: str, *, nbytes: int = 0, force: bool = False):
         raise
     finally:
         _trace_var.reset(token)
-        _TRACER.finish_op(ctx, op, ts, time.perf_counter() - t0,
-                          code=code, nbytes=nbytes)
+        close_op(ctx, op, t0, time.perf_counter() - t0, code=code,
+                 nbytes=nbytes)

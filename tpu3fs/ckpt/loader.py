@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.ckpt.manifest import (
     MANIFEST_NAME,
     Manifest,
@@ -109,8 +110,28 @@ class CheckpointLoader:
         """
         import time as _time
 
+        # ckpt.restore_ms's two clock reads are the op span's too
         t0 = _time.perf_counter()
-        manifest = self.manifest(step)
+        sp = _spans.open_op("ckpt.restore")
+        nbytes, code = 0, -1
+        try:
+            with _spans.trace_scope(sp):
+                tree, nbytes = self._restore(step, like, verify)
+            code = 0
+        finally:
+            dur = _time.perf_counter() - t0
+            _spans.close_op(sp, "ckpt.restore", t0, dur, code=code,
+                            nbytes=nbytes)
+        self._restore_ms.record(dur * 1e3)
+        return tree
+
+    def _restore(self, step: int, like, verify: bool):
+        """-> (tree, payload bytes fetched); restore()'s body, staged as
+        ``manifest``, ``read`` (with ``verify`` and ``assemble`` beneath
+        it, beside the meta and file calls) and ``device_put`` under its
+        op span."""
+        with _spans.span("ckpt.restore", "manifest"):
+            manifest = self.manifest(step)
         saved_leaves = manifest.leaves
         templates = self._match_templates(manifest, like)
 
@@ -132,19 +153,23 @@ class CheckpointLoader:
                 mine.append(idx)
             per_leaf_boxes.append(mine)
 
-        box_arrays = self._fetch_boxes(manifest, boxes, verify)
+        with _spans.span("ckpt.restore", "read"):
+            box_arrays = self._fetch_boxes(manifest, boxes, verify)
+        nbytes = 0
         for (li, _, _), arr in zip(boxes, box_arrays):
             self._restore_bytes.add(arr.nbytes)
+            nbytes += arr.nbytes
 
-        leaves_out = [
-            self._build_leaf(spec, templates[li],
-                             [(boxes[b][1], box_arrays[b])
-                              for b in per_leaf_boxes[li]])
-            for li, spec in enumerate(saved_leaves)
-        ]
-        tree = unflatten_tree(manifest.tree, leaves_out)
-        self._restore_ms.record((_time.perf_counter() - t0) * 1e3)
-        return tree
+        # the dispatch only (numpy leaves put nothing): landing is the
+        # caller's block_until_ready
+        with _spans.span("ckpt.restore", "device_put", nbytes=nbytes):
+            leaves_out = [
+                self._build_leaf(spec, templates[li],
+                                 [(boxes[b][1], box_arrays[b])
+                                  for b in per_leaf_boxes[li]])
+                for li, spec in enumerate(saved_leaves)
+            ]
+        return unflatten_tree(manifest.tree, leaves_out), nbytes
 
     # -- internals --------------------------------------------------------
     @staticmethod
@@ -246,11 +271,14 @@ class CheckpointLoader:
                     [(inodes[si], 0, manifest.shards[si].length)
                      for si in needed_shards])
                 shard_bytes = dict(zip(needed_shards, blobs))
-                for si, raw in shard_bytes.items():
-                    sh = manifest.shards[si]
-                    if len(raw) != sh.length or crc32c(raw) != sh.crc:
-                        raise _err(Code.CKPT_CORRUPT,
-                                   f"shard {sh.file}: CRC/length mismatch")
+                with _spans.span("ckpt.restore", "verify",
+                                 nbytes=sum(len(b) for b in blobs)):
+                    for si, raw in shard_bytes.items():
+                        sh = manifest.shards[si]
+                        if len(raw) != sh.length or crc32c(raw) != sh.crc:
+                            raise _err(
+                                Code.CKPT_CORRUPT,
+                                f"shard {sh.file}: CRC/length mismatch")
 
                 def part_bytes(bi: int, pi: int) -> bytes:
                     si = plans[bi][pi][0]
@@ -276,17 +304,19 @@ class CheckpointLoader:
                     return b"".join(gathered[(bi, pi)])
 
         out: List[np.ndarray] = []
-        for bi, ((li, off, shape), parts) in enumerate(zip(boxes, plans)):
-            dtype = parse_dtype(manifest.leaves[li].dtype)
-            buf = np.empty(shape, dtype=dtype)
-            for pi, (si, ooff, oshape) in enumerate(parts):
-                piece = np.frombuffer(
-                    part_bytes(bi, pi), dtype=dtype).reshape(oshape)
-                dst = tuple(slice(ooff[d] - off[d],
-                                  ooff[d] - off[d] + oshape[d])
-                            for d in range(len(shape)))
-                buf[dst] = piece
-            out.append(buf)
+        with _spans.span("ckpt.restore", "assemble"):
+            for bi, ((li, off, shape), parts) in enumerate(
+                    zip(boxes, plans)):
+                dtype = parse_dtype(manifest.leaves[li].dtype)
+                buf = np.empty(shape, dtype=dtype)
+                for pi, (si, ooff, oshape) in enumerate(parts):
+                    piece = np.frombuffer(
+                        part_bytes(bi, pi), dtype=dtype).reshape(oshape)
+                    dst = tuple(slice(ooff[d] - off[d],
+                                      ooff[d] - off[d] + oshape[d])
+                                for d in range(len(shape)))
+                    buf[dst] = piece
+                out.append(buf)
         return out
 
     @staticmethod

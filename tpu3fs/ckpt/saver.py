@@ -35,6 +35,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.ckpt.manifest import (
     MANIFEST_NAME,
     Manifest,
@@ -261,6 +262,11 @@ class CheckpointSaver:
         manifest. This is the only part that touches devices — async mode
         runs it synchronously so the training step can overwrite the
         arrays the moment save_async() returns."""
+        with _spans.span("ckpt.save", "snapshot"):
+            return self._snapshot(tree, step)
+
+    def _snapshot(self, tree, step: int
+                  ) -> Tuple[Manifest, List[_PlannedShard]]:
         skeleton, leaves = flatten_tree(tree)
         keys = leaf_keypaths(skeleton)
         manifest = Manifest(step=step, created=self._clock(), tree=skeleton)
@@ -402,28 +408,32 @@ class CheckpointSaver:
             # to the per-file self-throttle path. The manifest commits
             # AFTER the shards: its per-shard CRCs come from the write
             # path's own checksum pass (ONE pooled content pass per save)
-            items: List[Tuple[str, object]] = [
-                (f"{tpath}/{spec.file}",
-                 # via uint8: bfloat16 has no buffer-protocol format
-                 memoryview(np.ascontiguousarray(shard.data)
-                            .reshape(-1).view(np.uint8)))
-                for spec, shard in zip(manifest.shards, planned)]
+            with _spans.span("ckpt.save", "frame"):
+                items: List[Tuple[str, object]] = [
+                    (f"{tpath}/{spec.file}",
+                     # via uint8: bfloat16 has no buffer-protocol format
+                     memoryview(np.ascontiguousarray(shard.data)
+                                .reshape(-1).view(np.uint8)))
+                    for spec, shard in zip(manifest.shards, planned)]
             mpath = f"{tpath}/{MANIFEST_NAME}"
-            try:
-                sums = self._write_files_batched(items)
-                for spec, cs in zip(manifest.shards, sums):
-                    spec.crc = cs.value
-                self._write_files_batched([(mpath, manifest.encode())])
-            except FsError as e:
-                if e.code != Code.OVERLOADED:
-                    raise
-                for (path, data), spec in zip(
-                        items, manifest.shards):
-                    spec.crc = crc32c(data)
-                    self._write_file(path, data)
-                self._write_file(mpath, manifest.encode())
+            with _spans.span("ckpt.save", "write",
+                             nbytes=sum(len(d) for _, d in items)):
+                try:
+                    sums = self._write_files_batched(items)
+                    for spec, cs in zip(manifest.shards, sums):
+                        spec.crc = cs.value
+                    self._write_files_batched([(mpath, manifest.encode())])
+                except FsError as e:
+                    if e.code != Code.OVERLOADED:
+                        raise
+                    for (path, data), spec in zip(
+                            items, manifest.shards):
+                        spec.crc = crc32c(data)
+                        self._write_file(path, data)
+                    self._write_file(mpath, manifest.encode())
             # THE commit: one atomic rename makes the step visible
-            self._meta.rename(tpath, step_dir(self.root, step))
+            with _spans.span("ckpt.save", "commit"):
+                self._meta.rename(tpath, step_dir(self.root, step))
         self._save_ms.record((time.perf_counter() - t0) * 1e3)
 
     # -- public API -------------------------------------------------------
@@ -431,15 +441,18 @@ class CheckpointSaver:
         """Synchronous sharded save; returns the committed manifest."""
         if self._exists(step):
             raise _err(Code.META_EXISTS, step_dir(self.root, step))
-        session = SaveSession(self._kv, self.root, step, self._client_id,
-                              self._ttl, self._clock)
-        session.acquire()
-        try:
-            manifest, planned = self._plan(tree, step)
-            self._write_and_commit(manifest, planned)
-            return manifest
-        finally:
-            session.release()
+        with _spans.root_span("ckpt.save") as sp:
+            session = SaveSession(self._kv, self.root, step,
+                                  self._client_id, self._ttl, self._clock)
+            session.acquire()
+            try:
+                manifest, planned = self._plan(tree, step)
+                if sp is not None:
+                    sp.nbytes = sum(p.data.nbytes for p in planned)
+                self._write_and_commit(manifest, planned)
+                return manifest
+            finally:
+                session.release()
 
     def save_async(self, tree, step: int) -> AsyncCheckpoint:
         """Snapshot to host memory, then return immediately; a background

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Iterable, List, Optional, Tuple
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.client.storage_client import StorageClient
 from tpu3fs.meta.types import Inode, Layout
 from tpu3fs.storage.types import Checksum, ChunkId
@@ -109,6 +110,13 @@ class FileIoClient:
         flush in FILE ORDER, so a failure always leaves a clean written
         prefix of whole runs — never new data after a hole (within a run
         the batch may land partially, as in the reference's batch APIs)."""
+        with _spans.root_span("fio.write") as sp:
+            n = self._write(inode, offset, data)
+            if sp is not None:
+                sp.nbytes = n
+            return n
+
+    def _write(self, inode: Inode, offset: int, data: bytes) -> int:
         layout = inode.layout
         assert layout is not None, "write() needs a file inode with layout"
         cs = layout.chunk_size
@@ -192,6 +200,13 @@ class FileIoClient:
         ride down to batch_write as trusted CRCs, so an in-process chain
         (the fabric) does not checksum the payload again anywhere: the
         ckpt saver turns them directly into manifest shard CRCs."""
+        with _spans.root_span("fio.batch_write_files") as sp:
+            out = self._batch_write_files(files, with_checksums)
+            if sp is not None:
+                sp.nbytes = sum(out[0] if with_checksums else out)
+            return out
+
+    def _batch_write_files(self, files, with_checksums: bool):
         cr_runs: List[Tuple[list, int, list]] = []  # (ops, chunk_size, crc idxs)
         cr_ops: List[Tuple[int, ChunkId, int, object]] = []
         cr_idx: List[int] = []
@@ -292,12 +307,14 @@ class FileIoClient:
         if in_off == 0 and len(part) == chunk_size:
             return self._storage.write_stripe(
                 chain_id, cid, part, chunk_size=chunk_size)
-        fast = self._storage.write_stripe_rmw(
-            chain_id, cid, in_off, part, chunk_size=chunk_size)
+        with _spans.span("fio.write_ec_chunk", "rmw_probe"):
+            fast = self._storage.write_stripe_rmw(
+                chain_id, cid, in_off, part, chunk_size=chunk_size)
         if fast is not None:
             return fast
-        cur = self._storage.read_stripe(
-            chain_id, cid, 0, chunk_size, chunk_size=chunk_size)
+        with _spans.span("fio.write_ec_chunk", "stripe_read"):
+            cur = self._storage.read_stripe(
+                chain_id, cid, 0, chunk_size, chunk_size=chunk_size)
         if cur.ok:
             base = bytearray(cur.data.ljust(chunk_size, b"\x00"))
             # fresh-nonce encoded version: hand-computing commit_ver + 1
@@ -366,14 +383,15 @@ class FileIoClient:
         arm) the readahead window."""
         if inode.length:
             size = max(0, min(size, inode.length - offset))
-        pf = self._prefetch
-        if pf is None:
-            return self._read_direct(inode, offset, size)
-        data = pf.lookup(inode.id, offset, size)
-        if data is None:
-            data = self._read_direct(inode, offset, size)
-        pf.record_read(inode, offset, size)
-        return data
+        with _spans.root_span("fio.read", nbytes=size):
+            pf = self._prefetch
+            if pf is None:
+                return self._read_direct(inode, offset, size)
+            data = pf.lookup(inode.id, offset, size)
+            if data is None:
+                data = self._read_direct(inode, offset, size)
+            pf.record_read(inode, offset, size)
+            return data
 
     def _read_direct(self, inode: Inode, offset: int, size: int) -> bytes:
         """The uncached read path (also the prefetcher's fetch fn; size is
@@ -405,14 +423,19 @@ class FileIoClient:
         RDMA-WRITEs results into the user's registered iov,
         StorageOperator.cc:176-226). Returns bytes filled (short at EOF);
         holes and short chunks zero-fill their slots."""
-        from tpu3fs.client.storage_client import ReadReq
-
         layout = inode.layout
         assert layout is not None
         if inode.length:
             size = max(0, min(size, inode.length - offset))
         if size == 0:
             return 0
+        with _spans.root_span("fio.read_into", nbytes=size):
+            return self._read_into(inode, layout, offset, size, dest)
+
+    def _read_into(self, inode: Inode, layout: Layout, offset: int,
+                   size: int, dest) -> int:
+        from tpu3fs.client.storage_client import ReadReq
+
         pf = self._prefetch
         if pf is not None:
             hit = pf.lookup(inode.id, offset, size)
@@ -456,6 +479,15 @@ class FileIoClient:
         batching across files is what amortizes round trips. With prefetch
         on, ranges inside a readahead window are served from cache and the
         rest go out as one (smaller) batch."""
+        with _spans.root_span("fio.batch_read_files") as sp:
+            out = self._batch_read_files(files)
+            if sp is not None:
+                sp.nbytes = sum(len(blob) for blob in out)
+            return out
+
+    def _batch_read_files(
+        self, files: List[Tuple[Inode, int, int]]
+    ) -> List[bytes]:
         pf = self._prefetch
         if pf is None:
             return self._batch_read_files_direct(files)
@@ -491,27 +523,31 @@ class FileIoClient:
         reqs: List[ReadReq] = []
         spans: List[List[Tuple[int, int]]] = []  # per file: (req idx, n)
         sizes: List[int] = []
-        for inode, offset, size in files:
-            layout = inode.layout
-            assert layout is not None
-            if inode.length:
-                size = max(0, min(size, inode.length - offset))
-            sizes.append(size)
-            mine: List[Tuple[int, int]] = []
-            for idx, chain_id, in_off, n in self._split(layout, offset, size):
-                mine.append((len(reqs), n))
-                reqs.append(ReadReq(
-                    chain_id, ChunkId(inode.id, idx), in_off, n,
-                    chunk_size=layout.chunk_size,
-                ))
-            spans.append(mine)
+        with _spans.span("fio.batch_read_files", "plan"):
+            for inode, offset, size in files:
+                layout = inode.layout
+                assert layout is not None
+                if inode.length:
+                    size = max(0, min(size, inode.length - offset))
+                sizes.append(size)
+                mine: List[Tuple[int, int]] = []
+                for idx, chain_id, in_off, n in self._split(
+                        layout, offset, size):
+                    mine.append((len(reqs), n))
+                    reqs.append(ReadReq(
+                        chain_id, ChunkId(inode.id, idx), in_off, n,
+                        chunk_size=layout.chunk_size,
+                    ))
+                spans.append(mine)
         replies = self._storage.batch_read(reqs)
-        return [
-            self._assemble(
-                inode, [(replies[req_i], n) for req_i, n in mine], size
-            )
-            for (inode, _, _), mine, size in zip(files, spans, sizes)
-        ]
+        with _spans.span("fio.batch_read_files", "assemble",
+                         nbytes=sum(sizes)):
+            return [
+                self._assemble(
+                    inode, [(replies[req_i], n) for req_i, n in mine], size
+                )
+                for (inode, _, _), mine, size in zip(files, spans, sizes)
+            ]
 
     def file_length(self, inode: Inode) -> int:
         """Precise length: max over chains of last chunk end (FileHelper)."""

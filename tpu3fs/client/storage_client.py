@@ -580,68 +580,77 @@ class StorageClient:
         version skew — goes DEGRADED inline: the surviving shards of
         every degraded stripe are fetched in one more batched round and
         decoded client-side (any k of k+m), with ec.degraded_read /
-        ec.degraded_read_ms recording the detour."""
+        ec.degraded_read_ms recording the detour. Traced as two stages
+        around the wire reads: ``plan`` (requests -> per-node wire ops) and
+        ``finish`` (replies back to requests, stripe assembly and decode,
+        the single-op ladder for what failed)."""
+        from tpu3fs.analytics import spans as _spans
+
         routing = self._routing()
         replies: List[Optional[ReadReply]] = [None] * len(reqs)
         wire: List[Tuple[int, ReadReq]] = []   # (node_id, wire op)
         tags: List[Tuple] = []                 # ("cr", i) | ("ec", i, j)
         ec_specs: Dict[int, dict] = {}
-        for i, req in enumerate(reqs):
-            chain = routing.chains.get(req.chain_id)
-            if chain is None:
-                replies[i] = ReadReply(Code.CHAIN_NOT_FOUND)
-                continue
-            if chain.is_ec:
-                # EC reads are shard-addressed, not replica-selected; the
-                # shard size derives from the file's chunk_size, so a
-                # request without one cannot be served correctly — reject
-                # loudly instead of slicing at a guessed size
-                if not req.chunk_size:
-                    replies[i] = ReadReply(Code.INVALID_ARG)
+        with _spans.span("client.batch_read", "plan"):
+            for i, req in enumerate(reqs):
+                chain = routing.chains.get(req.chain_id)
+                if chain is None:
+                    replies[i] = ReadReply(Code.CHAIN_NOT_FOUND)
                     continue
-                spec = self._plan_stripe_read(chain, routing, req)
-                if spec["length"] == 0:
-                    replies[i] = ReadReply(Code.OK, data=b"")
+                if chain.is_ec:
+                    # EC reads are shard-addressed, not replica-selected; the
+                    # shard size derives from the file's chunk_size, so a
+                    # request without one cannot be served correctly — reject
+                    # loudly instead of slicing at a guessed size
+                    if not req.chunk_size:
+                        replies[i] = ReadReply(Code.INVALID_ARG)
+                        continue
+                    spec = self._plan_stripe_read(chain, routing, req)
+                    if spec["length"] == 0:
+                        replies[i] = ReadReply(Code.OK, data=b"")
+                        continue
+                    ec_specs[i] = spec
+                    for j, (node_id, rr) in spec["wire"].items():
+                        tags.append(("ec", i, j))
+                        wire.append((node_id, rr))
                     continue
-                ec_specs[i] = spec
-                for j, (node_id, rr) in spec["wire"].items():
-                    tags.append(("ec", i, j))
-                    wire.append((node_id, rr))
-                continue
-            targets = self._pick_targets(chain)
-            if not targets:
-                replies[i] = ReadReply(Code.TARGET_OFFLINE)
-                continue
-            target_id = req.target_id or targets[0]
-            node = routing.node_of_target(target_id)
-            if node is None:
-                replies[i] = ReadReply(Code.TARGET_NOT_FOUND)
-                continue
-            tags.append(("cr", i))
-            wire.append((node.node_id, ReadReq(
-                req.chain_id, req.chunk_id, req.offset, req.length, target_id
-            )))
+                targets = self._pick_targets(chain)
+                if not targets:
+                    replies[i] = ReadReply(Code.TARGET_OFFLINE)
+                    continue
+                target_id = req.target_id or targets[0]
+                node = routing.node_of_target(target_id)
+                if node is None:
+                    replies[i] = ReadReply(Code.TARGET_NOT_FOUND)
+                    continue
+                tags.append(("cr", i))
+                wire.append((node.node_id, ReadReq(
+                    req.chain_id, req.chunk_id, req.offset, req.length,
+                    target_id
+                )))
         wire_replies = self._issue_wire_reads(wire)
-        shard_replies: Dict[int, Dict[int, ReadReply]] = {
-            i: {} for i in ec_specs}
-        for tag, r in zip(tags, wire_replies):
-            if tag[0] == "cr":
-                replies[tag[1]] = r
-            else:
-                shard_replies[tag[1]][tag[2]] = r
-        if ec_specs:
-            self._finish_stripe_reads(
-                reqs, replies, ec_specs, shard_replies, routing)
-        # fall back to the single-op retry ladder for failures (EC replies
-        # already went through the degraded decode / read_stripe ladder)
-        for i, r in enumerate(replies):
-            if r is None or (not r.ok and r.code != Code.CHUNK_NOT_FOUND):
-                chain = routing.chains.get(reqs[i].chain_id)
-                if chain is not None and chain.is_ec:
-                    continue
-                replies[i] = self.read_chunk(
-                    reqs[i].chain_id, reqs[i].chunk_id, reqs[i].offset, reqs[i].length
-                )
+        with _spans.span("client.batch_read", "finish"):
+            shard_replies: Dict[int, Dict[int, ReadReply]] = {
+                i: {} for i in ec_specs}
+            for tag, r in zip(tags, wire_replies):
+                if tag[0] == "cr":
+                    replies[tag[1]] = r
+                else:
+                    shard_replies[tag[1]][tag[2]] = r
+            if ec_specs:
+                self._finish_stripe_reads(
+                    reqs, replies, ec_specs, shard_replies, routing)
+            # fall back to the single-op retry ladder for failures (EC replies
+            # already went through the degraded decode / read_stripe ladder)
+            for i, r in enumerate(replies):
+                if r is None or (not r.ok and r.code != Code.CHUNK_NOT_FOUND):
+                    chain = routing.chains.get(reqs[i].chain_id)
+                    if chain is not None and chain.is_ec:
+                        continue
+                    replies[i] = self.read_chunk(
+                        reqs[i].chain_id, reqs[i].chunk_id, reqs[i].offset,
+                        reqs[i].length
+                    )
         return replies  # type: ignore[return-value]
 
     def _issue_wire_reads(
@@ -908,7 +917,38 @@ class StorageClient:
         """Erasure-code one chunk into k data + m parity shards on device
         (RSCode encode + BatchCrc32c, Pallas on TPU) and install each shard
         on its chain-position target. update_ver=0 probes: try 1, bump past
-        any newer committed stripe on conflict."""
+        any newer committed stripe on conflict.
+
+        Traced as ``client.write_stripe`` with the ladder's stages:
+        ``encode``, then per attempt ``stage_shards`` and ``commit_shards``
+        (the shard RPCs one after another, each a ``rpc.client`` hop
+        beneath) and ``backoff`` (the sleep between attempts; bytes make no
+        sense there, so its ``nbytes`` holds the COUNT of attempts so
+        far)."""
+        from tpu3fs.analytics import spans as _spans
+
+        with _spans.root_span("client.write_stripe", nbytes=len(data)):
+            return self._write_stripe_op(chain_id, chunk_id, data,
+                                         chunk_size=chunk_size,
+                                         update_ver=update_ver)
+
+    def _backoff(self, attempt: int, hint_ms: int = 0) -> None:
+        """_sleep between two attempts of the stripe ladder, as a stage."""
+        from tpu3fs.analytics import spans as _spans
+
+        with _spans.span("client.write_stripe", "backoff", nbytes=attempt + 1):
+            self._sleep(attempt, hint_ms)
+
+    def _write_stripe_op(
+        self,
+        chain_id: int,
+        chunk_id: ChunkId,
+        data: bytes,
+        *,
+        chunk_size: int,
+        update_ver: int,
+    ) -> UpdateReply:
+        from tpu3fs.analytics import spans as _spans
         from tpu3fs.ops.stripe import get_codec, shard_size_of
 
         chain = self._chain(chain_id)
@@ -919,9 +959,13 @@ class StorageClient:
         k, m = chain.ec_k, chain.ec_m
         S = shard_size_of(chunk_size, k)
         codec = get_codec(k, m, S)
-        t_enc = time.monotonic()
+        # one pair of clock reads feeds encode_cpu_s and the encode stage
+        t_enc = time.perf_counter()
         shards, crcs = codec.encode_stripe(data)
-        self.encode_cpu_s += time.monotonic() - t_enc
+        dt_enc = time.perf_counter() - t_enc
+        self.encode_cpu_s += dt_enc
+        _spans.add_span_at(_spans.current_trace(), "client.write_stripe",
+                           "encode", t_enc, dt_enc, nbytes=k * S)
         ver = update_ver or self._ec_next_ver(0)
         last: Optional[UpdateReply] = None
         done: set = set()     # shard indices STAGED at `ver`
@@ -936,72 +980,75 @@ class StorageClient:
             acked = 0
             bump_to = 0
             hard: Optional[UpdateReply] = None
-            for j in range(k + m):
-                t = chain.target_of_shard(j)
-                if t is None or not t.public_state.can_write:
-                    continue  # non-writable targets rebuild before SERVING
-                writable += 1
-                if j in done:
-                    acked += 1
-                    continue
-                node = routing.node_of_target(t.target_id)
-                if node is None:
-                    continue
-                # data shards ship the trimmed host bytes; parity ships the
-                # device-encoded rows (always full S). The wire CRC covers
-                # the STORED (trimmed) bytes, so the server validates with
-                # the one CRC pass its engine does during staging
-                if j < k:
-                    payload = data[j * S : (j + 1) * S]
-                else:
-                    payload = shards[j].tobytes()
-                crc = (int(crcs[j]) if len(payload) == S
-                       else codec.crc_host(payload))
-                req = ShardWriteReq(
-                    chain_id=chain_id,
-                    chain_ver=chain.chain_version,
-                    target_id=t.target_id,
-                    chunk_id=chunk_id,
-                    data=payload,
-                    crc=crc,
-                    update_ver=ver,
-                    chunk_size=S,
-                    logical_len=len(data),
-                    phase=1,  # STAGE: the committed stripe survives failure
-                )
-                try:
-                    reply = self._messenger(node.node_id, "write_shard", req)
-                except FsError as e:
-                    reply = UpdateReply(e.code, message=e.status.message)
-                if reply.ok:
-                    acked += 1
-                    done.add(j)
-                elif reply.code in (Code.CHUNK_STALE_UPDATE,
-                                    Code.CHUNK_ADVANCE_UPDATE):
-                    # STALE: a newer COMMITTED stripe exists — re-write
-                    # above it (whole-stripe versioning, fresh nonce).
-                    # ADVANCE: an ABANDONED pending (e.g. an aborted
-                    # chain-encode relay or a crashed writer) sits above
-                    # our version with the same logical number — bumping
-                    # the logical version clears it (staging displaces
-                    # older pendings), where retrying the same ver would
-                    # wedge forever on the orphan.
-                    bump_to = max(
-                        bump_to,
-                        self._ec_next_ver(max(reply.commit_ver, ver)))
-                elif Status(reply.code).retryable() or reply.code in (
-                    Code.RPC_PEER_CLOSED, Code.RPC_CONNECT_FAILED,
-                ):
-                    last = reply
-                else:
-                    hard = reply
+            with _spans.span("client.write_stripe", "stage_shards"):
+                for j in range(k + m):
+                    t = chain.target_of_shard(j)
+                    if t is None or not t.public_state.can_write:
+                        continue  # non-writable targets rebuild before SERVING
+                    writable += 1
+                    if j in done:
+                        acked += 1
+                        continue
+                    node = routing.node_of_target(t.target_id)
+                    if node is None:
+                        continue
+                    # data shards ship the trimmed host bytes; parity ships the
+                    # device-encoded rows (always full S). The wire CRC covers
+                    # the STORED (trimmed) bytes, so the server validates with
+                    # the one CRC pass its engine does during staging
+                    if j < k:
+                        payload = data[j * S : (j + 1) * S]
+                    else:
+                        payload = shards[j].tobytes()
+                    crc = (int(crcs[j]) if len(payload) == S
+                           else codec.crc_host(payload))
+                    req = ShardWriteReq(
+                        chain_id=chain_id,
+                        chain_ver=chain.chain_version,
+                        target_id=t.target_id,
+                        chunk_id=chunk_id,
+                        data=payload,
+                        crc=crc,
+                        update_ver=ver,
+                        chunk_size=S,
+                        logical_len=len(data),
+                        # STAGE: the committed stripe survives failure
+                        phase=1,
+                    )
+                    try:
+                        reply = self._messenger(node.node_id, "write_shard",
+                                                req)
+                    except FsError as e:
+                        reply = UpdateReply(e.code, message=e.status.message)
+                    if reply.ok:
+                        acked += 1
+                        done.add(j)
+                    elif reply.code in (Code.CHUNK_STALE_UPDATE,
+                                        Code.CHUNK_ADVANCE_UPDATE):
+                        # STALE: a newer COMMITTED stripe exists — re-write
+                        # above it (whole-stripe versioning, fresh nonce).
+                        # ADVANCE: an ABANDONED pending (e.g. an aborted
+                        # chain-encode relay or a crashed writer) sits above
+                        # our version with the same logical number — bumping
+                        # the logical version clears it (staging displaces
+                        # older pendings), where retrying the same ver would
+                        # wedge forever on the orphan.
+                        bump_to = max(
+                            bump_to,
+                            self._ec_next_ver(max(reply.commit_ver, ver)))
+                    elif Status(reply.code).retryable() or reply.code in (
+                        Code.RPC_PEER_CLOSED, Code.RPC_CONNECT_FAILED,
+                    ):
+                        last = reply
+                    else:
+                        hard = reply
             if hard is not None:
                 return hard
             if bump_to:
                 ver = bump_to
                 done.clear()  # everything must be re-staged at the new ver
                 landed.clear()
-                self._sleep(attempt)
+                self._backoff(attempt)
                 continue
             # STRICT staging: every currently-writable shard staged (and at
             # least k overall, or the stripe would be undecodable). Only
@@ -1017,48 +1064,49 @@ class StorageClient:
                 # with fewer than the full writable coverage (review: ack
                 # with < k commits after displaced pendings).
                 full = set(done)
-                for j in sorted(done - landed):
-                    t = chain.target_of_shard(j)
-                    node = (routing.node_of_target(t.target_id)
-                            if t is not None else None)
-                    if node is None:
-                        continue
-                    creq = ShardWriteReq(
-                        chain_id=chain_id,
-                        chain_ver=chain.chain_version,
-                        target_id=t.target_id,
-                        chunk_id=chunk_id,
-                        data=b"",
-                        crc=0,
-                        update_ver=ver,
-                        chunk_size=S,
-                        logical_len=len(data),
-                        phase=2,
-                    )
-                    try:
-                        r2 = self._messenger(node.node_id, "write_shard",
-                                             creq)
-                    except FsError as e:
-                        r2 = UpdateReply(e.code, message=e.status.message)
-                    if r2.ok:
-                        landed.add(j)
-                    elif r2.code == Code.CHUNK_MISSING_UPDATE:
-                        # our pending was displaced (e.g. by a concurrent
-                        # writer's stage): re-STAGE this shard next attempt
-                        # instead of re-sending a commit that cannot land
-                        done.discard(j)
+                with _spans.span("client.write_stripe", "commit_shards"):
+                    for j in sorted(done - landed):
+                        t = chain.target_of_shard(j)
+                        node = (routing.node_of_target(t.target_id)
+                                if t is not None else None)
+                        if node is None:
+                            continue
+                        creq = ShardWriteReq(
+                            chain_id=chain_id,
+                            chain_ver=chain.chain_version,
+                            target_id=t.target_id,
+                            chunk_id=chunk_id,
+                            data=b"",
+                            crc=0,
+                            update_ver=ver,
+                            chunk_size=S,
+                            logical_len=len(data),
+                            phase=2,
+                        )
+                        try:
+                            r2 = self._messenger(node.node_id, "write_shard",
+                                                 creq)
+                        except FsError as e:
+                            r2 = UpdateReply(e.code, message=e.status.message)
+                        if r2.ok:
+                            landed.add(j)
+                        elif r2.code == Code.CHUNK_MISSING_UPDATE:
+                            # our pending was displaced (e.g. by a concurrent
+                            # writer's stage): re-STAGE this shard next attempt
+                            # instead of re-sending a commit that cannot land
+                            done.discard(j)
                 if landed >= full:
                     return UpdateReply(Code.OK, update_ver=ver,
                                        commit_ver=ver)
                 last = UpdateReply(
                     Code.TARGET_OFFLINE,
                     message=f"{len(landed)}/{len(full)} commits acked")
-                self._sleep(attempt)
+                self._backoff(attempt)
                 continue
             last = last or UpdateReply(
                 Code.TARGET_OFFLINE,
                 message=f"{acked}/{writable} writable shards acked")
-            self._sleep(attempt, _hint_ms(last))
+            self._backoff(attempt, _hint_ms(last))
         return last or UpdateReply(Code.CLIENT_RETRIES_EXHAUSTED)
 
     def _send_shard_batches(self, by_node) -> List[Tuple[int, object]]:
@@ -1126,6 +1174,7 @@ class StorageClient:
         stripes that still conflict fall back to write_stripe."""
         import numpy as np
 
+        from tpu3fs.analytics import spans as _spans
         from tpu3fs.ops.stripe import get_codec, shard_size_of
 
         chain = self._chain(chain_id)
@@ -1170,10 +1219,14 @@ class StorageClient:
         # parity-only encode: data-shard payloads below are slices of the
         # caller's bytes, so materializing a concatenated (B, k+m, S)
         # array would be a multi-MiB copy per batch for nothing
-        t_enc = time.monotonic()
+        # one pair of clock reads feeds encode_cpu_s, the gauge and the
+        # encode stage
+        t_enc = time.perf_counter()
         parity, crcs = codec.encode_parity(buf)
-        dt_enc = time.monotonic() - t_enc
+        dt_enc = time.perf_counter() - t_enc
         self.encode_cpu_s += dt_enc
+        _spans.add_span_at(_spans.current_trace(), "client.write_stripes",
+                           "encode", t_enc, dt_enc, nbytes=B * k * S)
         if dt_enc > 0:
             self._ec_encode_gibps.set(B * k * S / dt_enc / (1 << 30))
         by_node: Dict[int, List[Tuple[int, ShardWriteReq]]] = defaultdict(list)
@@ -1755,9 +1808,14 @@ class StorageClient:
         shard, gather any k same-version survivors and reconstruct
         (degraded read). Shares its planning/assembly/decode helpers with
         batch_read so the two paths cannot drift apart."""
-        with self._op_scope():
-            return self._read_stripe_op(chain_id, chunk_id, offset, length,
-                                        chunk_size=chunk_size)
+        from tpu3fs.analytics import spans as _spans
+
+        with _spans.root_span("client.read_stripe") as sp, self._op_scope():
+            reply = self._read_stripe_op(chain_id, chunk_id, offset, length,
+                                         chunk_size=chunk_size)
+            if sp is not None and reply.ok:
+                sp.nbytes = len(reply.data)
+            return reply
 
     def _read_stripe_op(
         self,

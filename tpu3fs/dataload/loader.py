@@ -38,6 +38,7 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.dataload.dataset import PackedDataset, dp_info
 from tpu3fs.dataload.state import DataloadState
 from tpu3fs.monitor.recorder import (
@@ -116,6 +117,9 @@ class Batch:
     nbytes: int = 0
     # dp rows this process fetched (mesh mode; [rank] otherwise)
     rows: List[int] = field(default_factory=list)
+    # the open dataload.fetch op span of a traced batch and the fetch's
+    # two clock reads (context, start, end): closed at its hand-off
+    trace: Optional[tuple] = field(default=None, repr=False, compare=False)
 
 
 class DataLoader:
@@ -256,7 +260,11 @@ class DataLoader:
                 raise self._error
             else:
                 raise StopIteration
-        self._stall_ms.record((time.perf_counter() - t0) * 1e3)
+        # the consumer's wait: dataload.stall_ms's two clock reads are the
+        # dataload.next op span's too
+        stall = time.perf_counter() - t0
+        self._stall_ms.record(stall * 1e3)
+        _spans.add_op("dataload.next", t0, stall, nbytes=batch.nbytes)
         return batch
 
     def close(self) -> None:
@@ -325,7 +333,9 @@ class DataLoader:
                 head = pending.pop(0)
                 batch = head.get() if hasattr(head, "get") \
                     else self._fetch(*head)
-                if not self._push(batch):
+                pushed = self._push(batch)
+                self._close_fetch(batch)
+                if not pushed:
                     return
         except BaseException as e:  # delivered on the consumer's next()
             with self._cond:
@@ -360,8 +370,47 @@ class DataLoader:
 
     # -- fetch + assembly -------------------------------------------------
     def _fetch(self, perm, epoch: int, step: int) -> Batch:
+        """One batch, traced as a ``dataload.fetch`` op span that stays
+        open until the batch is handed off (_close_fetch): its stages are
+        ``read``, ``assemble``, ``device_put`` and ``push_wait``."""
         cfg = self.config
         t0 = time.perf_counter()
+        sp = _spans.open_op("dataload.fetch")
+        try:
+            with _spans.trace_scope(sp):
+                batch, gap = self._fetch_traced(perm, epoch, step)
+        except BaseException:
+            _spans.close_op(sp, "dataload.fetch", t0,
+                            time.perf_counter() - t0, code=-1)
+            raise
+        # dataload.batch_ms's two clock reads are the span's too
+        t1 = time.perf_counter()
+        batch_ms = (t1 - t0) * 1e3
+        self._batch_ms.record(batch_ms)
+        if self.gap_controller is not None:
+            # feedback: the gap this batch used, its wall, its bytes
+            self.gap_controller.observe(gap, batch_ms, batch.nbytes)
+        if sp is not None:
+            batch.trace = (sp, t0, t1)
+        return batch
+
+    def _close_fetch(self, batch: Batch) -> None:
+        """The batch is in the consumer's queue (or the loader stopped):
+        what passed since its fetch ended — waiting its turn behind
+        earlier batches, then for room in a full queue — is ``push_wait``,
+        and the op span closes."""
+        if batch.trace is None:
+            return
+        sp, t0, t1 = batch.trace
+        batch.trace = None
+        now = time.perf_counter()
+        _spans.add_span_at(sp, "dataload.fetch", "push_wait", t1, now - t1)
+        _spans.close_op(sp, "dataload.fetch", t0, now - t0,
+                        nbytes=batch.nbytes)
+
+    def _fetch_traced(self, perm, epoch: int, step: int):
+        """-> (batch, the coalesce gap its reads used)."""
+        cfg = self.config
         rows = sorted(self._rows)
         ids: List[int] = []
         for r in rows:
@@ -370,9 +419,7 @@ class DataLoader:
                                           dp_size=self._dp_size))
         gap = (self.gap_controller.next_gap()
                if self.gap_controller is not None else cfg.coalesce_gap)
-        from tpu3fs.analytics import spans as _spans
-
-        with _spans.root_span("dataload.fetch"):
+        with _spans.span("dataload.fetch", "read"):
             recs = self._read_with_backoff(ids, gap)
         if cfg.transform is not None:
             # decode/augment between fetch and assembly — per record, on
@@ -380,20 +427,19 @@ class DataLoader:
             recs = [cfg.transform(r) for r in recs]
         nbytes = sum(_rec_nbytes(r) for r in recs)
         if cfg.dtype:
-            data = self._assemble_array(ids, recs)
+            with _spans.span("dataload.fetch", "assemble", nbytes=nbytes):
+                data = self._assemble_array(ids, recs)
         else:
             data = recs
         if self._mesh is not None:
-            data = self._to_device(data, rows)
+            # the dispatch only: landing is the consumer's
+            # block_until_ready
+            with _spans.span("dataload.fetch", "device_put", nbytes=nbytes):
+                data = self._to_device(data, rows)
         self._bytes.add(nbytes)
         self._batches.add()
-        batch_ms = (time.perf_counter() - t0) * 1e3
-        self._batch_ms.record(batch_ms)
-        if self.gap_controller is not None:
-            # feedback: the gap this batch used, its wall, its bytes
-            self.gap_controller.observe(gap, batch_ms, nbytes)
         return Batch(epoch=epoch, step=step, ids=ids, data=data,
-                     nbytes=nbytes, rows=rows)
+                     nbytes=nbytes, rows=rows), gap
 
     def _read_with_backoff(self, ids: List[int],
                            coalesce_gap: Optional[int] = None):

@@ -31,6 +31,7 @@ import struct
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.kvcache.layout import decode_array, encode_array
 from tpu3fs.utils.result import Code, FsError
 from tpu3fs.utils.result import err as _err
@@ -98,7 +99,8 @@ class PrefixBlockStore:
         keys = self.block_keys(token_ids)
         if not keys:
             return PrefixMatch()
-        present = self._cache.batch_contains(keys)
+        with _spans.root_span("kvcache.match_prefix"):
+            present = self._cache.batch_contains(keys)
         n = 0
         for hit in present:
             if not hit:
@@ -123,12 +125,22 @@ class PrefixBlockStore:
             raise _err(Code.INVALID_ARG,
                        f"{len(kv_blocks)} blocks at {start_block} but the "
                        f"sequence only chains {len(keys)} full blocks")
-        present = self._cache.batch_contains(want)
-        items = [(key, encode_array(arr))
-                 for key, arr, hit in zip(want, kv_blocks, present)
-                 if not hit]
-        if not items:
-            return 0
+        with _spans.root_span("kvcache.append_blocks") as sp:
+            with _spans.span("kvcache.append_blocks", "probe"):
+                present = self._cache.batch_contains(want)
+            fresh = [(key, arr) for key, arr, hit
+                     in zip(want, kv_blocks, present) if not hit]
+            if not fresh:
+                return 0
+            with _spans.span("kvcache.append_blocks", "encode_array"):
+                items = [(key, encode_array(arr)) for key, arr in fresh]
+            if sp is not None:   # the blocks' payload, headers apart
+                sp.nbytes = sum(getattr(arr, "nbytes", len(raw))
+                                for (_, arr), (_, raw) in zip(fresh, items))
+            self._put_items(items, write_through)
+        return len(items)
+
+    def _put_items(self, items, write_through: Optional[bool]) -> None:
         # drain as ONE batched put (KVCacheClient.batch_put: one
         # batch_create + one striped batch write + one batch_close for
         # the whole drain) — the last per-block serial-create path
@@ -149,7 +161,6 @@ class PrefixBlockStore:
                     self._cache.put(key, raw)
                 else:
                     self._cache.put(key, raw, write_through=write_through)
-        return len(items)
 
     # -- reads --------------------------------------------------------------
     def get_blocks(self, token_ids: Sequence[int], *,
@@ -162,17 +173,25 @@ class PrefixBlockStore:
         keys = self.block_keys(token_ids)
         if count is not None:
             keys = keys[:count]
-        blobs = self._cache.batch_get(keys)
-        out: List = [None] * len(blobs)
-        for i, raw in enumerate(blobs):
-            if raw is None:
-                continue
-            arr = self._decode(keys[i], raw)  # zero-copy view or None
-            if arr is not None and device is not None:
+        with _spans.root_span("kvcache.get_blocks") as sp:
+            blobs = self._cache.batch_get(keys)
+            with _spans.span("kvcache.get_blocks", "decode"):
+                # zero-copy views; None for a miss or a stale entry
+                out: List = [None if raw is None
+                             else self._decode(keys[i], raw)
+                             for i, raw in enumerate(blobs)]
+            nbytes = sum(arr.nbytes for arr in out if arr is not None)
+            if sp is not None:
+                sp.nbytes = nbytes
+            if device is not None:
                 import jax
 
-                arr = jax.device_put(arr, device)
-            out[i] = arr
+                # the dispatch only: landing is the caller's
+                # block_until_ready
+                with _spans.span("kvcache.get_blocks", "device_put",
+                                 nbytes=nbytes):
+                    out = [arr if arr is None
+                           else jax.device_put(arr, device) for arr in out]
         return out
 
     def _decode(self, key: str, raw):

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.ops.crc32c import BatchCrc32c, crc32c, crc32c_batch_host
 from tpu3fs.ops.rs import RSCode
 
@@ -123,24 +124,35 @@ class StripeCodec:
         n = max(1, DEVICE_BATCH_BYTES // (rows_per_item * self.shard_size))
         return 1 << (n.bit_length() - 1)
 
-    def _device_map(self, fn, items: np.ndarray, rows_per_item: int):
+    def _device_map(self, fn, items: np.ndarray, rows_per_item: int,
+                    op: str = "codec.encode"):
         """Run fn over items in bounded, power-of-two-bucketed dispatches
         and yield (lo, n, host outputs) per dispatch. XLA compiles one
         program per input SHAPE, so free-running batch sizes (every
         distinct run length the file client flushes) would each pay a
         fresh multi-second compile — with bucketing there are O(log B)
         programs per codec, reused forever. Zero rows encode to zero
-        parity, so the pad rows are simply sliced off by the caller."""
+        parity, so the pad rows are simply sliced off by the caller.
+
+        Traced per dispatch as two stages of ``op``: ``dispatch`` (pad,
+        host -> device, launch) and ``fetch`` (device_get: the wait for
+        the program and device -> host). Bytes make no sense for a
+        launch, so both stages' ``nbytes`` holds the COUNT of items
+        (stripes, for an encode) the dispatch carries."""
         step = self._device_step(rows_per_item)
         for lo in range(0, items.shape[0], step):
             part = items[lo:lo + step]
             n = part.shape[0]
-            bp = _bucket(n)
-            if bp != n:
-                part = np.concatenate(
-                    [part, np.zeros((bp - n,) + part.shape[1:],
-                                    dtype=np.uint8)], axis=0)
-            yield lo, n, jax.device_get(fn(part))
+            with _spans.span(op, "dispatch", nbytes=n):
+                bp = _bucket(n)
+                if bp != n:
+                    part = np.concatenate(
+                        [part, np.zeros((bp - n,) + part.shape[1:],
+                                        dtype=np.uint8)], axis=0)
+                launched = fn(part)
+            with _spans.span(op, "fetch", nbytes=n):
+                got = jax.device_get(launched)
+            yield lo, n, got
 
     # -- encode --------------------------------------------------------------
     def encode_parity(self, data: np.ndarray
@@ -156,14 +168,17 @@ class StripeCodec:
         if not self._use_host():
             shards, crcs = self.encode_batch(data)
             return shards[:, k:], crcs
-        parity = self.rs.encode_host(data)
-        crcs = np.empty((b, k + self.m), dtype=np.uint32)
-        crcs[:, :k] = crc32c_batch_host(
-            np.ascontiguousarray(data).reshape(b * k, s)).reshape(b, k)
-        if self.m:
-            crcs[:, k:] = crc32c_batch_host(
-                np.ascontiguousarray(parity).reshape(b * self.m, s)
-            ).reshape(b, self.m)
+        # the host kernels: a codec.encode op span with code 1 (the device
+        # path's, in encode_batch, has code 0)
+        with _spans.root_span("codec.encode", nbytes=b * k * s, code=1):
+            parity = self.rs.encode_host(data)
+            crcs = np.empty((b, k + self.m), dtype=np.uint32)
+            crcs[:, :k] = crc32c_batch_host(
+                np.ascontiguousarray(data).reshape(b * k, s)).reshape(b, k)
+            if self.m:
+                crcs[:, k:] = crc32c_batch_host(
+                    np.ascontiguousarray(parity).reshape(b * self.m, s)
+                ).reshape(b, self.m)
         return parity, crcs
 
     def encode_batch(self, data: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -177,12 +192,13 @@ class StripeCodec:
             parity, crcs_np = self.encode_parity(data)
             shards_np = np.concatenate([data, parity], axis=1)
             return shards_np, crcs_np
-        shards = np.empty((b, k + self.m, s), dtype=np.uint8)
-        crcs = np.empty((b, k + self.m), dtype=np.uint32)
-        for lo, n, (out_s, out_c) in self._device_map(
-                self._encode_dev, data, k + self.m):
-            shards[lo:lo + n] = out_s[:n]
-            crcs[lo:lo + n] = out_c[:n]
+        with _spans.root_span("codec.encode", nbytes=b * k * s):
+            shards = np.empty((b, k + self.m, s), dtype=np.uint8)
+            crcs = np.empty((b, k + self.m), dtype=np.uint32)
+            for lo, n, (out_s, out_c) in self._device_map(
+                    self._encode_dev, data, k + self.m):
+                shards[lo:lo + n] = out_s[:n]
+                crcs[lo:lo + n] = out_c[:n]
         return shards, crcs
 
     def _encode_device(self, data):
@@ -265,7 +281,7 @@ class StripeCodec:
                        dtype=np.uint8)
         for lo, n, rebuilt in self._device_map(
                 lambda part: fn(jnp.asarray(part)), present,
-                self.k + len(lost_idx)):
+                self.k + len(lost_idx), op="codec.reconstruct"):
             out[lo:lo + n] = rebuilt[:n]
         return out
 
@@ -274,7 +290,8 @@ class StripeCodec:
         if self._use_host():
             return crc32c_batch_host(shards)
         out = np.empty(shards.shape[0], dtype=np.uint32)
-        for lo, n, crcs in self._device_map(self._crc_dev, shards, 1):
+        for lo, n, crcs in self._device_map(self._crc_dev, shards, 1,
+                                            op="codec.crc"):
             out[lo:lo + n] = crcs[:n]
         return out
 

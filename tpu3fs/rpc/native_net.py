@@ -951,8 +951,8 @@ class NativeRpcClient:
 
     @staticmethod
     def _trace_hop():
-        """-> (rpc child context | None, envelope message bytes | None):
-        the trace + deadline + tenant stamping the Python client does in
+        """-> (spans.Hop | None, envelope message bytes | None): the
+        trace + deadline + tenant stamping the Python client does in
         start_call, for the native send entry points (all three ride the
         same envelope message field; rpc/deadline.py,
         tenant/identity.py)."""
@@ -960,28 +960,21 @@ class NativeRpcClient:
         from tpu3fs.rpc import deadline as _dl
         from tpu3fs.tenant import identity as _tid
 
-        ctx = _spans.current_trace()
-        rpc_ctx = ctx.child() if ctx is not None else None
+        hop = _spans.Hop.start()
         msg = _tid.append_wire(
             _dl.encode_envelope(
-                rpc_ctx.to_wire() if rpc_ctx is not None else "",
+                hop.ctx.to_wire() if hop is not None else "",
                 _dl.current_deadline()),
             _tid.current_tenant())
-        return rpc_ctx, (msg.encode() if msg else None)
+        return hop, (msg.encode() if msg else None)
 
     @staticmethod
-    def _trace_finish(rpc_ctx, service_id, method_id, t0, status) -> None:
-        if rpc_ctx is None:
-            return
-        import time as _time
-
-        from tpu3fs.analytics import spans as _spans
-
-        dur = _time.perf_counter() - t0
-        _spans.tracer().end_op(
-            rpc_ctx, f"rpc.client.{service_id}.{method_id}",
-            _time.time() - dur, dur,
-            code=status if status != int(Code.OK) else 0)
+    def _trace_finish(hop, service_id, method_id, t_wait, status) -> None:
+        """The reply is in (the native reply carries no server stamps:
+        issue and collect only)."""
+        if hop is not None:
+            hop.collected(f"rpc.client.{service_id}.{method_id}", t_wait,
+                          code=status if status != int(Code.OK) else 0)
 
     def call_bulk(
         self,
@@ -1008,11 +1001,8 @@ class NativeRpcClient:
         bulk_len = ctypes.c_size_t(0)
         has_bulk = ctypes.c_int(0)
         msg_ptr = ctypes.c_char_p()
-        rpc_ctx, trace_msg = self._trace_hop()
+        hop, trace_msg = self._trace_hop()
         self._fire_send_fault(addr, service_id, method_id)
-        import time as _time
-
-        t0 = _time.perf_counter()
         conn = self._get_conn(addr)
         try:
             rc = self._lib.tpu3fs_rpc_client_call3(
@@ -1041,7 +1031,9 @@ class NativeRpcClient:
             del keepalive
             if conn.lock.locked():
                 conn.lock.release()
-        self._trace_finish(rpc_ctx, service_id, method_id, t0, status.value)
+        # one native call sends and receives: the whole of it is the wait
+        self._trace_finish(hop, service_id, method_id,
+                           hop.t0 if hop is not None else 0.0, status.value)
         return self._unmarshal_reply(status, rsp_ptr, rsp_len, bulk_ptr,
                                      bulk_off, bulk_len, has_bulk, msg_ptr,
                                      rsp_type)
@@ -1064,11 +1056,8 @@ class NativeRpcClient:
         finishing any — the pipelined issue of the striped read fan-out."""
         raw, buf, iov_ptrs, iov_lens, n_iovs, keepalive = \
             self._marshal_req(req, req_type, bulk_iovs)
-        rpc_ctx, trace_msg = self._trace_hop()
+        hop, trace_msg = self._trace_hop()
         self._fire_send_fault(addr, service_id, method_id)
-        import time as _time
-
-        t0 = _time.perf_counter()
         conn = self._get_conn(addr)
         try:
             rc = self._lib.tpu3fs_rpc_client_send(
@@ -1091,20 +1080,16 @@ class NativeRpcClient:
             # failures to, so retry ladders behave identically
             raise FsError(Status(Code.RPC_PEER_CLOSED,
                                  f"{addr}: transport rc={rc}"))
-        if rpc_ctx is not None:
-            from tpu3fs.analytics import spans as _spans
-
-            dur = _time.perf_counter() - t0
-            _spans.add_span(rpc_ctx, "rpc.client", "issue",
-                            _time.time() - dur, dur)
-        return (addr, conn, rsp_type, service_id, method_id, rpc_ctx, t0)
+        if hop is not None:
+            hop.issued()
+        return (addr, conn, rsp_type, service_id, method_id, hop)
 
     def finish_call(self, pending):
         """Collect the reply of a start_call -> (rsp, segments|None)."""
-        addr, conn, rsp_type, service_id, method_id, rpc_ctx, t0 = pending
+        addr, conn, rsp_type, service_id, method_id, hop = pending
         import time as _time
 
-        t1 = _time.perf_counter()
+        t_wait = _time.perf_counter() if hop is not None else 0.0
         status = ctypes.c_int64(0)
         rsp_ptr = ctypes.POINTER(ctypes.c_uint8)()
         rsp_len = ctypes.c_size_t(0)
@@ -1128,16 +1113,7 @@ class NativeRpcClient:
         finally:
             if conn.lock.locked():
                 conn.lock.release()
-        if rpc_ctx is not None:
-            import time as _time
-
-            from tpu3fs.analytics import spans as _spans
-
-            dur = _time.perf_counter() - t1
-            _spans.add_span(rpc_ctx, "rpc.client", "collect",
-                            _time.time() - dur, dur)
-            self._trace_finish(rpc_ctx, service_id, method_id, t0,
-                               status.value)
+        self._trace_finish(hop, service_id, method_id, t_wait, status.value)
         return self._unmarshal_reply(status, rsp_ptr, rsp_len, bulk_ptr,
                                      bulk_off, bulk_len, has_bulk, msg_ptr,
                                      rsp_type)

@@ -733,8 +733,8 @@ class RpcClient:
         # request envelope's message field — a child span id per wire hop
         # so server spans nest under this rpc. Untraced calls pay one
         # ContextVar read and nothing else.
-        tctx = _spans.current_trace()
-        rpc_ctx = tctx.child() if tctx is not None else None
+        hop = _spans.Hop.start()
+        rpc_ctx = hop.ctx if hop is not None else None
         pkt = MessagePacket(
             uuid=uuid_mod.uuid4().hex,
             service_id=service_id,
@@ -786,21 +786,18 @@ class RpcClient:
             code = (Code.RPC_TIMEOUT if isinstance(e, socket.timeout)
                     else Code.RPC_PEER_CLOSED)
             raise FsError(Status(code, f"{addr}: {e}"))
-        if rpc_ctx is not None:
+        if hop is not None:
             # "issue" = serialize + put-on-wire; for MiB-scale bulk frames
             # the blocking send carries most of the wire transfer time, so
             # issue + server stages partition the client-observed latency
-            dur = time.monotonic() - pkt.timestamps.client_build
-            _spans.add_span(
-                rpc_ctx, "rpc.client", "issue", time.time() - dur, dur,
-                nbytes=(sum(len(b) for b in bulk_iovs)
-                        if bulk_iovs else len(pkt.payload)))
-        return (addr, conn, pkt, rsp_type, rpc_ctx)
+            hop.issued(sum(len(b) for b in bulk_iovs)
+                       if bulk_iovs else len(pkt.payload))
+        return (addr, conn, pkt, rsp_type, hop)
 
     def finish_call(self, pending):
         """Collect the reply of a start_call -> (rsp, reply_segments|None)."""
-        addr, conn, pkt, rsp_type, rpc_ctx = pending
-        t0 = time.monotonic()
+        addr, conn, pkt, rsp_type, hop = pending
+        t_wait = time.perf_counter() if hop is not None else 0.0
         try:
             try:
                 reply, reply_bulk = _recv_packet(conn.sock)
@@ -821,33 +818,29 @@ class RpcClient:
         finally:
             if conn.lock.locked():
                 conn.lock.release()
-        if rpc_ctx is not None:
-            now = time.monotonic()
-            total = now - pkt.timestamps.client_build
-            _spans.add_span(rpc_ctx, "rpc.client", "collect",
-                            time.time() - (now - t0), now - t0)
+        server, t_decode = None, 0.0
+        if hop is not None:
+            # the server's stamps share the server's monotonic clock, so
+            # their differences are valid cross-process: the hop's
+            # server_wait / server_run stages, and "wire" — the collect
+            # wait MINUS the server's receive->run_end window (frame
+            # receive on the server, reply serialize/send/receive/decode:
+            # the residue that would otherwise be invisible)
             rts = reply.timestamps
-            if rts.server_run_end >= rts.server_receive > 0:
-                # "wire" = the collect wait MINUS the server's
-                # receive->run_end window (which the server's own spans
-                # attribute): frame receive on the server, reply
-                # serialize/send/receive/decode — the residue that would
-                # otherwise be invisible in the stage breakdown. The two
-                # server stamps share the server's monotonic clock, so
-                # their difference is valid cross-process.
-                wire = (now - t0) - (rts.server_run_end
-                                     - rts.server_receive)
-                if wire > 0:
-                    _spans.add_span(rpc_ctx, "rpc.client", "wire",
-                                    time.time() - (now - t0), wire)
-            _spans.tracer().end_op(
-                rpc_ctx, f"rpc.client.{pkt.service_id}.{pkt.method_id}",
-                time.time() - total, total,
-                code=reply.status if reply.status != int(Code.OK) else 0)
+            if rts.server_run_end >= rts.server_run_start \
+                    >= rts.server_receive > 0:
+                server = (rts.server_run_start - rts.server_receive,
+                          rts.server_run_end - rts.server_run_start)
+            t_decode = time.perf_counter()
+        op = f"rpc.client.{pkt.service_id}.{pkt.method_id}"
         if reply.status != int(Code.OK):
+            if hop is not None:
+                hop.collected(op, t_wait, code=reply.status, server=server)
             raise FsError(Status(Code(reply.status), reply.message))
         reply.timestamps.client_done = time.monotonic()
         rsp = deserialize(reply.payload, rsp_type)
+        if hop is not None:
+            hop.collected(op, t_wait, server=server, t_decode=t_decode)
         return rsp, reply_bulk
 
     def close(self) -> None:

@@ -17,6 +17,7 @@ import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.meta.store import (
     BatchCloseItem,
     BatchCreateItem,
@@ -1786,6 +1787,21 @@ def _open_rsp(res: OpenResult) -> OpenRsp:
     return OpenRsp(res.inode, res.session_id)
 
 
+#: MetaSerde method id -> registry name (the ``s.method`` rows of
+#: bind_meta_service; tests/test_trace.py pins the two against each
+#: other): what the client's ``meta.<method>`` op spans are named from
+META_METHOD_NAMES = {
+    1: "statFs", 2: "stat", 3: "create", 4: "mkdirs", 5: "symlink",
+    6: "hardLink", 7: "remove", 8: "open", 9: "sync", 10: "close",
+    11: "rename", 12: "list", 13: "truncate", 14: "getRealPath",
+    15: "setAttr", 16: "pruneSession", 17: "batchStat", 18: "authenticate",
+    19: "setXattr", 20: "getXattr", 21: "listXattrs", 22: "removeXattr",
+    23: "batchClose", 24: "batchSetAttr", 25: "batchCreate",
+    26: "batchMkdirs", 27: "renamePrepare", 28: "renameFinish",
+    29: "renameResolve",
+}
+
+
 class MetaRpcClient:
     """Full meta API over RPC with server failover
     (ref MetaClient.h:55-226 + ServerSelectionStrategy).
@@ -1850,6 +1866,14 @@ class MetaRpcClient:
         return (node.host, node.port)
 
     def _call(self, method_id: int, req, rsp_type, *, pid: Optional[int] = None):
+        """The one place every meta call passes: one ``meta.<method>`` op
+        span a call (a batched op that fans out per partition is one span
+        a partition call), the RPC hop's stages beneath it."""
+        with _spans.root_span(
+                f"meta.{META_METHOD_NAMES.get(method_id, method_id)}"):
+            return self._call_op(method_id, req, rsp_type, pid=pid)
+
+    def _call_op(self, method_id: int, req, rsp_type, *, pid: Optional[int]):
         if self.token and hasattr(req, "token") and not req.token:
             req.token = self.token
         if pid is not None and self._mgmtd is not None:
@@ -1913,9 +1937,14 @@ class MetaRpcClient:
             for (i, _), r in zip(pairs, res):
                 out[i] = r
 
+        import contextvars
         from concurrent.futures import ThreadPoolExecutor
+
+        # each partition call runs under the caller's context (traffic
+        # class, tenant, deadline, trace): a fresh pool thread has none
         with ThreadPoolExecutor(max_workers=min(8, len(groups))) as ex:
-            for f in [ex.submit(run, pid, pairs)
+            for f in [ex.submit(contextvars.copy_context().run, run, pid,
+                                pairs)
                       for pid, pairs in groups.items()]:
                 f.result()
         return out

@@ -73,6 +73,25 @@ assert SQE_SIZE == 224
 RSP_HDR = struct.Struct("<IIII")
 
 
+def pack_stamps(wait_s: float, run_s: float) -> int:
+    """The serving side's two durations of an RPC-mode op — SQE dequeue to
+    handler start, and the handler — as the CQE's third word: microseconds,
+    wait + 1 in the high 32 bits and run in the low (saturating), so that
+    0 stays "no stamps" (file-mode ops, older agents). What the socket
+    reply's Timestamps carry, for the ring."""
+    cap = 0xFFFFFFFF
+    wait_us = min(cap - 1, max(0, int(wait_s * 1e6)))
+    run_us = min(cap, max(0, int(run_s * 1e6)))
+    return ((wait_us + 1) << 32) | run_us
+
+
+def unpack_stamps(word: int):
+    """-> (wait_s, run_s), or None where the CQE carried none."""
+    if not word >> 32:
+        return None
+    return ((word >> 32) - 1) / 1e6, (word & 0xFFFFFFFF) / 1e6
+
+
 #: handshake nonce files (usrbio/server.py): name embeds the serving pid
 #: as ``tpu3fs-hs-<pid>-<hex>`` so the reaper can collect crashed hosts'
 HS_PREFIX = "tpu3fs-hs-"
@@ -331,15 +350,19 @@ class IoRing:
             if not self.complete_sem.wait(timeout):
                 return out  # timeout: possibly partial
 
-    def reap(self):
-        """Consume all available CQEs (non-blocking)."""
+    def reap(self, *, with_stamps: bool = False):
+        """Consume all available CQEs (non-blocking) -> [(result,
+        userdata)], or with ``with_stamps`` [(result, userdata, stamps)]
+        (the CQE's third word: see pack_stamps)."""
         _, _, cq_h, cq_t = self._counters()
         out = []
         while cq_h < cq_t:
             slot = cq_h % self.entries
             off = self._cq_base + slot * CQE_SIZE
-            result, userdata, _ = _CQE.unpack(self.buf[off : off + CQE_SIZE])
-            out.append((result, userdata))
+            result, userdata, stamps = _CQE.unpack(
+                self.buf[off : off + CQE_SIZE])
+            out.append((result, userdata, stamps) if with_stamps
+                       else (result, userdata))
             cq_h += 1
         self._set_counter(2, cq_h)  # cq_head
         return out
@@ -358,11 +381,11 @@ class IoRing:
         self._set_counter(0, sq_h)  # sq_head
         return out
 
-    def push_cqe(self, result: int, userdata: int) -> None:
+    def push_cqe(self, result: int, userdata: int, stamps: int = 0) -> None:
         _, _, cq_h, cq_t = self._counters()
         slot = cq_t % self.entries
         off = self._cq_base + slot * CQE_SIZE
-        self.buf[off : off + CQE_SIZE] = _CQE.pack(result, userdata, 0)
+        self.buf[off : off + CQE_SIZE] = _CQE.pack(result, userdata, stamps)
         self._set_counter(3, cq_t + 1)  # cq_tail
         self.complete_sem.post()
 

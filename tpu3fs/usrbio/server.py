@@ -38,6 +38,7 @@ from tpu3fs.usrbio.ring import (
     SHM_DIR,
     Iov,
     IoRing,
+    pack_stamps,
     reap_stale_shm,
     validate_shm_name,
 )
@@ -199,8 +200,9 @@ class UsrbioRpcHost:
         with self._depth_lock:
             self._depth += 1
             recs["agent_depth"].set(self._depth)
+        stamps = 0
         try:
-            result = self._process_rpc_sqe(state, sqe)
+            result, stamps = self._process_rpc_sqe(state, sqe)
         except FsError as e:
             result = -int(e.code)
         except Exception:
@@ -213,24 +215,26 @@ class UsrbioRpcHost:
                 recs["agent_depth"].set(self._depth)
         try:
             with state.cq_lock:
-                state.ring.push_cqe(result, sqe.userdata)
+                state.ring.push_cqe(result, sqe.userdata, stamps)
         except (ValueError, FsError):
             pass  # ring torn down mid-op
         recs["completed"].add()
 
-    def _process_rpc_sqe(self, state: _RingState, sqe) -> int:
+    def _process_rpc_sqe(self, state: _RingState, sqe):
         """One RPC-mode SQE -> dispatched reply staged in the client's
-        reply region; -> total reply bytes or -Code."""
+        reply region; -> (total reply bytes or -Code, the CQE's stamps
+        word: the dispatch's receive->run_start and run_start->run_end,
+        which a socket reply carries in its Timestamps)."""
         if not sqe.is_rpc:
-            return -int(Code.USRBIO_UNSUPPORTED)
+            return -int(Code.USRBIO_UNSUPPORTED), 0
         if (sqe.service_id, sqe.method_id) not in RING_METHODS:
-            return -int(Code.USRBIO_UNSUPPORTED)
+            return -int(Code.USRBIO_UNSUPPORTED), 0
         iov = state.iov
         if sqe.iov_id != 0:
-            return -int(Code.USRBIO_BAD_IOV)
+            return -int(Code.USRBIO_BAD_IOV), 0
         if sqe.iov_offset + sqe.length > iov.size \
                 or sqe.rsp_offset + sqe.rsp_capacity > iov.size:
-            return -int(Code.USRBIO_BAD_IOV)
+            return -int(Code.USRBIO_BAD_IOV), 0
         region = iov.view(sqe.iov_offset, sqe.length)
         payload, bulk = parse_request(region, sqe.has_bulk)
         pkt = MessagePacket(
@@ -252,10 +256,15 @@ class UsrbioRpcHost:
                             reply.status, reply.message, reply.payload,
                             reply_iovs)
         if total < 0:
-            return -int(Code.USRBIO_REPLY_OVERFLOW)
+            return -int(Code.USRBIO_REPLY_OVERFLOW), 0
         nbytes = (sum(len(b) for b in bulk) if bulk else 0) + total
         recorders()["bytes"].add(nbytes)
-        return total
+        ts = reply.timestamps
+        stamps = 0
+        if ts.server_run_end >= ts.server_run_start >= ts.server_receive > 0:
+            stamps = pack_stamps(ts.server_run_start - ts.server_receive,
+                                 ts.server_run_end - ts.server_run_start)
+        return total, stamps
 
     # -- lifecycle -----------------------------------------------------------
     def reap_pass(self, *, iov_max_age_s: float = 3600.0) -> List[str]:

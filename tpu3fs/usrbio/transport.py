@@ -36,7 +36,14 @@ from typing import Dict, List, Optional, Tuple
 
 from tpu3fs.rpc.net import pack_bulk_header, split_bulk
 from tpu3fs.rpc.serde import deserialize, serialize
-from tpu3fs.usrbio.ring import RSP_HDR, TOKEN_CAP, Iov, IoRing, _pid_alive
+from tpu3fs.usrbio.ring import (
+    RSP_HDR,
+    TOKEN_CAP,
+    Iov,
+    IoRing,
+    _pid_alive,
+    unpack_stamps,
+)
 from tpu3fs.utils.result import Code, FsError, Status
 
 #: control-plane service the storage binary binds for ring registration
@@ -338,18 +345,17 @@ def _cleanup_shm(ring: IoRing, iov: Iov) -> None:
 
 class _Pending:
     __slots__ = ("userdata", "rsp_type", "req_off", "req_size",
-                 "rsp_off", "rsp_cap", "rpc_ctx", "t0", "nbytes")
+                 "rsp_off", "rsp_cap", "hop", "nbytes")
 
     def __init__(self, userdata, rsp_type, req_off, req_size, rsp_off,
-                 rsp_cap, rpc_ctx, t0, nbytes):
+                 rsp_cap, hop, nbytes):
         self.userdata = userdata
         self.rsp_type = rsp_type
         self.req_off = req_off
         self.req_size = req_size
         self.rsp_off = rsp_off
         self.rsp_cap = rsp_cap
-        self.rpc_ctx = rpc_ctx
-        self.t0 = t0
+        self.hop = hop   # spans.Hop of a traced call, else None
         self.nbytes = nbytes
 
 
@@ -375,7 +381,7 @@ class RingClient:
         self._arena = _ShmArena(self.iov)
         self._sq_lock = threading.Lock()
         self._cv = threading.Condition()
-        self._done: Dict[int, int] = {}
+        self._done: Dict[int, tuple] = {}   # userdata -> (result, stamps)
         #: ops whose caller gave up at a per-call deadline while the op
         #: was still in flight: userdata -> ((req_off, req_size),
         #: (rsp_off, rsp_cap)). The agent may yet read the request and
@@ -401,9 +407,8 @@ class RingClient:
 
         if self.closed:
             raise FsError(Status(Code.USRBIO_AGENT_GONE, "ring closed"))
-        tctx = _spans.current_trace()
-        rpc_ctx = tctx.child() if tctx is not None else None
-        token = encode_envelope_message(rpc_ctx)
+        hop = _spans.Hop.start()
+        token = encode_envelope_message(hop.ctx if hop is not None else None)
         if len(token.encode("utf-8")) > TOKEN_CAP:
             raise FsError(Status(
                 Code.USRBIO_BAD_IOV,
@@ -420,7 +425,6 @@ class RingClient:
             self._arena.free(req_off, req_size)
             raise FsError(Status(Code.USRBIO_RING_FULL,
                                  f"iov arena exhausted ({rsp_cap}B rsp)"))
-        t0 = time.monotonic()
         try:
             stage_request(self.iov, req_off, payload, bulk_iovs)
             with self._sq_lock:
@@ -443,12 +447,10 @@ class RingClient:
             raise
         nbytes = (sum(len(b) for b in bulk_iovs)
                   if bulk_iovs else len(payload))
-        if rpc_ctx is not None:
-            dur = time.monotonic() - t0
-            _spans.add_span(rpc_ctx, "rpc.client", "issue",
-                            time.time() - dur, dur, nbytes=nbytes)
+        if hop is not None:
+            hop.issued(nbytes)
         return _Pending(ud, rsp_type, req_off, req_size, rsp_off, rsp_cap,
-                        rpc_ctx, t0, nbytes)
+                        hop, nbytes)
 
     # -- collect -------------------------------------------------------------
     def finish(self, pending: _Pending, *,
@@ -461,16 +463,17 @@ class RingClient:
         RPC_TIMEOUT and the op is ABANDONED — its arena regions move to
         ``_abandoned`` and are reclaimed when the late CQE lands, never
         freed under an agent that may still be reading/writing them."""
-        from tpu3fs.analytics import spans as _spans
-
-        t_wait = time.monotonic()
+        hop = pending.hop
+        t_wait = time.perf_counter() if hop is not None else 0.0
         try:
-            result = self._await(pending.userdata, deadline_s=deadline_s)
+            result, stamps = self._await(pending.userdata,
+                                         deadline_s=deadline_s)
         except FsError as e:
             self._give_up(pending, e)
             raise
         self._arena.free(pending.req_off, pending.req_size)
-        rpc_ctx = pending.rpc_ctx
+        # what follows is this side's: the reply out of shm into objects
+        t_decode = time.perf_counter() if hop is not None else 0.0
         if result < 0:
             self._arena.free(pending.rsp_off, pending.rsp_cap)
             try:
@@ -486,15 +489,13 @@ class RingClient:
             status, message, payload, bulk = parse_reply(region, result)
         finally:
             del region
-        if rpc_ctx is not None:
-            now = time.monotonic()
-            _spans.add_span(rpc_ctx, "rpc.client", "collect",
-                            time.time() - (now - t_wait), now - t_wait)
-            total = now - pending.t0
-            _spans.tracer().end_op(
-                rpc_ctx, "rpc.client.ring", time.time() - total, total,
-                code=status if status != int(Code.OK) else 0)
+        # the serving side's two durations ride the CQE's third word
+        # (ring.pack_stamps), as a socket reply's Timestamps do
+        server = unpack_stamps(stamps) if hop is not None else None
         if status != int(Code.OK):
+            if hop is not None:
+                hop.collected("rpc.client.ring", t_wait, code=status,
+                              server=server, t_decode=t_decode)
             try:
                 code = Code(status)
             except ValueError:
@@ -504,6 +505,9 @@ class RingClient:
                 code = Code.INTERNAL
             raise FsError(Status(code, message))
         rsp = deserialize(payload, pending.rsp_type)
+        if hop is not None:
+            hop.collected("rpc.client.ring", t_wait, server=server,
+                          t_decode=t_decode)
         return rsp, bulk
 
     def call(self, service_id: int, method_id: int, req, rsp_type, *,
@@ -534,8 +538,8 @@ class RingClient:
                     (pending.req_off, pending.req_size),
                     (pending.rsp_off, pending.rsp_cap))
 
-    def _await(self, ud: int, *, deadline_s: Optional[float] = None) -> int:
-        """Wait for `ud`'s CQE. Many threads may wait concurrently: one of
+    def _await(self, ud: int, *, deadline_s: Optional[float] = None):
+        """Wait for `ud`'s CQE -> (result, stamps word). Many threads may wait concurrently: one of
         them at a time plays reaper (semaphore wait + reap + publish),
         the rest sleep on the condition. A caller ``deadline_s`` raises
         RPC_TIMEOUT (the op stays in flight — finish() abandons it);
@@ -567,7 +571,7 @@ class RingClient:
                     raise FsError(Status(
                         code, f"no completion in {timeout}s"))
                 self.ring.complete_sem.wait(timeout=min(0.2, remaining))
-                cqes = self.ring.reap()
+                cqes = self.ring.reap(with_stamps=True)
                 if not cqes and self._agent_pid \
                         and not _pid_alive(self._agent_pid):
                     raise FsError(Status(
@@ -589,14 +593,14 @@ class RingClient:
             with self._cv:
                 self._reaping = False
                 if cqes:
-                    for result, u in cqes:
+                    for result, u, stamps in cqes:
                         regions = self._abandoned.pop(u, None)
                         if regions is not None:
                             # the caller left at its deadline: reclaim
                             for off, size in regions:
                                 self._arena.free(off, size)
                         else:
-                            self._done[u] = result
+                            self._done[u] = (result, stamps)
                 self._cv.notify_all()
 
     def close(self) -> None:
