@@ -95,8 +95,9 @@ class _EcCluster:
         self.server_of_node = {}
         node_states: dict = {n: {} for n in self.node_ids}
         for node_id in self.node_ids:
-            mcli = MgmtdRpcClient(self.mgmtd_addr, self.shared_client,
-                                  routing_ttl_s=0.2)
+            # servers ask mgmtd per call: nothing heartbeats them here, and
+            # the rebuild phase needs them to see SYNCING when it happens
+            mcli = MgmtdRpcClient(self.mgmtd_addr, self.shared_client)
             svc = StorageService(node_id, mcli.refresh_routing)
             svc.set_messenger(RpcMessenger(mcli.refresh_routing,
                                            self.shared_client))
@@ -152,17 +153,15 @@ class _EcCluster:
         from tpu3fs.client.storage_client import StorageClient
 
         self._client_seq += 1
-        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client,
-                                   routing_ttl_s=0.2)
-        messenger = self._messenger_cls(mcli.refresh_routing,
+        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client)
+        messenger = self._messenger_cls(mcli.cached_routing,
                                         self.shared_client)
         return StorageClient(f"ec-bench-{self._client_seq}",
-                             mcli.refresh_routing, messenger, **kw)
+                             mcli.cached_routing, messenger, **kw)
 
     def messenger(self):
-        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client,
-                                   routing_ttl_s=0.2)
-        return self._messenger_cls(mcli.refresh_routing, self.shared_client)
+        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client)
+        return self._messenger_cls(mcli.cached_routing, self.shared_client)
 
     def close(self) -> None:
         self.shared_client.close()
@@ -497,8 +496,7 @@ def run_bench(*, k: int = 4, m: int = 2, stripes: int = 48,
             if all(t.public_state == PublicTargetState.SERVING
                    for t in chain.targets):
                 break
-            # let the 0.2s routing TTLs expire so every party sees the
-            # SYNCING transition (wall-clock noise, not rebuild time —
+            # a beat between rounds (wall-clock noise, not rebuild time —
             # mibps below comes from the worker's own round timing)
             time.sleep(0.25)
         dt = time.perf_counter() - t0

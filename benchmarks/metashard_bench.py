@@ -183,7 +183,7 @@ def worker_main(args) -> int:
     own directory set, routed per-op through the partition table."""
     from tpu3fs.rpc.services import MetaRpcClient, MgmtdRpcClient
 
-    mg = MgmtdRpcClient(("127.0.0.1", args.mgmtd_port), routing_ttl_s=5.0)
+    mg = MgmtdRpcClient(("127.0.0.1", args.mgmtd_port))
     ri = mg.refresh_routing()
     meta_addrs = [(n.host, n.port) for n in ri.nodes.values()
                   if n.node_id >= 201 and n.host]

@@ -304,13 +304,12 @@ class _RpcCluster:
         services = []
         svc_by_node = {}
         for node_id in node_ids:
-            # TTL-cached routing: per-op getRoutingInfo round trips were a
+            # the held snapshot: per-op getRoutingInfo round trips were a
             # measured double-digit share of served-read time; the bench
             # cluster's routing is static, retries invalidate anyway
-            mcli = MgmtdRpcClient(self.mgmtd_addr, self.shared_client,
-                                  routing_ttl_s=1.0)
-            svc = StorageService(node_id, mcli.refresh_routing)
-            svc.set_messenger(RpcMessenger(mcli.refresh_routing,
+            mcli = MgmtdRpcClient(self.mgmtd_addr, self.shared_client)
+            svc = StorageService(node_id, mcli.cached_routing)
+            svc.set_messenger(RpcMessenger(mcli.cached_routing,
                                            self.shared_client))
             server = ServerCls()
             bind_storage_service(server, svc)
@@ -358,12 +357,11 @@ class _RpcCluster:
         from tpu3fs.client.storage_client import StorageClient
 
         self._client_seq += 1
-        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client,
-                                   routing_ttl_s=1.0)
-        messenger = self._messenger_cls(mcli.refresh_routing,
+        mcli = self._mgmtd_cli_cls(self.mgmtd_addr, self.shared_client)
+        messenger = self._messenger_cls(mcli.cached_routing,
                                         self.shared_client)
         return StorageClient(f"bench-rpc-{self._client_seq}",
-                             mcli.refresh_routing, messenger, **kw)
+                             mcli.cached_routing, messenger, **kw)
 
     def close(self) -> None:
         self.shared_client.close()

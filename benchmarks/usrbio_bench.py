@@ -99,11 +99,10 @@ class _SubprocCluster:
     def routing_provider(self):
         from tpu3fs.rpc.services import MgmtdAdminRpcClient
 
-        # TTL-cached routing (the served-read production shape, PR 3):
-        # without it every batch pays getRoutingInfo round trips that
+        # clients resolve through its cached_routing (the served-read
+        # production shape): a getRoutingInfo round trip a batch would
         # mask the transport difference being measured
-        return MgmtdAdminRpcClient(("127.0.0.1", self.mport),
-                                   routing_ttl_s=5.0)
+        return MgmtdAdminRpcClient(("127.0.0.1", self.mport))
 
     def stop(self) -> None:
         for p in self.procs:
@@ -164,8 +163,7 @@ class _InprocCluster:
     def routing_provider(self):
         from tpu3fs.rpc.services import MgmtdRpcClient
 
-        return MgmtdRpcClient(self._mgmtd_server.address, self._shared,
-                              routing_ttl_s=5.0)
+        return MgmtdRpcClient(self._mgmtd_server.address, self._shared)
 
     def stop(self) -> None:
         self.host.stop()
@@ -181,9 +179,9 @@ def _mk_client(cluster, tag: str, ring: bool, iov_mb: int):
         os.environ["TPU3FS_USRBIO"] = "0"
     try:
         mcli = cluster.routing_provider()
-        m = RpcMessenger(mcli.refresh_routing)
+        m = RpcMessenger(mcli.cached_routing)
         m._usrbio_iov_bytes = iov_mb << 20
-        sc = StorageClient(tag, mcli.refresh_routing, m,
+        sc = StorageClient(tag, mcli.cached_routing, m,
                            retry=RetryOptions(max_retries=2,
                                               backoff_base_s=0.01))
         return sc, m

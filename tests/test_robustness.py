@@ -27,7 +27,7 @@ from tpu3fs.utils.fault_injection import (
     parse_spec,
     plane,
 )
-from tpu3fs.utils.result import Code, FsError
+from tpu3fs.utils.result import Code, FsError, Status
 
 
 # -- deadline wire codec ------------------------------------------------------
@@ -428,7 +428,7 @@ class TestHedging:
             for t in chain.targets[1:]:
                 n = routing.node_of_target(t.target_id).node_id
                 sc._health.observe(n, 0.001, ok=True)
-            order = sc._pick_targets(chain)
+            order = sc._pick_targets(chain, routing)
             gray_targets = {t.target_id for t in chain.targets
                             if routing.node_of_target(t.target_id).node_id
                             == gray}
@@ -576,7 +576,7 @@ class TestMgmtdHotKnobs:
     def test_known_routing_version(self):
         from tpu3fs.mgmtd.types import RoutingInfo
 
-        c = MgmtdRpcClient(("127.0.0.1", 1), routing_ttl_s=30.0)
+        c = MgmtdRpcClient(("127.0.0.1", 1))
         assert c.known_routing_version() == -1
         ri = RoutingInfo()
         ri.version = 9
@@ -585,6 +585,162 @@ class TestMgmtdHotKnobs:
         assert c.known_routing_version() == 9
         c.invalidate_routing()
         assert c._routing_ts == float("-inf")
+
+
+# -- the library client's routing snapshot (unit level) ------------------------
+
+
+class TestRoutingSnapshot:
+    """MgmtdRpcClient.cached_routing against a scripted mgmtd (the live
+    cluster side is tests/test_cli_rpc.py)."""
+
+    @staticmethod
+    def _client(answers):
+        """A client whose getRoutingInfo replies come from ``answers``
+        (versions; None = 'unchanged'); other calls raise like a dead peer."""
+        from tpu3fs.mgmtd.types import RoutingInfo
+        from tpu3fs.rpc.services import RoutingRsp
+
+        c = MgmtdRpcClient(("127.0.0.1", 1))
+        c.asked = []
+
+        def scripted(method_id, req, rsp_type):
+            if method_id != 2:
+                raise FsError(Status(Code.RPC_TIMEOUT, "scripted"))
+            c.asked.append(req.known_version)
+            ver = answers.pop(0)
+            if callable(ver):
+                ver = ver()
+            if ver is None:
+                return RoutingRsp(changed=False, routing=None)
+            return RoutingRsp(changed=True, routing=RoutingInfo(version=ver))
+
+        c._failover_call = scripted
+        return c
+
+    def test_held_snapshot_is_served_and_polls_are_version_gated(self):
+        c = self._client([3, None])
+        assert c.cached_routing().version == 3
+        for _ in range(10):
+            assert c.cached_routing().version == 3
+        assert c.asked == [-1]
+        c.invalidate_routing()
+        assert c.cached_routing().version == 3
+        assert c.asked == [-1, 3]
+        assert (c.routing_polls._value, c.routing_cached._value) == (2, 10)
+
+    def test_an_older_answer_is_never_installed(self):
+        c = self._client([7, 5])
+        assert c.refresh_routing().version == 7
+        assert c.refresh_routing().version == 7  # lagging standby said 5
+
+    def test_a_poll_in_flight_across_an_invalidation_does_not_stamp_fresh(
+            self):
+        # the reply "unchanged" was computed BEFORE the mutation that
+        # invalidated: it must not make the stale snapshot look fresh
+        def unchanged_then_invalidated():
+            c.invalidate_routing()
+            return None
+
+        c = self._client([4, unchanged_then_invalidated, 6])
+        c.refresh_routing()
+        assert c.cached_routing().version == 4   # held, fresh
+        c.invalidate_routing()
+        assert c.cached_routing().version == 4   # polled, raced
+        assert c.cached_routing().version == 6   # so it polls again
+        assert c.asked == [-1, 4, 4]
+
+    def test_threads_polling_and_invalidating_never_see_routing_go_back(
+            self):
+        """More threads than cores resolve, invalidate and refresh against
+        a mgmtd whose version moves on and whose standby sometimes answers
+        with an older snapshot: no reader ever sees a version below one it
+        saw before, and the newest version is reached."""
+        import itertools
+        import sys
+
+        from tpu3fs.mgmtd.types import RoutingInfo
+        from tpu3fs.rpc.services import RoutingRsp
+
+        c = MgmtdRpcClient(("127.0.0.1", 1))
+        clock = itertools.count(1)
+
+        def scripted(method_id, req, rsp_type):
+            n = next(clock)
+            ver = n - 3 if n % 5 == 0 else n   # a lagging standby
+            return RoutingRsp(changed=True, routing=RoutingInfo(version=ver))
+
+        c._failover_call = scripted
+        stop = time.monotonic() + 0.6
+        went_back = []
+
+        def worker(i):
+            seen = 0
+            while time.monotonic() < stop:
+                if i % 3 == 0:
+                    c.invalidate_routing()
+                ver = (c.refresh_routing() if i % 3 == 1
+                       else c.cached_routing()).version
+                if ver < seen:
+                    went_back.append((i, seen, ver))
+                seen = ver
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=worker, args=(i,))
+                       for i in range(12)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+        assert went_back == []
+        assert c.refresh_routing().version >= next(clock) - 4
+
+    @pytest.mark.parametrize("method_id,invalidates", [
+        (3, True), (4, True), (5, True), (6, True), (9, True), (10, True),
+        (11, True), (12, True), (17, True), (18, True),
+        (1, False), (7, False), (8, False), (13, False), (14, False),
+    ])
+    def test_calls_that_change_routing_invalidate_even_when_they_fail(
+            self, method_id, invalidates):
+        c = self._client([2])
+        c.refresh_routing()
+        with pytest.raises(FsError):
+            c._call(method_id, None, None)
+        assert (c._routing_ts == float("-inf")) == invalidates
+
+    @pytest.mark.parametrize("shape", ["bound_method", "callable_object",
+                                       "plain_function"])
+    def test_storage_client_finds_the_invalidation_hook(self, shape):
+        from tpu3fs.mgmtd.types import RoutingInfo, routing_invalidator
+
+        if shape == "bound_method":
+            c = self._client([1])
+            provider, expect = c.cached_routing, c.invalidate_routing
+        elif shape == "callable_object":
+            class Provider:
+                def __call__(self):
+                    return RoutingInfo()
+
+                def invalidate(self):
+                    pass
+
+            provider = Provider()
+            expect = provider.invalidate
+        else:
+            provider, expect = (lambda: RoutingInfo()), None
+        hook = routing_invalidator(provider)
+        sc = StorageClient("hook", provider, lambda *a: None)
+        if expect is None:
+            assert hook() is None  # nothing held, nothing to expire
+        else:
+            assert hook == expect
+            assert sc._routing_invalidate == expect
+        sc.close()
 
 
 # -- idempotency table --------------------------------------------------------

@@ -110,3 +110,30 @@ class TestStubFactory:
         finally:
             sc.close()
             stubs.close()
+
+    def test_storage_stub_resolves_against_a_held_snapshot(
+            self, socket_cluster):
+        """The factory's storage client polls mgmtd for routing once, not
+        once an op, and its retry ladders can still expire the snapshot."""
+        stubs = StubFactory(transport="tcp",
+                            mgmtd_addr=socket_cluster.mgmtd_addr)
+        sc = stubs.storage_client("stub-snap")
+        try:
+            mcli = stubs.mgmtd_client()
+            assert sc._routing == mcli.cached_routing
+            assert sc._routing_invalidate == mcli.invalidate_routing
+            chain = socket_cluster.chain_ids[0]
+            for i in range(16):
+                assert sc.write_chunk(chain, ChunkId(2, i), 0, b"s%d" % i,
+                                      chunk_size=4096).ok
+            for i in range(16):
+                assert sc.read_chunk(chain, ChunkId(2, i)).data == b"s%d" % i
+            assert mcli.routing_polls._value == 1
+            assert mcli.routing_cached._value >= 32
+            # a direct refresh on the same client still asks every time
+            before = mcli._routing_ts
+            mcli.refresh_routing()
+            assert mcli._routing_ts > before
+        finally:
+            sc.close()
+            stubs.close()

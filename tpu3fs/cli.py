@@ -1818,7 +1818,10 @@ class RpcFabricView:
         self._storage_id_base = f"{client_id}-{uuid.uuid4().hex[:8]}"
         self._storage_seq = itertools.count(1)
         self.mgmtd = MgmtdAdminRpcClient(mgmtd_addr, self._rpc)
-        self._messenger = RpcMessenger(self.mgmtd.refresh_routing, self._rpc)
+        # data-plane ops resolve against the snapshot the mgmtd client
+        # holds (polled at a fixed interval and whenever something says it
+        # is stale); routing() below, the operator's read, asks every time
+        self._messenger = RpcMessenger(self.mgmtd.cached_routing, self._rpc)
         self._StorageClient = StorageClient
         self._FileIoClient = FileIoClient
         meta_addrs = [
@@ -1846,7 +1849,7 @@ class RpcFabricView:
     def storage_client(self, **kw):
         return self._StorageClient(
             f"{self._storage_id_base}-{next(self._storage_seq)}",
-            self.mgmtd.refresh_routing, self._messenger, **kw)
+            self.mgmtd.cached_routing, self._messenger, **kw)
 
     def file_client(self, **kw):
         return self._FileIoClient(self.storage_client(**kw))

@@ -19,7 +19,13 @@ from collections import defaultdict
 from dataclasses import dataclass, replace
 from typing import Callable, Dict, List, Optional, Tuple
 
-from tpu3fs.mgmtd.types import ChainInfo, NodeType, PublicTargetState, RoutingInfo
+from tpu3fs.mgmtd.types import (
+    ChainInfo,
+    NodeType,
+    PublicTargetState,
+    RoutingInfo,
+    routing_invalidator,
+)
 from tpu3fs.storage.craq import (
     Messenger,
     ReadReply,
@@ -145,14 +151,11 @@ class StorageClient:
     ):
         self.client_id = client_id
         self._routing = routing_provider
-        # TTL-cached providers (MgmtdRpcClient with routing_ttl_s) expose
-        # an invalidation hook; retry ladders call it before re-resolving
-        # so failover convergence never waits out the cache TTL
-        owner = getattr(routing_provider, "__self__", None)
-        self._routing_invalidate = (
-            getattr(routing_provider, "invalidate", None)
-            or getattr(owner, "invalidate_routing", None)
-            or (lambda: None))
+        # a provider that holds a snapshot (MgmtdRpcClient.cached_routing,
+        # what the client factories hand out) has an invalidation hook;
+        # retry ladders and resolves that missed call it before resolving
+        # again, so convergence never waits out the poll interval
+        self._routing_invalidate = routing_invalidator(routing_provider)
         self._messenger = messenger
         self._retry = retry or RetryOptions()
         self._selection = selection
@@ -257,11 +260,38 @@ class StorageClient:
                     self, WorkerPool.shutdown, self._pool, False)
             pool = self._pool
         pool.map(fn, items)
-    def _chain(self, chain_id: int) -> ChainInfo:
-        chain = self._routing().chains.get(chain_id)
+
+    def _repoll(self) -> RoutingInfo:
+        """Expire the provider's held snapshot and ask again: what a
+        resolve owes a chain or a target's node the snapshot does not
+        know, once, before the miss goes back to the caller."""
+        self._routing_invalidate()
+        return self._routing()
+
+    def _route(self, chain_id: int) -> Tuple[RoutingInfo, ChainInfo]:
+        """A chain and the snapshot it came from: one attempt resolves
+        chain, targets and nodes against ONE routing version."""
+        routing = self._routing()
+        chain = routing.chains.get(chain_id)
         if chain is None:
-            raise FsError(Status(Code.CHAIN_NOT_FOUND, str(chain_id)))
-        return chain
+            routing = self._repoll()
+            chain = routing.chains.get(chain_id)
+            if chain is None:
+                raise FsError(Status(Code.CHAIN_NOT_FOUND, str(chain_id)))
+        return routing, chain
+
+    def _chain(self, chain_id: int) -> ChainInfo:
+        return self._route(chain_id)[1]
+
+    def _node_of(self, routing: RoutingInfo, target_id: int):
+        """The target's node, looked up once more after a repoll when the
+        snapshot has none; raises TARGET_NOT_FOUND when mgmtd has none."""
+        node = (routing.node_of_target(target_id)
+                or self._repoll().node_of_target(target_id))
+        if node is None:
+            raise FsError(Status(Code.TARGET_NOT_FOUND,
+                                 f"no node for target {target_id}"))
+        return node
 
     def next_stripe_ver(self, prev_encoded: int) -> int:
         """Public face of the encoded-version generator for callers doing
@@ -372,7 +402,7 @@ class StorageClient:
             last: Optional[UpdateReply] = None
             for attempt in range(self._retry.max_retries + 1):
                 try:
-                    chain = self._chain(chain_id)
+                    routing, chain = self._route(chain_id)
                 except FsError as e:
                     return UpdateReply(e.code, message=e.status.message)
                 head = chain.head()
@@ -380,7 +410,7 @@ class StorageClient:
                     last = UpdateReply(Code.TARGET_OFFLINE, message="no head")
                     self._sleep(attempt)
                     continue
-                node = self._routing().node_of_target(head.target_id)
+                node = routing.node_of_target(head.target_id)
                 if node is None:
                     last = UpdateReply(Code.TARGET_NOT_FOUND, message="no head node")
                     self._sleep(attempt)
@@ -426,7 +456,10 @@ class StorageClient:
             self._channels.release(channel)
 
     # -- reads ----------------------------------------------------------------
-    def _pick_targets(self, chain: ChainInfo) -> List[int]:
+    def _pick_targets(self, chain: ChainInfo,
+                      routing: RoutingInfo) -> List[int]:
+        """Serving targets of ``chain`` in read order; ``routing`` is the
+        snapshot the caller resolved the chain from."""
         serving = [
             t.target_id
             for t in chain.targets
@@ -451,8 +484,6 @@ class StorageClient:
         # observation instead of after a 60s heartbeat timeout. Stable:
         # the selection mode's order is preserved within each class.
         if self._retry.health_reorder and len(order) > 1:
-            routing = self._routing()
-
             def _suspect(tid: int) -> bool:
                 node = routing.node_of_target(tid)
                 return (node is not None
@@ -506,12 +537,15 @@ class StorageClient:
             if self._deadline_expired():
                 return ReadReply(Code.DEADLINE_EXCEEDED)
             try:
-                chain = self._chain(chain_id)
+                routing, chain = self._route(chain_id)
             except FsError as e:
                 return ReadReply(e.code)
-            targets = self._pick_targets(chain)
-            routing = self._routing()
+            targets = self._pick_targets(chain, routing)
             resolved = [(t, routing.node_of_target(t)) for t in targets]
+            if any(n is None for _, n in resolved):
+                # a serving target whose node the snapshot does not know
+                routing = self._repoll()
+                resolved = [(t, routing.node_of_target(t)) for t in targets]
             resolved = [(t, n) for t, n in resolved if n is not None]
 
             def _attempt(pair):
@@ -587,6 +621,8 @@ class StorageClient:
         from tpu3fs.analytics import spans as _spans
 
         routing = self._routing()
+        if any(req.chain_id not in routing.chains for req in reqs):
+            routing = self._repoll()
         replies: List[Optional[ReadReply]] = [None] * len(reqs)
         wire: List[Tuple[int, ReadReq]] = []   # (node_id, wire op)
         tags: List[Tuple] = []                 # ("cr", i) | ("ec", i, j)
@@ -614,7 +650,7 @@ class StorageClient:
                         tags.append(("ec", i, j))
                         wire.append((node_id, rr))
                     continue
-                targets = self._pick_targets(chain)
+                targets = self._pick_targets(chain, routing)
                 if not targets:
                     replies[i] = ReadReply(Code.TARGET_OFFLINE)
                     continue
@@ -645,8 +681,8 @@ class StorageClient:
             for i, r in enumerate(replies):
                 if r is None or (not r.ok and r.code != Code.CHUNK_NOT_FOUND):
                     chain = routing.chains.get(reqs[i].chain_id)
-                    if chain is not None and chain.is_ec:
-                        continue
+                    if chain is None or chain.is_ec:
+                        continue  # unknown after the repoll above: final
                     replies[i] = self.read_chunk(
                         reqs[i].chain_id, reqs[i].chunk_id, reqs[i].offset,
                         reqs[i].length
@@ -821,6 +857,8 @@ class StorageClient:
         re-verified server-side."""
         replies: List[Optional[UpdateReply]] = [None] * len(writes)
         routing = self._routing()
+        if any(w[0] not in routing.chains for w in writes):
+            routing = self._repoll()
         by_node: Dict[int, List[int]] = defaultdict(list)
         reqs: List[Optional[WriteReq]] = [None] * len(writes)
         channels: List[Optional[Tuple[int, int]]] = [None] * len(writes)
@@ -831,15 +869,19 @@ class StorageClient:
         try:
             for i, (chain_id, chunk_id, offset, data) in enumerate(writes):
                 chain = routing.chains.get(chain_id)
-                if chain is not None and chain.is_ec:
+                if chain is None:  # unknown after the repoll above: final
+                    replies[i] = UpdateReply(Code.CHAIN_NOT_FOUND,
+                                             message=str(chain_id))
+                    continue
+                if chain.is_ec:
                     replies[i] = UpdateReply(
                         Code.INVALID_ARG,
                         message="CRAQ batch_write on EC chain: use write_stripes")
                     continue
-                head = chain.head() if chain is not None else None
+                head = chain.head()
                 node = (routing.node_of_target(head.target_id)
                         if head is not None else None)
-                if chain is None or head is None or node is None:
+                if head is None or node is None:
                     replies[i] = UpdateReply(Code.TARGET_OFFLINE)
                     continue
                 ch, seq = self._channels.acquire()
@@ -895,9 +937,10 @@ class StorageClient:
                 if slot is not None:
                     self._channels.release(slot[0])
         # single-op ladder mops up failures (chain bumps, dead heads);
-        # hard rejections (EC misuse) are final
+        # hard rejections (EC misuse, unknown chain) are final
         for i, r in enumerate(replies):
-            if r is None or (not r.ok and r.code != Code.INVALID_ARG):
+            if r is None or (not r.ok and r.code not in (
+                    Code.INVALID_ARG, Code.CHAIN_NOT_FOUND)):
                 chain_id, chunk_id, offset, data = writes[i]
                 replies[i] = self.write_chunk(
                     chain_id, chunk_id, offset, data, chunk_size=chunk_size,
@@ -974,8 +1017,7 @@ class StorageClient:
             if attempt and self._deadline_expired():
                 return UpdateReply(Code.DEADLINE_EXCEEDED,
                                    message="op deadline exhausted")
-            chain = self._chain(chain_id)
-            routing = self._routing()
+            routing, chain = self._route(chain_id)
             writable = 0
             acked = 0
             bump_to = 0
@@ -1177,7 +1219,7 @@ class StorageClient:
         from tpu3fs.analytics import spans as _spans
         from tpu3fs.ops.stripe import get_codec, shard_size_of
 
-        chain = self._chain(chain_id)
+        routing, chain = self._route(chain_id)
         if not chain.is_ec:
             raise FsError(Status(Code.INVALID_ARG, "write_stripes on CR chain"))
         k, m = chain.ec_k, chain.ec_m
@@ -1186,7 +1228,6 @@ class StorageClient:
         B = len(items)
         if B == 0:
             return []
-        routing = self._routing()
         # one-RPC version probe: max committed over probed shards is the
         # floor for this batch's stripe versions (a later shard write may
         # still be ahead — that stripe falls to the per-stripe ladder)
@@ -1464,7 +1505,7 @@ class StorageClient:
 
         from tpu3fs.ops.stripe import get_codec, shard_size_of
 
-        chain = self._chain(chain_id)
+        routing, chain = self._route(chain_id)
         if not chain.is_ec:
             raise FsError(Status(Code.INVALID_ARG,
                                  "write_stripe_rmw on CR chain"))
@@ -1473,7 +1514,6 @@ class StorageClient:
         if m == 0 or n == 0 or in_off + n > chunk_size:
             return None
         S = shard_size_of(chunk_size, k)
-        routing = self._routing()
         ja0, ja1 = in_off // S, (in_off + n - 1) // S + 1
         touched = list(range(ja0, ja1))
         if len(touched) >= k:
@@ -1839,8 +1879,7 @@ class StorageClient:
 
         last = ReadReply(Code.TARGET_NOT_FOUND)
         for attempt in range(self._retry.max_retries + 1):
-            chain = self._chain(chain_id)
-            routing = self._routing()
+            routing, chain = self._route(chain_id)
             spec = self._plan_stripe_read(chain, routing, req)
             wire = list(spec["wire"].items())
             direct: Dict[int, ReadReply] = {}
@@ -1885,9 +1924,9 @@ class StorageClient:
         return last
 
     # -- maintenance ----------------------------------------------------------
-    def _chain_nodes(self, chain: ChainInfo) -> List[int]:
+    def _chain_nodes(self, chain: ChainInfo,
+                     routing: RoutingInfo) -> List[int]:
         """Distinct node ids hosting any target of the chain (EC fan-out)."""
-        routing = self._routing()
         seen: List[int] = []
         for t in chain.targets:
             node = routing.node_of_target(t.target_id)
@@ -1896,10 +1935,10 @@ class StorageClient:
         return seen
 
     def remove_file_chunks(self, chain_id: int, file_id: int) -> None:
-        chain = self._chain(chain_id)
+        routing, chain = self._route(chain_id)
         if chain.is_ec:
             # no propagation order on EC chains: address every node directly
-            for node_id in self._chain_nodes(chain):
+            for node_id in self._chain_nodes(chain, routing):
                 try:
                     self._messenger(
                         node_id, "remove_file_chunks", (chain_id, file_id))
@@ -1909,15 +1948,15 @@ class StorageClient:
         head = chain.head()
         if head is None:
             raise FsError(Status(Code.TARGET_OFFLINE, "no head"))
-        node = self._routing().node_of_target(head.target_id)
+        node = self._node_of(routing, head.target_id)
         self._messenger(node.node_id, "remove_file_chunks", (chain_id, file_id))
 
     def truncate_file_chunks(
         self, chain_id: int, file_id: int, last_index: int, last_length: int
     ) -> None:
-        chain = self._chain(chain_id)
+        routing, chain = self._route(chain_id)
         if chain.is_ec:
-            for node_id in self._chain_nodes(chain):
+            for node_id in self._chain_nodes(chain, routing):
                 try:
                     self._messenger(
                         node_id, "truncate_file_chunks",
@@ -1928,7 +1967,7 @@ class StorageClient:
         head = chain.head()
         if head is None:
             raise FsError(Status(Code.TARGET_OFFLINE, "no head"))
-        node = self._routing().node_of_target(head.target_id)
+        node = self._node_of(routing, head.target_id)
         self._messenger(
             node.node_id,
             "truncate_file_chunks",
@@ -2030,7 +2069,7 @@ class StorageClient:
         no-serving windows during failover."""
         last_err: Optional[FsError] = None
         for attempt in range(self._retry.max_retries + 1):
-            chain = self._chain(chain_id)
+            routing, chain = self._route(chain_id)
             if chain.is_ec:
                 # each target holds a different shard: the precise length
                 # is the max over ALL serving targets' contributions — a
@@ -2042,7 +2081,7 @@ class StorageClient:
                 for t in chain.targets:
                     if t.public_state != PublicTargetState.SERVING:
                         continue
-                    node = self._routing().node_of_target(t.target_id)
+                    node = routing.node_of_target(t.target_id)
                     if node is None:
                         # SERVING but unroutable counts as a failure: a
                         # partial sweep could under-report the tail shard
@@ -2072,7 +2111,7 @@ class StorageClient:
                 for t in chain.targets[::-1]:  # prefer tail: committed
                     if t.public_state != PublicTargetState.SERVING:
                         continue
-                    node = self._routing().node_of_target(t.target_id)
+                    node = routing.node_of_target(t.target_id)
                     if node is None:
                         continue
                     try:

@@ -97,8 +97,8 @@ class StubFactory:
         from tpu3fs.rpc.services import RpcMessenger
 
         mcli = self._mgmtd()
-        messenger = RpcMessenger(mcli.refresh_routing, self.rpc_client())
-        return StorageClient(client_id, mcli.refresh_routing, messenger,
+        messenger = RpcMessenger(mcli.cached_routing, self.rpc_client())
+        return StorageClient(client_id, mcli.cached_routing, messenger,
                              **kw)
 
     def file_client(self, client_id: str = "stub-client", **kw):

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 
 class NodeType(enum.IntEnum):
@@ -218,3 +218,16 @@ class RoutingInfo:
     def node_of_target(self, target_id: int) -> Optional[NodeInfo]:
         info = self.targets.get(target_id)
         return self.nodes.get(info.node_id) if info else None
+
+
+def routing_invalidator(provider) -> Callable[[], None]:
+    """The hook that expires the snapshot a routing provider holds, so
+    that its next call polls mgmtd: ``provider.invalidate`` on a callable
+    object, ``invalidate_routing`` on the owner of a bound method
+    (``MgmtdRpcClient.cached_routing``), a no-op for providers that hold
+    nothing (the fabric's). Retry ladders and resolves that missed call
+    it before they resolve again (docs/robustness.md)."""
+    owner = getattr(provider, "__self__", None)
+    return (getattr(provider, "invalidate", None)
+            or getattr(owner, "invalidate_routing", None)
+            or (lambda: None))

@@ -13,6 +13,7 @@ ResyncWorker run unchanged over sockets.
 
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
@@ -35,8 +36,14 @@ from tpu3fs.metashard.partition import (
 )
 from tpu3fs.metashard.twophase import IntentRecord
 from tpu3fs.mgmtd.service import HeartbeatReply, Mgmtd
-from tpu3fs.mgmtd.types import LocalTargetState, NodeType, RoutingInfo
+from tpu3fs.mgmtd.types import (
+    LocalTargetState,
+    NodeType,
+    RoutingInfo,
+    routing_invalidator,
+)
 from tpu3fs.migration.types import MigrationJob, MoveSpec
+from tpu3fs.monitor.recorder import CounterRecorder
 from tpu3fs.rpc.net import RpcClient, RpcServer, ServiceDef
 from tpu3fs.storage.craq import (
     ReadReply,
@@ -382,6 +389,8 @@ class RpcMessenger:
         from tpu3fs.rpc.health import HealthRegistry
 
         self._routing = routing_provider
+        self._routing_invalidate = routing_invalidator(routing_provider)
+        self._resolved: Dict[int, Tuple[str, int]] = {}  # node -> last _addr
         self._client = client or RpcClient()
         # USRBIO shm rings: node id -> RingClient (None = handshake tried
         # and failed / not same-host — sockets forever for that node).
@@ -438,7 +447,12 @@ class RpcMessenger:
     def _addr(self, node_id: int) -> Tuple[str, int]:
         node = self._routing().nodes.get(node_id)
         if node is None or not node.host:
+            # the held snapshot may predate the node: one poll, then fail
+            self._routing_invalidate()
+            node = self._routing().nodes.get(node_id)
+        if node is None or not node.host:
             raise FsError(Status(Code.RPC_CONNECT_FAILED, f"no address for node {node_id}"))
+        self._resolved[node_id] = (node.host, node.port)
         return node.host, node.port
 
     # -- USRBIO ring transport (tpu3fs/usrbio) ------------------------------
@@ -661,6 +675,12 @@ class RpcMessenger:
             self.health.observe(node_id, time.monotonic() - t0, ok=True)
         elif err.code in self._HEALTH_ERROR_CODES:
             self.health.observe(node_id, 0.0, ok=False)
+            if err.code == Code.RPC_CONNECT_FAILED:
+                # nobody listens at the address the held snapshot names:
+                # the node is down or came back elsewhere — the next
+                # resolve polls (an EC read goes degraded instead of
+                # failing, so no retry ladder would say so)
+                self._routing_invalidate()
         elif err.code == Code.PEER_UNHEALTHY:
             pass  # our own fail-fast: no new evidence about the peer
         else:
@@ -965,14 +985,25 @@ class RpcMessenger:
 
     def __call__(self, node_id: int, method: str, payload):
         self._guard(node_id, method)
-        t0 = time.monotonic()
-        try:
-            out = self._dispatch_method(node_id, method, payload)
-        except FsError as e:
-            self._observe(node_id, t0, err=e)
-            raise
-        self._observe(node_id, t0)
-        return out
+        resent = False
+        while True:
+            t0 = time.monotonic()
+            try:
+                out = self._dispatch_method(node_id, method, payload)
+            except FsError as e:
+                self._observe(node_id, t0, err=e)
+                # a refused connection sent nothing, and _observe expired
+                # the snapshot: where mgmtd now names ANOTHER address (the
+                # node restarted on a new port) the call goes there, once;
+                # where it names the same one, the node is down
+                tried = self._resolved.get(node_id)
+                if (e.code != Code.RPC_CONNECT_FAILED or resent
+                        or tried is None or self._addr(node_id) == tried):
+                    raise
+                resent = True
+                continue
+            self._observe(node_id, t0)
+            return out
 
     def _dispatch_method(self, node_id: int, method: str, payload):
         ring = (self._ring_for(node_id)
@@ -1097,6 +1128,22 @@ def bind_mgmtd_service(server: RpcServer, mgmtd: Mgmtd) -> ServiceDef:
     return s
 
 
+#: How old the snapshot behind ``MgmtdRpcClient.cached_routing`` may get
+#: before a data-plane resolve polls mgmtd again: 10 s, the servers' default
+#: heartbeat period (``TwoPhaseApplication.heartbeat_interval_s``), which is
+#: how stale a storage or meta server's own view of routing may be. A
+#: snapshot is no consistency mechanism — writes are fenced by the server
+#: on ``chain_ver``, reads by the server's own view of the target — so the
+#: interval only bounds how long a client overlooks what nothing refuses
+#: (a replica that came back to SERVING, a node that joined). Whatever
+#: says the snapshot is stale (a retry ladder's backoff, a chain / target /
+#: node it does not know, a connect failure at an address it names, an
+#: admin mutation through the same client) invalidates it, and the next
+#: resolve polls at once. A constant, not an option: no caller has a
+#: reason to want another value.
+ROUTING_POLL_INTERVAL_S = 10.0
+
+
 class MgmtdRpcClient:
     """Routing-info poller + heartbeat sender over RPC (ref MgmtdClient's
     ForClient/ForServer split: this class serves both roles).
@@ -1105,7 +1152,26 @@ class MgmtdRpcClient:
     server list): calls stick to the last-good server and fail over on
     transport errors or MGMTD_NOT_PRIMARY — a dead primary's lease
     expires and a standby's tick acquires it, so rotating through the
-    list finds the new primary."""
+    list finds the new primary.
+
+    Three readers of routing, told apart by who asks:
+
+    * ``refresh_routing()`` asks mgmtd NOW, every call (version-gated
+      ``getRoutingInfo(known)``, monotonic install). The servers' boot
+      and heartbeat loops, the migration worker, operators
+      (``RpcFabricView.routing()``) and the benchmark's after-window
+      checks want exactly that.
+    * ``routing()`` returns what the last poll installed and never polls
+      again by itself: the servers' and the FUSE daemon's data path,
+      refreshed by their heartbeat loop.
+    * ``cached_routing()`` is the library client's: the held snapshot,
+      polled again once it is ``ROUTING_POLL_INTERVAL_S`` old or after
+      ``invalidate_routing()``. The client factories
+      (``RpcFabricView``, ``client/stubs.py``) hand THIS one to
+      ``StorageClient`` and ``RpcMessenger``, which resolve chains,
+      targets and node addresses on every op — a ``getRoutingInfo``
+      round trip before every storage call was 41 of a 48-ms
+      ``train_dataload`` batch (PERF.md section 6, PR 26)."""
 
     # codes that mean "try the next mgmtd in the list"
     _FAILOVER_CODES = (
@@ -1113,8 +1179,7 @@ class MgmtdRpcClient:
         Code.RPC_SEND_FAILED, Code.MGMTD_NOT_PRIMARY,
     )
 
-    def __init__(self, addr, client: Optional[RpcClient] = None, *,
-                 routing_ttl_s: float = 0.0):
+    def __init__(self, addr, client: Optional[RpcClient] = None):
         try:
             if (isinstance(addr, (tuple, list)) and len(addr) == 2
                     and isinstance(addr[0], str)):
@@ -1131,21 +1196,36 @@ class MgmtdRpcClient:
         self._cursor = 0
         self._client = client or RpcClient()
         self._routing: Optional[RoutingInfo] = None
-        # refresh_routing TTL: with ttl 0 (default) every call is an RPC
-        # (legacy behavior); a positive ttl serves the cached snapshot and
-        # only polls mgmtd when it expires — data-plane hot paths resolve
-        # node addresses on EVERY op, and one getRoutingInfo round trip
-        # per read was a measured double-digit share of served-read time.
-        # Retry ladders call invalidate_routing() before re-resolving, so
-        # failover convergence does not wait out the TTL.
-        self._routing_ttl_s = float(routing_ttl_s)
+        # when the held snapshot was last confirmed by mgmtd (monotonic
+        # clock); -inf = invalidated, the next cached_routing() polls.
+        # The generation counts invalidations: a poll that was in flight
+        # while one happened must not stamp the snapshot fresh.
         self._routing_ts = float("-inf")
+        self._routing_gen = 0
+        self._install_mu = threading.Lock()
+        # cached_routing()'s two outcomes (docs/observability.md)
+        self.routing_polls = CounterRecorder("client.routing_poll")
+        self.routing_cached = CounterRecorder("client.routing_cached")
 
     @property
     def _addr(self):  # sticky current server (back-compat accessor)
         return self._addrs[self._cursor % len(self._addrs)]
 
+    #: calls that change routing invalidate the held snapshot (also when
+    #: they fail: a timed-out mutation may have been applied) —
+    #: registerNode, createTarget, uploadChain, uploadChainTable, tick
+    #: (failure detection), addChainTarget, dropChainTarget, setNodeTags,
+    #: servingRegister, servingUnregister
+    _ROUTING_MUTATIONS = frozenset({3, 4, 5, 6, 9, 10, 11, 12, 17, 18})
+
     def _call(self, method_id: int, req, rsp_type):
+        try:
+            return self._failover_call(method_id, req, rsp_type)
+        finally:
+            if method_id in self._ROUTING_MUTATIONS:
+                self.invalidate_routing()
+
+    def _failover_call(self, method_id: int, req, rsp_type):
         last: Optional[FsError] = None
         for i in range(len(self._addrs)):
             addr = self._addrs[(self._cursor + i) % len(self._addrs)]
@@ -1187,35 +1267,51 @@ class MgmtdRpcClient:
         return self._call(1, req, HeartbeatReply)
 
     def invalidate_routing(self) -> None:
-        """Expire the TTL cache now: the next refresh_routing polls mgmtd.
-        Called by retry ladders before re-resolving a failed op."""
-        self._routing_ts = float("-inf")
+        """Expire the snapshot now: the next cached_routing() polls mgmtd.
+        Called by retry ladders before re-resolving a failed op, by a
+        resolve that missed, and after an admin mutation."""
+        with self._install_mu:
+            self._routing_gen += 1
+            self._routing_ts = float("-inf")
 
     def known_routing_version(self) -> int:
-        """Version of the cached snapshot (-1 = none yet) — lets the
-        heartbeat loop detect a routing bump in the reply and expire the
-        TTL cache promptly (no full-TTL stale window after a demotion)."""
+        """Version of the held snapshot (-1 = none yet) — lets the
+        heartbeat loop detect a routing bump in the reply and refresh
+        promptly instead of at its next routing poll."""
         return self._routing.version if self._routing is not None else -1
 
     def refresh_routing(self) -> RoutingInfo:
-        import time as _time
-
-        if (self._routing is not None and self._routing_ttl_s > 0
-                and _time.monotonic() - self._routing_ts
-                < self._routing_ttl_s):
-            return self._routing
+        """Ask mgmtd now (see the class docstring for who wants that)."""
         known = self._routing.version if self._routing else -1
+        gen = self._routing_gen
         rsp = self._call(2, RoutingReq(known), RoutingRsp)
-        if rsp.changed and rsp.routing is not None:
-            # MONOTONIC install only: after a failover rotation a lagging
-            # standby may answer with an OLDER snapshot — installing it
-            # would resurrect targets the primary already rotated out
-            if self._routing is None or \
-                    rsp.routing.version > self._routing.version:
-                self._routing = rsp.routing
-        self._routing_ts = _time.monotonic()
-        assert self._routing is not None
-        return self._routing
+        with self._install_mu:
+            if rsp.changed and rsp.routing is not None:
+                # MONOTONIC install only: after a failover rotation a
+                # lagging standby may answer with an OLDER snapshot —
+                # installing it would resurrect targets the primary
+                # already rotated out (and two threads polling side by
+                # side must not install the older answer last)
+                if self._routing is None or \
+                        rsp.routing.version > self._routing.version:
+                    self._routing = rsp.routing
+            if gen == self._routing_gen:
+                self._routing_ts = time.monotonic()
+            assert self._routing is not None
+            return self._routing
+
+    def cached_routing(self) -> RoutingInfo:
+        """The held snapshot; polls only when it is older than
+        ROUTING_POLL_INTERVAL_S or was invalidated. The library client's
+        routing provider: ``StorageClient`` finds ``invalidate_routing``
+        through this bound method's ``__self__``."""
+        routing = self._routing
+        if (routing is not None and time.monotonic() - self._routing_ts
+                < ROUTING_POLL_INTERVAL_S):
+            self.routing_cached.add()
+            return routing
+        self.routing_polls.add()
+        return self.refresh_routing()
 
     def routing(self) -> RoutingInfo:
         if self._routing is None:
@@ -2454,7 +2550,11 @@ def bind_mgmtd_admin(service: "ServiceDef", mgmtd: Mgmtd) -> None:
 
 class MgmtdAdminRpcClient(MgmtdRpcClient):
     """ForAdmin role: same method names as the in-process Mgmtd so AdminCli
-    and launchers work against a live cluster unchanged."""
+    and launchers work against a live cluster unchanged.
+
+    Its mutations are among ``_ROUTING_MUTATIONS``: a data-plane client
+    built on the same object (``RpcFabricView``) resolves against what
+    the operator just made."""
 
     def create_target(self, target_id: int, node_id: int = 0,
                       disk_index: int = 0) -> None:
