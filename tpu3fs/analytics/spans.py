@@ -659,7 +659,9 @@ def span(op: str, stage: str, *, nbytes: int = 0):
     """Clock a block as a stage span under the current context (no-op —
     not even a clock read — when untraced). What the block calls parents
     to the stage, so a tree's leaves are what is attributed and a stage
-    that holds other spans is seen to (``TraceTree.coverage``)."""
+    that holds other spans is seen to (``TraceTree.coverage``). A block
+    that learns its payload only at the end sets ``nbytes`` on
+    ``current_trace()``, which inside the block is the stage's own."""
     ctx = _trace_var.get()
     if ctx is None:
         yield None
@@ -676,8 +678,8 @@ def span(op: str, stage: str, *, nbytes: int = 0):
         _trace_var.reset(token)
         if mark is not None:
             mark.__exit__(None, None, None)
-        add_span(ctx, op, stage, ts, dur, nbytes=nbytes, t_perf=t0,
-                 span_id=inner.span_id)
+        add_span(ctx, op, stage, ts, dur, nbytes=nbytes or inner.nbytes,
+                 t_perf=t0, span_id=inner.span_id)
 
 
 def open_op(op: str, *, force: bool = False,

@@ -30,7 +30,16 @@ service binary follows.
 
     python -m tpu3fs.bin.kvcache_gc_main --connect HOST:PORT \
         [--root /kvcache] [--ttl 3600] [--capacity-bytes 0] \
-        [--max-shards 64] [--per-tenant] [--interval 60] [--once]
+        [--max-shards 64] [--per-tenant] [--interval 60] [--once] \
+        [--verbose]
+
+Every tick prints one line: what the two passes removed, then what the
+capacity pass left behind (``entries=``, ``resident=`` bytes) and what it
+took (``scan_s=``, ``remove_s=``). With ``--verbose`` every removal is
+one line of its own, printed before the next removal starts
+(``kvcache-gc: removed <path> mtime=<s> bytes=<n>``): the operator's
+audit trail of what the collector took. SIGTERM stops the daemon between
+two removals, never inside one.
 
 Tests drive run_loop() directly against an in-process Fabric.
 """
@@ -38,8 +47,9 @@ Tests drive run_loop() directly against an in-process Fabric.
 from __future__ import annotations
 
 import argparse
+import signal
 import sys
-import time
+import threading
 from typing import Dict, List, Optional
 
 from tpu3fs.kvcache.cache import KVCacheGC
@@ -84,8 +94,9 @@ def tenant_roots(meta, root: str) -> Dict[str, str]:
     return out
 
 
-def build_gc(meta, root: str, args: argparse.Namespace) -> KVCacheGC:
-    return KVCacheGC(
+def build_gc(meta, root: str, args: argparse.Namespace, *,
+             out=sys.stdout, stop=None) -> KVCacheGC:
+    gc = KVCacheGC(
         meta,
         root=root,
         ttl_s=args.ttl,
@@ -93,14 +104,24 @@ def build_gc(meta, root: str, args: argparse.Namespace) -> KVCacheGC:
         capacity_bytes=args.capacity_bytes or None,
         client_id="kvcache-gc",
     )
+    if getattr(args, "verbose", False):
+        gc.on_remove = lambda path, mtime, length: print(
+            f"kvcache-gc: removed {path} mtime={mtime:.3f} bytes={length}",
+            file=out, flush=True)
+    if stop is not None:
+        gc.stopping = stop.is_set
+    return gc
 
 
 def run_once(fabric, args: argparse.Namespace, *,
-             gcs: Dict[str, KVCacheGC], out=sys.stdout) -> Dict[str, int]:
+             gcs: Dict[str, KVCacheGC], out=sys.stdout,
+             stop=None) -> Dict[str, float]:
     """One tick: quota refresh, TTL + capacity passes (global or
-    per-tenant), resident-gauge publish. Returns counters."""
+    per-tenant), resident-gauge publish. Returns counters, and what the
+    capacity passes left and took (summed over the roots)."""
     meta = fabric.meta
-    stats = {"removed_ttl": 0, "removed_capacity": 0, "tenants": 0}
+    stats = {"removed_ttl": 0, "removed_capacity": 0, "tenants": 0,
+             "entries": 0, "resident": 0, "scan_s": 0.0, "remove_s": 0.0}
     _refresh_quota_table(fabric, out=out)
     roots: Dict[str, str] = {}
     if args.per_tenant:
@@ -110,7 +131,7 @@ def run_once(fabric, args: argparse.Namespace, *,
     for tenant, root in sorted(roots.items()):
         gc = gcs.get(root)
         if gc is None:
-            gc = gcs[root] = build_gc(meta, root, args)
+            gc = gcs[root] = build_gc(meta, root, args, out=out, stop=stop)
         stats["removed_ttl"] += gc.run_once()
         budget = args.capacity_bytes or None
         if tenant:
@@ -121,6 +142,8 @@ def run_once(fabric, args: argparse.Namespace, *,
         if budget:
             stats["removed_capacity"] += gc.capacity_pass(
                 capacity_bytes=budget)
+            for field, value in gc.last_pass.items():
+                stats[field] += value
         if tenant:
             # authoritative resident figure AFTER eviction: one scan,
             # published to the registry gauge the writer-side budget
@@ -133,20 +156,28 @@ def run_once(fabric, args: argparse.Namespace, *,
     return stats
 
 
-def run_loop(fabric, args: argparse.Namespace, *, out=sys.stdout) -> int:
+def run_loop(fabric, args: argparse.Namespace, *, out=sys.stdout,
+             stop=None) -> int:
     """Sweep until stopped (or once); returns total entries removed."""
     gcs: Dict[str, KVCacheGC] = {}
+    stop = threading.Event() if stop is None else stop
     total = 0
     while True:
-        stats = run_once(fabric, args, gcs=gcs, out=out)
+        stats = run_once(fabric, args, gcs=gcs, out=out, stop=stop)
         total += stats["removed_ttl"] + stats["removed_capacity"]
+        if stop.is_set():   # a pass cut short is no account of the tier
+            print(f"kvcache-gc: stopped inside a pass ({total} removed in "
+                  f"all)", file=out, flush=True)
+            return total
         print(f"kvcache-gc: root={args.root} "
               f"ttl_removed={stats['removed_ttl']} "
               f"capacity_removed={stats['removed_capacity']} "
-              f"tenants={stats['tenants']}", file=out)
-        if args.once:
+              f"tenants={stats['tenants']} entries={stats['entries']} "
+              f"resident={stats['resident']} "
+              f"scan_s={stats['scan_s']:.3f} "
+              f"remove_s={stats['remove_s']:.3f}", file=out, flush=True)
+        if args.once or stop.wait(args.interval):
             return total
-        time.sleep(args.interval)
 
 
 def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
@@ -168,6 +199,8 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                         "stores budgeted by their kvcache_bytes quota")
     p.add_argument("--interval", type=float, default=60.0)
     p.add_argument("--once", action="store_true")
+    p.add_argument("--verbose", action="store_true",
+                   help="one line a removed entry: the audit trail")
     return p.parse_args(argv)
 
 
@@ -182,7 +215,9 @@ def main(argv: Optional[List[str]] = None) -> int:  # pragma: no cover
     host, port_s = args.connect.rsplit(":", 1)
     fabric = RpcFabricView((host, int(port_s)), token=args.token,
                            client_id="kvcache-gc")
-    run_loop(fabric, args)
+    stop = threading.Event()
+    signal.signal(signal.SIGTERM, lambda *_: stop.set())
+    run_loop(fabric, args, stop=stop)
     return 0
 
 

@@ -1831,8 +1831,11 @@ class StorageClient:
                     ec_specs[i]["offset"], ec_specs[i]["length"],
                     chunk_size=reqs[i].chunk_size)
             replies[i] = out
-            self._ec_degraded.add()
-            self._ec_degraded_ms.record(dt_ms)
+            if out.ok:   # as the single-op ladder counts: a stripe that is
+                # absent on every shard (a removed entry read through a
+                # held inode) is a hole, nothing was decoded
+                self._ec_degraded.add()
+                self._ec_degraded_ms.record(dt_ms)
 
     def read_stripe(
         self,

@@ -33,6 +33,7 @@ from typing import List, Optional, Sequence
 
 from tpu3fs.analytics import spans as _spans
 from tpu3fs.kvcache.layout import decode_array, encode_array
+from tpu3fs.monitor.recorder import CounterRecorder
 from tpu3fs.utils.result import Code, FsError
 from tpu3fs.utils.result import err as _err
 
@@ -82,6 +83,7 @@ class PrefixBlockStore:
         self.block_tokens = block_tokens
         self._salt = salt
         self._leases = leases
+        self._stale_reads = CounterRecorder("kvcache.stale_reads")
 
     @property
     def cache(self):
@@ -203,14 +205,16 @@ class PrefixBlockStore:
         except FsError as e:
             if e.code != Code.KVCACHE_STALE:
                 raise
+        self._stale_reads.add()
         invalidate = getattr(self._cache, "invalidate", None)
         if invalidate is None:
             return None
-        invalidate(key)
-        raw = self._cache.get(key)
-        if raw is None:
-            return None
-        return decode_array(raw)
+        with _spans.span("kvcache.get_blocks", "reprobe"):
+            invalidate(key)
+            raw = self._cache.get(key)
+            if raw is None:
+                return None
+            return decode_array(raw)
 
     # -- leases -------------------------------------------------------------
     def pin_prefix(self, match: PrefixMatch, ttl_s: Optional[float] = None):

@@ -38,6 +38,7 @@ import time
 from collections import OrderedDict
 from typing import List, Optional, Sequence, Tuple
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.client.file_io import FileIoClient
 from tpu3fs.kvcache.layout import (
     decode_array,
@@ -483,6 +484,13 @@ class KVCacheGC:
     inference session holding a lease on its prefix blocks can never lose
     them mid-decode, however old or over-budget the tier is. Removals go
     through the normal remove path (chunks reclaimed by meta GC scan).
+
+    Each pass is one root op ``kvcache.gc.pass`` (stages ``scan`` and
+    ``remove``) and leaves ``last_pass`` behind for the daemon's tick
+    line. ``on_remove(path, mtime, length)`` is called after each removal,
+    before the next one starts — the operator's audit trail; ``stopping``
+    is asked between entries, so a daemon told to stop never leaves a
+    removal unreported.
     """
 
     def __init__(
@@ -502,6 +510,12 @@ class KVCacheGC:
         self.capacity_bytes = capacity_bytes
         self._client_id = client_id
         self._cursor: Tuple[int, int] = (0, 0)
+        self.on_remove = None
+        self.stopping = lambda: False
+        #: the last capacity pass: entries and bytes it left resident,
+        #: seconds it scanned and removed
+        self.last_pass = {"entries": 0, "resident": 0, "scan_s": 0.0,
+                          "remove_s": 0.0}
         self._removes = CounterRecorder("kvcache.gc.removes")
         self._scans = CounterRecorder("kvcache.gc.scans")
         self._lease_skips = CounterRecorder("kvcache.gc.lease_skips")
@@ -512,13 +526,16 @@ class KVCacheGC:
         except FsError:
             return []
 
-    def _try_remove(self, path: str) -> bool:
+    def _try_remove(self, path: str, mtime: float = 0.0,
+                    length: int = 0) -> bool:
         try:
             self._meta.remove(path)
-            self._removes.add()
-            return True
         except FsError:
             return False  # concurrent remove/touch: next pass decides
+        self._removes.add()
+        if self.on_remove is not None:
+            self.on_remove(path, mtime, length)
+        return True
 
     def run_once(self, now: Optional[float] = None) -> int:
         """Scan up to max_shards leaf dirs; returns entries removed.
@@ -526,7 +543,11 @@ class KVCacheGC:
         Sub-shard lists are fetched lazily per top dir as the cursor reaches
         it, so a pass costs 1 (root) + tops-touched + leafs-visited list_dir
         calls — never a full enumeration of the whole shard tree up front."""
-        now = time.time() if now is None else now
+        with _spans.root_span("kvcache.gc.pass"), \
+                _spans.span("kvcache.gc.pass", "scan"):
+            return self._ttl_pass(time.time() if now is None else now)
+
+    def _ttl_pass(self, now: float) -> int:
         removed = 0
         tops = sorted(self._list(self.root))
         if not tops:
@@ -541,7 +562,8 @@ class KVCacheGC:
                and not wrapped):
             top = tops[ti]
             subs = sorted(self._list(f"{self.root}/{top}"))
-            while si < len(subs) and visited < self.max_shards:
+            while (si < len(subs) and visited < self.max_shards
+                   and not self.stopping()):
                 key = (top, subs[si])
                 if key in seen_leafs:
                     wrapped = True  # full cycle: stop, cursor stays here
@@ -562,8 +584,10 @@ class KVCacheGC:
                     if lease_active(inode, now):
                         self._lease_skips.add()
                         continue
-                    if self._try_remove(path):
+                    if self._try_remove(path, inode.mtime, inode.length):
                         removed += 1
+            if self.stopping():
+                break
             if not wrapped and si >= len(subs):
                 ti = (ti + 1) % len(tops)
                 si = 0
@@ -577,6 +601,8 @@ class KVCacheGC:
         now = time.time() if now is None else now
         out = []
         for top in self._list(self.root):
+            if self.stopping():
+                break
             for sub in self._list(f"{self.root}/{top}"):
                 leaf = f"{self.root}/{top}/{sub}"
                 for name in self._list(leaf):
@@ -600,18 +626,25 @@ class KVCacheGC:
         if budget is None:
             return 0
         now = time.time() if now is None else now
-        entries = self.scan_entries(now)
-        total = sum(length for _, length, _, _ in entries)
-        if total <= budget:
-            return 0
-        removed = 0
-        for mtime, length, leased, path in sorted(entries):
-            if total <= budget:
-                break
-            if leased:
-                self._lease_skips.add()
-                continue
-            if self._try_remove(path):
-                total -= length
-                removed += 1
+        with _spans.root_span("kvcache.gc.pass"):
+            t0 = time.perf_counter()
+            with _spans.span("kvcache.gc.pass", "scan"):
+                entries = self.scan_entries(now)
+            t1 = time.perf_counter()
+            total = sum(length for _, length, _, _ in entries)
+            removed = 0
+            if total > budget:
+                with _spans.span("kvcache.gc.pass", "remove"):
+                    for mtime, length, leased, path in sorted(entries):
+                        if total <= budget or self.stopping():
+                            break
+                        if leased:
+                            self._lease_skips.add()
+                            continue
+                        if self._try_remove(path, mtime, length):
+                            total -= length
+                            removed += 1
+            self.last_pass = {
+                "entries": len(entries) - removed, "resident": total,
+                "scan_s": t1 - t0, "remove_s": time.perf_counter() - t1}
         return removed

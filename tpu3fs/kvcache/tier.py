@@ -25,9 +25,11 @@ entirely — a key's value never changes, so staleness cannot be observed.
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.kvcache.cache import KVCacheClient
 from tpu3fs.kvcache.layout import decode_array, encode_array
 from tpu3fs.monitor.recorder import CounterRecorder, ValueRecorder
@@ -207,20 +209,31 @@ class TieredKVCache:
         fs batch (one node-grouped batch_read_files underneath)."""
         out: List[Optional[bytes]] = [None] * len(keys)
         missing: List[int] = []
+        ctx = _spans.current_trace()
+        t0 = time.perf_counter() if ctx is not None else 0.0
+        served = 0
         for i, key in enumerate(keys):
             v = self._local(key)
             if v is not None:
                 out[i] = v
+                served += len(v)
                 self._host_hits.add()
             else:
                 missing.append(i)
+        if ctx is not None:   # the RAM lookups, nothing beneath them
+            _spans.add_span_at(ctx, "kvcache.get_blocks", "host_tier", t0,
+                               time.perf_counter() - t0, nbytes=served)
         if missing:
             self._host_misses.add(len(missing))
-            got = self._miss_fill_batch([keys[i] for i in missing])
-            for i, blob in zip(missing, got):
-                out[i] = blob
-                if blob is not None:
-                    self._fill(keys[i], blob)
+            with _spans.span("kvcache.get_blocks", "fill"):
+                got = self._miss_fill_batch([keys[i] for i in missing])
+                for i, blob in zip(missing, got):
+                    out[i] = blob
+                    if blob is not None:
+                        self._fill(keys[i], blob)
+                if ctx is not None:
+                    _spans.current_trace().nbytes = sum(
+                        len(b) for b in got if b is not None)
         return out
 
     # -- miss path (the serving fleet's interposition point) ----------------
@@ -260,11 +273,17 @@ class TieredKVCache:
                 Code.KVCACHE_FLUSH_POISONED,
                 f"write-back flusher failed {self._flush_fail_streak} "
                 f"consecutive cycles (budget {self.flush_error_budget})"))
+        ctx = _spans.current_trace()
+        t0 = time.perf_counter() if ctx is not None else 0.0
         with self._cond:
             while (not self._stop.is_set() and self._dirty
                    and self._dirty_bytes + len(value)
                    > self.dirty_max_bytes):
                 self._cond.wait(0.5)
+            if ctx is not None:   # the producer's stand at the bound
+                _spans.add_span_at(ctx, "kvcache.append_blocks",
+                                   "dirty_wait", t0,
+                                   time.perf_counter() - t0)
             old = self._dirty.pop(key, None)
             if old is not None:
                 self._dirty_bytes -= len(old)
@@ -392,30 +411,43 @@ class TieredKVCache:
         pipelined write path) when the fs tier supports it; a failed
         batch falls back to per-key puts so one bad entry cannot wedge
         the rest. Every all-failed cycle burns one unit of the error
-        budget (see flush_error_budget); any success resets it."""
+        budget (see flush_error_budget); any success resets it. One drain
+        is one root op ``kvcache.flush`` (``nbytes`` = bytes flushed): the
+        flusher thread has no producer's op above it."""
+        with _spans.root_span("kvcache.flush") as sp:
+            flushed = self._flush_drain(batch)
+            if sp is not None:
+                sp.nbytes = flushed
+
+    def _flush_drain(self, batch) -> int:
+        """-> bytes flushed."""
         batch_put = getattr(self._fs, "batch_put", None)
         if batch_put is not None and len(batch) > 1:
             try:
-                batch_put(batch)
+                # nbytes = entries of the drain: a count
+                with _spans.span("kvcache.flush", "batch_put",
+                                 nbytes=len(batch)):
+                    batch_put(batch)
                 for key, value in batch:
                     self._flush_bytes.add(len(value))
                     self._retire(key, value)
                 self._flush_fail_streak = 0
-                return
+                return sum(len(value) for _, value in batch)
             except FsError:
                 pass  # per-key fallback isolates the failing entry
-        flushed_any = False
-        for key, value in batch:
-            try:
-                self._fs.put(key, value)
-                self._flush_bytes.add(len(value))
-            except FsError:
-                self._flush_err.add()
-                self._stop.wait(0.05)  # storage unhappy: back off, retry
-                continue
-            flushed_any = True
-            self._retire(key, value)
-        if flushed_any:
+        flushed = 0
+        with _spans.span("kvcache.flush", "put_each", nbytes=len(batch)):
+            for key, value in batch:
+                try:
+                    self._fs.put(key, value)
+                    self._flush_bytes.add(len(value))
+                except FsError:
+                    self._flush_err.add()
+                    self._stop.wait(0.05)  # storage unhappy: back off
+                    continue
+                flushed += len(value)
+                self._retire(key, value)
+        if flushed:
             self._flush_fail_streak = 0
         else:
             self._flush_fail_streak += 1
@@ -423,6 +455,7 @@ class TieredKVCache:
                 # poisoned: stop hammering a dead tier at full tilt; one
                 # retry cycle per interval keeps probing for recovery
                 self._stop.wait(0.2)
+        return flushed
 
     def flush(self, timeout: float = 30.0) -> bool:
         """Block until the dirty buffer drains (True) or timeout."""
