@@ -890,3 +890,162 @@ class TestBatchPutCreateFanIn:
         # no leaked write sessions: a fresh put on the same key succeeds
         c.put("ok", b"z")
         assert c.get("ok") == b"z"
+
+
+# -- over RPC the batched stat is ONE round trip (MetaSerde 30) -------------
+
+META_SID, STAT, BATCH_STAT_BY_PATH = 4, 2, 30
+
+
+def _meta_calls(mc, record=lambda addr, method_id, req: method_id):
+    """What ``record`` makes (the method id) of every MetaSerde RPC the
+    client sends from now on."""
+    seen = []
+    real = mc._client.call
+
+    def spy(addr, service_id, method_id, req, rsp_type, *a, **kw):
+        if service_id == META_SID:
+            seen.append(record(addr, method_id, req))
+        return real(addr, service_id, method_id, req, rsp_type, *a, **kw)
+
+    mc._client.call = spy
+    return seen
+
+
+@pytest.fixture
+def rpc_cache():
+    """A KVCacheClient whose meta is a MetaRpcClient over a real
+    RpcServer bound to the fabric's store (the storage side stays
+    in-fabric: the count is of meta round trips)."""
+    from tpu3fs.rpc.net import RpcServer
+    from tpu3fs.rpc.services import MetaRpcClient, bind_meta_service
+
+    fab = Fabric(SystemSetupConfig(num_storage_nodes=2, num_chains=4,
+                                   num_replicas=2, chunk_size=4096))
+    server = RpcServer()
+    bind_meta_service(server, fab.meta)
+    server.start()
+    mc = MetaRpcClient([server.address], client_id="kv-rpc")
+    yield mc, KVCacheClient(mc, fab.file_client())
+    server.stop()
+    fab.close()
+
+
+class TestBatchedStatOverRpc:
+    BT = 4
+
+    def _pages(self, n):
+        return [np.full((2, 2, self.BT, 8), i, dtype=np.float16)
+                for i in range(n)]
+
+    @pytest.mark.parametrize("nblocks", [1, 5, 22])
+    def test_load_is_two_batched_stats_and_no_stat(self, rpc_cache, nblocks):
+        mc, base = rpc_cache
+        store = PrefixBlockStore(base, block_tokens=self.BT)
+        toks = list(range(nblocks * self.BT))
+        pages = self._pages(nblocks)
+        assert store.append_blocks(toks, pages) == nblocks
+        seen = _meta_calls(mc)
+        m = store.match_prefix(toks)
+        out = store.get_blocks(toks, count=m.blocks)
+        assert m.blocks == nblocks
+        for got, want in zip(out, pages):
+            np.testing.assert_array_equal(got, want)
+        assert seen.count(BATCH_STAT_BY_PATH) == 2
+        assert seen.count(STAT) == 0
+
+    def test_append_probe_is_one_batched_stat(self, rpc_cache):
+        mc, base = rpc_cache
+        store = PrefixBlockStore(base, block_tokens=self.BT)
+        toks = list(range(6 * self.BT))
+        store.append_blocks(toks[:3 * self.BT], self._pages(3))
+        seen = _meta_calls(mc)
+        # three blocks are there already: the probe finds them in one call
+        assert store.append_blocks(toks, self._pages(6)) == 3
+        assert seen.count(BATCH_STAT_BY_PATH) == 1
+        assert seen.count(STAT) == 0
+
+    def test_hole_and_miss_read_as_before(self, rpc_cache):
+        mc, base = rpc_cache
+        store = PrefixBlockStore(base, block_tokens=self.BT)
+        toks = list(range(5 * self.BT))
+        store.append_blocks(toks, self._pages(5))
+        base.remove(store.block_keys(toks)[2])
+        seen = _meta_calls(mc)
+        assert store.match_prefix(toks).blocks == 2
+        out = store.get_blocks(toks)
+        assert [o is None for o in out] == [False, False, True, False, False]
+        assert base.batch_contains(["never/put"]) == [False]
+        assert seen.count(BATCH_STAT_BY_PATH) == 3
+        assert seen.count(STAT) == 0
+
+    def test_unreachable_meta_raises_instead_of_missing(self, rpc_cache):
+        from tpu3fs.rpc.services import MetaRpcClient
+
+        _, base = rpc_cache
+        dead = KVCacheClient(MetaRpcClient([("127.0.0.1", 1)]), base._fio)
+        with pytest.raises(FsError) as ei:
+            dead.batch_contains(["a", "b"])
+        assert ei.value.code == Code.RPC_CONNECT_FAILED
+
+    def test_routed_client_makes_one_call_a_partition(self):
+        """MetaRpcClient(mgmtd=...) over two ShardedMetaStore servers that
+        own half the partitions each: the kvcache shard directories of one
+        probe lie in several partitions; one batchStatByPath goes to each
+        partition's owner, and the answers merge in request order."""
+        from types import SimpleNamespace
+
+        from tpu3fs.kv import MemKVEngine
+        from tpu3fs.kvcache.layout import shard_path
+        from tpu3fs.meta.store import ChainAllocator
+        from tpu3fs.metashard import ShardedMetaStore, partition_of_path
+        from tpu3fs.rpc.net import RpcServer
+        from tpu3fs.rpc.services import MetaRpcClient, bind_meta_service
+
+        nparts = 4
+        eng = MemKVEngine()
+        servers, owner = [], {}
+        for half in (0, 1):
+            owned = {p for p in range(nparts) if p % 2 == half}
+            st = ShardedMetaStore(eng, ChainAllocator(1, [901, 902]),
+                                  nparts=nparts,
+                                  owner_view=lambda v=owned: v)
+            srv = RpcServer()
+            bind_meta_service(srv, st)
+            srv.start()
+            servers.append(srv)
+            owner.update({p: srv.address for p in owned})
+        try:
+            table = SimpleNamespace(
+                meta_owner=lambda pid: SimpleNamespace(
+                    host=owner[pid][0], port=owner[pid][1]))
+            mgmtd = SimpleNamespace(routing=lambda: table,
+                                    invalidate_routing=lambda: None,
+                                    refresh_routing=lambda: None)
+            mc = MetaRpcClient([s.address for s in servers], mgmtd=mgmtd,
+                               nparts=nparts)
+            keys = [f"blk{i}" for i in range(24)]
+            paths = [shard_path("/kvcache", k) for k in keys]
+            pids = [partition_of_path(p, nparts) for p in paths]
+            assert len(set(pids)) >= 2
+            mc.batch_mkdirs(sorted({p.rsplit("/", 1)[0] for p in paths}))
+            made = {}
+            for i, p in enumerate(paths):
+                if i % 3:       # every third key is never put
+                    made[p] = mc.create(p).inode.id
+            sent = _meta_calls(
+                mc, lambda addr, mid, req: (addr, mid, list(req.paths)))
+            got = mc.batch_stat_by_path(paths)
+            assert [None if g is None else g.id for g in got] == \
+                [made.get(p) for p in paths]
+            # one call a partition, each to its owner, each carrying
+            # exactly that partition's paths in request order
+            assert sorted(m for _, m, _ in sent) == \
+                [BATCH_STAT_BY_PATH] * len(set(pids))
+            for addr, _, sub in sent:
+                (pid,) = {partition_of_path(p, nparts) for p in sub}
+                assert addr == owner[pid]
+                assert sub == [p for p, q in zip(paths, pids) if q == pid]
+        finally:
+            for srv in servers:
+                srv.stop()

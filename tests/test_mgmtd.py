@@ -243,6 +243,56 @@ class TestHeartbeatAndChains:
         c = m.get_routing_info().chains[900001]
         assert all(t.public_state == PS.SERVING for t in c.targets)
 
+    # tick()'s self-stall grace: `stall` real seconds since this process
+    # last ticked, `silent` seconds of every node's silence on the
+    # injected clock, heartbeat_timeout_s = 60
+    @pytest.mark.parametrize("stall,silent,dead", [
+        (0.0, 61, True),     # a fake-clock jump alone stalls nobody
+        (10.0, 61, True),    # a stall under T/2 forgives nothing
+        (40.0, 61, False),   # the stall is the primary's own silence
+        (61.0, 61, False),
+        (40.0, 101, True),   # only the stall's own length is forgiven
+    ])
+    def test_own_stall_is_not_the_nodes_silence(self, cluster, stall,
+                                                silent, dead):
+        m, _, clock = cluster
+        self._boot(m)
+        m.tick()
+        v0 = m.get_routing_info().version
+        clock.t += silent
+        m._last_tick_mono -= stall   # the last tick ran that long ago
+        m.tick()
+        ri = m.get_routing_info()
+        serving = [t.public_state == PS.SERVING
+                   for t in ri.chains[900001].targets]
+        if dead:
+            assert ri.version > v0 and not all(serving)
+        else:
+            assert ri.version == v0 and all(serving)
+            assert all(n.last_heartbeat <= clock.t
+                       for n in ri.nodes.values())
+
+    def test_node_that_died_in_a_stall_is_found_a_timeout_later(self, cluster):
+        m, _, clock = cluster
+        self._boot(m)
+        m.tick()
+        clock.t += 61
+        m._last_tick_mono -= 61
+        m.tick()             # forgiven: nobody judged by the stall
+        m.heartbeat(10, 2, {101: LS.UPTODATE})
+        m.heartbeat(12, 2, {103: LS.UPTODATE})
+        clock.t += 30
+        m.tick()             # node 11: 30 s of silence that count
+        c = m.get_routing_info().chains[900001]
+        assert all(t.public_state == PS.SERVING for t in c.targets)
+        clock.t += 31
+        m.heartbeat(10, 3, {101: LS.UPTODATE})
+        m.heartbeat(12, 3, {103: LS.UPTODATE})
+        m.tick()
+        c = m.get_routing_info().chains[900001]
+        assert states(c.targets) == [
+            (101, PS.SERVING), (103, PS.SERVING), (102, PS.OFFLINE)]
+
     def test_config_distribution(self, cluster):
         m, _, _ = cluster
         m.register_node(10, NodeType.STORAGE)

@@ -336,3 +336,183 @@ class TestEcOverSockets:
         out = meta.batch_set_attr(inode_ids=ids, atime=2222.0)
         assert [o.id for o in out] == ids
         assert meta.stat("/touch/f2").atime == 2222.0
+
+
+# -- batchStatByPath (MetaSerde 30): the batched stat's RPC twin ------------
+
+def _call_spy(mc):
+    """Record (service id, method id) of every RPC the client's transport
+    sends; returns the list."""
+    seen = []
+    real = mc._client.call
+
+    def spy(addr, service_id, method_id, req, rsp_type, *a, **kw):
+        seen.append((service_id, method_id))
+        return real(addr, service_id, method_id, req, rsp_type, *a, **kw)
+
+    mc._client.call = spy
+    return seen
+
+
+def _stat_or_none(mc, path):
+    try:
+        return mc.stat(path)
+    except FsError:
+        return None
+
+
+class TestBatchStatByPathOverRpc:
+    """MetaRpcClient.batch_stat_by_path answers what per-path stat answers
+    (None where stat raises missing/forbidden), in request order, in one
+    RPC a BATCH_STAT_PATHS_MAX paths."""
+
+    @pytest.fixture
+    def served(self):
+        meta = MetaStore(MemKVEngine(), ChainAllocator(1, [101, 102]))
+        server = RpcServer()
+        bind_meta_service(server, meta)
+        server.start()
+        mc = MetaRpcClient([server.address], client_id="bs")
+        mc.mkdirs("/d/sub", recursive=True)
+        for i in range(3):
+            rsp = mc.create(f"/d/f{i}", flags=2)
+            mc.close(rsp.inode.id, rsp.session_id, length_hint=10 + i)
+        mc.symlink("/d/ln", "/d/f1")
+        mc.symlink("/d/dangling", "/d/nowhere")
+        yield mc
+        server.stop()
+
+    CASES = {
+        "present": ["/d/f0", "/d/f1", "/d/f2"],
+        "missing": ["/d/nope", "/nodir/f", "/d/f0/under_a_file"],
+        "directory": ["/d", "/d/sub", "/"],
+        "symlink": ["/d/ln", "/d/dangling"],
+        "duplicates": ["/d/f1", "/d/f1", "/d/nope", "/d/f1", "/d/nope"],
+        "mixed_in_request_order": ["/d/f2", "/d/nope", "/d", "/d/ln",
+                                   "/d/f0", "/nodir/x", "/d/f2"],
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_answers_what_stat_answers(self, served, case):
+        paths = self.CASES[case]
+        want = [_stat_or_none(served, p) for p in paths]
+        seen = _call_spy(served)
+        got = served.batch_stat_by_path(paths)
+        assert got == want
+        # one round trip, and it is not a stat
+        assert seen == [(4, 30)]
+
+    def test_symlink_is_followed_like_stat(self, served):
+        ln, dangling = served.batch_stat_by_path(["/d/ln", "/d/dangling"])
+        assert ln.id == served.stat("/d/f1").id and ln.length == 11
+        assert dangling is None
+
+    def test_empty_list_makes_no_call(self, served):
+        seen = _call_spy(served)
+        assert served.batch_stat_by_path([]) == []
+        assert seen == []
+
+    def test_long_list_splits_at_the_client_constant(self, served):
+        from tpu3fs.rpc.services import BATCH_STAT_PATHS_MAX
+
+        assert BATCH_STAT_PATHS_MAX > 64  # longer than a store transaction
+        n = 2 * BATCH_STAT_PATHS_MAX + 7
+        paths = [f"/d/f{i % 3}" if i % 5 else f"/d/miss{i}"
+                 for i in range(n)]
+        want = {p: _stat_or_none(served, p) for p in set(paths)}
+        seen = _call_spy(served)
+        got = served.batch_stat_by_path(paths)
+        assert got == [want[p] for p in paths]
+        assert seen == [(4, 30)] * 3
+
+    def test_list_at_the_constant_is_one_call(self, served):
+        from tpu3fs.rpc.services import BATCH_STAT_PATHS_MAX
+
+        seen = _call_spy(served)
+        got = served.batch_stat_by_path(["/d/f0"] * BATCH_STAT_PATHS_MAX)
+        assert len(got) == BATCH_STAT_PATHS_MAX and None not in got
+        assert seen == [(4, 30)]
+
+    def test_accepts_any_iterable_of_paths(self, served):
+        got = served.batch_stat_by_path(p for p in ("/d/f0", "/d/nope"))
+        assert got[0].id == served.stat("/d/f0").id and got[1] is None
+
+    def test_raises_when_no_meta_server_answers(self, served):
+        # the loop of stat calls swallowed this as misses; an unreachable
+        # server is an error, not N misses
+        dead = MetaRpcClient([("127.0.0.1", 1)])
+        with pytest.raises(FsError) as ei:
+            dead.batch_stat_by_path(["/d/f0", "/d/f1"])
+        assert ei.value.code == Code.RPC_CONNECT_FAILED
+
+    def test_fails_over_to_the_next_server(self, served):
+        live = served._addrs[0]
+        mc = MetaRpcClient([("127.0.0.1", 1), live])
+        got = mc.batch_stat_by_path(["/d/f0", "/d/nope"])
+        assert got[0].id == served.stat("/d/f0").id and got[1] is None
+
+    def test_op_span_carries_the_path_count(self, served, tmp_path):
+        from tpu3fs.analytics import spans
+        from tpu3fs.analytics.trace import read_records
+
+        old = spans._TRACER
+        tracer = spans._TRACER = spans.Tracer()
+        try:
+            tracer.configure(service="c", node=0, directory=str(tmp_path),
+                             sample_rate=1.0)
+            served.batch_stat_by_path(["/d/f0", "/d/nope", "/d/f1"])
+            tracer.flush()
+        finally:
+            spans._TRACER = old
+        ops = [(r["op"], r["nbytes"]) for p in tracer.span_paths
+               for r in read_records(p) if not r["stage"]]
+        assert ("meta.batchStatByPath", 3) in ops
+        assert not any(op == "meta.stat" for op, _ in ops)
+
+
+class TestBatchStatByPathAuth:
+    """Auth mode: the user is the token's; a path that user may not walk
+    comes back as nothing, exactly where stat raises NO_PERMISSION."""
+
+    @pytest.fixture
+    def authed(self):
+        from tpu3fs.core.user import UserStore
+
+        engine = MemKVEngine()
+        users = UserStore(engine)
+        meta = MetaStore(engine, ChainAllocator(1, [101, 102]))
+        server = RpcServer()
+        bind_meta_service(server, meta, user_store=users, acl_ttl_s=0.0)
+        server.start()
+        meta.mkdirs("/pub", perm=0o777)
+        meta.mkdirs("/private", perm=0o700)  # root-owned, no group/other
+        meta.create("/pub/f")
+        meta.create("/private/f")
+        yield server, users
+        server.stop()
+
+    def test_forbidden_path_is_nothing(self, authed):
+        server, users = authed
+        alice = users.add_user(1000, "alice")
+        mc = MetaRpcClient([server.address], token=alice.token)
+        with pytest.raises(FsError) as ei:
+            mc.stat("/private/f")
+        assert ei.value.code == Code.META_NO_PERMISSION
+        got = mc.batch_stat_by_path(["/pub/f", "/private/f", "/pub/nope"])
+        assert got[0].id == mc.stat("/pub/f").id
+        assert got[1] is None and got[2] is None
+
+    def test_root_user_sees_it(self, authed):
+        server, users = authed
+        boss = users.add_user(9999, "boss", root=True)
+        mc = MetaRpcClient([server.address], token=boss.token)
+        got = mc.batch_stat_by_path(["/private/f", "/pub/f"])
+        assert None not in got
+
+    def test_bad_token_is_an_error_not_misses(self, authed):
+        server, _ = authed
+        for mc in (MetaRpcClient([server.address]),
+                   MetaRpcClient([server.address], token="ffff" * 8)):
+            with pytest.raises(FsError) as ei:
+                mc.batch_stat_by_path(["/pub/f"])
+            assert ei.value.code == Code.META_NO_PERMISSION

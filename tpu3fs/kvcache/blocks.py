@@ -95,7 +95,8 @@ class PrefixBlockStore:
     # -- lookup -------------------------------------------------------------
     def match_prefix(self, token_ids: Sequence[int]) -> PrefixMatch:
         """Longest-prefix lookup: ONE batched presence probe over the
-        whole chain, then the longest run of present blocks from the
+        whole chain (over RPC one batchStatByPath round trip a meta
+        partition), then the longest run of present blocks from the
         start. (A mid-chain hole ends the match — later blocks' KV
         depends on the missing tokens' positions being resident.)"""
         keys = self.block_keys(token_ids)

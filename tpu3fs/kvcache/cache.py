@@ -402,9 +402,10 @@ class KVCacheClient:
             return data
 
     def batch_get(self, keys: Sequence[str]) -> List[Optional[bytes]]:
-        """Stat all keys, then read every hit as ONE node-grouped chunk
-        batch (StorageClient.batch_read underneath) and refresh every
-        hit's mtime as ONE batched touch."""
+        """Stat all keys as ONE batched stat (over RPC one
+        batchStatByPath round trip a meta partition), then read every
+        hit as ONE node-grouped chunk batch (StorageClient.batch_read
+        underneath) and refresh every hit's mtime as ONE batched touch."""
         with tagged(TrafficClass.KVCACHE), self._tenant_ctx():
             paths = [shard_path(self.root, k) for k in keys]
             inodes: List[object] = [self._cached_inode(k) for k in keys]
@@ -453,7 +454,9 @@ class KVCacheClient:
     def batch_contains(self, keys: Sequence[str]) -> List[bool]:
         """Presence of many keys via one batched stat — the prefix-match
         probe (blocks.match_prefix) where per-key stats would make prefix
-        lookup O(chain length) round trips."""
+        lookup O(chain length) round trips. Over RPC that is one
+        batchStatByPath a meta partition; a meta server that cannot be
+        reached raises, it is not a list of misses."""
         paths = [shard_path(self.root, k) for k in keys]
         with tagged(TrafficClass.KVCACHE):
             inodes = self._meta.batch_stat_by_path(paths)

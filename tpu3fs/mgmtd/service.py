@@ -39,6 +39,7 @@ from tpu3fs.mgmtd.types import (
     TargetInfo,
 )
 from tpu3fs.rpc.serde import deserialize, serialize
+from tpu3fs.utils.logging import xlog
 from tpu3fs.utils.result import Code, FsError, Status
 
 _LEASE_KEY = KeyPrefix.LEASE.value + b"primary"
@@ -130,6 +131,10 @@ class Mgmtd:
         # primacy edge detection for tick(): a standby reloads from KV on
         # promotion before running any background mutator
         self._was_primary = False
+        # self-stall detection for tick(): when this process last ran a
+        # tick, on the monotonic clock (never the injected one: a test
+        # that advances its fake clock has not stalled anybody)
+        self._last_tick_mono: Optional[float] = None
         # version-gated getRoutingInfo fast-path counter (lazy: most unit
         # tests never poll with a current version)
         self._not_modified_rec = None
@@ -820,6 +825,9 @@ class Mgmtd:
                 dead.append(node.node_id)
         if not dead:
             return dead
+        xlog("WARN", "mgmtd %d: nodes %s silent for over %.1fs: declared "
+             "dead, their targets go OFFLINE", self.node_id, dead,
+             self.config.heartbeat_timeout_s)
 
         def op(txn: ITransaction) -> None:
             for node_id in dead:
@@ -995,6 +1003,10 @@ class Mgmtd:
         src/mgmtd/background/): lease extension, heartbeat checking, chain
         updates, newborn-chain promotion, target-info persistence, metrics."""
         now = self._clock() if now is None else now
+        mono = time.monotonic()
+        stalled = (0.0 if self._last_tick_mono is None
+                   else mono - self._last_tick_mono)
+        self._last_tick_mono = mono
         was_primary = self._was_primary
         lease = self.extend_lease(now)  # updates _was_primary
         if lease.primary_node_id != self.node_id:
@@ -1024,6 +1036,20 @@ class Mgmtd:
             # get a full timeout to re-report before being judged.
             for node in self._routing.nodes.values():
                 node.last_heartbeat = max(node.last_heartbeat, now)
+        elif stalled > self.config.heartbeat_timeout_s / 2:
+            # SELF-STALL GRACE: this process did not tick for `stalled`
+            # seconds (stopped, or its host took the cores away) and in
+            # that time could not take a heartbeat either, so the silence
+            # is its own, not the nodes'. Judging them by it declares the
+            # whole fleet dead in one sweep, every chain loses all its
+            # targets at once and none is left to resync the others from.
+            # The stall does not count against anybody; a node that did
+            # die meanwhile is found one stall later.
+            xlog("WARN", "mgmtd %d did not tick for %.1fs (> T/2 = %.1fs): "
+                 "the silence is its own, no node is judged by it",
+                 self.node_id, stalled, self.config.heartbeat_timeout_s / 2)
+            for node in self._routing.nodes.values():
+                node.last_heartbeat = min(now, node.last_heartbeat + stalled)
         self.check_heartbeats(now)
         try:
             self._prune_serving(now)

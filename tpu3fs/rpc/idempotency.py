@@ -73,6 +73,7 @@ CLASSIFICATION: Dict[Tuple[str, str], str] = {
     ("MetaSerde", "setAttr"): MUTATING,
     ("MetaSerde", "pruneSession"): MUTATING,
     ("MetaSerde", "batchStat"): IDEMPOTENT,
+    ("MetaSerde", "batchStatByPath"): IDEMPOTENT,
     ("MetaSerde", "authenticate"): IDEMPOTENT,
     ("MetaSerde", "setXattr"): MUTATING,
     ("MetaSerde", "getXattr"): IDEMPOTENT,

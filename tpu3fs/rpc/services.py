@@ -1562,6 +1562,19 @@ class BatchStatRsp:
 
 
 @dataclass
+class BatchStatByPathReq:
+    """Batched stat by path — the kvcache probe / ckpt restore shape: one
+    RPC for a whole prefix's block files instead of one stat round trip
+    per path. The reply is a BatchStatRsp in request order, nothing for a
+    path that is missing or that the user may not walk."""
+
+    paths: List[str] = field(default_factory=list)
+    uid: int = 0
+    gid: int = 0
+    token: str = ""
+
+
+@dataclass
 class BatchMkdirsReq:
     """Batched ensure-directory (mkdir -p semantics by default) — the
     kvcache cold-drain shape: one RPC for every uncached shard dir
@@ -1848,6 +1861,9 @@ def bind_meta_service(server: RpcServer, meta: MetaStore, *,
         return BatchMkdirsRsp(out)
 
     s.method(26, "batchMkdirs", BatchMkdirsReq, BatchMkdirsRsp, batch_mkdirs)
+    # 64 walks a read-only transaction (MetaStore.batch_stat_by_path)
+    s.method(30, "batchStatByPath", BatchStatByPathReq, BatchStatRsp,
+             lambda r: BatchStatRsp(meta.batch_stat_by_path(r.paths, u(r))))
 
     # Two-phase participant plane (cross-partition rename/hardlink): bound
     # only when the store is sharded. All three are replay-safe — prepare
@@ -1894,8 +1910,13 @@ META_METHOD_NAMES = {
     19: "setXattr", 20: "getXattr", 21: "listXattrs", 22: "removeXattr",
     23: "batchClose", 24: "batchSetAttr", 25: "batchCreate",
     26: "batchMkdirs", 27: "renamePrepare", 28: "renameFinish",
-    29: "renameResolve",
+    29: "renameResolve", 30: "batchStatByPath",
 }
+
+#: most paths one batchStatByPath RPC carries: a reply stays at tens of KiB
+#: and one caller's long list does not hold a single-threaded server from
+#: the others; a longer list goes out as several
+BATCH_STAT_PATHS_MAX = 256
 
 
 class MetaRpcClient:
@@ -1961,12 +1982,14 @@ class MetaRpcClient:
             return None
         return (node.host, node.port)
 
-    def _call(self, method_id: int, req, rsp_type, *, pid: Optional[int] = None):
+    def _call(self, method_id: int, req, rsp_type, *, pid: Optional[int] = None,
+              nbytes: int = 0):
         """The one place every meta call passes: one ``meta.<method>`` op
         span a call (a batched op that fans out per partition is one span
         a partition call), the RPC hop's stages beneath it."""
         with _spans.root_span(
-                f"meta.{META_METHOD_NAMES.get(method_id, method_id)}"):
+                f"meta.{META_METHOD_NAMES.get(method_id, method_id)}",
+                nbytes=nbytes):
             return self._call_op(method_id, req, rsp_type, pid=pid)
 
     def _call_op(self, method_id: int, req, rsp_type, *, pid: Optional[int]):
@@ -2223,16 +2246,27 @@ class MetaRpcClient:
             [self._pid_inode(i) for i in inode_ids], inode_ids, one)
 
     def batch_stat_by_path(self, paths: List[str]) -> List[Optional[Inode]]:
-        """Missing/forbidden paths come back as None (MetaStore parity —
-        consumers like the ckpt loader and kvcache batch_get treat None
-        as a miss)."""
-        out: List[Optional[Inode]] = []
-        for p in paths:
-            try:
-                out.append(self.stat(p))
-            except FsError:
-                out.append(None)
-        return out
+        """Stat many paths in one RPC a partition (a list longer than
+        BATCH_STAT_PATHS_MAX goes out as several), results in request
+        order. Missing/forbidden paths come back as None (MetaStore
+        parity — consumers like the ckpt loader and kvcache batch_get
+        treat None as a miss). A meta server that cannot be reached is
+        NOT a miss: the call raises after the failover ladder like every
+        other meta call. The op span ``meta.batchStatByPath`` carries the
+        number of paths as its ``nbytes`` (a count)."""
+        paths = list(paths)
+
+        def one(pid, sub):
+            out: List[Optional[Inode]] = []
+            for base in range(0, len(sub), BATCH_STAT_PATHS_MAX):
+                part = sub[base:base + BATCH_STAT_PATHS_MAX]
+                out.extend(self._call(
+                    30, BatchStatByPathReq(part), BatchStatRsp, pid=pid,
+                    nbytes=len(part)).inodes)
+            return out
+
+        return self._fan_batches(
+            [self._pid_path(p) for p in paths], paths, one)
 
     def rename(self, src: str, dst: str, user=None) -> None:
         # src's owner coordinates (it clears the src dirent at commit);

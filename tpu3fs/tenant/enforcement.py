@@ -82,6 +82,7 @@ ENFORCEMENT: Dict[Tuple[str, str], str] = {
     ("MetaSerde", "setAttr"): IOPS,
     ("MetaSerde", "pruneSession"): EXEMPT,
     ("MetaSerde", "batchStat"): IOPS,
+    ("MetaSerde", "batchStatByPath"): IOPS,
     ("MetaSerde", "authenticate"): EXEMPT,   # the op that NAMES a tenant
     ("MetaSerde", "setXattr"): IOPS,
     ("MetaSerde", "getXattr"): IOPS,
