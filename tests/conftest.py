@@ -3,7 +3,7 @@
 Mirrors the reference's trick of running the full multi-node suite in one
 process (tests/lib/UnitTestFabric.h): multi-chip sharding is validated on a
 virtual CPU mesh. Tests pin the CPU whatever the machine holds; what runs on
-a chip is chip_smoke.py and bench.py, never pytest.
+a chip is chip_smoke.py and perfbench/run.py, never pytest.
 """
 
 import os

@@ -24,19 +24,13 @@ def _run(args, **env):
         timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu", **env})
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_bare_run_without_a_tpu_fails_and_reports_nothing(script):
     out = _run([script])
     assert out.returncode != 0
     assert "no TPU" in out.stderr
     # no result object, under any name
     assert not [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
-
-
-def test_bench_worker_alone_fails_without_a_tpu():
-    out = _run(["bench.py", "--worker", "e2e_tpu"])
-    assert out.returncode != 0 and "no TPU" in out.stderr
-    assert "{" not in out.stdout
 
 
 def test_smoke_children_are_pinned_to_the_cpu(monkeypatch):

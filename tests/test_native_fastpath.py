@@ -19,6 +19,7 @@ from tpu3fs.rpc.services import (
     bind_mgmtd_service,
     bind_storage_service,
 )
+from tpu3fs.storage import native_fastpath
 from tpu3fs.storage.craq import StorageService
 from tpu3fs.storage.native_fastpath import sync_read_fastpath
 from tpu3fs.storage.target import StorageTarget
@@ -402,12 +403,21 @@ class TestNativeWriteFastpath:
             ChunkId(26, 0)) == b"q" * 100
 
 
+def _python_head(monkeypatch) -> None:
+    """From the next sync on, every node's head writes ride the Python
+    dispatch: `_sync_head` stands the native head down while it observes
+    a write fault armed on the node, and this makes it observe one."""
+    monkeypatch.setattr(native_fastpath, "_write_faults_armed",
+                        lambda node_id: True)
+
+
 class TestNativeHeadWritePath:
     """Client-entry write/batchWrite served end to end by the C++ head
     (fp_try_head_write): decode, admission, exactly-once, engine stage,
     chain forward, CRC cross-check, commit — all below the GIL. The
-    contract: byte-identical to the Python dispatch under the
-    TPU3FS_NATIVE_WRITE A/B lever, exactly-once intact across the
+    contract: byte-identical to the Python dispatch (which the head
+    stands down to while a write fault is armed on the node: the seam
+    `_python_head` pulls), exactly-once intact across the
     fast-path/fallback boundary, and the planted skip-crc chaos bug
     observable only when armed."""
 
@@ -415,7 +425,7 @@ class TestNativeHeadWritePath:
         for n in env["nodes"].values():
             sync_read_fastpath(n["server"], n["svc"])
 
-    def test_ab_lever_byte_identity_and_worker_bypass(self, native_chain,
+    def test_byte_identity_and_worker_bypass(self, native_chain,
                                                       monkeypatch):
         """The same payloads against disjoint chunks through each path:
         field-identical replies, identical replica bytes + metadata — and
@@ -440,9 +450,9 @@ class TestNativeHeadWritePath:
             "head batchWrite must be served natively"
         assert update_worker.rounds_run() == r0, \
             "a natively served write must never run a Python worker round"
-        # the A/B lever: TPU3FS_NATIVE_WRITE=0 stands the head down at the
-        # next sync; the same writes then ride the Python dispatch
-        monkeypatch.setenv("TPU3FS_NATIVE_WRITE", "0")
+        # an armed write fault stands the head down at the next sync; the
+        # same writes then ride the Python dispatch
+        _python_head(monkeypatch)
         self._sync_all(env)
         s1 = head.fastpath_write_stats()
         golden = sc.batch_write(
@@ -450,7 +460,7 @@ class TestNativeHeadWritePath:
             chunk_size=CHUNK)
         assert all(r.ok for r in golden), golden
         assert head.fastpath_write_stats()[0] == s1[0], \
-            "lever off: the head must not serve natively"
+            "stood down: the head must not serve natively"
         assert update_worker.rounds_run() > r0, \
             "the Python head path runs through the update workers"
         for f, g, p in zip(fast, golden, payloads.values()):
@@ -471,7 +481,7 @@ class TestNativeHeadWritePath:
     def test_exactly_once_replay_across_path_swap(self, native_chain,
                                                   monkeypatch):
         """One channel table serves both paths: a retry replayed natively,
-        and then replayed AGAIN after the lever swaps the head to Python,
+        and then replayed AGAIN after the head stood down to Python,
         must splice back the stored reply — applied exactly once."""
         from tpu3fs.rpc.services import RpcMessenger
         from tpu3fs.storage.craq import WriteReq
@@ -505,7 +515,7 @@ class TestNativeHeadWritePath:
         assert stale.code == Code.CHUNK_STALE_UPDATE
         # swap the head to the Python dispatch: the C channel table is
         # SHARED, so the same replays still dedupe across the boundary
-        monkeypatch.setenv("TPU3FS_NATIVE_WRITE", "0")
+        _python_head(monkeypatch)
         self._sync_all(env)
         replay2 = send(10, "write", req(1, b"once" * 100))
         assert (replay2.code, replay2.update_ver, replay2.commit_ver,
@@ -610,8 +620,8 @@ class TestNativeHeadWriteGates:
     def test_tenant_throttle_rides_native_and_python_identically(
             self, native_node, monkeypatch):
         """TENANT_THROTTLED + typed retry_after_ms through the native head
-        gate, and the same hint through the Python dispatch under the A/B
-        lever (satellite: the hints must survive the path swap)."""
+        gate, and the same hint through the Python dispatch once the head
+        stood down (the hints must survive the path swap)."""
         from tpu3fs.client.storage_client import RetryOptions, StorageClient
         from tpu3fs.qos.core import AdmissionController, QosConfig
         from tpu3fs.tenant import registry, tenant_scope
@@ -646,9 +656,9 @@ class TestNativeHeadWriteGates:
                          if r.code == Code.TENANT_THROTTLED]
             assert throttled, [r.code for r in native]
             assert all(r.retry_after_ms > 0 for r in throttled)
-            # the A/B lever: the same flood through the Python dispatch
-            # carries the same typed hint
-            monkeypatch.setenv("TPU3FS_NATIVE_WRITE", "0")
+            # the same flood through the Python dispatch carries the
+            # same typed hint
+            _python_head(monkeypatch)
             sync_read_fastpath(server, svc)
             s1 = server.fastpath_write_stats()
             with tenant_scope("wg-alice"):
@@ -656,7 +666,7 @@ class TestNativeHeadWriteGates:
                     [(CHAIN, ChunkId(40, 2), 0, b"p" * 256)],
                     chunk_size=CHUNK)[0] for _ in range(10)]
             assert server.fastpath_write_stats()[0] == s1[0], \
-                "lever off: the head must not serve natively"
+                "stood down: the head must not serve natively"
             py_throttled = [r for r in pyth
                             if r.code == Code.TENANT_THROTTLED]
             assert py_throttled, [r.code for r in pyth]

@@ -217,7 +217,7 @@ class TestRSXorFastPath:
 class TestPallasKernel:
     """Fused GF(2) matmul kernel vs the einsum/gold paths (interpret mode
     so the kernel logic runs in CPU CI; the real lowering is exercised on
-    TPU by bench.py)."""
+    TPU by chip_smoke.py)."""
 
     def test_encode_bit_exact(self):
         from tpu3fs.ops.pallas_rs import gf2_matmul, prepare_matrix

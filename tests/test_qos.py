@@ -659,8 +659,7 @@ class TestOverloadSoak:
         runs against foreground reads for a few seconds, with QoS
         scheduling ON vs OFF. Asserts the scheduled run keeps queue depth
         bounded and sheds background instead of foreground; records both
-        p99s (the comparative number is captured by benchmarks/
-        qos_bench.py under BENCH_* conventions)."""
+        p99s."""
 
         def drive(qos_on: bool) -> dict:
             qcfg = None
@@ -737,7 +736,7 @@ class TestOverloadSoak:
         assert scheduled["depth"] <= 8
         # loose comparative bound: scheduling must not make foreground
         # reads worse than the unscheduled chaos by more than 2x (it is
-        # typically much better; exact numbers land in BENCH_QOS.json)
+        # typically much better)
         assert scheduled["p99_ms"] <= max(unscheduled["p99_ms"] * 2.0, 50.0), (
             scheduled, unscheduled)
 

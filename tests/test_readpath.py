@@ -328,15 +328,14 @@ class TestZeroCopyFraming:
 
     @pytest.fixture
     def rpc_cluster(self):
-        from benchmarks.storage_bench import _RpcCluster
+        from rpc_cluster import RpcCluster
 
-        cluster = _RpcCluster(replicas=2, chains=2, size=CHUNK,
-                              transport="python", engine="mem")
+        cluster = RpcCluster(replicas=2, chains=2, size=CHUNK)
         yield cluster
         cluster.close()
 
     def test_batch_read_zero_copy_and_exact(self, rpc_cluster):
-        from benchmarks.storage_bench import FILE_ID
+        from rpc_cluster import FILE_ID
         from tpu3fs.client.storage_client import ReadReq, RetryOptions
         from tpu3fs.storage.types import ChunkId
 
@@ -363,7 +362,7 @@ class TestZeroCopyFraming:
 
     def test_striped_fanout_equivalence(self, rpc_cluster):
         """Forced striping returns byte-identical results to unstriped."""
-        from benchmarks.storage_bench import FILE_ID
+        from rpc_cluster import FILE_ID
         from tpu3fs.client.storage_client import ReadReq, RetryOptions
         from tpu3fs.storage.types import ChunkId
 

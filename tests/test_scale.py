@@ -61,7 +61,7 @@ class TestScaleFabricSmall:
     def test_routing_fast_reply_version_gated(self):
         """getRoutingInfo(current_version) -> None, counted on
         mgmtd.routing_not_modified; any routing change reopens the full
-        snapshot path (the fleet-wide fan-out saver BENCH_SCALE prices)."""
+        snapshot path (what saves the fleet-wide fan-out)."""
         sf = ScaleFabric(ScaleConfig(num_nodes=12, num_domains=3))
         ri = sf.mgmtd.get_routing_info(-1)
         assert ri is not None

@@ -57,9 +57,9 @@ class TestStorageClientInMem:
 @pytest.fixture
 def socket_cluster():
     """Small live cluster; the factory must build working stubs for it."""
-    from benchmarks.storage_bench import _RpcCluster
+    from rpc_cluster import RpcCluster
 
-    cluster = _RpcCluster(replicas=2, chains=2, size=4096)
+    cluster = RpcCluster(replicas=2, chains=2, size=4096)
     yield cluster
     cluster.close()
 

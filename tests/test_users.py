@@ -195,7 +195,7 @@ class TestAuthGateRegressions:
     def test_session_ops_authorize_not_just_authenticate(self, cluster):
         """A VALID non-root token must still be denied on other users' state:
         prune_session needs admin, close/sync need PERM_W on the inode,
-        batch_stat masks unreadable inodes (ADVICE r1 high finding)."""
+        batch_stat masks unreadable inodes (a round-1 review's high finding)."""
         server, users, meta = cluster
         from tpu3fs.meta.store import OpenFlags, User
 

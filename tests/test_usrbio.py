@@ -594,20 +594,18 @@ class TestRingTransport:
                              for i in range(8)])
         assert [bytes(r.data) for r in got] == [
             bytes([i + 1]) * 700 for i in range(8)]
-        # equivalence against a sockets-only client
-        import os as _os
-
-        _os.environ["TPU3FS_USRBIO"] = "0"
-        try:
-            sc2, m2 = _mk_client(ring_cluster, "rc-sock")
-            got2 = sc2.batch_read([ReadReq(chain, ChunkId(1, i), 0, -1)
-                                   for i in range(8)])
-            assert [bytes(r.data) for r in got2] == \
-                [bytes(r.data) for r in got]
-            assert not m2._usrbio_rings
-            sc2.close()
-        finally:
-            del _os.environ["TPU3FS_USRBIO"]
+        # equivalence against a sockets-only client: every node already
+        # in the "handshake tried and failed" state, as a cross-host or
+        # pre-USRBIO node would be
+        sc2, m2 = _mk_client(ring_cluster, "rc-sock")
+        for node_id in m2._routing().nodes:
+            m2._usrbio_rings[node_id] = None
+        got2 = sc2.batch_read([ReadReq(chain, ChunkId(1, i), 0, -1)
+                               for i in range(8)])
+        assert [bytes(r.data) for r in got2] == \
+            [bytes(r.data) for r in got]
+        assert not any(m2._usrbio_rings.values())
+        sc2.close()
         sc.close()
 
     def test_a_traced_ring_hop_carries_the_server_s_stamps(self,

@@ -16,11 +16,11 @@ check's shape:
 
 2. SPEC POINTS — every ``point=<name>`` occurrence in the repo's
    Python, JSON (the ``tests/chaos_seeds/`` corpus), TOML, and Markdown
-   files (drive scripts, tests, benches, docs examples, deploy
-   configs), plus the chaos generator's ``FAULT_POINTS`` menu. Fire
-   sites in tests/drive scripts count too (a test may fire its own
-   synthetic point), and a line carrying ``# fault-ok`` is exempt
-   (parse-only grammar tests).
+   files (drive scripts, tests, docs examples, deploy configs), plus
+   the chaos generator's ``FAULT_POINTS`` menu. Fire sites in
+   tests/drive scripts count too (a test may fire its own synthetic
+   point), and a line carrying ``# fault-ok`` is exempt (parse-only
+   grammar tests).
 
 3. RESOLUTION — a spec point ``S`` resolves iff some fired name can
    start with it: a static point ``P`` with ``P.startswith(S)``, or a
@@ -39,7 +39,7 @@ from typing import List, Set, Tuple
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: directories scanned for fault specs (point= occurrences)
-SPEC_DIRS = ("tpu3fs", "tests", "benchmarks", "tools", "docs", "deploy",
+SPEC_DIRS = ("tpu3fs", "tests", "tools", "docs", "deploy",
              os.path.join(".claude", "skills", "verify"))
 SPEC_EXTS = (".py", ".json", ".toml", ".md")
 
@@ -53,9 +53,9 @@ _POINT_RE = re.compile(
 
 INJECT_FNS = {"inject", "inject_result"}
 
-#: fire sites may also live in tests/benches/drive scripts (a test that
+#: fire sites may also live in tests/drive scripts (a test that
 #: defines AND fires its own synthetic point is self-contained)
-FIRE_DIRS = ("tpu3fs", "tests", "benchmarks",
+FIRE_DIRS = ("tpu3fs", "tests",
              os.path.join(".claude", "skills", "verify"))
 
 

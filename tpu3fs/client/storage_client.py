@@ -184,7 +184,7 @@ class StorageClient:
         self._ec_chain_fallback = CounterRecorder("ec.chain_encode_fallback")
         # cumulative client-side encode CPU (seconds inside encode_parity
         # on the write path) — the offload the chain encode exists to
-        # deliver; read by benchmarks/ec_bench.py, not a wire metric
+        # deliver; read by tests/test_chain_encode.py, not a wire metric
         self.encode_cpu_s = 0.0
         # gray-failure defenses (docs/robustness.md): per-peer health —
         # the socket messenger shares its registry (its breaker also
@@ -237,11 +237,8 @@ class StorageClient:
         direct dispatch completes in microseconds and the pool handoff
         would cost 5x the work itself (measured 21 -> 4 GiB/s on the
         fabric batch-read path)."""
-        import os
-
         if (len(items) <= 1
-                or not getattr(self._messenger, "parallel_fanout", False)
-                or os.environ.get("TPU3FS_CLIENT_FANOUT", "1") == "0"):
+                or not getattr(self._messenger, "parallel_fanout", False)):
             for item in items:
                 fn(item)
             return
@@ -260,6 +257,35 @@ class StorageClient:
                     self, WorkerPool.shutdown, self._pool, False)
             pool = self._pool
         pool.map(fn, items)
+
+    def _write_groups(self, groups: List[Tuple[int, List]],
+                      method: str) -> List[List[UpdateReply]]:
+        """Send [(node_id, [op, ...])] as one `method` batch a node ->
+        per-group reply lists aligned with the ops. The ONE place the
+        write fan-out is chosen, by what the messenger is: one that has
+        `batch_write_pipelined` (the socket and ring transports) gets
+        every node group striped over pooled connections with ALL
+        requests on the wire before any reply is collected — bulk frames
+        gathered straight from the caller's buffers, so the server
+        overlaps engine staging and chain forwarding of one stripe with
+        the upload of the next; the in-process fabric messenger has none
+        and keeps `_fan_out` direct dispatch. A transport error comes
+        back as that group's per-op replies on both."""
+        pipelined = getattr(self._messenger, "batch_write_pipelined", None)
+        if pipelined is not None:
+            return pipelined(groups, method=method)
+        out: List[List[UpdateReply]] = [[] for _ in groups]
+
+        def _send(item) -> None:
+            gi, (node_id, ops) = item
+            try:
+                out[gi] = list(self._messenger(node_id, method, ops))
+            except FsError as e:
+                out[gi] = [UpdateReply(e.code, message=e.status.message)
+                           for _ in ops]
+
+        self._fan_out(_send, list(enumerate(groups)))
+        return out
 
     def _repoll(self) -> RoutingInfo:
         """Expire the provider's held snapshot and ask again: what a
@@ -903,35 +929,12 @@ class StorageClient:
                 by_node[node.node_id].append(i)
 
             items = list(by_node.items())
-            pipelined = getattr(self._messenger, "batch_write_pipelined",
-                                None)
-            if pipelined is not None and items and getattr(
-                    self._messenger, "write_pipelined", True):
-                # striped multi-connection fan-out with pipelined issue:
-                # every node group's stripes (bulk frames gathered straight
-                # from the caller's buffers) go on the wire BEFORE any
-                # reply is collected — the server overlaps engine staging
-                # and chain forwarding of one stripe with the upload of
-                # the next (socket messengers only; the in-process fabric
-                # keeps direct dispatch below)
-                groups = [(node_id, [reqs[i] for i in idxs])
-                          for node_id, idxs in items]
-                for (node_id, idxs), got in zip(items, pipelined(groups)):
-                    for i, reply in zip(idxs, got):
-                        replies[i] = reply
-            else:
-                def _issue_write(item) -> None:
-                    node_id, idxs = item
-                    try:
-                        got = self._messenger(
-                            node_id, "batch_write", [reqs[i] for i in idxs])
-                        for i, reply in zip(idxs, got):
-                            replies[i] = reply
-                    except FsError as e:
-                        for i in idxs:
-                            replies[i] = UpdateReply(e.code)
-
-                self._fan_out(_issue_write, items)
+            groups = [(node_id, [reqs[i] for i in idxs])
+                      for node_id, idxs in items]
+            for (_, idxs), got in zip(
+                    items, self._write_groups(groups, "batch_write")):
+                for i, reply in zip(idxs, got):
+                    replies[i] = reply
         finally:
             for slot in channels:
                 if slot is not None:
@@ -1152,37 +1155,17 @@ class StorageClient:
         return last or UpdateReply(Code.CLIENT_RETRIES_EXHAUSTED)
 
     def _send_shard_batches(self, by_node) -> List[Tuple[int, object]]:
-        """One batch_write_shard per node — striped + pipelined across
-        pooled connections when the messenger supports it (socket
-        transports), thread-pool fan-out otherwise; -> merged
-        [(stripe index, reply)] collected after the barrier (list.append
-        is atomic; the CALLER merges counters single-threaded to avoid
-        lost-update races on shared indices)."""
-        events: List[Tuple[int, object]] = []
+        """One batch_write_shard per node (`_write_groups`) -> merged
+        [(stripe index, reply)] collected after the barrier (the CALLER
+        merges counters single-threaded to avoid lost-update races on
+        shared indices)."""
         items = list(by_node.items())
-        pipelined = getattr(self._messenger, "batch_write_pipelined", None)
-        if pipelined is not None and items and getattr(
-                self._messenger, "write_pipelined", True):
-            groups = [(node_id, [r for _, r in group])
-                      for node_id, group in items]
-            for (node_id, group), got in zip(
-                    items, pipelined(groups, method="batch_write_shard")):
-                for (b, _), reply in zip(group, got):
-                    events.append((b, reply))
-            return events
-
-        def _send(item) -> None:
-            node_id, group = item
-            try:
-                got = self._messenger(
-                    node_id, "batch_write_shard", [r for _, r in group])
-            except FsError:
-                return
-            for (b, _), reply in zip(group, got):
-                events.append((b, reply))
-
-        self._fan_out(_send, items)
-        return events
+        groups = [(node_id, [r for _, r in group])
+                  for node_id, group in items]
+        return [(b, reply)
+                for (_, group), got in zip(
+                    items, self._write_groups(groups, "batch_write_shard"))
+                for (b, _), reply in zip(group, got)]
 
     def write_stripes(
         self,
@@ -2051,15 +2034,7 @@ class StorageClient:
         come back as per-op replies — the caller's round loop retries."""
         if not reqs:
             return []
-        pipelined = getattr(self._messenger, "batch_write_pipelined", None)
-        if pipelined is not None and getattr(
-                self._messenger, "write_pipelined", True):
-            return pipelined([(node_id, reqs)], method="batch_update")[0]
-        try:
-            return list(self._messenger(node_id, "batch_update", reqs))
-        except FsError as e:
-            return [UpdateReply(e.code, message=e.status.message)
-                    for _ in reqs]
+        return self._write_groups([(node_id, reqs)], "batch_update")[0]
 
     def query_last_chunk(self, chain_id: int, file_id: int) -> Tuple[int, int]:
         """Last (chunk index, byte length) of a file on one chain — the

@@ -17,8 +17,8 @@ monitor recorders — no private storage path.
 - ``state``    — the four-integer resumable cursor, composing with ckpt
   save sessions (a restored job resumes mid-epoch exactly)
 
-Driven by ``admin_cli dataload-pack|dataload-inspect``,
-``bin/dataload_pack_main.py`` and ``benchmarks/dataload_bench.py``.
+Driven by ``admin_cli dataload-pack|dataload-inspect`` and
+``bin/dataload_pack_main.py``.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ observed batch latency.
 ``recordio.plan_coalesced`` merges sorted record extents whose gap is
 below a threshold — trading over-read wire bytes against per-span round
 trips. The 64 KiB default was measured ONCE on one host/record-size
-combination (dataload_bench sweep); the right value moves with record
+combination; the right value moves with record
 size, transport and storage load. This controller learns it online from
 the ``dataload.batch_ms`` signal the loader already measures per batch
 (the stage-timing substrate of the tracing PR), with no extra IO:

@@ -64,13 +64,13 @@ class LoaderConfig:
     # CONCURRENTLY (delivery stays in order) — batch K+1's round trips
     # overlap K's. Default 1: on a single-host python transport the GIL
     # serializes the per-request work and extra threads only contend
-    # (measured in dataload_bench); raise it when fetches are genuinely
+    # (measured on a 1-CPU host); raise it when fetches are genuinely
     # wait-bound (many storage nodes, native transport)
     workers: int = 1
     max_buffered_bytes: int = 256 << 20
     verify_crc: bool = True
     # merge sorted record extents when the gap is below this: 64 KiB
-    # measured best on the served read path (dataload_bench sweep —
+    # measured best on the served read path (a 1-CPU sweep —
     # over-read costs wire bytes faster than spans cost round trips
     # beyond that). <= 0 = ADAPTIVE: a GapController (autotune.py)
     # learns the gap online from observed dataload.batch_ms

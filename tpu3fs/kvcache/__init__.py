@@ -19,8 +19,8 @@ GC remove-op IOPS), grown into a serving subsystem:
   lease encoding)
 
 All IO rides the ``kvcache`` QoS class (foreground-weighted,
-share-bounded). Driven by ``admin_cli kvcache-stats|kvcache-gc`` and
-``benchmarks/kvcache_bench.py``; docs/kvcache.md has the contracts.
+share-bounded). Driven by ``admin_cli kvcache-stats|kvcache-gc``;
+docs/kvcache.md has the contracts.
 """
 
 from tpu3fs.kvcache.blocks import (  # noqa: F401

@@ -151,7 +151,7 @@ def incidence_of_routing(
 
 def _stats(M: np.ndarray, node_ids: List[int], factor: int) -> PlanStats:
     # float64 BLAS then round: integer matmul has no BLAS path in numpy
-    # and runs ~100x slower at 10k-chain tables (BENCH_SCALE rebalance);
+    # and runs ~100x slower at 10k-chain tables;
     # co-occurrence counts are << 2^53 so the float trip is exact
     Mf = M.astype(np.float64)
     C = (Mf.T @ Mf).astype(np.int64)

@@ -350,6 +350,33 @@ def bind_storage_service(server: RpcServer, svc: StorageService) -> None:
     server.add_service(s)
 
 
+#: Striped read fan-out: a node group whose estimated payload clears
+#: ``READ_STRIPE_MIN_BYTES`` is split into up to ``READ_STRIPES``
+#: sub-batches, each pipelined on its OWN pooled connection — the server's
+#: workers run the stripes concurrently and the replies stream back in
+#: parallel instead of serializing on one socket. Why 4 MiB: sub-MiB
+#: stripes cost more in per-RPC serde/GIL than they win in parallelism,
+#: so only multi-MiB node groups (ckpt restore, large batch loads) split.
+READ_STRIPES = 4
+READ_STRIPE_MIN_BYTES = 4 << 20
+#: write-side twins of the read striping plan (payload-weighted: a write's
+#: size is known exactly from the op data)
+WRITE_STRIPES = 4
+WRITE_STRIPE_MIN_BYTES = 4 << 20
+#: Ring WRITE stripe cap: socket write stripes exist to pipeline bytes
+#: over separate connections, but over shm a stripe is a separate
+#: chain-batch on the server (its own engine crossing, update-queue round
+#: and commit) with no wire to overlap — measured ~35% faster as ONE SQE
+#: per node group. Reads keep the socket striping (stripe replies pipeline
+#: the agent's copy with the client's parse even on one core; measured
+#: ~2x vs one SQE).
+USRBIO_WRITE_STRIPES = 1
+#: ring depth (SQEs) and registered-buffer size of a messenger's ring to
+#: one same-host node (docs/usrbio.md)
+USRBIO_ENTRIES = 128
+USRBIO_IOV_BYTES = 64 << 20
+
+
 class _RingPending:
     """A pipelined fan-out entry riding a shm ring instead of a socket."""
 
@@ -382,8 +409,13 @@ class RpcMessenger:
     # (StorageClient._fan_out); in-process messengers leave this unset
     parallel_fanout = True
 
+    # the stripe plan (the constants above say why each value)
+    _stripes = READ_STRIPES
+    _stripe_min_bytes = READ_STRIPE_MIN_BYTES
+    _write_stripes = WRITE_STRIPES
+    _write_stripe_min_bytes = WRITE_STRIPE_MIN_BYTES
+
     def __init__(self, routing_provider, client: Optional[RpcClient] = None):
-        import os
         import threading
 
         from tpu3fs.rpc.health import HealthRegistry
@@ -393,25 +425,10 @@ class RpcMessenger:
         self._resolved: Dict[int, Tuple[str, int]] = {}  # node -> last _addr
         self._client = client or RpcClient()
         # USRBIO shm rings: node id -> RingClient (None = handshake tried
-        # and failed / not same-host — sockets forever for that node).
-        # TPU3FS_USRBIO=0 is the A/B lever the bench uses.
-        self._usrbio = os.environ.get("TPU3FS_USRBIO", "1") != "0"
-        self._usrbio_entries = int(os.environ.get(
-            "TPU3FS_USRBIO_ENTRIES", "128"))
-        self._usrbio_iov_bytes = int(os.environ.get(
-            "TPU3FS_USRBIO_IOV_MB", "64")) << 20
+        # and failed / not same-host — sockets forever for that node)
         self._usrbio_rings: Dict[int, object] = {}
         self._usrbio_pending: set = set()
         self._usrbio_lock = threading.Lock()
-        # ring WRITE stripe cap: socket write stripes exist to pipeline
-        # bytes over separate connections, but over shm a stripe is a
-        # separate chain-batch on the server (its own engine crossing,
-        # update-queue round and commit) with no wire to overlap —
-        # measured ~35% faster as ONE SQE per node group. Reads keep the
-        # socket striping (stripe replies pipeline the agent's copy with
-        # the client's parse even on one core; measured ~2x vs one SQE).
-        self._ring_write_stripes = max(1, int(os.environ.get(
-            "TPU3FS_USRBIO_WRITE_STRIPES", "1")))
         # per-peer health + circuit breakers (rpc/health.py): every timed
         # call feeds the node's EWMA/error streak; an OPEN breaker makes
         # MUTATING calls fail fast with the retryable PEER_UNHEALTHY
@@ -419,30 +436,6 @@ class RpcMessenger:
         # free probes). StorageClient shares this registry for its
         # replica ordering + hedge delays.
         self.health = HealthRegistry()
-        # A/B lever: TPU3FS_RPC_INLINE=1 turns bulk framing off so the
-        # two wire forms can be benchmarked against each other
-        self._bulk = os.environ.get("TPU3FS_RPC_INLINE", "") != "1"
-        # striped read fan-out: a node group whose estimated payload
-        # clears the threshold is split into up to TPU3FS_READ_STRIPES
-        # sub-batches, each pipelined on its OWN pooled connection — the
-        # server's workers run the stripes concurrently and the replies
-        # stream back in parallel instead of serializing on one socket
-        # threshold tuned on the rpc storage_bench: sub-MiB stripes cost
-        # more in per-RPC serde/GIL than they win in parallelism, so only
-        # multi-MiB node groups (ckpt restore, large batch loads) split
-        self._stripes = max(1, int(os.environ.get(
-            "TPU3FS_READ_STRIPES", "4")))
-        self._stripe_min_bytes = int(os.environ.get(
-            "TPU3FS_READ_STRIPE_MIN", str(4 << 20)))
-        # write-side twin of the read striping knobs; write_pipelined is
-        # the A/B lever the write bench uses (off = the per-node fan-out
-        # path, the pre-pipelining wire behavior)
-        self.write_pipelined = os.environ.get(
-            "TPU3FS_WRITE_PIPELINED", "1") != "0"
-        self._write_stripes = max(1, int(os.environ.get(
-            "TPU3FS_WRITE_STRIPES", "4")))
-        self._write_stripe_min_bytes = int(os.environ.get(
-            "TPU3FS_WRITE_STRIPE_MIN", str(4 << 20)))
 
     def _addr(self, node_id: int) -> Tuple[str, int]:
         node = self._routing().nodes.get(node_id)
@@ -469,8 +462,6 @@ class RpcMessenger:
         handshake in flight — callers use sockets). The first caller per
         node performs the handshake outside the lock; concurrent callers
         fall back to sockets meanwhile instead of queueing."""
-        if not self._usrbio:
-            return None
         with self._usrbio_lock:
             if node_id in self._usrbio_rings:
                 ring = self._usrbio_rings[node_id]
@@ -514,8 +505,8 @@ class RpcMessenger:
                 nonce = f.read().strip()
         except OSError:
             return None  # cannot read the server's shm: different host
-        ring = _ut.RingClient(entries=self._usrbio_entries,
-                              iov_bytes=self._usrbio_iov_bytes,
+        ring = _ut.RingClient(entries=USRBIO_ENTRIES,
+                              iov_bytes=USRBIO_IOV_BYTES,
                               agent_pid=rsp.pid)
         try:
             reg = self._client.call(
@@ -740,16 +731,6 @@ class RpcMessenger:
             except FsError as e:
                 pend.append((gi, 0, len(reqs), e))
                 continue
-            if not self._bulk:
-                # inline wire form: one unstriped call per group (the A/B
-                # lever measures framing, not fan-out)
-                try:
-                    pend.append((gi, 0, len(reqs), c.start_call(
-                        addr, STORAGE_SERVICE_ID, 11, BatchReadReq(reqs),
-                        BatchReadRsp)))
-                except FsError as e:
-                    pend.append((gi, 0, len(reqs), e))
-                continue
             ring = self._ring_for(node_id)
             for lo, hi in self._stripe_spans(reqs):
                 span = reqs[lo:hi]
@@ -870,20 +851,10 @@ class RpcMessenger:
             except FsError as e:
                 pend.append((gi, 0, len(ops), e))
                 continue
-            if not self._bulk:
-                # inline wire form: one unstriped call per group (the A/B
-                # lever measures framing, not fan-out)
-                try:
-                    pend.append((gi, 0, len(ops), c.start_call(
-                        addr, STORAGE_SERVICE_ID, method_id, req_cls(ops),
-                        BatchWriteRsp)))
-                except FsError as e:
-                    pend.append((gi, 0, len(ops), e))
-                continue
             ring = self._ring_for(node_id)
             spans = self._write_stripe_spans(ops)
             if ring is not None:
-                spans = self._cap_spans(spans, self._ring_write_stripes)
+                spans = self._cap_spans(spans, USRBIO_WRITE_STRIPES)
             for lo, hi in spans:
                 span = ops[lo:hi]
                 ctrl = req_cls([replace(op, data=b"") for op in span])
@@ -963,9 +934,6 @@ class RpcMessenger:
         """Single write-ish op: the chunk payload rides the bulk section,
         the control envelope carries everything else — no payload
         concatenation anywhere on the send path."""
-        if not self._bulk:
-            return self._client.call(addr, STORAGE_SERVICE_ID, method_id,
-                                     op, UpdateReply)
         ctrl = replace(op, data=b"")
         rsp, _ = self._client.call_bulk(
             addr, STORAGE_SERVICE_ID, method_id, ctrl, UpdateReply,
@@ -973,9 +941,6 @@ class RpcMessenger:
         return rsp
 
     def _batch_write(self, addr, method_id: int, ops, req_cls):
-        if not self._bulk:
-            return self._client.call(addr, STORAGE_SERVICE_ID, method_id,
-                                     req_cls(ops), BatchWriteRsp).replies
         iovs = [op.data for op in ops]
         ctrl = req_cls([replace(op, data=b"") for op in ops])
         rsp, _ = self._client.call_bulk(
@@ -1028,8 +993,6 @@ class RpcMessenger:
         if method == "update":
             return self._one_write(addr, 2, payload)
         if method == "read":
-            if not self._bulk:
-                return c.call(addr, sid, 3, payload, ReadReply)
             # empty bulk section = "I speak bulk; reply with data in bulk"
             rsp, segs = c.call_bulk(addr, sid, 3, payload, ReadReply,
                                     bulk_iovs=())
@@ -1059,9 +1022,6 @@ class RpcMessenger:
         if method == "space_info":
             return c.call(addr, sid, 10, Empty(), SpaceInfo)
         if method == "batch_read":
-            if not self._bulk:
-                return c.call(addr, sid, 11, BatchReadReq(payload),
-                              BatchReadRsp).replies
             rsp, segs = c.call_bulk(addr, sid, 11, BatchReadReq(payload),
                                     BatchReadRsp, bulk_iovs=())
             return self._attach_read_segs(rsp.replies, segs)

@@ -20,9 +20,8 @@ skipped validation and the seeded search catches it).
 ``fillClaim``/``fillRelease`` expose the TTL-leased fill-intent table
 (singleflight.FillClaims) that makes storage fills cluster-wide
 single-flight; ``servingStats`` snapshots the host; ``servingLoad`` is
-the bench/driver workload surface (threads inside the REAL process, so
-BENCH_SERVING.json measures actual cross-process serving, not a
-harness).
+the driver workload surface (threads inside the REAL process, so a
+drive measures actual cross-process serving, not a harness).
 """
 
 from __future__ import annotations

@@ -197,19 +197,14 @@ def _inproc_messenger(messenger) -> bool:
 
 
 def _overlap_enabled() -> bool:
-    v = os.environ.get("TPU3FS_WRITE_OVERLAP")
-    if v is not None:
-        return v != "0"
-    # adaptive default: a single hardware thread cannot actually run the
-    # local stage and the forward concurrently — the helper-thread
-    # handoff only adds latency there (the reference assumes dedicated
-    # IO threads). TPU3FS_WRITE_OVERLAP=1/0 forces either way.
+    # a single hardware thread cannot actually run the local stage and
+    # the forward concurrently — the helper-thread handoff only adds
+    # latency there (the reference assumes dedicated IO threads)
     return (os.cpu_count() or 1) > 1
 
 
-def _overlap_min_bytes() -> int:
-    # below this, a thread handoff costs more than the overlap wins
-    return int(os.environ.get("TPU3FS_WRITE_OVERLAP_MIN", str(32 << 10)))
+# below this, a thread handoff costs more than the overlap wins
+_OVERLAP_MIN_BYTES = 32 << 10
 
 
 class _SyncReplaceNeeded(Exception):
@@ -1090,7 +1085,7 @@ class StorageService:
                 inproc = _inproc_messenger(self._messenger)
                 if (self._messenger is not None and not inproc
                         and _overlap_enabled()
-                        and len(req.data) >= _overlap_min_bytes()
+                        and len(req.data) >= _OVERLAP_MIN_BYTES
                         and self._successor_of(target, chain) is not None):
                     overlap = _OverlapForward(
                         lambda: self._forward(target, req, update_ver,
@@ -1816,7 +1811,7 @@ class StorageService:
                 self._messenger is not None and self._ici is None
                 and not _inproc_messenger(self._messenger)
                 and _overlap_enabled()
-                and sum(len(r.data) for r in reqs) >= _overlap_min_bytes()
+                and sum(len(r.data) for r in reqs) >= _OVERLAP_MIN_BYTES
                 and self._successor_of(target, chain) is not None)
             ops: List[EngineUpdateOp] = []
             op_idx: List[int] = []
@@ -2456,7 +2451,7 @@ class StorageService:
                 if (not _inproc_messenger(self._messenger)
                         and _overlap_enabled()
                         and sum(len(r.data or b"") for r in freqs)
-                        >= _overlap_min_bytes()):
+                        >= _OVERLAP_MIN_BYTES):
                     # stream the remaining shards + updated accumulators
                     # to the successor WHILE the local engine stages —
                     # the chain pipelines: hop latency ~ max(stage, relay)

@@ -17,7 +17,6 @@ only_chunk_engine switch, src/storage/store/StorageTarget.h:85-162):
 from __future__ import annotations
 
 import abc
-import os
 import sys
 import threading
 from dataclasses import dataclass, replace
@@ -296,108 +295,52 @@ class _Arena:
     - an extent is recycled only when NOTHING references it anymore —
       live content views (including zero-copy read replies and buffers
       adopted by a successor replica) hold buffer exports on the extent,
-      so ``sys.getrefcount`` gates reuse exactly;
-    - ``prefault_bytes`` touches extents once at construction so the
-      first install burst (e.g. a checkpoint save right after bringup)
-      does not pay the first-touch cost either; set via
-      TPU3FS_MEM_PREALLOC_MB (benchmarks/daemons — tests default to 0).
+      so ``sys.getrefcount`` gates reuse exactly.
 
     The trade: one live content slice pins its whole extent. For the mem
     engine's workloads (serving + simulation) that bounded slack is
-    cheaper than re-faulting every write.
-
-    Extents are drawn from (and on close returned to) a PROCESS-GLOBAL
-    warm pool shared by every engine instance: a closed fabric's extents
-    re-warm the next one instead of going back to the OS cold, and total
-    arena RSS stays bounded by the pool cap."""
+    cheaper than re-faulting every write. An engine reuses only its OWN
+    retired extents; they go back to the allocator with the engine."""
 
     _EXTENT_BYTES = 8 << 20
-    _pool: List = []          # process-global warm extents
-    _pool_lock = threading.Lock()
-    _pool_prefaulted = False
     # process-wide arena accounting for the memory-observability gauges
     # (mem.arena_* via monitor/memory.py): extents ever materialized and
     # extent draws satisfied by recycling instead of fresh allocation
+    _stats_lock = threading.Lock()
     _created_extents = 0
     _recycled_extents = 0
 
-    @classmethod
-    def _pool_cap_bytes(cls) -> int:
-        return int(os.environ.get("TPU3FS_MEM_PREALLOC_MB", "0")) << 20
-
-    @classmethod
-    def _prefault_pool(cls, prefault_bytes: int) -> None:
-        """Touch the warm pool into existence ONCE per process (engine
-        preallocation happens at bringup, never inside a timed install)."""
-        with cls._pool_lock:
-            if cls._pool_prefaulted:
-                return
-            cls._pool_prefaulted = True
-            for _ in range(max(0, prefault_bytes) // cls._EXTENT_BYTES):
-                ext = np.empty(cls._EXTENT_BYTES, dtype=np.uint8)
-                ext[:] = 0  # touch every page now
-                cls._pool.append(ext)
-
-    def __init__(self, prefault_bytes: int = 0):
+    def __init__(self):
         self._extent_bytes = self._EXTENT_BYTES
         self._retired: List = []  # fully-bumped extents (maybe pinned)
         self._cur = None
         self._off = 0
-        if prefault_bytes:
-            self._prefault_pool(prefault_bytes)
 
     def _next_extent(self):
         cls = type(self)
-        with self._pool_lock:
-            pool = cls._pool
-            for i in range(len(pool)):
-                # list slot + getrefcount argument == 2: no content view
-                # (buffer export) pins this extent anymore. NOTE: indexed
-                # access on purpose — a `for ... in enumerate(...)` loop
-                # binding holds a third reference and defeats the gate.
-                if sys.getrefcount(pool[i]) == 2:
-                    cls._recycled_extents += 1
-                    return pool.pop(i)
         for i in range(len(self._retired)):
+            # list slot + getrefcount argument == 2: no content view
+            # (buffer export) pins this extent anymore. NOTE: indexed
+            # access on purpose — a `for ... in enumerate(...)` loop
+            # binding holds a third reference and defeats the gate.
             if sys.getrefcount(self._retired[i]) == 2:
-                with self._pool_lock:
+                with self._stats_lock:
                     cls._recycled_extents += 1
                 return self._retired.pop(i)
-        with self._pool_lock:
+        with self._stats_lock:
             cls._created_extents += 1
         return np.empty(self._extent_bytes, dtype=np.uint8)
-
-    def close(self) -> None:
-        """Hand this arena's extents back to the process-global warm pool
-        (up to the cap) — the next engine starts warm instead of paying
-        first-touch again. Pinned extents are handed back too: the draw
-        path refcount-gates them, so they become usable the moment their
-        last content view dies."""
-        exts = self._retired
-        self._retired = []
-        if self._cur is not None:
-            exts.append(self._cur)
-            self._cur = None
-        with self._pool_lock:
-            budget = self._pool_cap_bytes() - len(
-                type(self)._pool) * self._extent_bytes
-            for ext in exts:
-                if budget < self._extent_bytes:
-                    break
-                type(self)._pool.append(ext)
-                budget -= self._extent_bytes
 
     @classmethod
     def stats(cls) -> dict:
         """Process-wide arena accounting for the mem.arena_* gauges:
-        resident = extents ever materialized (they live in arenas or the
-        warm pool until their last content view dies), recycled =
-        cumulative draws served warm instead of via fresh allocation."""
-        with cls._pool_lock:
+        resident = extents ever materialized (they live in their arena
+        until their last content view dies), recycled = cumulative
+        draws served warm instead of via fresh allocation."""
+        with cls._stats_lock:
             return {
                 "resident_bytes": cls._created_extents * cls._EXTENT_BYTES,
                 "recycled_bytes": cls._recycled_extents * cls._EXTENT_BYTES,
-                "pool_extents": len(cls._pool),
             }
 
     def alloc(self, n: int) -> Optional[memoryview]:
@@ -423,21 +366,14 @@ def arena_stats() -> dict:
 class MemChunkEngine(ChunkEngine):
     """In-memory engine with exact version/commit semantics."""
 
-    def __init__(self, prealloc_bytes: Optional[int] = None):
+    def __init__(self):
         self._chunks: Dict[bytes, _Slot] = {}
         self._lock = threading.RLock()
         # chunk keys with a staged pending version: keeps pending_metas()
         # O(pendings) — the healthy-chain repair probe must not scan the
         # whole index at steady state
         self._pending_keys: set = set()
-        if prealloc_bytes is None:
-            prealloc_bytes = int(os.environ.get(
-                "TPU3FS_MEM_PREALLOC_MB", "0")) << 20
-        self._arena = _Arena(prefault_bytes=prealloc_bytes)
-
-    def close(self) -> None:
-        # return arena extents to the process-global warm pool
-        self._arena.close()
+        self._arena = _Arena()
 
     def _own_content(self, data) -> object:
         """Own `data` as immutable content with ONE memcpy into warm

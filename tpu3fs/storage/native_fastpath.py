@@ -41,12 +41,12 @@ Head eligibility is strict on purpose: CR chain (not EC), every member
 SERVING, the local target IS the head, no other writer-chain member
 local (the forward must leave the node — a local successor would
 re-enter locks the C worker holds), no ICI replicator, and the
-successor's node resolvable to a host:port. ``TPU3FS_NATIVE_WRITE=0``
-is the A/B lever (byte-identity harness, benches). While the cluster
-fault plane carries a rule that could fire on this node's Python write
-path, head serving stands down for the sync interval — the C workers
-cannot evaluate plane rules per request, and a chaos schedule that
-arms ``storage.update`` must keep injecting.
+successor's node resolvable to a host:port. Every other target keeps
+the Python head, which is also what the parity tests compare the native
+one against. While the cluster fault plane carries a rule that could
+fire on this node's Python write path, head serving stands down for the
+sync interval — the C workers cannot evaluate plane rules per request,
+and a chaos schedule that arms ``storage.update`` must keep injecting.
 
 Ref: the reference's read AND write paths are native end to end by
 construction (src/storage/service/StorageOperator.cc + AioReadWorker.h,
@@ -57,7 +57,6 @@ bridges between the two .so's.
 from __future__ import annotations
 
 import ctypes
-import os
 
 from tpu3fs.mgmtd.types import LocalTargetState, PublicTargetState
 
@@ -82,13 +81,6 @@ _WRITE_FAULT_POINTS = (
     "rpc.dispatch.StorageSerde.write",
     "rpc.dispatch.StorageSerde.batchWrite",
 )
-
-
-def native_write_enabled() -> bool:
-    """The A/B lever: TPU3FS_NATIVE_WRITE=0 keeps head writes on the
-    Python dispatch (read every sync, so flipping mid-run takes effect
-    at the next target scan)."""
-    return os.environ.get("TPU3FS_NATIVE_WRITE", "1") != "0"
 
 
 def _native_engine_handle(target):
@@ -232,9 +224,7 @@ def _sync_head(server, svc, wanted_head: dict, lib) -> int:
     # planted chaos bug native_commit_skip_crc (tpu3fs/chaos/bugs.py):
     # synced every scan so the chaos drive's arm/disarm takes effect
     server.fastpath_set_skip_crc(bug_fire("native_commit_skip_crc"))
-    if wanted_head and (not native_write_enabled()
-                        or _write_faults_armed(svc.node_id)
-                        or svc.stopped):
+    if wanted_head and (_write_faults_armed(svc.node_id) or svc.stopped):
         wanted_head = {}
     stage_fn = commit_fn = None
     if wanted_head and lib is not None \

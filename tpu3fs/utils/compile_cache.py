@@ -3,7 +3,7 @@
 StripeCodec compiles one program per (k, m, S) x power-of-two batch bucket,
 so a cold process that serves through the device codec compiles dozens of
 shapes. Every entry point of this repo that compiles for the chip
-(chip_smoke.py, bench.py's workers, __graft_entry__.py) calls
+(chip_smoke.py, perfbench/run.py, __graft_entry__.py) calls
 enable_compile_cache() before its first jit; services pinned to the CPU do
 not.
 """
