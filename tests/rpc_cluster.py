@@ -22,7 +22,11 @@ FILE_ID = 4242
 
 
 class RpcCluster:
-    def __init__(self, *, replicas: int, chains: int, size: int):
+    def __init__(self, *, replicas: int, chains: int, size: int,
+                 ec: tuple = ()):
+        """ec=(k, m) makes every chain an RS(k, m) group of k+m targets
+        (target i holds shard i; `replicas` is then ignored) whose engine
+        chunk size is the shard size."""
         self.mgmtd = Mgmtd(1, MemKVEngine())
         self.mgmtd.extend_lease()
         mgmtd_server = RpcServer()
@@ -32,7 +36,14 @@ class RpcCluster:
         self.mgmtd_addr = mgmtd_server.address
         self.shared_client = RpcClient()
 
-        num_nodes = max(3, replicas)
+        if ec:
+            from tpu3fs.ops.stripe import shard_size_of
+
+            replicas = sum(ec)
+            target_size = shard_size_of(size, ec[0])
+        else:
+            target_size = size
+        num_nodes = 3 if ec else max(3, replicas)
         node_ids = [10 + i for i in range(num_nodes)]
         self.chain_ids = [900_001 + i for i in range(chains)]
         node_states: dict = {n: {} for n in node_ids}
@@ -59,12 +70,14 @@ class RpcCluster:
                 node_id = node_ids[(ci + r) % num_nodes]
                 target_id = 1000 + ci * 16 + r
                 svc_by_node[node_id].add_target(
-                    StorageTarget(target_id, chain_id, chunk_size=size,
-                                  engine="mem"))
+                    StorageTarget(target_id, chain_id,
+                                  chunk_size=target_size, engine="mem"))
                 self.mgmtd.create_target(target_id, node_id=node_id)
                 node_states[node_id][target_id] = LocalTargetState.UPTODATE
                 targets.append(target_id)
-            self.mgmtd.upload_chain(chain_id, targets)
+            self.mgmtd.upload_chain(chain_id, targets,
+                                    ec_k=ec[0] if ec else 0,
+                                    ec_m=ec[1] if ec else 0)
         self.mgmtd.upload_chain_table(1, self.chain_ids)
         for node_id in node_ids:
             self.mgmtd.heartbeat(node_id, 1, node_states[node_id])

@@ -790,3 +790,536 @@ class TestBatchReadRebuild:
         # peer must have served some recovery reads
         assert len(stats["read_sources"]) >= 4, stats["read_sources"]
         fab.close()
+
+
+# -- fresh partial stripes on the batch path (FileIoClient._write_ec_heads) --
+
+ENTRY = CHUNK * 9 // 16 + 64    # a KVCache entry's share of its chunk
+HEAD_LENGTHS = {"one_byte": 1, "S_minus_1": S - 1, "S": S, "S_plus_1": S + 1,
+                "kvcache_entry": ENTRY, "chunk_minus_1": CHUNK - 1}
+
+
+class _Spy:
+    """A messenger that counts (method, node) and can fail a method."""
+
+    def __init__(self, fab):
+        self.fab = fab
+        self.calls = []
+        self.fail = {}          # method -> how many calls still fail
+
+    def __call__(self, node_id, method, payload):
+        from tpu3fs.utils.result import FsError, Status
+
+        self.calls.append((method, node_id))
+        if self.fail.get(method, 0) > 0:
+            self.fail[method] -= 1
+            raise FsError(Status(Code.RPC_PEER_CLOSED, "injected"))
+        return self.fab.send(node_id, method, payload)
+
+    def count(self, method):
+        return sum(1 for m, _ in self.calls if m == method)
+
+
+def _spied(fab, **kw):
+    """(FileIoClient, StorageClient, spy) over a counting messenger."""
+    from tpu3fs.client.file_io import FileIoClient
+    from tpu3fs.client.storage_client import RetryOptions, StorageClient
+
+    spy = _Spy(fab)
+    kw.setdefault("retry", RetryOptions(
+        max_retries=3, backoff_base_s=0.0005, backoff_max_s=0.005))
+    client = StorageClient("spied", fab.routing, spy, **kw)
+    return FileIoClient(client), client, spy
+
+
+def _encode_sizes(monkeypatch):
+    """Batch sizes of every StripeCodec.encode_parity call from here on."""
+    from tpu3fs.ops.stripe import StripeCodec
+
+    sizes = []
+    inner = StripeCodec.encode_parity
+
+    def encode_parity(self, data):
+        sizes.append(int(data.shape[0]))
+        return inner(self, data)
+
+    monkeypatch.setattr(StripeCodec, "encode_parity", encode_parity)
+    return sizes
+
+
+def _open(fab, path):
+    return fab.meta.create(path, flags=OpenFlags.WRITE, client_id="c1").inode
+
+
+def _shards(fab, chain_id, cid, writable_only=False):
+    """[(engine, committed meta)] of a stripe by shard index; nothing may
+    be left pending."""
+    routing = fab.routing()
+    chain = routing.chains[chain_id]
+    out = []
+    for j in range(chain.ec_k + chain.ec_m):
+        t = chain.target_of_shard(j)
+        if writable_only and not t.public_state.can_write:
+            continue
+        engine = fab.nodes[routing.node_of_target(t.target_id).node_id] \
+            .service.target(t.target_id).engine
+        meta = engine.get_meta(cid)
+        assert meta is not None and meta.pending_ver == 0, (j, meta)
+        out.append((engine, meta))
+    return out
+
+
+def _stored(fab, chain_id, cid):
+    """What every shard target holds of a stripe: [(bytes, crc, logical
+    length, stored length)] by shard index."""
+    return [(bytes(engine.read(cid)), meta.checksum.value, meta.aux,
+             meta.length) for engine, meta in _shards(fab, chain_id, cid)]
+
+
+def _one_version(fab, chain_id, cid, writable_only=False):
+    """The committed version all shards of a stripe agree on."""
+    vers = {meta.committed_ver
+            for _, meta in _shards(fab, chain_id, cid, writable_only)}
+    assert len(vers) == 1, vers
+    return vers.pop()
+
+
+class TestHeadPartialBatch:
+    @pytest.mark.parametrize("name", sorted(HEAD_LENGTHS))
+    def test_fresh_batch_is_one_probe_one_encode_two_rounds(
+            self, name, monkeypatch):
+        """N fresh files of one length through batch_write_files: one
+        stat_chunks, one encode of B=N, one batch_write_shard a node a
+        phase, no single-shard RPC and no read — and every target stores
+        what write_stripe stores for the same bytes."""
+        n_bytes, N = HEAD_LENGTHS[name], 5
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        sizes = _encode_sizes(monkeypatch)
+        rng = np.random.default_rng(n_bytes)
+        bodies = [rng.integers(0, 256, n_bytes, dtype=np.uint8).tobytes()
+                  for _ in range(N)]
+        inodes = [_open(fab, f"/h{i}") for i in range(N)]
+        assert fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)]) \
+            == [n_bytes] * N
+        assert spy.count("stat_chunks") == 1
+        assert sizes == [N]
+        nodes = {n for m, n in spy.calls if m == "batch_write_shard"}
+        assert spy.count("batch_write_shard") == 2 * len(nodes)
+        assert {m for m, _ in spy.calls} == {"stat_chunks",
+                                             "batch_write_shard"}
+        assert client._ec_head_batched._value == (
+            N if n_bytes < CHUNK else 0)
+        assert client._ec_head_ladder._value == 0
+        # the single-stripe ladder on a twin chunk id is the reference
+        for i, (ino, body) in enumerate(zip(inodes, bodies)):
+            twin = ChunkId(10_000 + i, 0)
+            assert client.write_stripe(chain, twin, body,
+                                       chunk_size=CHUNK).ok
+            assert _stored(fab, chain, ChunkId(ino.id, 0)) == \
+                _stored(fab, chain, twin)
+            assert fio.read(ino, 0, n_bytes) == body
+        assert client.query_last_chunk(chain, inodes[0].id) == (0, n_bytes)
+
+    @pytest.mark.parametrize("old_len,new_len", [(1000, 300), (300, 1000)],
+                             ids=["over_longer", "over_shorter"])
+    def test_over_a_committed_stripe_takes_the_ladder(self, old_len,
+                                                      new_len):
+        """A short write over a longer committed stripe keeps the tail,
+        over a shorter one extends it: the probe found it present, the
+        ladder was taken and the recorder says so."""
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        ino = _open(fab, "/over")
+        fio.write(ino, 0, b"A" * old_len)
+        assert client._ec_head_batched._value == 1
+        spy.calls.clear()
+        fio.batch_write_files([(ino, 0, b"B" * new_len)])
+        assert client._ec_head_ladder._value == 1
+        assert client._ec_head_batched._value == 1
+        assert spy.count("stat_chunks") == 1
+        want = (b"B" * new_len + b"A" * old_len)[:max(old_len, new_len)] \
+            if new_len < old_len else b"B" * new_len
+        got = client.read_stripe(chain, ChunkId(ino.id, 0), 0, CHUNK,
+                                 chunk_size=CHUNK)
+        assert got.ok and got.logical_len == max(old_len, new_len)
+        assert bytes(got.data[:got.logical_len]) == want
+        _one_version(fab, chain, ChunkId(ino.id, 0))
+
+    @pytest.mark.parametrize("how", ["disk_lost", "written_while_down"])
+    def test_a_shard0_that_is_not_serving_cannot_say_absent(self, how):
+        """Shard 0's target answers (0, 0, 0) for stripes the other 15
+        hold — its disk was lost, or it was down while they were written
+        — and is SYNCING: it takes writes and answers the probe. Its
+        answer is not trusted: the short write takes the ladder, which
+        merges from k survivors, and every tail survives (a batched short
+        stripe would win the nonce about every second time)."""
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        routing = fab.routing()
+        t0 = routing.chains[chain].target_of_shard(0)
+        node0 = routing.node_of_target(t0.target_id).node_id
+        inodes = [_open(fab, f"/t{i}") for i in range(6)]
+        olds = [bytes([97 + i]) * (1000 + i) for i in range(6)]
+
+        def put_old():
+            fio.batch_write_files(
+                [(ino, 0, old) for ino, old in zip(inodes, olds)])
+
+        if how == "disk_lost":
+            put_old()
+            fab.fail_node(node0)
+            from tpu3fs.storage.engine import MemChunkEngine
+
+            fab.nodes[node0].service.target(t0.target_id).engine = \
+                MemChunkEngine()
+        else:
+            fab.fail_node(node0)
+            put_old()
+        fab.restart_node(node0)
+        fab.tick()
+        state = fab.routing().chains[chain].target_of_shard(0).public_state
+        assert state.name == "SYNCING" and state.can_write
+        engine0 = fab.nodes[node0].service.target(t0.target_id).engine
+        assert engine0.get_meta(ChunkId(inodes[0].id, 0)) is None
+        fio2, client2, spy2 = _spied(fab)
+        news = [bytes([65 + i]) * (300 + i) for i in range(6)]
+        fio2.batch_write_files(
+            [(ino, 0, new) for ino, new in zip(inodes, news)])
+        assert spy2.count("stat_chunks") == 1
+        assert client2._ec_head_ladder._value == 6
+        assert client2._ec_head_batched._value == 0
+        fab.resync_all()
+        for ino, old, new in zip(inodes, olds, news):
+            cid = ChunkId(ino.id, 0)
+            got = client2.read_stripe(chain, cid, 0, CHUNK, chunk_size=CHUNK)
+            assert got.ok and got.logical_len == len(old)
+            assert bytes(got.data[:got.logical_len]) == \
+                new + old[len(new):]
+            _one_version(fab, chain, cid)
+
+    def test_a_large_batch_goes_in_slices_of_one_dispatch(self,
+                                                          monkeypatch):
+        """40 fresh entries in one call: ONE probe, then slices of 16
+        stripes (an encode dispatch), each its own two shard rounds — the
+        client never holds more than a slice's shards."""
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        sizes = _encode_sizes(monkeypatch)
+        inodes = [_open(fab, f"/s{i}") for i in range(40)]
+        bodies = [bytes([i]) * (ENTRY + i) for i in range(40)]
+        fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)])
+        assert spy.count("stat_chunks") == 1
+        assert sizes == [16, 16, 8]
+        nodes = {n for m, n in spy.calls if m == "batch_write_shard"}
+        assert spy.count("batch_write_shard") == 3 * 2 * len(nodes)
+        assert client._ec_head_batched._value == 40
+        for ino, body in zip(inodes, bodies):
+            assert fio.read(ino, 0, len(body)) == body
+            _one_version(fab, chain, ChunkId(ino.id, 0))
+
+    def test_every_partial_stripe_write_passes_write_ec_chunk(
+            self, monkeypatch):
+        """FileIoClient._write_ec_chunk is called once for every
+        partial-stripe segment before anything of it is sent, the fresh
+        short ones of a batch included — the seam on which the
+        benchmark's fault kv_ack_without_write stands (it answers OK for
+        every second call and writes nothing). What it lets pass still
+        rides ONE batch; what it swallows never reaches a target."""
+        from tpu3fs.client.file_io import FileIoClient
+        from tpu3fs.storage.craq import UpdateReply
+        from tpu3fs.utils.result import Code
+
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        sizes = _encode_sizes(monkeypatch)
+        inner = FileIoClient._write_ec_chunk
+        seen = []
+
+        def acks_every_second(self, inode, chain_id, idx, in_off, part, cs):
+            seen.append((inode.id, idx, in_off, len(part)))
+            if len(seen) % 2 == 0:
+                return UpdateReply(Code.OK)
+            return inner(self, inode, chain_id, idx, in_off, part, cs)
+
+        monkeypatch.setattr(FileIoClient, "_write_ec_chunk",
+                            acks_every_second)
+        inodes = [_open(fab, f"/g{i}") for i in range(5)]
+        bodies = [bytes([48 + i]) * (ENTRY + i) for i in range(5)]
+        # five fresh entries, and a whole stripe with a short tail
+        tail = _open(fab, "/gt")
+        assert fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)]
+            + [(tail, 0, b"t" * (CHUNK + 9))]) == \
+            [len(b) for b in bodies] + [CHUNK + 9]
+        assert seen == [(ino.id, 0, 0, len(body))
+                        for ino, body in zip(inodes, bodies)] \
+            + [(tail.id, 1, 0, 9)]
+        # calls 2, 4 and 6 were swallowed: files 1, 3 and the tail
+        assert sizes == [4] and spy.count("stat_chunks") == 1
+        assert client._ec_head_batched._value == 3
+        for i, (ino, body) in enumerate(zip(inodes, bodies)):
+            got = client.read_stripe(chain, ChunkId(ino.id, 0), 0, CHUNK,
+                                     chunk_size=CHUNK)
+            if i % 2:
+                assert got.code == Code.CHUNK_NOT_FOUND
+            else:
+                assert bytes(got.data[:got.logical_len]) == body
+        assert client.read_stripe(chain, ChunkId(tail.id, 1), 0, CHUNK,
+                                  chunk_size=CHUNK).code == \
+            Code.CHUNK_NOT_FOUND
+        # a write through fio.write passes the same seam
+        fio.write(_open(fab, "/gw"), 0, b"w" * 77)
+        assert seen[-1][2:] == (0, 77)
+
+    def test_a_segment_inside_its_chunk_never_joins(self, monkeypatch):
+        fab = ec_fabric(chains=1)
+        fio, client, spy = _spied(fab)
+        ino = _open(fab, "/mid")
+        batches = []
+        inner = client.write_stripe_heads
+        monkeypatch.setattr(
+            client, "write_stripe_heads",
+            lambda *a, **kw: batches.append(a) or inner(*a, **kw))
+        fio.write(ino, 100, b"m" * 500)
+        fio.batch_write_files([(ino, 700, b"n" * 50)])
+        assert batches == [] and spy.count("stat_chunks") == 0
+        assert client._ec_head_batched._value == 0
+        assert client._ec_head_ladder._value == 0
+        assert fio.read(ino, 0, 750) == (b"\x00" * 100 + b"m" * 500
+                                         + b"\x00" * 100 + b"n" * 50)
+
+    def test_one_call_full_fresh_and_existing_over_two_files_two_chains(
+            self, monkeypatch):
+        """Full stripes, fresh and existing head-partials of two files
+        striped over two chains: one probe and one encode a chain."""
+        fab = ec_fabric(chains=2)
+        fio, client, spy = _spied(fab)
+        sizes = _encode_sizes(monkeypatch)
+        a = fab.meta.create("/a", flags=OpenFlags.WRITE, client_id="c1",
+                            stripe=2).inode
+        b = fab.meta.create("/b", flags=OpenFlags.WRITE, client_id="c1",
+                            stripe=2).inode
+        assert len(set(a.layout.chains)) == 2
+        # b's tail chunk exists already, longer than what comes
+        fio.write(b, CHUNK, b"old" * 400)
+        spy.calls.clear()
+        del sizes[:]
+        rng = np.random.default_rng(7)
+        body_a = rng.integers(0, 256, 2 * CHUNK + 777,
+                              dtype=np.uint8).tobytes()
+        body_b = rng.integers(0, 256, CHUNK + 500, dtype=np.uint8).tobytes()
+        before = client._ec_head_batched._value
+        fio.batch_write_files([(a, 0, body_a), (b, 0, body_b)])
+        assert spy.count("stat_chunks") == 2
+        # one encode a chain of what stayed in its batch: a's three chunks
+        # and b's first — b's existing tail left for the ladder
+        stayed = [a.layout.chain_of_chunk(i) for i in range(3)] \
+            + [b.layout.chain_of_chunk(0)]
+        assert sorted(sizes[:2]) == sorted(
+            stayed.count(c) for c in set(stayed)), sizes
+        assert client._ec_head_batched._value - before == 1   # a's tail
+        assert client._ec_head_ladder._value == 1             # b's tail
+        assert fio.read(a, 0, len(body_a)) == body_a
+        assert fio.read(b, 0, CHUNK + 1200) == \
+            body_b + (b"old" * 400)[500:]
+
+    def test_write_keeps_file_order_with_a_short_tail(self, monkeypatch):
+        """A multi-chunk write that starts inside a chunk and ends with a
+        short tail: the partial head run first, then ONE batch of the full
+        stripes and the tail; a failure of that batch leaves the clean
+        prefix and nothing after it."""
+        from tpu3fs.utils.result import FsError, Status
+
+        fab = ec_fabric(chains=1)
+        fio, client, spy = _spied(fab)
+        sizes = _encode_sizes(monkeypatch)
+        ino = _open(fab, "/order")
+        body = bytes(range(256)) * ((3 * CHUNK + 900) // 256)
+        fio.write(ino, 100, body)
+        assert sizes[-1] == 3 and spy.count("stat_chunks") == 1
+        assert fio.read(ino, 100, len(body)) == body
+        # now the same shape onto a fresh file, the batch failing
+        ino2 = _open(fab, "/order2")
+        order = []
+        inner_chunk = fio._write_ec_chunk
+
+        def boom(chain_id, items, *, chunk_size):
+            order.append(("heads", len(items)))
+            raise FsError(Status(Code.TARGET_OFFLINE, "injected"))
+
+        monkeypatch.setattr(
+            fio, "_write_ec_chunk",
+            lambda *a: order.append(("chunk", a[2])) or inner_chunk(*a))
+        monkeypatch.setattr(client, "write_stripe_heads", boom)
+        with pytest.raises(FsError):
+            fio.write(ino2, 100, body)
+        # the in-chunk head took the ladder; the short tail was announced
+        # (and handed back) before its run's batch, which then failed
+        assert order == [("chunk", 0), ("chunk", 3), ("heads", 3)]
+        assert fio.read(ino2, 100, CHUNK - 100) == body[:CHUNK - 100]
+        assert client.query_last_chunk(fab.chain_ids[0], ino2.id) == \
+            (0, CHUNK)
+
+    @pytest.mark.parametrize("fault", ["probe_target_offline",
+                                       "stage_batch_fails", "probe_fails"])
+    def test_faults_end_on_the_ladder_with_the_strict_rule(self, fault,
+                                                           monkeypatch):
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio, client, spy = _spied(fab)
+        routing = fab.routing()
+        if fault == "probe_target_offline":
+            t0 = routing.chains[chain].target_of_shard(0)
+            fab.fail_node(routing.node_of_target(t0.target_id).node_id)
+        elif fault == "stage_batch_fails":
+            spy.fail["batch_write_shard"] = 1
+        else:
+            spy.fail["stat_chunks"] = 1
+        inodes = [_open(fab, f"/f{i}") for i in range(3)]
+        bodies = [bytes([65 + i]) * (ENTRY + i) for i in range(3)]
+        fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)])
+        if fault == "stage_batch_fails":
+            # the batch was tried, a node's stage round was lost, and the
+            # single-stripe ladder finished every stripe at its version
+            assert client._ec_head_batched._value == 3
+            assert spy.count("write_shard") > 0
+        else:
+            # no answer from the probe: nothing short stays in the batch
+            assert client._ec_head_ladder._value == 3
+            assert spy.count("batch_write_shard") == 0
+        for ino, body in zip(inodes, bodies):
+            cid = ChunkId(ino.id, 0)
+            # strict: EVERY writable shard committed at one version
+            _one_version(fab, chain, cid, writable_only=True)
+            got = client.read_stripe(chain, cid, 0, CHUNK, chunk_size=CHUNK)
+            assert got.ok and bytes(got.data[:got.logical_len]) == body
+
+    @pytest.mark.parametrize("first_is_longer", [True, False],
+                             ids=["loser_shorter", "loser_longer"])
+    def test_two_writers_of_a_fresh_stripe_converge(self, first_is_longer):
+        """Both probe the stripe absent; B lands whole between A's probe
+        and A's stage. Whatever the nonces decide, every shard ends on ONE
+        committed version, nothing pending, and the stripe reads as bytes
+        that were sent, at an exact length (test_model_ec's E1 and E4)."""
+        fab = ec_fabric(chains=1)
+        chain = fab.chain_ids[0]
+        fio_a, client_a, _ = _spied(fab)
+        fio_b = fab.file_client()
+        ino = _open(fab, "/race")
+        a = b"A" * (700 if first_is_longer else 300)
+        b = b"B" * (300 if first_is_longer else 700)
+        state = {"raced": False}
+
+        class Racing(_Spy):
+            def __call__(self, node_id, method, payload):
+                if method == "batch_write_shard" and not state["raced"]:
+                    state["raced"] = True
+                    fio_b.write(ino, 0, b)
+                return super().__call__(node_id, method, payload)
+
+        client_a._messenger = Racing(fab)
+        fio_a.write(ino, 0, a)
+        assert state["raced"]
+        cid = ChunkId(ino.id, 0)
+        _one_version(fab, chain, cid)
+        got = client_a.read_stripe(chain, cid, 0, CHUNK, chunk_size=CHUNK)
+        assert got.ok
+        payload = bytes(got.data[:got.logical_len])
+        assert payload in (a, a + b[len(a):]), payload[:8]
+        assert not bytes(got.data[got.logical_len:]).strip(b"\x00")
+
+
+class TestCodecBuckets:
+    """No encode program is built on a later request's path: the first
+    dispatch prepares every bucket (counted where jit keeps its programs,
+    so it runs without a TPU)."""
+
+    STEP = 16
+
+    @pytest.fixture(scope="class")
+    def codec(self):
+        from tpu3fs.ops import stripe
+
+        codec = stripe.StripeCodec(K, M, 64)
+        codec._host_mode = False    # the 'device' is the CPU backend
+        assert stripe.DEVICE_BATCH_ITEMS == self.STEP
+        return codec
+
+    def _encode(self, codec, data, monkeypatch):
+        """encode_batch, and the batch size of each dispatch it made."""
+        sizes = []
+        inner = codec._encode_dev
+
+        def counted(part):
+            sizes.append(part.shape[0])
+            return inner(part)
+
+        monkeypatch.setattr(codec, "_encode_dev", counted)
+        out, _crcs = codec.encode_batch(data)
+        monkeypatch.undo()
+        return out, sizes
+
+    def test_the_first_dispatch_builds_every_bucket(self, codec,
+                                                    monkeypatch):
+        assert codec._encode_buckets() == [1, 2, 4, 8, 16]
+        assert codec._encode_dev._cache_size() == 0
+        codec.encode_batch(np.ones((3, K, 64), dtype=np.uint8))
+        assert codec._encode_dev._cache_size() == 5
+
+    @pytest.mark.parametrize("b", range(1, 2 * STEP + 1, 1))
+    def test_a_batch_of_any_size_builds_nothing_new(self, codec, b,
+                                                    monkeypatch):
+        rng = np.random.default_rng(b)
+        data = rng.integers(0, 256, (b, K, 64), dtype=np.uint8)
+        codec.encode_batch(data[:1])        # whoever comes first prepares
+        built = codec._encode_dev._cache_size()
+        assert built == 5
+        out, sizes = self._encode(codec, data, monkeypatch)
+        assert codec._encode_dev._cache_size() == built
+        assert all(s in codec._encode_buckets() for s in sizes)
+        assert sum(sizes) < 2 * b + 1 and len(sizes) == -(-b // self.STEP)
+        assert np.array_equal(out[:, K:], codec.rs.encode_host(data))
+        assert np.array_equal(out[:, :K], data)
+
+    def test_only_the_encode_is_held_to_sixteen(self, codec):
+        """The rebuild's reconstruct and the CRC keep the bound by bytes:
+        their dispatches return a fraction of what an encode's does."""
+        assert codec._device_step(K + 1) > self.STEP
+        assert codec._device_step(1) > self.STEP
+        big = np.random.default_rng(7).integers(
+            0, 256, (3 * self.STEP, 64), dtype=np.uint8)
+        sizes = []
+        inner = codec._crc_dev
+        codec._crc_dev = lambda part: (sizes.append(part.shape[0]),
+                                       inner(part))[1]
+        try:
+            crcs = codec.crc_batch(big)
+        finally:
+            codec._crc_dev = inner
+        assert crcs.shape == (3 * self.STEP,)
+        assert sizes == [64]        # one dispatch: 48 padded to a bucket
+
+    def test_the_serving_encode_prepares(self):
+        """encode_parity on the device branch is the call that prepares
+        (here the 'device' is the CPU backend)."""
+        from tpu3fs.ops import stripe
+
+        codec = stripe.StripeCodec(K, M, 64)
+        codec._host_mode = False
+        data = np.random.default_rng(1).integers(
+            0, 256, (3, K, 64), dtype=np.uint8)
+        parity, crcs = codec.encode_parity(data)
+        assert np.array_equal(parity, codec.rs.encode_host(data))
+        assert crcs.shape == (3, K + M)
+        assert codec._prepared
+        assert codec._encode_dev._cache_size() == \
+            len(codec._encode_buckets())

@@ -401,8 +401,10 @@ class TestTheDeploymentsSpans:
             stage = (names.get("kvcache.flush.batch_put")
                      or names["kvcache.flush.put_each"])[0]
             entries += stage["nbytes"]   # a count: entries of the drain
-            for want in ("client.write_stripe", "codec.encode",
-                         "client.write_stripe.stage_shards"):
+            for want in ("client.write_stripes", "codec.encode",
+                         "fio.write_ec_chunk.rmw_probe",
+                         "client.write_stripe.stage_shards",
+                         "client.write_stripe.commit_shards"):
                 assert want in names, (want, sorted(names))
         assert entries == 6
 

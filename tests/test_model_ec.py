@@ -27,6 +27,7 @@ is defense-in-depth past the modeled envelope.
 """
 
 import random
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -36,6 +37,7 @@ from tpu3fs.fabric.fabric import Fabric, SystemSetupConfig
 from tpu3fs.mgmtd.types import PublicTargetState
 from tpu3fs.storage.types import ChunkId
 from tpu3fs.utils.fault_injection import fault_injection
+from tpu3fs.utils.result import Code
 
 K, M = 3, 1
 CHUNK = 12 << 10
@@ -44,6 +46,8 @@ FILE_ID = 31
 
 
 class EcExplorer:
+    CHUNKS = NUM_CHUNKS
+
     def __init__(self, seed: int, *, nodes: int = 4, k: int = K, m: int = M):
         self.rng = random.Random(seed)
         self.np_rng = np.random.default_rng(seed)
@@ -57,8 +61,8 @@ class EcExplorer:
         self.client = self.fab.storage_client(retry=fast)
         self.chain = self.fab.chain_ids[0]
         # model state per chunk
-        self.sent = {i: set() for i in range(NUM_CHUNKS)}
-        self.acked = {i: {} for i in range(NUM_CHUNKS)}   # ver -> payload
+        self.sent = {i: set() for i in range(self.CHUNKS)}
+        self.acked = {i: {} for i in range(self.CHUNKS)}   # ver -> payload
 
     # -- actions -------------------------------------------------------------
     def _payload(self, idx: int) -> bytes:
@@ -69,7 +73,7 @@ class EcExplorer:
         return self.np_rng.integers(0, 256, n, dtype=np.uint8).tobytes()
 
     def act_write(self, faulty: bool = False) -> None:
-        idx = self.rng.randrange(NUM_CHUNKS)
+        idx = self.rng.randrange(self.CHUNKS)
         payload = self._payload(idx)
         self.sent[idx].add(payload)
         try:
@@ -88,7 +92,7 @@ class EcExplorer:
             self.acked[idx][r.commit_ver or r.update_ver] = payload
 
     def act_read(self) -> None:
-        idx = self.rng.randrange(NUM_CHUNKS)
+        idx = self.rng.randrange(self.CHUNKS)
         try:
             got = self.client.read_stripe(
                 self.chain, ChunkId(FILE_ID, idx), 0, CHUNK,
@@ -170,7 +174,7 @@ class EcExplorer:
         return bytes(got.data)
 
     def _check_reads(self, phase: str) -> None:
-        for idx in range(NUM_CHUNKS):
+        for idx in range(self.CHUNKS):
             if not self.acked[idx]:
                 continue
             got = self.client.read_stripe(
@@ -210,3 +214,136 @@ def test_random_ec_schedules_double_parity(seed):
     """RS(4,2): multi-loss rebuilds — the degraded-serving check (E3)
     kills m=2 nodes simultaneously after healing."""
     EcExplorer(900 + seed, nodes=6, k=4, m=2).run(steps=80)
+
+
+class HeadBatchExplorer(EcExplorer):
+    """The same schedules with the file client's stripe batch among the
+    writers: write_stripe_heads of one to three chunks at once, half of
+    them short. A short item is a write AT OFFSET 0, not a replacement:
+    fresh it rides the batch, over a stripe it comes back None and the
+    file client's ladder merges it — so what the model expects to read
+    is the new bytes followed by the TAIL of what was there (read just
+    before; the explorer is one thread). A short stripe installed over a
+    longer acknowledged one reads as bytes nobody was promised, and E1 /
+    E2 fail. Nodes also come back WITHOUT a rebuild round, so batches
+    meet SYNCING targets that hold nothing yet, and the chunks are many,
+    so that most stripes are at their first version when that happens
+    (a batch at version 1 is refused by a stripe that is further on)."""
+
+    CHUNKS = 24
+
+    def __init__(self, seed: int, **kw):
+        super().__init__(seed, **kw)
+        from tpu3fs.client.file_io import FileIoClient
+
+        self.fio = FileIoClient(self.client)
+
+    def _current(self, idx: int):
+        """The stripe's bytes now, b"" if absent, None if unreadable."""
+        try:
+            got = self.client.read_stripe(
+                self.chain, ChunkId(FILE_ID, idx), 0, CHUNK,
+                chunk_size=CHUNK)
+        except Exception:
+            return None
+        if got.ok:
+            return self._clamp(got)
+        return b"" if got.code == Code.CHUNK_NOT_FOUND else None
+
+    def act_write_batch(self, faulty: bool = False) -> None:
+        idxs = self.rng.sample(range(self.CHUNKS), self.rng.randrange(1, 4))
+        items, expect = [], []
+        for idx in idxs:
+            n = (self.rng.randrange(1, CHUNK) if self.rng.random() < 0.5
+                 else CHUNK)
+            payload = self.np_rng.integers(
+                0, 256, n, dtype=np.uint8).tobytes()
+            old = self._current(idx)
+            if old is None:
+                # unreadable now: merged with whichever sent payload lies
+                # there, or with nothing
+                olds = list(self.sent[idx]) + [b""]
+            else:
+                olds = [old]
+                if old not in self.acked[idx].values():
+                    # a torn, never acknowledged stripe may be replaced
+                    olds.append(b"")
+            merged = [payload + o[len(payload):] for o in olds]
+            self.sent[idx].update(merged)
+            expect.append(merged[0] if old is not None else None)
+            items.append((ChunkId(FILE_ID, idx), payload))
+        inode = SimpleNamespace(id=FILE_ID)
+
+        def put():
+            out = []
+            for idx, (cid, payload), r in zip(
+                    idxs, items, self.client.write_stripe_heads(
+                        self.chain, items, chunk_size=CHUNK)):
+                if r is None:
+                    assert len(payload) < CHUNK  # only a short one leaves
+                    try:
+                        r = self.fio._write_ec_ladder(
+                            inode, self.chain, idx, 0, payload, CHUNK)
+                    except Exception:
+                        r = None
+                out.append(r)
+            return out
+
+        try:
+            if faulty:
+                with fault_injection(0.4, times=1):
+                    replies = put()
+            else:
+                replies = put()
+        except Exception:
+            return
+        for idx, want, r in zip(idxs, expect, replies):
+            if r is not None and r.ok and want is not None:
+                self.acked[idx][r.commit_ver or r.update_ver] = want
+
+    def act_kill(self) -> None:
+        """One node at a time, and only off a fully rebuilt chain — a
+        node that came back unsynced still counts against the erasure
+        budget. Half the victims lose their disk with it."""
+        chain = self.fab.routing().chains[self.chain]
+        if not all(n.alive for n in self.fab.nodes.values()) or any(
+                t.public_state != PublicTargetState.SERVING
+                for t in chain.targets):
+            return
+        victim = self.rng.choice(list(self.fab.nodes.values()))
+        self.fab.fail_node(victim.node_id)
+        if self.rng.random() < 0.5:
+            from tpu3fs.storage.engine import MemChunkEngine
+
+            for target in victim.service.targets():
+                target.engine = MemChunkEngine()
+
+    def act_recover_unsynced(self) -> None:
+        dead = [n for n in self.fab.nodes.values() if not n.alive]
+        if dead:
+            self.fab.restart_node(self.rng.choice(dead).node_id)
+            self.fab.tick()
+
+    def run(self, steps: int = 60) -> None:
+        actions = [
+            (self.act_write, 8),
+            (self.act_write_batch, 24),
+            (lambda: self.act_write_batch(faulty=True), 10),
+            (self.act_read, 20),
+            (self.act_kill, 10),
+            (self.act_recover, 8),
+            (self.act_recover_unsynced, 8),
+            (self.act_tick, 8),
+        ]
+        fns = [fn for fn, w in actions for _ in range(w)]
+        for _ in range(steps):
+            self.rng.choice(fns)()
+        self.heal_and_check()
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_random_ec_schedules_with_head_batches(seed):
+    """Mutation-tested: trusting a SYNCING shard 0's "absent" (a short
+    stripe batched over a longer one the other shards hold) is caught at
+    seeds 4 and 9."""
+    HeadBatchExplorer(2000 + seed).run(steps=70)

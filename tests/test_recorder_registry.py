@@ -41,3 +41,13 @@ class TestValidators:
         # other tables in the doc (stage glossary, knobs) must NOT leak
         assert "issue" not in names
         assert "trace.sample_rate" not in names
+
+    def test_the_head_partial_counters_are_declared_and_documented(self):
+        """How often the fresh-partial-stripe batch engages: both ways of
+        a head-partial write have a recorder and a row."""
+        from tools.check_recorder_registry import collect_declarations
+
+        want = {"ec.head_partial_batched", "ec.head_partial_ladder"}
+        assert want <= set(doc_table_names())
+        declared = {d[0]: d[3] for d in collect_declarations()[0]}
+        assert want <= set(declared)

@@ -806,8 +806,8 @@ SPAN_TREES = {
     "kvcache.append_blocks": [
         "kvcache.append_blocks.probe", "kvcache.append_blocks.encode_array",
         "meta.*", "fio.batch_write_files", "fio.write_ec_chunk.rmw_probe",
-        "fio.write_ec_chunk.stripe_read", "client.write_stripe",
-        "client.write_stripe.encode", "client.write_stripe.stage_shards",
+        "client.write_stripes", "client.write_stripes.encode",
+        "client.write_stripe.stage_shards",
         "client.write_stripe.commit_shards", "codec.encode"],
     "kvcache.get_blocks": [
         "meta.*", "fio.batch_read_files", "fio.batch_read_files.plan",
@@ -867,29 +867,98 @@ class TestProfiledCapture:
         cov = sorted(t.coverage() for t in trees)
         assert cov[-1] >= 0.8 and cov[0] >= 0.6, cov
 
-    def test_the_put_ladder_down_to_the_shard_rpcs(self, profiled):
-        """(b) under each client.write_stripe: k+m stage hops and k+m
-        commit hops, each with the server's wait and run beside issue and
-        collect, and one codec.encode with a dispatch or the host's code."""
+    def test_the_batched_put_down_to_the_shard_rounds(self, profiled):
+        """(b) a put of three fresh blocks is ONE client.write_stripes: the
+        probe once with its one hop, one stage round and one commit round
+        of a hop a node, each hop with the server's wait and run beside
+        issue and collect, and one codec.encode of all three stripes."""
+        nodes = 3
         for tree in _trees_of(profiled, "kvcache.append_blocks"):
-            stripes = [r for r in tree.rows if r["op"] == "client.write_stripe"
-                       and not r["stage"]]
-            assert len(stripes) == 3    # a block is one partial stripe
-            for stripe in stripes:
-                for stage in ("stage_shards", "commit_shards"):
-                    (st,) = [r for r in tree.children[stripe["span_id"]]
-                             if r["stage"] == stage]
-                    hops = [r for r in tree.children[st["span_id"]]
-                            if r["op"].startswith("rpc.client.3.")]
-                    assert len(hops) == EC_K + EC_M
-                    for hop in hops:
-                        stages = {r["stage"] for r in
-                                  tree.children[hop["span_id"]]}
-                        assert {"issue", "collect", "server_wait",
-                                "server_run"} <= stages
-                (enc,) = [r for r in tree.children[stripe["span_id"]]
-                          if r["op"] == "codec.encode" and not r["stage"]]
-                assert enc["nbytes"] == EC_K * 8192 and enc["code"] == 1
+            assert not [r for r in tree.rows
+                        if r["op"] == "client.write_stripe" and not r["stage"]]
+            (batch,) = [r for r in tree.rows
+                        if r["op"] == "client.write_stripes"
+                        and not r["stage"]]
+            kids = tree.children[batch["span_id"]]
+            (probe,) = [r for r in kids if r["op"] == "fio.write_ec_chunk"
+                        and r["stage"] == "rmw_probe"]
+            assert len([r for r in tree.children[probe["span_id"]]
+                        if r["op"].startswith("rpc.client.3.")]) == 1
+            for stage in ("stage_shards", "commit_shards"):
+                (st,) = [r for r in kids if r["op"] == "client.write_stripe"
+                         and r["stage"] == stage]
+                hops = [r for r in tree.children[st["span_id"]]
+                        if r["op"].startswith("rpc.client.3.")]
+                assert len(hops) == nodes
+                for hop in hops:
+                    stages = {r["stage"] for r in
+                              tree.children[hop["span_id"]]}
+                    assert {"issue", "collect", "server_wait",
+                            "server_run"} <= stages
+            (enc,) = [r for r in kids
+                      if r["op"] == "codec.encode" and not r["stage"]]
+            assert enc["nbytes"] == 3 * EC_K * 8192 and enc["code"] == 1
+            # 1 probe + a stage and a commit batch a node: what
+            # sp.put.rpcs_per_block counts besides the meta calls
+            storage_hops = [r for r in tree.rows if not r["stage"]
+                            and r["op"].startswith("rpc.client.3.")]
+            assert len(storage_hops) == 1 + 2 * nodes
+
+    def test_a_put_over_existing_blocks_keeps_the_ladder_s_tree(
+            self, span_cluster, tracer, tmp_path):
+        """A head-partial over a committed stripe leaves the batch after
+        the probe: rmw_probe twice (the batch's, then the ladder's
+        delta-parity attempt, which lands here: the stripe is whole), no
+        shard round of the batch and no re-encode."""
+        from tpu3fs.meta.store import OpenFlags
+
+        meta, fio = span_cluster("ec")
+        res = meta.create("/over.bin", flags=OpenFlags.WRITE | OpenFlags.CREATE)
+        fio.write(res.inode, 0, b"a" * 9000)
+        tracer.configure(service="t", node=1, directory=str(tmp_path),
+                         sample_rate=1.0)
+        fio.batch_write_files([(res.inode, 0, b"b" * 300)])
+        rows = _rows(tracer)
+        names = [f"{r['op']}.{r['stage']}" if r["stage"] else r["op"]
+                 for r in rows]
+        assert names.count("fio.write_ec_chunk.rmw_probe") == 2
+        assert names.count("client.write_stripes") == 1
+        assert names.count("client.write_stripe_rmw") == 1
+        assert "client.write_stripe.stage_shards" not in names
+        assert "client.write_stripe" not in names
+        assert fio.read(res.inode, 0, 9000) == b"b" * 300 + b"a" * 8700
+
+    def test_the_device_dispatch_carries_the_batch(self, span_cluster,
+                                                   tracer, tmp_path):
+        """On the device branch (here: the CPU backend standing in) one
+        put of N fresh blocks is one codec.encode.dispatch whose nbytes is
+        N, the count sp.codec.stripes_per_dispatch reads."""
+        from tpu3fs.ops.stripe import get_codec, shard_size_of
+
+        codec = get_codec(EC_K, EC_M, shard_size_of(SPAN_CHUNK, EC_K))
+        store = _kv_store(span_cluster)
+        codec._host_mode = False
+        try:
+            store.append_blocks(list(range(7000, 7004)), _kv_blocks(70, 1))
+            tracer.configure(service="t", node=1, directory=str(tmp_path),
+                             sample_rate=1.0)
+            n = 5
+            tokens = list(range(7100, 7100 + 4 * n))
+            assert store.append_blocks(tokens, _kv_blocks(71, n)) == n
+        finally:
+            codec._host_mode = None
+        rows = _rows(tracer)
+        (dispatch,) = [r for r in rows if r["op"] == "codec.encode"
+                       and r["stage"] == "dispatch"]
+        assert dispatch["nbytes"] == n
+        (enc,) = [r for r in rows if r["op"] == "codec.encode"
+                  and not r["stage"]]
+        assert enc["code"] == 0 and enc["nbytes"] == n * EC_K * 8192
+        for stage in ("stage_shards", "commit_shards"):
+            assert len([r for r in rows if r["op"] == "client.write_stripe"
+                        and r["stage"] == stage]) == 1
+        assert len([r for r in rows if r["op"] == "fio.write_ec_chunk"
+                    and r["stage"] == "rmw_probe"]) == 1
 
     def test_annotations_lie_on_the_spans_by_the_anchor(self, profiled):
         """(c) every t3: annotation of the trace, converted by the anchor,

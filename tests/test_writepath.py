@@ -328,3 +328,153 @@ class TestBatchWriteFiles:
                                    length_hint=len(blob), wrote=True)
             assert fio.read(inode, 0, len(blob) + 10) == blob
         fab.close()
+
+
+# -- fresh partial EC stripes on the batch path, over real sockets -----------
+
+EC_K, EC_M = 3, 1
+EC_LENGTHS = {"one_byte": 1, "kvcache_entry": CHUNK * 9 // 16 + 64,
+              "chunk_minus_1": CHUNK - 1}
+
+
+@pytest.fixture
+def ec_cluster():
+    from rpc_cluster import RpcCluster
+
+    cluster = RpcCluster(replicas=0, chains=2, size=CHUNK, ec=(EC_K, EC_M))
+    yield cluster
+    cluster.close()
+
+
+def _ec_inode(cluster, file_id, chains=None):
+    from tpu3fs.meta.types import Acl, Inode, InodeType, Layout
+
+    return Inode(id=file_id, type=InodeType.FILE, acl=Acl(),
+                 layout=Layout(table_id=1,
+                               chains=list(chains or cluster.chain_ids[:1]),
+                               chunk_size=CHUNK))
+
+
+def _counting(client):
+    """Count the client's RPCs by (method, node): single calls and the
+    pipelined batch rounds alike."""
+    m = client._messenger
+    calls = []
+    inner_call, inner_pipe = m.__class__.__call__, m.batch_write_pipelined
+
+    class Counting(m.__class__):
+        def __call__(self, node_id, method, payload):
+            calls.append((method, node_id))
+            return inner_call(self, node_id, method, payload)
+
+        def batch_write_pipelined(self, groups, method="batch_write"):
+            calls.extend((method, node_id) for node_id, _ in groups)
+            return inner_pipe(groups, method=method)
+
+    m.__class__ = Counting
+    return calls
+
+
+def _ec_stored(cluster, chain_id, cid):
+    routing = cluster.mgmtd.get_routing_info()
+    out = []
+    for t in routing.chains[chain_id].targets:
+        (tgt,) = [svc.target(t.target_id) for svc in cluster.services
+                  if svc.target(t.target_id) is not None]
+        meta = tgt.engine.get_meta(cid)
+        assert meta is not None and meta.pending_ver == 0
+        out.append((bytes(tgt.engine.read(cid)), meta.checksum.value,
+                    meta.aux, meta.length))
+    return out
+
+
+class TestEcHeadPartialOverSockets:
+    @pytest.mark.parametrize("name", sorted(EC_LENGTHS))
+    def test_fresh_batch_rpc_counts_and_stored_shards(self, ec_cluster, name):
+        """N fresh head-partial files: one stat_chunks, one
+        batch_write_shard a node a phase, nothing else on the wire; what
+        the targets hold equals the single-stripe ladder's."""
+        from tpu3fs.client.file_io import FileIoClient
+
+        n_bytes, N = EC_LENGTHS[name], 4
+        chain = ec_cluster.chain_ids[0]
+        client = ec_cluster.storage_client()
+        fio = FileIoClient(client)
+        calls = _counting(client)
+        bodies = [os.urandom(n_bytes) for _ in range(N)]
+        inodes = [_ec_inode(ec_cluster, 800 + i) for i in range(N)]
+        assert fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)]) \
+            == [n_bytes] * N
+        assert [m for m, _ in calls].count("stat_chunks") == 1
+        rounds = [n for m, n in calls if m == "batch_write_shard"]
+        assert len(rounds) == 2 * len(set(rounds)) <= 2 * 3
+        assert {m for m, _ in calls} == {"stat_chunks", "batch_write_shard"}
+        assert client._ec_head_batched._value == N
+        for i, (ino, body) in enumerate(zip(inodes, bodies)):
+            twin = ChunkId(900 + i, 0)
+            assert client.write_stripe(chain, twin, body,
+                                       chunk_size=CHUNK).ok
+            assert _ec_stored(ec_cluster, chain, ChunkId(ino.id, 0)) == \
+                _ec_stored(ec_cluster, chain, twin)
+            assert fio.read(ino, 0, n_bytes) == body
+        client.close()
+
+    def test_existing_stripes_keep_their_tails_fresh_ones_batch(
+            self, ec_cluster):
+        """One call over two chains: full stripes, fresh head-partials and
+        one over a longer committed stripe — that one alone is laddered."""
+        from tpu3fs.client.file_io import FileIoClient
+
+        client = ec_cluster.storage_client()
+        fio = FileIoClient(client)
+        a = _ec_inode(ec_cluster, 820, ec_cluster.chain_ids)
+        b = _ec_inode(ec_cluster, 821, ec_cluster.chain_ids)
+        fio.write(b, 0, b"old" * 500)
+        calls = _counting(client)
+        body_a = os.urandom(2 * CHUNK + 321)
+        fio.batch_write_files([(a, 0, body_a), (b, 0, b"new" * 100)])
+        assert [m for m, _ in calls].count("stat_chunks") == 2
+        assert client._ec_head_ladder._value == 1
+        assert client._ec_head_batched._value == 2   # b's first, a's tail
+        assert fio.read(a, 0, len(body_a)) == body_a
+        assert fio.read(b, 0, 1500) == b"new" * 100 + (b"old" * 500)[300:]
+        client.close()
+
+    def test_failed_stage_round_ends_on_the_ladder(self, ec_cluster):
+        """A node's stage batch lost on the wire: those stripes finish on
+        the single-stripe ladder at the version the batch chose."""
+        from tpu3fs.client.file_io import FileIoClient
+        from tpu3fs.client.storage_client import RetryOptions
+        from tpu3fs.utils.result import FsError, Status
+
+        chain = ec_cluster.chain_ids[0]
+        client = ec_cluster.storage_client(retry=RetryOptions(
+            max_retries=3, backoff_base_s=0.001, backoff_max_s=0.01))
+        fio = FileIoClient(client)
+        m = client._messenger
+        inner = m.batch_write_pipelined
+        lost = []
+
+        def lossy(groups, method="batch_write"):
+            if method == "batch_write_shard" and not lost:
+                lost.append(groups[0][0])
+                got = inner(groups[1:], method=method)
+                return [[UpdateReply(Code.RPC_PEER_CLOSED)
+                         for _ in groups[0][1]]] + got
+            return inner(groups, method=method)
+
+        from tpu3fs.storage.craq import UpdateReply
+
+        m.batch_write_pipelined = lossy
+        calls = _counting(client)
+        inodes = [_ec_inode(ec_cluster, 840 + i) for i in range(3)]
+        bodies = [os.urandom(1000 + i) for i in range(3)]
+        fio.batch_write_files(
+            [(ino, 0, body) for ino, body in zip(inodes, bodies)])
+        assert lost and "write_shard" in {m for m, _ in calls}
+        for ino, body in zip(inodes, bodies):
+            stored = _ec_stored(ec_cluster, chain, ChunkId(ino.id, 0))
+            assert {aux for _, _, aux, _ in stored} == {len(body)}
+            assert fio.read(ino, 0, len(body)) == body
+        client.close()

@@ -63,7 +63,8 @@ HOT_PATH: List[Tuple[str, List[str]]] = [
      # tracing wrappers (root spans); the hot bodies are the _op twins
      ["_batch_read_op",
       # write path: pipelined batch fan-out + batched stripe writes
-      "_batch_write_op", "_write_stripes_op", "_send_shard_batches",
+      "_batch_write_op", "_write_stripes_op", "_write_stripe_batch",
+      "_send_shard_batches",
       # EC data plane: batched shard fetch, clean/degraded stripe
       # assembly (the degraded fill), delta-parity sub-stripe RMW
       "_issue_wire_reads", "_plan_stripe_read", "_stripe_clean",

@@ -104,9 +104,11 @@ class FileIoClient:
         """Write a byte range. Chunk ops are BATCHED, not issued one at a
         time: consecutive CR chunks go through StorageClient.batch_write
         (one request per node, ref StorageClientImpl.cc:1030,1771) and
-        consecutive full EC stripes through write_stripes (ONE device
-        encode for the run + one BatchShardWrite per node); boundary
-        partial-stripe EC writes take the read-modify-write path. Runs
+        consecutive EC segments that start at offset 0 of their chunk —
+        full stripes and a short tail alike — through ONE stripe batch a
+        chain (_write_ec_heads: one probe, one device encode, one
+        BatchShardWrite per node and phase); an EC segment that starts
+        inside its chunk takes the read-modify-write path. Runs
         flush in FILE ORDER, so a failure always leaves a clean written
         prefix of whole runs — never new data after a hole (within a run
         the batch may land partially, as in the reference's batch APIs)."""
@@ -128,17 +130,8 @@ class FileIoClient:
                 for reply in self._storage.batch_write(run, chunk_size=cs):
                     if not reply.ok:
                         raise FsError(Status(reply.code, reply.message))
-            elif kind == "ec_full":
-                # one run may span the layout's chains (chunks round-robin
-                # over them): one write_stripes per chain covers the run
-                by_chain: dict = {}
-                for chain_id, cid, part in run:
-                    by_chain.setdefault(chain_id, []).append((cid, part))
-                for chain_id, items in by_chain.items():
-                    for reply in self._storage.write_stripes(
-                            chain_id, items, chunk_size=cs):
-                        if not reply.ok:
-                            raise FsError(Status(reply.code, reply.message))
+            elif kind == "ec_head":
+                self._write_ec_heads(run)
             else:  # ec_partial
                 for chain_id, idx, in_off, part in run:
                     reply = self._write_ec_chunk(
@@ -164,9 +157,9 @@ class FileIoClient:
                             and type(data) is bytes) else mv[pos : pos + n]
             pos += n
             if self._is_ec(chain_id):
-                if in_off == 0 and n == cs:
-                    seg_kind, seg = "ec_full", (chain_id,
-                                                ChunkId(inode.id, idx), part)
+                if in_off == 0:
+                    seg_kind, seg = "ec_head", (inode, chain_id, idx, part,
+                                                cs)
                 else:
                     seg_kind, seg = "ec_partial", (chain_id, idx, in_off, part)
             else:
@@ -188,8 +181,10 @@ class FileIoClient:
         batch_read_files (ckpt save / kvcache write-back: batching across
         files is what amortizes round trips and feeds the striped
         pipelined fan-out). CR chunk ops across ALL files gather into one
-        batch; full EC stripes group into one write_stripes per chain;
-        partial EC stripes take the read-modify-write ladder. Any failed
+        batch; EC segments that start at offset 0 of their chunk, full
+        stripes and short ones, group into one stripe batch per chain
+        (_write_ec_heads); EC segments that start inside their chunk take
+        the read-modify-write ladder. Any failed
         op raises (after batch_write's internal retry ladder); on success
         returns per-file byte counts.
 
@@ -211,7 +206,7 @@ class FileIoClient:
         cr_ops: List[Tuple[int, ChunkId, int, object]] = []
         cr_idx: List[int] = []
         cr_cs: Optional[int] = None
-        ec_full: dict = {}          # chain_id -> [(ChunkId, part)]
+        ec_heads: list = []         # (inode, chain_id, idx, part, cs)
         ec_partial: list = []       # (inode, chain_id, idx, in_off, part, cs)
         counts: List[int] = []
         parts: List[object] = []    # every written slice, file order
@@ -234,9 +229,8 @@ class FileIoClient:
                 pos += n
                 parts.append(part)
                 if self._is_ec(chain_id):
-                    if in_off == 0 and n == cs:
-                        ec_full.setdefault(chain_id, []).append(
-                            (ChunkId(inode.id, idx), part))
+                    if in_off == 0:
+                        ec_heads.append((inode, chain_id, idx, part, cs))
                     else:
                         ec_partial.append(
                             (inode, chain_id, idx, in_off, part, cs))
@@ -268,13 +262,7 @@ class FileIoClient:
             self._flush_cr(ops, run_cs,
                            op_crcs=([part_crcs[j].value for j in idxs]
                                     if part_crcs is not None else None))
-        for chain_id, items in ec_full.items():
-            # full stripes only land here, so any part's length IS the
-            # layout chunk size
-            for reply in self._storage.write_stripes(
-                    chain_id, items, chunk_size=len(items[0][1])):
-                if not reply.ok:
-                    raise FsError(Status(reply.code, reply.message))
+        self._write_ec_heads(ec_heads)
         for inode, chain_id, idx, in_off, part, cs in ec_partial:
             reply = self._write_ec_chunk(inode, chain_id, idx, in_off,
                                          part, cs)
@@ -292,8 +280,57 @@ class FileIoClient:
             if not reply.ok:
                 raise FsError(Status(reply.code, reply.message))
 
+    def _write_ec_heads(self, segs) -> None:
+        """EC segments that start at offset 0 of their chunk — whole
+        stripes and shorter ("head-partial") ones — as ONE stripe batch a
+        chain (StorageClient.write_stripe_heads): segs is
+        [(inode, chain_id, idx, part, chunk_size)] in file order (chunks
+        round-robin over a layout's chains, so one run may span several).
+        A short one is a partial-stripe write and is announced to
+        _write_ec_chunk first, as every one is. A head-partial the
+        batch's probe did not find absent comes back None and takes the
+        ladder, in order, after its chain's batch: a short write over a
+        longer committed stripe keeps its tail."""
+        by_chain: dict = {}
+        for seg in segs:
+            inode, chain_id, idx, part, cs = seg
+            if len(part) < cs:
+                reply = self._write_ec_chunk(inode, chain_id, idx, 0, part,
+                                             cs)
+                if reply is not None:   # settled there
+                    if not reply.ok:
+                        raise FsError(Status(reply.code, reply.message))
+                    continue
+            by_chain.setdefault((chain_id, cs), []).append(seg)
+        for (chain_id, cs), group in by_chain.items():
+            replies = self._storage.write_stripe_heads(
+                chain_id, [(ChunkId(inode.id, idx), part)
+                           for inode, _, idx, part, _ in group],
+                chunk_size=cs)
+            for (inode, _, idx, part, _), reply in zip(group, replies):
+                if reply is None:
+                    reply = self._write_ec_ladder(inode, chain_id, idx, 0,
+                                                  part, cs)
+                if not reply.ok:
+                    raise FsError(Status(reply.code, reply.message))
+
     def _write_ec_chunk(self, inode: Inode, chain_id: int, idx: int,
                         in_off: int, part: bytes, chunk_size: int):
+        """ONE partial-stripe write of an EC file: every one passes here,
+        once, before any of it is sent — the seam where a test or the
+        benchmark's fault stands in for a client that acknowledges what
+        it never wrote. A segment that starts inside its chunk is merged
+        into the stripe by the ladder, here and now. One that starts at
+        offset 0 of its chunk has nothing before it to keep, and whether
+        anything lies behind it the chain's batch finds out for all of
+        them in one probe: None hands it back to _write_ec_heads."""
+        if in_off == 0:
+            return None
+        return self._write_ec_ladder(inode, chain_id, idx, in_off, part,
+                                     chunk_size)
+
+    def _write_ec_ladder(self, inode: Inode, chain_id: int, idx: int,
+                         in_off: int, part: bytes, chunk_size: int):
         """EC chunks are whole stripes: a full-chunk write encodes directly.
         A partial write first tries DELTA-PARITY RMW (write_stripe_rmw:
         read touched data + parity shards, ``P' = P ^ c*(D'^D)``, stage

@@ -176,6 +176,10 @@ class StorageClient:
         self._ec_degraded_ms = DistributionRecorder("ec.degraded_read_ms")
         self._ec_parity_rmw = CounterRecorder("ec.parity_rmw")
         self._ec_rmw_fallback = CounterRecorder("ec.parity_rmw_fallback")
+        # partial writes at a chunk's offset 0: those that rode the stripe
+        # batch (nothing was there) vs those sent to the RMW ladder
+        self._ec_head_batched = CounterRecorder("ec.head_partial_batched")
+        self._ec_head_ladder = CounterRecorder("ec.head_partial_ladder")
         self._ec_encode_gibps = ValueRecorder("ec.encode_gibps")
         # pipelined chain encode (TPU3FS_EC_CHAIN_ENCODE=1): stripes
         # staged through the chain relay vs stripes that fell back to the
@@ -1174,59 +1178,171 @@ class StorageClient:
         *,
         chunk_size: int = 1 << 20,
     ) -> List[UpdateReply]:
-        """Traced entry: see _write_stripes_op."""
+        """Whole-stripe writes as one batch: each item REPLACES its stripe
+        (a short item leaves a short stripe). See _write_stripes_op."""
         from tpu3fs.analytics import spans as _spans
 
         with _spans.root_span("client.write_stripes",
                               nbytes=sum(len(d) for _, d in items)), \
                 self._op_scope():
-            return self._write_stripes_op(chain_id, items,
-                                          chunk_size=chunk_size)
+            return self._write_stripes_op(chain_id, items, chunk_size, None)
 
-    def _write_stripes_op(
+    def write_stripe_heads(
         self,
         chain_id: int,
         items: List[Tuple[ChunkId, bytes]],
         *,
         chunk_size: int = 1 << 20,
-    ) -> List[UpdateReply]:
+    ) -> List[Optional[UpdateReply]]:
+        """The file client's batch: byte ranges that START at offset 0 of
+        their chunk, whole stripes and shorter ones ("head-partial")
+        alike, through the same probe, encode and two shard rounds as
+        write_stripes. A short range may only replace a stripe that is
+        not there — over a committed one it would cut the tail — so a
+        head-partial the probe does not find absent, or that a stage
+        finds committed since the probe, comes back None: the caller's
+        read-modify-write ladder takes it
+        (FileIoClient._write_ec_ladder). ec.head_partial_batched /
+        ec.head_partial_ladder count both ways."""
+        from tpu3fs.analytics import spans as _spans
+
+        short = [len(d) < chunk_size for _, d in items]
+        with _spans.root_span("client.write_stripes",
+                              nbytes=sum(len(d) for _, d in items)), \
+                self._op_scope():
+            out = self._write_stripes_op(chain_id, items, chunk_size, short)
+        # a full stripe always gets a reply: every None is a head-partial
+        left = out.count(None)
+        self._ec_head_ladder.add(left)
+        self._ec_head_batched.add(sum(short) - left)
+        return out
+
+    def _write_stripes_op(
+        self,
+        chain_id: int,
+        items: List[Tuple[ChunkId, bytes]],
+        chunk_size: int,
+        need_absent: Optional[List[bool]],
+    ) -> List[Optional[UpdateReply]]:
         """Batched EC writes: encode MANY stripes with ONE device kernel
         launch (amortizing the PCIe round trip — the whole point of the TPU
-        data plane) and install shards with one BatchShardWrite per node.
-        Overwrites are handled by probing the current stripe versions with
-        ONE statChunks RPC up front (shard 0's target holds every stripe of
-        the chain), so rewriting existing stripes stays on the batch path;
-        stripes that still conflict fall back to write_stripe."""
+        data plane) and install shards with one BatchShardWrite per node
+        and phase (_write_stripe_batch). Overwrites are handled by probing
+        the current stripe versions with ONE statChunks RPC up front
+        (_probe_stripes), so rewriting existing stripes stays on the batch
+        path; stripes that still conflict fall back to write_stripe.
+
+        An item flagged in ``need_absent`` (write_stripe_heads' short
+        ones) may only be written where nothing is: it joins the batch
+        when the probe says so, and it comes back None when it does not,
+        or when a stage finds a version committed since the probe —
+        write_stripe would bump past that and cut its tail. (A stripe
+        whose rounds merely did not fully land still holds nobody else's
+        bytes, and write_stripe finishes it at the batch's version.)"""
+        from tpu3fs.ops.stripe import DEVICE_BATCH_ITEMS
+
+        routing, chain = self._route(chain_id)
+        if not chain.is_ec:
+            raise FsError(Status(Code.INVALID_ARG, "write_stripes on CR chain"))
+        if not items:
+            return []
+        vers, absent = self._probe_stripes(routing, chain,
+                                           [cid for cid, _ in items])
+        if need_absent is None:
+            need_absent = [False] * len(items)
+        out: List[Optional[UpdateReply]] = [None] * len(items)
+        batch = [b for b in range(len(items))
+                 if absent[b] or not need_absent[b]]
+        # in slices of one encode dispatch: past that a batch saves no
+        # device round trip, and it would hold (B, k+m, S) in this process
+        # and put B shards a node into one request (a 128-block document:
+        # 179 MB, 45 MB a node)
+        for lo in range(0, len(batch), DEVICE_BATCH_ITEMS):
+            part = batch[lo:lo + DEVICE_BATCH_ITEMS]
+            got = self._write_stripe_batch(
+                routing, chain, [items[b] for b in part],
+                [vers[b] for b in part], chunk_size)
+            for b, reply in zip(part, got):
+                if reply is None or not (reply.ok or need_absent[b]):
+                    # partial, or a conflict on a stripe that may be
+                    # replaced: the single-stripe ladder re-probes
+                    cid, data = items[b]
+                    reply = self.write_stripe(
+                        chain_id, cid, data, chunk_size=chunk_size,
+                        update_ver=vers[b])
+                elif not reply.ok:
+                    reply = None    # committed since the probe: merge
+                out[b] = reply
+        return out
+
+    def _probe_stripes(
+        self,
+        routing: RoutingInfo,
+        chain: ChainInfo,
+        cids: List[ChunkId],
+    ) -> Tuple[List[int], List[bool]]:
+        """ONE stat_chunks on shard 0's target, which holds a shard of
+        every stripe of the chain. Returns (vers, absent). vers: the
+        version each stripe's write takes — past the committed one the
+        probe saw (a later shard write may still be ahead: that stripe
+        falls to the per-stripe ladder). absent: whether NOTHING is there
+        ((0, 0, 0)), said only by a SERVING target — a SYNCING one takes
+        writes and answers, but its rebuild may not have reached a stripe
+        the other shards hold, and one that was down while a stripe was
+        written never got it — and all False when the probe has no answer
+        (the target is not routable, the RPC failed).
+
+        Traced as ``fio.write_ec_chunk.rmw_probe``, the stage of the
+        ladder it stands in for: what a partial-stripe write spends
+        finding out what is there reads the same on both paths."""
+        from tpu3fs.analytics import spans as _spans
+
+        stats = None
+        t0 = chain.target_of_shard(0)
+        with _spans.span("fio.write_ec_chunk", "rmw_probe"):
+            node0 = (routing.node_of_target(t0.target_id)
+                     if t0 is not None else None)
+            if node0 is not None:
+                try:
+                    stats = self._messenger(
+                        node0.node_id, "stat_chunks", (t0.target_id, cids))
+                except FsError:
+                    pass  # probe is an optimization; conflicts still ladder
+        if stats is None:
+            return [self._ec_next_ver(0)] * len(cids), [False] * len(cids)
+        trusted = t0.public_state == PublicTargetState.SERVING
+        return ([self._ec_next_ver(int(st[0])) for st in stats],
+                [trusted and not any(st) for st in stats])
+
+    def _write_stripe_batch(
+        self,
+        routing: RoutingInfo,
+        chain: ChainInfo,
+        items: List[Tuple[ChunkId, bytes]],
+        vers: List[int],
+        chunk_size: int,
+    ) -> List[Optional[UpdateReply]]:
+        """_write_stripes_op past its probe: ONE encode of (B, k, S) and
+        the two shard rounds for stripes whose versions are chosen — the
+        ShardWriteReqs are write_stripe's. A stripe the strict rule does
+        not pass (every writable shard staged, at least k, then every one
+        committed) comes back as the CHUNK_STALE_UPDATE a stage met, or
+        None where the rounds just did not fully land: the caller picks
+        its ladder.
+
+        The rounds are traced under write_stripe's stage names,
+        ``client.write_stripe.stage_shards`` / ``.commit_shards`` (the
+        ``rpc.client`` hops of a round beneath its stage)."""
         import numpy as np
 
         from tpu3fs.analytics import spans as _spans
         from tpu3fs.ops.stripe import get_codec, shard_size_of
 
-        routing, chain = self._route(chain_id)
-        if not chain.is_ec:
-            raise FsError(Status(Code.INVALID_ARG, "write_stripes on CR chain"))
+        chain_id = chain.chain_id
         k, m = chain.ec_k, chain.ec_m
         S = shard_size_of(chunk_size, k)
         codec = get_codec(k, m, S)
         B = len(items)
-        if B == 0:
-            return []
-        # one-RPC version probe: max committed over probed shards is the
-        # floor for this batch's stripe versions (a later shard write may
-        # still be ahead — that stripe falls to the per-stripe ladder)
-        vers = [self._ec_next_ver(0)] * B
-        t0 = chain.target_of_shard(0)
-        if t0 is not None:
-            node0 = routing.node_of_target(t0.target_id)
-            if node0 is not None:
-                try:
-                    stats = self._messenger(
-                        node0.node_id, "stat_chunks",
-                        (t0.target_id, [cid for cid, _ in items]))
-                    vers = [self._ec_next_ver(int(st[0]))
-                            for st in stats]
-                except FsError:
-                    pass  # probe is an optimization; conflicts still ladder
         if _chain_encode_enabled():
             # pipelined chain encode: ship RAW data shards down the
             # encode-ordered chain — the hops compute the parity
@@ -1288,7 +1404,9 @@ class StorageClient:
         # -- phase 1: stage every shard (pending only) -----------------------
         # merge AFTER the _send_shard_batches barrier: `acked[b] += 1`
         # from concurrent node threads would be a lost-update race
-        for b, reply in self._send_shard_batches(by_node):
+        with _spans.span("client.write_stripe", "stage_shards"):
+            staged = self._send_shard_batches(by_node)
+        for b, reply in staged:
             if reply.ok:
                 acked[b] += 1
             elif reply.code == Code.CHUNK_STALE_UPDATE:
@@ -1309,21 +1427,15 @@ class StorageClient:
                 if b in full_staged:
                     commit_by_node[node_id].append((b, replace(
                         r, data=b"", crc=0, phase=2)))
-        for b, reply in self._send_shard_batches(commit_by_node):
+        with _spans.span("client.write_stripe", "commit_shards"):
+            landed = self._send_shard_batches(commit_by_node)
+        for b, reply in landed:
             if reply.ok:
                 committed[b] += 1
-        out: List[UpdateReply] = []
-        for b, (cid, data) in enumerate(items):
-            # strict rule: every writable shard staged AND committed
-            if b in full_staged and committed[b] == acked[b]:
-                out.append(UpdateReply(
-                    Code.OK, update_ver=vers[b], commit_ver=vers[b]))
-            else:
-                # conflict or partial: the single-stripe ladder re-probes
-                out.append(self.write_stripe(
-                    chain_id, cid, data, chunk_size=chunk_size,
-                    update_ver=vers[b]))
-        return out
+        # strict rule: every writable shard staged AND committed
+        return [UpdateReply(Code.OK, update_ver=vers[b], commit_ver=vers[b])
+                if b in full_staged and committed[b] == acked[b] else hard[b]
+                for b in range(B)]
 
     def _write_stripes_chain(
         self,

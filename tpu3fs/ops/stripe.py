@@ -58,6 +58,15 @@ def _bucket(b: int) -> int:
 # planes, 8x the bytes — so the device branches run a large batch as a
 # sequence of dispatches of at most this size.
 DEVICE_BATCH_BYTES = 128 << 20
+# ... and the most stripes of an ENCODE dispatch. Every power of two up
+# to it is a program the codec builds before it serves
+# (StripeCodec._prepare): 5 a codec. And on a v5e the round trip of one
+# encode stops scaling past 16 stripes of RS(12,4) S=87552 (PERF.md, PR
+# 31: 0.9 ms a stripe at 16, 3.6 at 32, 3.9 at 64 — the 45 MB and more
+# coming back), so a large batch goes faster as dispatches of 16. The
+# reconstruct and the CRC return a fraction of that and keep the bound
+# by bytes alone.
+DEVICE_BATCH_ITEMS = 16
 
 
 def aligned_shard_size(n: int) -> int:
@@ -94,6 +103,8 @@ class StripeCodec:
         # device programs (jit is lazy: building them touches no backend)
         self._encode_dev = jax.jit(self._encode_device)
         self._crc_dev = jax.jit(self._crc.compute)
+        self._prepared = False      # every encode bucket is built
+        self._prepare_lock = threading.Lock()
 
     def _use_host(self) -> bool:
         """The serving path stays on host kernels even when a TPU is
@@ -124,22 +135,47 @@ class StripeCodec:
         n = max(1, DEVICE_BATCH_BYTES // (rows_per_item * self.shard_size))
         return 1 << (n.bit_length() - 1)
 
-    def _device_map(self, fn, items: np.ndarray, rows_per_item: int,
+    def _encode_buckets(self) -> List[int]:
+        """Every batch size an encode dispatch can have: the powers of two
+        up to DEVICE_BATCH_ITEMS, or to _device_step where that is less."""
+        step = min(self._device_step(self.k + self.m), DEVICE_BATCH_ITEMS)
+        return [1 << i for i in range(step.bit_length())]
+
+    def _prepare(self) -> None:
+        """Build the encode program of every bucket, once a codec: XLA
+        compiles a program the first time it meets a shape, and which
+        batch sizes a put will bring is the traffic's to say (a KVCache
+        suffix of 1 to 16 blocks, a drain of the write-back tier, a
+        document of 128), so whichever encode comes first pays for all of
+        them — at start-up, in practice — and none compiles on a later
+        request's path. Each is run once on zeros: that is what fills
+        jit's own table."""
+        if self._prepared:
+            return
+        with self._prepare_lock:
+            if self._prepared:
+                return
+            for bp in self._encode_buckets():
+                jax.block_until_ready(self._encode_dev(np.zeros(
+                    (bp, self.k, self.shard_size), dtype=np.uint8)))
+            self._prepared = True
+
+    def _device_map(self, fn, items: np.ndarray, step: int,
                     op: str = "codec.encode"):
-        """Run fn over items in bounded, power-of-two-bucketed dispatches
-        and yield (lo, n, host outputs) per dispatch. XLA compiles one
-        program per input SHAPE, so free-running batch sizes (every
-        distinct run length the file client flushes) would each pay a
-        fresh multi-second compile — with bucketing there are O(log B)
-        programs per codec, reused forever. Zero rows encode to zero
-        parity, so the pad rows are simply sliced off by the caller.
+        """Run fn over items in power-of-two-bucketed dispatches of at
+        most ``step`` (a power of two: _device_step's, or the encode's
+        largest bucket) and yield (lo, n, host outputs) per dispatch. XLA
+        compiles one program per input SHAPE, so free-running batch sizes
+        (every distinct run length the file client flushes) would each
+        pay a fresh multi-second compile — with bucketing there are
+        O(log B) programs per codec, reused forever. Zero rows encode to
+        zero parity, so the pad rows are simply sliced off by the caller.
 
         Traced per dispatch as two stages of ``op``: ``dispatch`` (pad,
         host -> device, launch) and ``fetch`` (device_get: the wait for
         the program and device -> host). Bytes make no sense for a
         launch, so both stages' ``nbytes`` holds the COUNT of items
         (stripes, for an encode) the dispatch carries."""
-        step = self._device_step(rows_per_item)
         for lo in range(0, items.shape[0], step):
             part = items[lo:lo + step]
             n = part.shape[0]
@@ -195,8 +231,9 @@ class StripeCodec:
         with _spans.root_span("codec.encode", nbytes=b * k * s):
             shards = np.empty((b, k + self.m, s), dtype=np.uint8)
             crcs = np.empty((b, k + self.m), dtype=np.uint32)
+            self._prepare()
             for lo, n, (out_s, out_c) in self._device_map(
-                    self._encode_dev, data, k + self.m):
+                    self._encode_dev, data, self._encode_buckets()[-1]):
                 shards[lo:lo + n] = out_s[:n]
                 crcs[lo:lo + n] = out_c[:n]
         return shards, crcs
@@ -281,7 +318,8 @@ class StripeCodec:
                        dtype=np.uint8)
         for lo, n, rebuilt in self._device_map(
                 lambda part: fn(jnp.asarray(part)), present,
-                self.k + len(lost_idx), op="codec.reconstruct"):
+                self._device_step(self.k + len(lost_idx)),
+                op="codec.reconstruct"):
             out[lo:lo + n] = rebuilt[:n]
         return out
 
@@ -290,7 +328,8 @@ class StripeCodec:
         if self._use_host():
             return crc32c_batch_host(shards)
         out = np.empty(shards.shape[0], dtype=np.uint32)
-        for lo, n, crcs in self._device_map(self._crc_dev, shards, 1,
+        for lo, n, crcs in self._device_map(self._crc_dev, shards,
+                                            self._device_step(1),
                                             op="codec.crc"):
             out[lo:lo + n] = crcs[:n]
         return out
