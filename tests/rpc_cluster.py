@@ -1,8 +1,12 @@
 """A socket cluster inside the test process: mgmtd + 3 storage services
 over real TCP (Python transport, mem engine), the socket-mode twin of the
 fabric — the same shape as the reference running its UnitTestFabric
-against live transports. Helper of test_readpath, test_stubs and
-test_writepath; not a test module."""
+against live transports. Helper of test_readpath, test_stubs,
+test_writepath and test_node_loss (which stops a node hard, has mgmtd
+declare it dead, brings it back empty and drives the rebuild); not a
+test module."""
+
+import time
 
 from tpu3fs.client.storage_client import StorageClient
 from tpu3fs.kv.mem import MemKVEngine
@@ -23,10 +27,11 @@ FILE_ID = 4242
 
 class RpcCluster:
     def __init__(self, *, replicas: int, chains: int, size: int,
-                 ec: tuple = ()):
+                 ec: tuple = (), nodes: int = 0):
         """ec=(k, m) makes every chain an RS(k, m) group of k+m targets
         (target i holds shard i; `replicas` is then ignored) whose engine
-        chunk size is the shard size."""
+        chunk size is the shard size. `nodes` overrides how many storage
+        services there are (shard j of chain c on node (c + j) % nodes)."""
         self.mgmtd = Mgmtd(1, MemKVEngine())
         self.mgmtd.extend_lease()
         mgmtd_server = RpcServer()
@@ -43,16 +48,20 @@ class RpcCluster:
             target_size = shard_size_of(size, ec[0])
         else:
             target_size = size
-        num_nodes = 3 if ec else max(3, replicas)
+        num_nodes = nodes or (3 if ec else max(3, replicas))
+        self.target_size = target_size
         node_ids = [10 + i for i in range(num_nodes)]
         self.chain_ids = [900_001 + i for i in range(chains)]
         node_states: dict = {n: {} for n in node_ids}
         self.services = []
-        svc_by_node = {}
+        self.node_server = {}
+        self.mclis = {}
+        svc_by_node = self.svc_by_node = {}
         for node_id in node_ids:
             # the held snapshot: this cluster's routing is static, and
             # retries invalidate it anyway
-            mcli = MgmtdRpcClient(self.mgmtd_addr, self.shared_client)
+            mcli = self.mclis[node_id] = MgmtdRpcClient(
+                self.mgmtd_addr, self.shared_client)
             svc = StorageService(node_id, mcli.cached_routing)
             svc.set_messenger(RpcMessenger(mcli.cached_routing,
                                            self.shared_client))
@@ -63,6 +72,7 @@ class RpcCluster:
                                      host=server.host, port=server.port)
             self.servers.append(server)
             self.services.append(svc)
+            self.node_server[node_id] = server
             svc_by_node[node_id] = svc
         for ci, chain_id in enumerate(self.chain_ids):
             targets = []
@@ -82,6 +92,8 @@ class RpcCluster:
         for node_id in node_ids:
             self.mgmtd.heartbeat(node_id, 1, node_states[node_id])
         self._client_seq = 0
+        self._hb = {}
+        self.resync_workers = {}    # id(service) -> its EcResyncWorker
 
     def storage_client(self, **kw) -> StorageClient:
         self._client_seq += 1
@@ -89,6 +101,90 @@ class RpcCluster:
         messenger = RpcMessenger(mcli.cached_routing, self.shared_client)
         return StorageClient(f"test-rpc-{self._client_seq}",
                              mcli.cached_routing, messenger, **kw)
+
+    # -- a node lost and replaced (test_node_loss) ---------------------------
+    def stop_node(self, node_id: int) -> None:
+        """The node's process is gone: nothing listens at its address.
+        mgmtd does not know yet."""
+        self.svc_by_node[node_id].stopped = True
+        self.node_server[node_id].stop()
+
+    def declare_dead(self, node_id: int) -> None:
+        """What mgmtd's tick does once heartbeat_timeout_s has passed."""
+        self.mgmtd._routing.nodes[node_id].last_heartbeat = 0.0
+        assert self.mgmtd.check_heartbeats() == [node_id]
+        self.beat()
+
+    def restart_empty(self, node_id: int) -> StorageService:
+        """A new process under the node's id on empty disks: a new service
+        on a new port, registered, its targets opened empty and reported
+        ONLINE (what storage_main.scan_targets does past chain version 1)."""
+        mcli = self.mclis[node_id] = MgmtdRpcClient(
+            self.mgmtd_addr, self.shared_client)
+        svc = StorageService(node_id, mcli.cached_routing)
+        svc.set_messenger(RpcMessenger(mcli.cached_routing,
+                                       self.shared_client))
+        server = RpcServer()
+        bind_storage_service(server, svc)
+        server.start()
+        self.mgmtd.register_node(node_id, NodeType.STORAGE,
+                                 host=server.host, port=server.port)
+        routing = self.mgmtd.get_routing_info()
+        for info in routing.targets.values():
+            if info.node_id == node_id and info.chain_id:
+                target = StorageTarget(info.target_id, info.chain_id,
+                                       chunk_size=self.target_size,
+                                       engine="mem")
+                target.local_state = LocalTargetState.ONLINE
+                svc.add_target(target)
+        self.servers.append(server)
+        self.services[self.services.index(self.svc_by_node[node_id])] = svc
+        self.node_server[node_id] = server
+        self.svc_by_node[node_id] = svc
+        self._hb[node_id] = self._hb.get(node_id, 1) + 1000
+        return svc
+
+    def beat(self) -> None:
+        """Every live node's heartbeat with its targets' local states,
+        mgmtd's chain update, and the nodes' routing refresh that a
+        heartbeat reply ahead of their snapshot sets off."""
+        live = [n for n, svc in self.svc_by_node.items() if not svc.stopped]
+        for node_id in live:
+            self._hb[node_id] = self._hb.get(node_id, 1) + 1
+            self.mgmtd.heartbeat(
+                node_id, self._hb[node_id],
+                {t.target_id: t.local_state
+                 for t in self.svc_by_node[node_id].targets()})
+        self.mgmtd.update_chains()
+        for node_id in live:
+            self.mclis[node_id].refresh_routing()
+
+    def recover(self, budget_s: float = 60.0, each_round=None) -> int:
+        """Heartbeats, chain updates and every node's EC resync worker,
+        round after round, until every target of every chain is SERVING.
+        -> rounds it took."""
+        from tpu3fs.mgmtd.types import PublicTargetState
+        from tpu3fs.storage.ec_resync import EcResyncWorker
+
+        workers = self.resync_workers
+        deadline = time.time() + budget_s
+        rounds = 0
+        while True:
+            self.beat()
+            routing = self.mgmtd.get_routing_info()
+            if each_round is not None:
+                each_round(routing)
+            if all(t.public_state == PublicTargetState.SERVING
+                   for c in routing.chains.values() for t in c.targets):
+                return rounds
+            assert time.time() < deadline, "the chain never recovered"
+            rounds += 1
+            for svc in self.services:
+                if svc.stopped:
+                    continue
+                if id(svc) not in workers:
+                    workers[id(svc)] = EcResyncWorker(svc, svc._messenger)
+                workers[id(svc)].run_once()
 
     def close(self) -> None:
         self.shared_client.close()

@@ -32,6 +32,7 @@ MODULES = (
     "test_contract",
     "test_proxies",
     "test_readers",
+    "test_rebuild_pieces",
     "test_reference",
     "test_span_ms",
     "test_trace",

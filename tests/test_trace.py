@@ -1069,6 +1069,22 @@ class TestPins:
             np.zeros((1, 2, 512), np.uint8)).as_text()
         assert "module @jit__encode_device" in text.splitlines()[0]
 
+    def test_the_decode_program_keeps_its_name(self):
+        """(e) likewise the decode: `decode_device`, ONE program whatever
+        the loss pattern (the matrix is an operand), found by that name in
+        the device trace."""
+        import numpy as np
+
+        from tpu3fs.ops.stripe import get_codec
+
+        codec = get_codec(4, 2, 512)
+        data = np.zeros((1, 4, 512), np.uint8)
+        texts = [codec._decode_dev.lower(
+            codec.rs.decode_operand(present, lost), data).as_text()
+            for present, lost in (((0, 1, 2, 4), (3,)), ((1, 2, 3, 5), (0,)))]
+        assert "module @jit__decode_device" in texts[0].splitlines()[0]
+        assert texts[0] == texts[1]
+
     def test_the_profiler_switch_is_where_the_tracer_looks(self):
         """(f) a jaxlib that moves TraceMe.is_enabled fails here and does
         not silently end all capture."""
@@ -1105,3 +1121,129 @@ class TestPins:
         assert unpack_stamps(pack_stamps(1e9, 1e9)) is not None
 
 
+
+
+class TestNodeLossSpans:
+    """What a dead storage node adds to the trees of a load and of a put
+    (docs/observability.md): the ``degraded`` stage with the second round
+    and the device decode beneath it, and the put's ``await_routing``."""
+
+    K, M, CHUNK, S = 12, 4, 12 * 1024, 1024
+
+    @pytest.fixture
+    def lossy(self, tmp_path):
+        """RS(12,4) over four socket nodes, node 13 (shards 3, 7, 11, 15)
+        stopped hard, the codec on its device programs, one profiler
+        session -> (cluster, client, run) where run(fn) captures fn's
+        trees."""
+        import jax
+
+        from tests.rpc_cluster import RpcCluster
+        from tpu3fs.client.storage_client import RetryOptions
+        from tpu3fs.ops.stripe import get_codec
+
+        c = RpcCluster(replicas=0, chains=1, size=self.CHUNK,
+                       ec=(self.K, self.M), nodes=4)
+        client = c.storage_client(retry=RetryOptions(
+            max_retries=4, backoff_base_s=0.005, backoff_max_s=0.05,
+            routing_wait_s=30.0))
+        codec = get_codec(self.K, self.M, self.S)
+        saved, codec._host_mode = codec._host_mode, False
+        tracer = spans.tracer()
+
+        def run(fn):
+            tracer.reset_captured()
+            jax.profiler.start_trace(str(tmp_path / "xplane"))
+            try:
+                fn()
+            finally:
+                jax.profiler.stop_trace()
+            rows = assemble.rows_of_captured(tracer.captured())
+            tracer.reset_captured()
+            return list(assemble.assemble_traces(rows).values())
+
+        try:
+            yield c, client, run
+        finally:
+            codec._host_mode = saved
+            c.close()
+
+    def test_a_load_s_degraded_stage_holds_the_round_and_the_decode(
+            self, lossy):
+        from tpu3fs.storage.craq import ReadReq
+        from tpu3fs.storage.types import ChunkId
+
+        c, client, run = lossy
+        chain = c.chain_ids[0]
+        data = bytes(range(256)) * 27          # shards 0..6
+        for i in range(2):
+            assert client.write_stripe(chain, ChunkId(9, i), data,
+                                       chunk_size=self.CHUNK).ok
+        c.stop_node(13)
+        # the decode's program is built outside the traced part
+        client.read_stripe(chain, ChunkId(9, 0), 0, len(data),
+                           chunk_size=self.CHUNK)
+
+        def load():
+            with spans.root_span("kvcache.get_blocks"):
+                got = client.batch_read([
+                    ReadReq(chain, ChunkId(9, i), 0, len(data),
+                            chunk_size=self.CHUNK) for i in range(2)])
+                assert all(bytes(r.data) == data for r in got)
+                one = client.read_stripe(chain, ChunkId(9, 1), 0, len(data),
+                                         chunk_size=self.CHUNK)
+                assert bytes(one.data) == data
+
+        (tree,) = run(load)
+        for op, stripes in (("client.batch_read", 2),
+                            ("client.read_stripe", 1)):
+            (stage,) = [r for r in tree.rows
+                        if r["op"] == op and r["stage"] == "degraded"]
+            assert stage["nbytes"] == stripes * len(data)
+            names = _names(tree, stage)
+            assert any(n.startswith("rpc.client.") for n in names)
+            recon = [r for r in tree.rows if r["op"] == "codec.reconstruct"
+                     and not r["stage"] and r["span_id"] in {
+                         k["span_id"] for k in _below(tree, stage)}]
+            # one stripe a dispatch: B = 1, k survivors of S bytes
+            assert len(recon) == stripes
+            for r in recon:
+                assert r["nbytes"] == self.K * self.S and r["code"] == 0
+                kids = {k["stage"]: k for k in tree.children[r["span_id"]]}
+                assert set(kids) == {"dispatch", "fetch"}
+                assert kids["dispatch"]["nbytes"] == 1
+
+    def test_a_put_s_await_routing_counts_the_shards_it_waited_for(
+            self, lossy):
+        from tpu3fs.storage.types import ChunkId
+
+        c, client, run = lossy
+        chain = c.chain_ids[0]
+        c.stop_node(13)
+        timer = threading.Timer(0.3, c.declare_dead, args=(13,))
+
+        def put():
+            timer.start()
+            with spans.root_span("kvcache.append_blocks"):
+                assert client.write_stripe(chain, ChunkId(10, 0),
+                                           b"w" * 5000,
+                                           chunk_size=self.CHUNK).ok
+
+        (tree,) = run(put)
+        timer.join()
+        waits = [r for r in tree.rows if r["op"] == "client.write_stripe"
+                 and r["stage"] == "await_routing"]
+        assert waits and all(r["nbytes"] == 4 for r in waits)
+        assert sum(r["dur_us"] for r in waits) >= 0.2e6
+        (ladder,) = [r for r in tree.rows
+                     if r["op"] == "client.write_stripe" and not r["stage"]]
+        assert all(r["parent_id"] == ladder["span_id"] for r in waits)
+
+
+def _below(tree, row):
+    out, todo = [], list(tree.children.get(row["span_id"], []))
+    while todo:
+        r = todo.pop()
+        out.append(r)
+        todo.extend(tree.children.get(r["span_id"], []))
+    return out

@@ -26,7 +26,7 @@ from tpu3fs.tenant.quota import TenantConfig
 from tpu3fs.rpc.net import RpcServer
 from tpu3fs.rpc.services import RpcMessenger, bind_storage_service
 from tpu3fs.storage.craq import StorageService
-from tpu3fs.storage.ec_resync import EcResyncWorker
+from tpu3fs.storage.ec_resync import EcResyncWorker, pass_line
 from tpu3fs.storage.resync import ResyncWorker
 from tpu3fs.storage.target import StorageTarget
 from tpu3fs.storage.workers import (
@@ -284,6 +284,12 @@ class StorageApp(TwoPhaseApplication):
                 ec_worker.run_once()
             except Exception:
                 pass
+            while ec_worker is not None and ec_worker.finished_passes:
+                # one line a finished target pass, on stdout whatever the
+                # log level: what the rebuild read and installed lives in
+                # this process and no RPC carries it
+                print(pass_line(ec_worker.finished_passes.popleft()),
+                      flush=True)
 
     def _check_loop(self) -> None:
         worker = CheckWorker(

@@ -960,21 +960,29 @@ class AdminCli:
                     f"{node.node_id if node else '?'} "
                     f"{t.public_state.name}{extra}")
             if deep and degraded:
-                # rebuild progress: the recovering shard's stripe count vs
-                # the fullest serving peer; degraded files = files whose
-                # stripes a serving peer still holds (reads decode inline)
+                # rebuild progress: the stripes a recovering shard holds
+                # COMMITTED (installed) over the stripes any serving peer
+                # holds committed (known: what the rebuild's inventory
+                # takes); degraded files = files whose stripes a serving
+                # peer still holds (reads decode inline)
                 serving_ids = {t.target_id for t in chain.targets
                                if t.public_state.name == "SERVING"}
-                peer_counts = [len(v) for tid, v in metas.items()
-                               if v is not None and tid in serving_ids]
-                goal = max(peer_counts, default=0)
+
+                def committed(tid) -> set:
+                    return {m.chunk_id.to_bytes()
+                            for m in metas.get(tid) or ()
+                            if m.committed_ver > 0}
+
+                known = set().union(*(committed(tid)
+                                      for tid in serving_ids))
                 for t in chain.targets:
                     if t.public_state.name != "SYNCING":
                         continue
-                    have = metas.get(t.target_id)
-                    have_n = len(have) if have is not None else 0
-                    lines.append(f"  rebuild: target {t.target_id} "
-                                 f"{have_n}/{goal} stripes")
+                    lines.append(
+                        f"  rebuild: shard {chain.shard_index(t.target_id)} "
+                        f"target {t.target_id} "
+                        f"{len(committed(t.target_id) & known)}/"
+                        f"{len(known)} stripes installed")
                 files = sorted({m.chunk_id.file_id
                                 for tid, v in metas.items()
                                 if v is not None and tid in serving_ids

@@ -66,6 +66,14 @@ def _chain_encode_enabled() -> bool:
     return os.environ.get("TPU3FS_EC_CHAIN_ENCODE", "0") == "1"
 
 
+#: what a call to a node that is not there comes back as (the breaker's
+#: fail-fast for a peer it already suspects included): the node's shards may
+#: still be writable in routing, and only mgmtd can say they are not
+UNREACHABLE_CODES = frozenset({
+    Code.RPC_CONNECT_FAILED, Code.RPC_SEND_FAILED, Code.RPC_TIMEOUT,
+    Code.RPC_PEER_CLOSED, Code.PEER_UNHEALTHY})
+
+
 def _hint_ms(reply) -> int:
     """Server retry-after hint of a shed reply: the typed field when the
     reply carries one, else parsed from the envelope message."""
@@ -124,6 +132,14 @@ class RetryOptions:
     # rides every RPC envelope (rpc/deadline.py): servers shed expired
     # work, _sleep never sleeps past it, ladders stop at it.
     op_deadline_s: float = 0.0
+    # how long an EC put outlives a node that does not answer while routing
+    # still calls its shards writable: it waits for mgmtd's verdict (the
+    # shards leave the writable set, or the node answers again) and neither
+    # gives up nor acknowledges without them. Attempts that fail for this
+    # alone are not counted against max_retries until the time is up, so
+    # it has to exceed mgmtd's heartbeat_timeout_s plus a tick. 0 = the
+    # ladder's own retries only.
+    routing_wait_s: float = 90.0
     # hedged reads (client/hedging.py): arm a backup read to the next
     # replica after delay = max(floor, factor x per-peer latency EWMA);
     # hedges spend a token budget earning budget_ratio per primary, so
@@ -174,6 +190,11 @@ class StorageClient:
 
         self._ec_degraded = CounterRecorder("ec.degraded_read")
         self._ec_degraded_ms = DistributionRecorder("ec.degraded_read_ms")
+        # puts acknowledged on fewer than k + m shards (the chain was
+        # degraded: every shard routing called writable, at least k), and
+        # what a put waited for routing to give up an unreachable shard
+        self._ec_degraded_write = CounterRecorder("ec.degraded_write")
+        self._routing_wait_ms = DistributionRecorder("client.routing_wait_ms")
         self._ec_parity_rmw = CounterRecorder("ec.parity_rmw")
         self._ec_rmw_fallback = CounterRecorder("ec.parity_rmw_fallback")
         # partial writes at a chunk's offset 0: those that rode the stripe
@@ -989,6 +1010,31 @@ class StorageClient:
         with _spans.span("client.write_stripe", "backoff", nbytes=attempt + 1):
             self._sleep(attempt, hint_ms)
 
+    def _await_routing(self, since: float, shards: int,
+                       op: str = "client.write_stripe") -> bool:
+        """A put whose only missing shards sit on a node that does not
+        answer, while routing still calls them writable, cannot finish
+        and must not be acknowledged without them: it waits for a routing
+        version in which they are no longer writable (mgmtd's verdict
+        after heartbeat_timeout_s) or for the node to answer again. One
+        jittered sleep of at most backoff_max_s with the held snapshot
+        expired, as the stage ``await_routing`` of ``op`` (``nbytes`` =
+        the shards waited for: a count); the caller then tries again
+        WITHOUT spending one of its retries. False once
+        RetryOptions.routing_wait_s (or the op's deadline) is used up: the
+        ladder's own budget decides from there. The length sweep of an EC
+        chain (query_last_chunk) waits the same way."""
+        from tpu3fs.analytics import spans as _spans
+
+        if (time.monotonic() - since >= self._retry.routing_wait_s
+                or self._deadline_expired()):
+            return False
+        t0 = time.monotonic()
+        with _spans.span(op, "await_routing", nbytes=shards):
+            self._sleep(self._retry.max_retries)
+        self._routing_wait_ms.record((time.monotonic() - t0) * 1000.0)
+        return True
+
     def _write_stripe_op(
         self,
         chain_id: int,
@@ -1020,7 +1066,9 @@ class StorageClient:
         last: Optional[UpdateReply] = None
         done: set = set()     # shard indices STAGED at `ver`
         landed: set = set()   # shard indices COMMITTED at `ver`
-        for attempt in range(self._retry.max_retries + 1):
+        attempt = 0           # attempts spent; a wait for routing is none
+        t_first = time.monotonic()
+        while attempt <= self._retry.max_retries:
             if attempt and self._deadline_expired():
                 return UpdateReply(Code.DEADLINE_EXCEEDED,
                                    message="op deadline exhausted")
@@ -1028,6 +1076,7 @@ class StorageClient:
             writable = 0
             acked = 0
             bump_to = 0
+            unreachable = 0   # writable shards whose node did not answer
             hard: Optional[UpdateReply] = None
             with _spans.span("client.write_stripe", "stage_shards"):
                 for j in range(k + m):
@@ -1089,6 +1138,7 @@ class StorageClient:
                         Code.RPC_PEER_CLOSED, Code.RPC_CONNECT_FAILED,
                     ):
                         last = reply
+                        unreachable += reply.code in UNREACHABLE_CODES
                     else:
                         hard = reply
             if hard is not None:
@@ -1098,6 +1148,7 @@ class StorageClient:
                 done.clear()  # everything must be re-staged at the new ver
                 landed.clear()
                 self._backoff(attempt)
+                attempt += 1
                 continue
             # STRICT staging: every currently-writable shard staged (and at
             # least k overall, or the stripe would be undecodable). Only
@@ -1139,23 +1190,34 @@ class StorageClient:
                             r2 = UpdateReply(e.code, message=e.status.message)
                         if r2.ok:
                             landed.add(j)
-                        elif r2.code == Code.CHUNK_MISSING_UPDATE:
+                        elif r2.code in (Code.CHUNK_MISSING_UPDATE,
+                                         Code.CHUNK_NOT_FOUND):
                             # our pending was displaced (e.g. by a concurrent
-                            # writer's stage): re-STAGE this shard next attempt
-                            # instead of re-sending a commit that cannot land
+                            # writer's stage) or is gone with its chunk (a
+                            # target that came back empty since the stage):
+                            # re-STAGE this shard next attempt instead of
+                            # re-sending a commit that cannot land
                             done.discard(j)
                 if landed >= full:
+                    if len(full) < k + m:
+                        self._ec_degraded_write.add()
                     return UpdateReply(Code.OK, update_ver=ver,
                                        commit_ver=ver)
                 last = UpdateReply(
                     Code.TARGET_OFFLINE,
                     message=f"{len(landed)}/{len(full)} commits acked")
                 self._backoff(attempt)
+                attempt += 1
                 continue
+            if (unreachable and acked + unreachable == writable
+                    and acked >= k
+                    and self._await_routing(t_first, unreachable)):
+                continue   # not an attempt: mgmtd has not spoken yet
             last = last or UpdateReply(
                 Code.TARGET_OFFLINE,
                 message=f"{acked}/{writable} writable shards acked")
             self._backoff(attempt, _hint_ms(last))
+            attempt += 1
         return last or UpdateReply(Code.CLIENT_RETRIES_EXHAUSTED)
 
     def _send_shard_batches(self, by_node) -> List[Tuple[int, object]]:
@@ -1433,9 +1495,12 @@ class StorageClient:
             if reply.ok:
                 committed[b] += 1
         # strict rule: every writable shard staged AND committed
+        ok = [b in full_staged and committed[b] == acked[b]
+              for b in range(B)]
+        if writable < k + m:
+            self._ec_degraded_write.add(sum(ok))
         return [UpdateReply(Code.OK, update_ver=vers[b], commit_ver=vers[b])
-                if b in full_staged and committed[b] == acked[b] else hard[b]
-                for b in range(B)]
+                if ok[b] else hard[b] for b in range(B)]
 
     def _write_stripes_chain(
         self,
@@ -1893,6 +1958,19 @@ class StorageClient:
                 degraded.append(i)
         if not degraded:
             return
+        from tpu3fs.analytics import spans as _spans
+
+        with _spans.span("client.batch_read", "degraded"):
+            self._degraded_round(reqs, replies, ec_specs, shard_replies,
+                                 routing, degraded)
+
+    def _degraded_round(self, reqs, replies, ec_specs, shard_replies,
+                        routing, degraded: List[int]) -> None:
+        """_finish_stripe_reads past its clean stripes: the second round
+        and the decodes, under the stage ``client.batch_read.degraded``
+        (``nbytes`` = payload bytes the decodes answered with)."""
+        from tpu3fs.analytics import spans as _spans
+
         t0 = time.monotonic()
         wire: List[Tuple[int, ReadReq]] = []
         tags: List[Tuple[int, int]] = []
@@ -1916,6 +1994,7 @@ class StorageClient:
         for (i, j), r in zip(tags, self._issue_wire_reads(wire)):
             shard_replies[i][j] = r
         dt_ms = (time.monotonic() - t0) * 1000.0
+        decoded = 0
         for i in degraded:
             out = self._stripe_degraded(ec_specs[i], shard_replies[i])
             if out is None:
@@ -1931,6 +2010,10 @@ class StorageClient:
                 # held inode) is a hole, nothing was decoded
                 self._ec_degraded.add()
                 self._ec_degraded_ms.record(dt_ms)
+                decoded += len(out.data)
+        stage = _spans.current_trace()
+        if stage is not None:
+            stage.nbytes = decoded
 
     def read_stripe(
         self,
@@ -1964,6 +2047,8 @@ class StorageClient:
         *,
         chunk_size: int = 1 << 20,
     ) -> ReadReply:
+        from tpu3fs.analytics import spans as _spans
+
         chain = self._chain(chain_id)
         if not chain.is_ec:
             raise FsError(Status(Code.INVALID_ARG, "read_stripe on CR chain"))
@@ -1990,23 +2075,27 @@ class StorageClient:
             # degraded: gather every remaining readable shard, group by
             # version, reconstruct from the newest k-quorum
             t0 = time.monotonic()
-            extra: List[Tuple[int, Tuple[int, ReadReq]]] = []
-            for j in range(spec["k"] + spec["m"]):
-                r = direct.get(j)
-                if r is not None and r.ok:
-                    continue
-                t = chain.target_of_shard(j)
-                if t is None or not t.public_state.can_read:
-                    continue
-                node = routing.node_of_target(t.target_id)
-                if node is None:
-                    continue
-                extra.append((j, (node.node_id, ReadReq(
-                    chain_id, chunk_id, 0, -1, t.target_id))))
-            for (j, _), r in zip(extra, self._issue_wire_reads(
-                    [entry for _, entry in extra])):
-                direct[j] = r
-            out = self._stripe_degraded(spec, direct)
+            with _spans.span("client.read_stripe", "degraded"):
+                extra: List[Tuple[int, Tuple[int, ReadReq]]] = []
+                for j in range(spec["k"] + spec["m"]):
+                    r = direct.get(j)
+                    if r is not None and r.ok:
+                        continue
+                    t = chain.target_of_shard(j)
+                    if t is None or not t.public_state.can_read:
+                        continue
+                    node = routing.node_of_target(t.target_id)
+                    if node is None:
+                        continue
+                    extra.append((j, (node.node_id, ReadReq(
+                        chain_id, chunk_id, 0, -1, t.target_id))))
+                for (j, _), r in zip(extra, self._issue_wire_reads(
+                        [entry for _, entry in extra])):
+                    direct[j] = r
+                out = self._stripe_degraded(spec, direct)
+                stage = _spans.current_trace()
+                if stage is not None and out is not None and out.ok:
+                    stage.nbytes = len(out.data)
             if out is not None:
                 if out.ok:
                     self._ec_degraded.add()
@@ -2158,16 +2247,24 @@ class StorageClient:
         the just-killed-but-still-SERVING heartbeat window and transient
         no-serving windows during failover."""
         last_err: Optional[FsError] = None
-        for attempt in range(self._retry.max_retries + 1):
+        attempt = 0           # attempts spent; a wait for routing is none
+        t_first = time.monotonic()
+        while attempt <= self._retry.max_retries:
             routing, chain = self._route(chain_id)
             if chain.is_ec:
                 # each target holds a different shard: the precise length
                 # is the max over ALL serving targets' contributions — a
                 # partial sweep could under-report the tail shard, so any
-                # per-target failure fails the whole attempt
+                # per-target failure fails the whole attempt. Where the
+                # only failures are nodes that do not answer while routing
+                # still calls their targets SERVING, the sweep waits for
+                # mgmtd's verdict like a put does (_await_routing): a
+                # close inside the detection window settles, it does not
+                # fail
                 best = (-1, 0)
                 failed: Optional[FsError] = None
                 queried = 0
+                unreachable = other = 0
                 for t in chain.targets:
                     if t.public_state != PublicTargetState.SERVING:
                         continue
@@ -2185,6 +2282,10 @@ class StorageClient:
                             (chain_id, file_id))
                     except FsError as e:
                         failed = e
+                        if e.code in UNREACHABLE_CODES:
+                            unreachable += 1
+                        else:
+                            other += 1
                         continue
                     queried += 1
                     if got[0] > best[0] or (
@@ -2196,6 +2297,11 @@ class StorageClient:
                 last_err = failed or FsError(Status(
                     Code.TARGET_OFFLINE,
                     f"no serving shard target on chain {chain_id}"))
+                if (unreachable and not other and queried >= chain.ec_k
+                        and self._await_routing(
+                            t_first, unreachable,
+                            op="client.query_last_chunk")):
+                    continue   # not an attempt: mgmtd has not spoken yet
             else:
                 answered = False
                 for t in chain.targets[::-1]:  # prefer tail: committed
@@ -2223,4 +2329,5 @@ class StorageClient:
                     raise FsError(Status(Code.DEADLINE_EXCEEDED,
                                          "op deadline exhausted"))
                 self._sleep(attempt)
+            attempt += 1
         raise last_err

@@ -15,11 +15,11 @@ from __future__ import annotations
 
 import itertools
 import tempfile
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict, List, Optional
 
 from tpu3fs.client.file_io import FileIoClient
-from tpu3fs.client.storage_client import StorageClient
+from tpu3fs.client.storage_client import RetryOptions, StorageClient
 from tpu3fs.kv import MemKVEngine
 from tpu3fs.meta.store import ChainAllocator, MetaStore
 from tpu3fs.mgmtd.service import Mgmtd, MgmtdConfig
@@ -348,7 +348,15 @@ class Fabric:
         raise FsError(Status(Code.RPC_METHOD_NOT_FOUND, method))
 
     # -- clients ------------------------------------------------------------
-    def storage_client(self, **kw) -> StorageClient:
+    def storage_client(self, *, routing_wait_s: float = 0.0,
+                       **kw) -> StorageClient:
+        """A client of the in-process cluster. Its mgmtd speaks only when
+        the caller ticks it, on a simulated clock, so a put that waited in
+        real time for mgmtd's verdict on a dead node
+        (RetryOptions.routing_wait_s) would wait for nothing: no wait
+        unless the caller, who then ticks from another thread, asks."""
+        kw["retry"] = replace(kw.get("retry") or RetryOptions(),
+                              routing_wait_s=routing_wait_s)
         return StorageClient(
             f"client-{next(self._client_seq)}", self.routing, self.send, **kw
         )

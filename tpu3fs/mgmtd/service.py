@@ -672,15 +672,31 @@ class Mgmtd:
                 node_id, node_type, NodeStatus.HEARTBEAT_CONNECTING, host, port
             )
             existing = txn.get(_node_key(node_id))
+            replaced = False    # a process still believed connected
             if existing is not None:
                 old = deserialize(existing, NodeInfo)
                 info.heartbeat_version = old.heartbeat_version
+                replaced = old.status == NodeStatus.HEARTBEAT_CONNECTED
             txn.set(_node_key(node_id), serialize(info))
-            return info, self._bump_routing_in_txn(txn)
+            return info, self._bump_routing_in_txn(txn), replaced
 
-        info, ver = with_transaction(self._engine, op)
+        info, ver, replaced = with_transaction(self._engine, op)
         self._routing.nodes[node_id] = info
         self._routing.version = ver
+        if replaced and node_type == NodeType.STORAGE:
+            # a process registers under an id whose last process was never
+            # declared dead: it died and came back inside the heartbeat
+            # timeout. Its targets were down meanwhile and may have come
+            # back on an empty disk, so they leave their chains NOW and
+            # return through SYNCING like any returning target; the chain
+            # versions move before the new process opens its targets, and
+            # it reports them ONLINE (storage_main.scan_targets), not up
+            # to date on the strength of a chain that never noticed.
+            xlog("WARN", "mgmtd %d: storage node %d registered again "
+                 "while still connected: a restart inside the heartbeat "
+                 "timeout, its targets go OFFLINE", self.node_id, node_id)
+            self._targets_offline({node_id})
+            self.update_chains()
 
     # -- KVCache serving endpoints (tpu3fs/serving peer directory) ----------
     def serving_register(self, node_id: int, host: str, port: int,
@@ -834,11 +850,16 @@ class Mgmtd:
                 txn.set(_node_key(node_id), serialize(self._routing.nodes[node_id]))
 
         with_transaction(self._engine, op)
-        dead_set = set(dead)
+        self._targets_offline(set(dead))
+        return dead
+
+    def _targets_offline(self, node_ids: set) -> None:
+        """The local state of every chain target on these nodes goes
+        OFFLINE; the next update_chains moves their public states."""
         for chain in self._routing.chains.values():
             for t in chain.targets:
                 info = self._routing.targets.get(t.target_id)
-                if info is not None and info.node_id in dead_set:
+                if info is not None and info.node_id in node_ids:
                     t.local_state = LocalTargetState.OFFLINE
                     info.local_state = LocalTargetState.OFFLINE
                     # every writer of local_state must mark the target
@@ -846,7 +867,6 @@ class Mgmtd:
                     # OFFLINE state and a primary restart resurrects the
                     # dead node's last heartbeat as UPTODATE
                     self._dirty_targets.add(t.target_id)
-        return dead
 
     # -- metadata partition assigner (tpu3fs/metashard) ----------------------
     def update_meta_partitions(self, now: Optional[float] = None) -> int:

@@ -87,6 +87,7 @@ class RSCode:
         self._reconstruct_mats: dict = {}
         self._reconstruct_fns: dict = {}
         self._pallas_matrices: dict = {}
+        self._decode_operands: dict = {}
         self._einsum_fns: dict = {}
         self._xor_schedule: Optional[list] = None
         self._delta_cols: dict = {}
@@ -325,6 +326,38 @@ class RSCode:
                 )
             self._reconstruct_fns[key] = fn
         return fn
+
+    def decode_operand(self, present_idx: Sequence[int],
+                       lost_idx: Sequence[int]):
+        """The decode of ``lost`` from ``present`` as the (8*lost, 8k) bit
+        matrix a device program takes as an OPERAND (cached): laid out for
+        the Pallas kernel on a TPU, symbol-major for the einsum elsewhere.
+        Every loss pattern that loses as many shards then shares ONE
+        compiled program (``apply_operand`` under the caller's jit), where
+        a matrix baked into the program would compile once a pattern."""
+        from tpu3fs.ops import pallas_rs
+
+        key = (tuple(int(i) for i in present_idx),
+               tuple(int(i) for i in lost_idx))
+        op = self._decode_operands.get(key)
+        if op is None:
+            op = GF.expand_to_bits(
+                self._reconstruct_matrix(*key)).astype(np.int8)
+            if pallas_rs.backend_supports_pallas():
+                op = pallas_rs.prepare_matrix(op)
+            self._decode_operands[key] = op
+        return op
+
+    @staticmethod
+    def apply_operand(matrix, data):
+        """``decode_operand``'s matrix applied to (..., k, S) survivors ->
+        (..., lost, S), for use INSIDE a jitted program: the fused Pallas
+        kernel on a TPU, the einsum form elsewhere."""
+        from tpu3fs.ops import pallas_rs
+
+        if pallas_rs.backend_supports_pallas():
+            return pallas_rs.gf2_matmul(matrix, data)
+        return _bit_matmul(matrix, data)
 
     def _xor_rebuild_applies(self, present, lost) -> bool:
         """True when lost is one shard rebuildable from parity row 0: the

@@ -2,9 +2,9 @@
 
 One stripe = one file chunk split into k data shards of S bytes plus m
 parity shards. Encode (RS(k,m) GF(2) bit-matmul, Pallas on TPU) and batched
-CRC32C run on device; decode/reconstruct goes through the same
-RSCode.reconstruct_fn the rebuild benches and the multi-chip dryrun use, so
-a kernel improvement lands everywhere at once.
+CRC32C run on device; decode/reconstruct applies the same RSCode decode matrix
+and kernel (pallas_rs.gf2_matmul) the multi-chip rebuild uses, as ONE
+jitted program of its own, ``decode_device``.
 
 The reference has no RS path (it replicates via CRAQ, docs/design_notes.md
 "Data replication"); "EC" exists there as a chain-table type in the
@@ -103,6 +103,7 @@ class StripeCodec:
         # device programs (jit is lazy: building them touches no backend)
         self._encode_dev = jax.jit(self._encode_device)
         self._crc_dev = jax.jit(self._crc.compute)
+        self._decode_dev = jax.jit(self._decode_device)
         self._prepared = False      # every encode bucket is built
         self._prepare_lock = threading.Lock()
 
@@ -305,23 +306,30 @@ class StripeCodec:
     ) -> np.ndarray:
         """(B, k, S) survivors at present_idx -> (B, len(lost), S) rebuilt.
         The single-chip serving path; the pod-scale variant is
-        tpu3fs.parallel.rebuild.rebuild_lost_shard over a mesh (same
-        reconstruct_fn underneath)."""
+        tpu3fs.parallel.rebuild.rebuild_lost_shard over a mesh (the same
+        RSCode decode matrix underneath)."""
         if self._use_host():
             return self.rs.reconstruct_host(present_idx, lost_idx, present)
-        # NOT wrapped in one outer jit: the decode matrix is an OPERAND of
-        # the jitted kernel underneath, so every loss pattern of one shape
-        # shares one compiled program; an outer jit would bake the matrix
-        # in and compile once per pattern
-        fn = self.rs.reconstruct_fn(tuple(present_idx), tuple(lost_idx))
-        out = np.empty((present.shape[0], len(lost_idx), self.shard_size),
-                       dtype=np.uint8)
-        for lo, n, rebuilt in self._device_map(
-                lambda part: fn(jnp.asarray(part)), present,
-                self._device_step(self.k + len(lost_idx)),
-                op="codec.reconstruct"):
-            out[lo:lo + n] = rebuilt[:n]
+        b = present.shape[0]
+        matrix = self.rs.decode_operand(present_idx, lost_idx)
+        out = np.empty((b, len(lost_idx), self.shard_size), dtype=np.uint8)
+        with _spans.root_span("codec.reconstruct",
+                              nbytes=b * self.k * self.shard_size):
+            for lo, n, rebuilt in self._device_map(
+                    lambda part: self._decode_dev(matrix, part), present,
+                    self._device_step(self.k + len(lost_idx)),
+                    op="codec.reconstruct"):
+                out[lo:lo + n] = rebuilt[:n]
         return out
+
+    def _decode_device(self, matrix, present):
+        """(8*lost, 8k) decode matrix x (Bp, k, S) survivors -> (Bp, lost,
+        S): ONE jitted program per (lost count, batch bucket), named
+        ``decode_device`` as the encode's is ``encode_device``. The matrix
+        is an OPERAND (RSCode.decode_operand), so every loss pattern of one
+        shape shares the program; the kernel's lane padding and the slice
+        back to S are inside it, not dispatches of their own."""
+        return self.rs.apply_operand(matrix, present)
 
     def crc_batch(self, shards: np.ndarray) -> np.ndarray:
         """(N, S) uint8 -> (N,) uint32 (device; host CRC on CPU backends)."""

@@ -1412,6 +1412,23 @@ class StorageService:
                 lease.release()
         return self._write_shard_locked(req, target)
 
+    @staticmethod
+    def _stale_stage(req: ShardWriteReq,
+                     chain: ChainInfo) -> Optional[UpdateReply]:
+        """The chain-version fence of a stripe STAGE (phase 1), as the CR
+        write has it: a client that staged against another version of the
+        chain chose its shard set from another writable set — a target
+        that has come back SYNCING since would never get the stripe, and
+        be promoted with a hole in it. CHAIN_VERSION_MISMATCH is
+        retryable: the ladder re-resolves and stages on what is writable
+        NOW. Commits (phase 2) of what was staged and rebuild installs
+        (phase 0) of proven content land whatever the version."""
+        if req.phase == 1 and req.chain_ver != chain.chain_version:
+            return UpdateReply(
+                Code.CHAIN_VERSION_MISMATCH,
+                message=f"client {req.chain_ver} != {chain.chain_version}")
+        return None
+
     def _write_shard_locked(self, req: ShardWriteReq,
                             target: StorageTarget) -> UpdateReply:
         with self._chunk_lock(req.target_id, req.chunk_id):
@@ -1420,6 +1437,9 @@ class StorageService:
                 self._check_target_serving(target)
                 chain = self._chain(req.chain_id)  # re-check under the lock
                 engine = target.engine
+                stale = self._stale_stage(req, chain)
+                if stale is not None:
+                    return stale
                 if req.phase == 2:
                     # COMMIT a staged stripe version: idempotent for
                     # duplicates (committed >= ver returns OK); missing
@@ -2156,6 +2176,10 @@ class StorageService:
                 if not chain.is_ec:
                     replies[i] = UpdateReply(Code.INVALID_ARG,
                                              message="not an EC chain")
+                    continue
+                stale = self._stale_stage(r, chain)
+                if stale is not None:
+                    replies[i] = stale
                     continue
                 if r.phase == 2:
                     commits.append((r.chunk_id, r.update_ver))

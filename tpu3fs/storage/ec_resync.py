@@ -20,14 +20,29 @@ the worker is driven off routing exactly like the CR resync worker.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Deque, Dict, List, Optional
 
 import numpy as np
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.mgmtd.types import ChainInfo, PublicTargetState, RoutingInfo
 from tpu3fs.storage.craq import Messenger, ReadReq, ShardWriteReq, StorageService
 from tpu3fs.storage.types import ChunkId, ChunkMeta
 from tpu3fs.utils.result import Code, FsError
+
+
+def pass_line(stats: Dict) -> str:
+    """One finished target pass as the line the storage binary logs:
+    ``ec.rebuild target= stripes= installed= installed_bytes= read_bytes=
+    seconds= done=`` (stripes the inventory knew, shards installed, their
+    bytes, bytes of the survivor reads that made them, wall seconds, 1
+    where the pass ended in sync_done)."""
+    return (f"ec.rebuild target={stats['target']} stripes={stats['stripes']} "
+            f"installed={stats['installed']} "
+            f"installed_bytes={stats['bytes']} "
+            f"read_bytes={stats['read_bytes']} "
+            f"seconds={stats['seconds']:.3f} done={int(stats['done'])}")
 
 
 class EcResyncWorker:
@@ -48,10 +63,15 @@ class EcResyncWorker:
         # bench's source-spread verification: recovery reads per SOURCE
         # target prove the source-disjoint rotation actually spreads load
         self.last_stats: Dict = {
-            "stripes": 0, "installed": 0, "bytes": 0,
-            "read_sources": {}, "mibps": 0.0}
+            "target": 0, "stripes": 0, "installed": 0, "bytes": 0,
+            "read_bytes": 0, "read_sources": {}, "seconds": 0.0,
+            "mibps": 0.0, "done": False}
         self._round_stats: Dict = dict(self.last_stats,
                                        read_sources={})
+        # one entry a target pass that installed something (last_stats'
+        # keys), oldest first; the storage binary drains it into its log
+        # (pass_line), one line a pass
+        self.finished_passes: Deque[Dict] = deque(maxlen=64)
         # healthy-repair memo: per chain, the pending signature of the last
         # sweep that committed nothing. A pending set that can never reach
         # the roll-forward quorum (e.g. a phase-1 crash that staged < k
@@ -181,8 +201,10 @@ class EcResyncWorker:
         # fresh per-round stats dict; published to last_stats only when
         # the round actually rebuilt something, so a later no-op sweep
         # does not wipe the numbers ec-status / the bench report
-        round_stats: Dict = {"stripes": len(todo), "installed": 0,
-                             "bytes": 0, "read_sources": {}, "mibps": 0.0}
+        round_stats: Dict = {"target": target_id, "stripes": len(todo),
+                             "installed": 0, "bytes": 0, "read_bytes": 0,
+                             "read_sources": {}, "seconds": 0.0,
+                             "mibps": 0.0, "done": False}
         self._round_stats = round_stats
         t0 = _time.monotonic()
         for base in range(0, len(todo), self._batch):
@@ -190,8 +212,6 @@ class EcResyncWorker:
             # each rebuild batch is a traceable op: head-sampled like any
             # client op, its recovery reads/installs carry the context
             # over the batchReadRebuild / batch_write_shard RPCs
-            from tpu3fs.analytics import spans as _spans
-
             with _spans.root_span("ec.rebuild_batch"):
                 ok, bad = self._rebuild_batch(
                     routing, chain, batch, lost_shard, node.node_id,
@@ -200,32 +220,85 @@ class EcResyncWorker:
             failed += bad
         dt = _time.monotonic() - t0
         round_stats["installed"] = moved
+        round_stats["seconds"] = round(dt, 3)
         if moved:
             if dt > 0:
                 mibps = round_stats["bytes"] / dt / (1 << 20)
                 round_stats["mibps"] = round(mibps, 3)
                 self._rebuild_mibps.set(mibps)
             self.last_stats = round_stats
-        # stale-chunk cleanup: shards on the recovering target for stripes
-        # no peer knows anymore
+        # the pass's closing inventory, then the stale-chunk cleanup:
+        # shards on the recovering target for stripes no peer knows — not
+        # at the pass's opening and not NOW, and not a write in flight (a
+        # put that resolved after the target went SYNCING stages on it
+        # like on any writable shard: its pending is not stale, and
+        # removing it fails the put's commit)
+        late = 0
         try:
+            known_now, late_keys = self._closing_inventory(
+                routing, chain, target_id)
             have: List[ChunkMeta] = self._messenger(
                 node.node_id, "dump_chunkmeta", target_id)
+            held = {m.chunk_id.to_bytes() for m in have
+                    if m.committed_ver > 0}
+            late = len(late_keys - set(stripes) - held)
             for meta in have:
-                if meta.chunk_id.to_bytes() not in stripes:
+                key = meta.chunk_id.to_bytes()
+                if (key not in stripes and key not in known_now
+                        and meta.pending_ver == 0):
                     self._messenger(
                         node.node_id, "remove_chunk", (target_id, meta.chunk_id))
         except FsError:
             failed += 1
+        # stripes a SERVING peer holds committed now that the opening
+        # inventory did not have and the target does not hold: a put that
+        # resolved its routing while this target was still OFFLINE staged
+        # and committed on the other shards only, and where that commit
+        # landed after the opening inventory the pass never saw the
+        # stripe. Promoting now would leave the target SERVING with a hole
+        # in it: the pass is not done, the next round rebuilds them.
+        failed += late
         if failed == 0:
             # only promote when EVERY stripe was rebuilt this round —
             # skipped stripes (in-flight writes, failed installs) must get
             # another pass before the target may serve reads
             try:
                 self._messenger(node.node_id, "sync_done", target_id)
+                round_stats["done"] = True
             except FsError:
                 pass  # recovering node died again; next round retries
+        if moved:
+            self.finished_passes.append(round_stats)
         return moved
+
+    def _closing_inventory(self, routing: RoutingInfo, chain: ChainInfo,
+                           target_id: int) -> tuple:
+        """What the peers hold at the END of a pass -> (every stripe some
+        peer knows, committed or pending; the stripes a SERVING peer holds
+        committed). Raises FsError where a serving peer does not answer:
+        the pass cannot tell, and is not done."""
+        known: set = set()
+        committed: set = set()
+        serving_ids = {t.target_id for t in chain.serving_targets()}
+        for t in chain.targets:
+            if t.target_id == target_id:
+                continue
+            pn = routing.node_of_target(t.target_id)
+            if pn is None:
+                continue
+            try:
+                metas: List[ChunkMeta] = self._messenger(
+                    pn.node_id, "dump_chunkmeta", t.target_id)
+            except FsError:
+                if t.target_id in serving_ids:
+                    raise
+                continue
+            for m in metas:
+                key = m.chunk_id.to_bytes()
+                known.add(key)
+                if m.committed_ver > 0 and t.target_id in serving_ids:
+                    committed.add(key)
+        return known, committed
 
     def _repair_healthy(self, routing: RoutingInfo, chain: ChainInfo) -> int:
         """Roll forward partially-committed two-phase stripe writes on a
@@ -559,6 +632,7 @@ class EcResyncWorker:
                 continue
             r, safe = rs
             by_ver.setdefault(r.commit_ver, {})[j] = r.data
+            self._round_stats["read_bytes"] += len(r.data)
             if j == lost_shard:
                 own_ver = r.commit_ver
             if safe:
@@ -614,6 +688,7 @@ class EcResyncWorker:
         bytes of a decode) — direct_rows carries
         (cid, ver, payload, crc, S, logical); any mismatch (a write
         landed after the swap froze the leftover) decodes as usual."""
+        from tpu3fs.ops.crc32c import crc32c
         from tpu3fs.ops.stripe import aligned_shard_size
 
         k, m = chain.ec_k, chain.ec_m
@@ -630,27 +705,29 @@ class EcResyncWorker:
         stats: Dict[int, list] = {}
         safe: Dict[int, bool] = {}
         route: Dict[int, tuple] = {}
-        for j in range(k + m):
-            t = chain.target_of_shard(j)
-            if t is None:
-                continue
-            pn = routing.node_of_target(t.target_id)
-            if pn is None:
-                continue
-            try:
-                st = self._messenger(pn.node_id, "stat_chunks",
-                                     (t.target_id, list(chunk_ids)))
-            except FsError:
-                continue
-            if len(st) != len(chunk_ids):
-                continue
-            stats[j] = st
-            safe[j] = t.public_state.can_read
-            route[j] = (t.target_id, pn.node_id)
+        with _spans.span("ec.rebuild_batch", "inventory"):
+            for j in range(k + m):
+                t = chain.target_of_shard(j)
+                if t is None:
+                    continue
+                pn = routing.node_of_target(t.target_id)
+                if pn is None:
+                    continue
+                try:
+                    st = self._messenger(pn.node_id, "stat_chunks",
+                                         (t.target_id, list(chunk_ids)))
+                except FsError:
+                    continue
+                if len(st) != len(chunk_ids):
+                    continue
+                stats[j] = st
+                safe[j] = t.public_state.can_read
+                route[j] = (t.target_id, pn.node_id)
         if sum(1 for j in stats if j != lost_shard) < k:
             # stats too thin: serial decides
             return [], [], list(chunk_ids), []
         plans: List[dict] = []
+        empty_rows: List[tuple] = []
         skip_cids: List[ChunkId] = []
         fallback: List[ChunkId] = []
         reads: Dict[int, list] = {}  # node -> [(plan idx, shard j, req)]
@@ -692,6 +769,17 @@ class EcResyncWorker:
             S_work = max(lens.get((ver, j), 0) for j in by_ver[ver])
             if S_work == 0:
                 continue  # all-empty stripe: nothing to rebuild
+            logical = aux_by_ver.get(ver, 0)
+            if lost_shard < k and 0 < logical <= \
+                    lost_shard * aligned_shard_size(S_work):
+                # the stripe ends before this data shard: it holds no
+                # bytes, and the quorum's persisted logical length proves
+                # it — an empty install at the proven version, no survivor
+                # read and no decode (a 576-KiB entry in a 1-MiB chunk
+                # leaves five of twelve data shards so)
+                empty_rows.append((cid, ver, b"", crc32c(b""),
+                                   aligned_shard_size(S_work), logical))
+                continue
             if lo_stats is not None and lo_stats[idx][0] == ver:
                 # DIRECT COPY: the swap's outgoing member still holds
                 # this stripe's shard at the PROVEN version (the swap
@@ -719,28 +807,31 @@ class EcResyncWorker:
                 tid, nid = route[j]
                 reads.setdefault(nid, []).append((pi, j, ReadReq(
                     chain.chain_id, cid, 0, -1, tid)))
-        for nid, entries in reads.items():
-            try:
-                replies = self._messenger(
-                    nid, "batch_read_rebuild", [rq for _, _, rq in entries])
-            except FsError:
-                replies = [None] * len(entries)
-            for (pi, j, _rq), r in zip(entries, replies):
-                plan = plans[pi]
-                if r is None or not r.ok or r.commit_ver != plan["ver"]:
-                    plan["bad"] = True  # raced/failed: serial decides
-                    continue
-                if plan.get("direct"):
-                    plan["payload"] = bytes(r.data)  # copy-ok: install input
-                    plan["crc"] = r.checksum.value
-                    src = leftover[0]
-                else:
-                    plan["shards"][j] = bytes(r.data)  # copy-ok: decode input
-                    src = route[j][0]
-                sources = self._round_stats["read_sources"]
-                sources[src] = sources.get(src, 0) + 1
+        with _spans.span("ec.rebuild_batch", "read"):
+            for nid, entries in reads.items():
+                try:
+                    replies = self._messenger(
+                        nid, "batch_read_rebuild", [rq for _, _, rq in entries])
+                except FsError:
+                    replies = [None] * len(entries)
+                for (pi, j, _rq), r in zip(entries, replies):
+                    plan = plans[pi]
+                    if r is None or not r.ok or r.commit_ver != plan["ver"]:
+                        plan["bad"] = True  # raced/failed: serial decides
+                        continue
+                    if plan.get("direct"):
+                        plan["payload"] = bytes(r.data)  # copy-ok: install input
+                        plan["crc"] = r.checksum.value
+                        src = leftover[0]
+                    else:
+                        plan["shards"][j] = bytes(r.data)  # copy-ok: decode input
+                        src = route[j][0]
+                    sources = self._round_stats["read_sources"]
+                    sources[src] = sources.get(src, 0) + 1
+                    self._round_stats["read_bytes"] += len(  # copy-ok: a count
+                        r.data)
         rows = []
-        direct_rows = []
+        direct_rows = empty_rows
         for plan in plans:
             if plan.get("direct"):
                 if plan["bad"] or plan["payload"] is None:
@@ -848,48 +939,49 @@ class EcResyncWorker:
                 logical_len=logical,
             ))
             install_cids.append(cid)
-        for (present, S), idxs in groups.items():
-            codec = get_codec(k, m, S)
-            surv = np.stack([
-                np.stack([
-                    np.frombuffer(
-                        gathered[i][2][j].ljust(S, b"\x00"), dtype=np.uint8)
-                    for j in present
-                ])
-                for i in idxs
-            ])  # (B, k, S)
-            rebuilt = self._reconstruct(codec, present, (lost_shard,), surv)
-            for row, i in enumerate(idxs):
-                cid, ver, shards, _, logical = gathered[i]
-                raw = rebuilt[row, 0].tobytes()
-                if logical and lost_shard < k:
-                    # EXACT trim from the survivors' persisted stripe
-                    # logical length (engine aux tag) — no zero-stripping
-                    # ambiguity even when true content ends in zeros
-                    extent = min(max(logical - lost_shard * S, 0), S)
-                    payload = raw[:extent]
-                elif lost_shard >= k:
-                    payload = raw  # parity shards are stored full
-                else:
-                    lens = {j: len(b) for j, b in shards.items() if j < k}
-                    payload = trim_rebuilt_shard(
-                        raw, lost_shard, lens, k, S)
-                installs.append(ShardWriteReq(
-                    chain_id=chain.chain_id,
-                    chain_ver=chain.chain_version,
-                    target_id=target_id,
-                    chunk_id=cid,
-                    data=payload,
-                    crc=codec.crc_host(payload),
-                    update_ver=ver,
-                    chunk_size=S,
-                    logical_len=logical,
-                ))
-                install_cids.append(cid)
+        with _spans.span("ec.rebuild_batch", "decode"):
+            for (present, S), idxs in groups.items():
+                codec = get_codec(k, m, S)
+                surv = np.stack([
+                    np.stack([
+                        np.frombuffer(
+                            gathered[i][2][j].ljust(S, b"\x00"), dtype=np.uint8)
+                        for j in present
+                    ])
+                    for i in idxs
+                ])  # (B, k, S)
+                rebuilt = self._reconstruct(codec, present, (lost_shard,), surv)
+                for row, i in enumerate(idxs):
+                    cid, ver, shards, _, logical = gathered[i]
+                    raw = rebuilt[row, 0].tobytes()
+                    if logical and lost_shard < k:
+                        # EXACT trim from the survivors' persisted stripe
+                        # logical length (engine aux tag) — no zero-stripping
+                        # ambiguity even when true content ends in zeros
+                        extent = min(max(logical - lost_shard * S, 0), S)
+                        payload = raw[:extent]
+                    elif lost_shard >= k:
+                        payload = raw  # parity shards are stored full
+                    else:
+                        lens = {j: len(b) for j, b in shards.items() if j < k}
+                        payload = trim_rebuilt_shard(
+                            raw, lost_shard, lens, k, S)
+                    installs.append(ShardWriteReq(
+                        chain_id=chain.chain_id,
+                        chain_ver=chain.chain_version,
+                        target_id=target_id,
+                        chunk_id=cid,
+                        data=payload,
+                        crc=codec.crc_host(payload),
+                        update_ver=ver,
+                        chunk_size=S,
+                        logical_len=logical,
+                    ))
+                    install_cids.append(cid)
+        with _spans.span("ec.rebuild_batch", "install"):
+            replies = self._install_batch(node_id, installs)
         moved = 0
-        for cid, req, reply in zip(
-                install_cids, installs,
-                self._install_batch(node_id, installs)):
+        for cid, req, reply in zip(install_cids, installs, replies):
             if reply is not None and reply.ok:
                 moved += 1
                 nbytes = len(req.data)
