@@ -1238,6 +1238,198 @@ class TestHeadPartialBatch:
         assert not bytes(got.data[got.logical_len:]).strip(b"\x00")
 
 
+class TestLengthSweep:
+    """query_last_chunks: a close batch's length sweep asks every distinct
+    node that hosts a SERVING target ONCE with all the file ids, and is the
+    per-file answer under the per-file policy."""
+
+    K, M, NODES = 12, 4, 4
+    CS = 12 * 1024
+    SH = shard_size_of(CS, 12)
+
+    def _fab(self):
+        return ec_fabric(nodes=self.NODES, chains=1, k=self.K, m=self.M,
+                         chunk_size=self.CS)
+
+    def _files(self, fab, client):
+        """file id -> the (index, length) the sweep has to answer: an empty
+        file, one that ends in shard 0, one whose tail shard (11) sits on
+        the last node, a full stripe, and one of three chunks."""
+        chain = fab.chain_ids[0]
+        want = {40: (-1, 0), 41: (0, 77), 42: (0, 11 * self.SH + 5),
+                43: (0, self.CS), 44: (2, 3 * self.SH + 1)}
+        rng = np.random.default_rng(44)
+        for fid, (idx, n) in want.items():
+            for i in range(idx + 1):
+                size = n if i == idx else self.CS
+                data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+                assert client.write_stripe(chain, ChunkId(fid, i), data,
+                                           chunk_size=self.CS).ok
+        return want
+
+    def test_the_tail_shard_of_file_42_sits_on_the_last_node(self):
+        fab = self._fab()
+        routing = fab.routing()
+        chain = routing.chains[fab.chain_ids[0]]
+        nodes = [routing.node_of_target(chain.target_of_shard(j).target_id)
+                 .node_id for j in range(self.K + self.M)]
+        assert len(set(nodes)) == self.NODES
+        assert nodes[11] == max(nodes)
+
+    def test_batched_answer_equals_the_per_file_answers(self):
+        fab = self._fab()
+        _, client, _ = _spied(fab)
+        want = self._files(fab, client)
+        chain = fab.chain_ids[0]
+        ids = list(want)
+        got = client.query_last_chunks(chain, ids)
+        assert got == [want[f] for f in ids]
+        assert got == [client.query_last_chunk(chain, f) for f in ids]
+        # order and repeats are the caller's
+        assert client.query_last_chunks(chain, [44, 40, 44]) == [
+            want[44], want[40], want[44]]
+        assert client.query_last_chunks(chain, []) == []
+
+    def test_every_node_answers_for_all_its_local_targets(self):
+        """The server's batch is its single answer a file."""
+        fab = self._fab()
+        _, client, _ = _spied(fab)
+        want = self._files(fab, client)
+        chain = fab.chain_ids[0]
+        for node in fab.nodes:
+            assert fab.send(node, "query_last_chunks", (chain, list(want))) \
+                == [fab.send(node, "query_last_chunk", (chain, f))
+                    for f in want]
+
+    def test_one_request_a_distinct_node_a_sweep(self):
+        fab = self._fab()
+        _, client, spy = _spied(fab)
+        want = self._files(fab, client)
+        spy.calls.clear()
+        client.query_last_chunks(fab.chain_ids[0], list(want))
+        asked = [n for m, n in spy.calls if m == "query_last_chunks"]
+        assert sorted(asked) == sorted(fab.nodes)       # 4, never 16 x files
+        assert spy.count("query_last_chunk") == 0
+        assert client._length_rpcs._value >= self.NODES
+
+    def _with_states(self, fab, states):
+        """A routing provider whose copy calls shard j `states[j]`."""
+        import copy
+
+        def routing():
+            ri = copy.deepcopy(fab.routing())
+            chain = ri.chains[fab.chain_ids[0]]
+            for j, st in states.items():
+                chain.target_of_shard(j).public_state = st
+            return ri
+
+        return routing
+
+    def test_a_mixed_node_is_still_asked_and_a_lost_one_is_not(self):
+        from tpu3fs.client.storage_client import StorageClient
+        from tpu3fs.mgmtd.types import PublicTargetState as P
+
+        fab = self._fab()
+        _, client, _ = _spied(fab)
+        want = self._files(fab, client)
+        chain = fab.chain_ids[0]
+        # shard 3 SYNCING, its node's other three (7, 11, 15) SERVING
+        spy = _Spy(fab)
+        mixed = StorageClient("mixed", self._with_states(
+            fab, {3: P.SYNCING}), spy)
+        assert mixed.query_last_chunks(chain, list(want)) == list(
+            want.values())
+        assert len([1 for m, _ in spy.calls
+                    if m == "query_last_chunks"]) == self.NODES
+        # all four of that node out of SERVING: three nodes are the sweep
+        spy = _Spy(fab)
+        lost = StorageClient("lost", self._with_states(
+            fab, {j: P.OFFLINE for j in (3, 7, 11, 15)}), spy)
+        lost.query_last_chunks(chain, list(want))
+        assert len(spy.calls) == self.NODES - 1
+
+    def test_a_failing_node_fails_the_attempt_for_every_file(self):
+        from tpu3fs.utils.result import FsError
+
+        fab = self._fab()
+        _, client, spy = _spied(fab)
+        want = self._files(fab, client)
+        chain = fab.chain_ids[0]
+        spy.calls.clear()
+        spy.fail["query_last_chunks"] = 1     # one node, once
+        assert client.query_last_chunks(chain, list(want)) == list(
+            want.values())
+        assert spy.count("query_last_chunks") == 2 * self.NODES
+        spy.fail["query_last_chunks"] = 10 ** 6
+        with pytest.raises(FsError):          # an error, never a short length
+            client.query_last_chunks(chain, list(want))
+
+    def test_a_silent_node_counts_for_its_four_targets(self):
+        """queried >= k reads as before: three nodes that answered are
+        twelve targets, so a sweep whose fourth node does not answer waits
+        for routing (no attempt spent) instead of giving up."""
+        from tpu3fs.client.storage_client import RetryOptions
+
+        from tpu3fs.client.storage_client import StorageClient
+        from tpu3fs.utils.result import FsError, Status
+
+        fab = self._fab()
+        _, writer, _ = _spied(fab)
+        want = self._files(fab, writer)
+        silent, left, asked = max(fab.nodes), [3], []
+
+        def messenger(node_id, method, payload):
+            asked.append(node_id)
+            if node_id == silent and left[0] > 0:
+                left[0] -= 1
+                raise FsError(Status(Code.RPC_CONNECT_FAILED, "gone"))
+            return fab.send(node_id, method, payload)
+
+        patient = StorageClient("patient", fab.routing, messenger,
+                                retry=RetryOptions(
+                                    max_retries=0, backoff_max_s=0.005,
+                                    routing_wait_s=30.0))
+        assert patient.query_last_chunks(
+            fab.chain_ids[0], list(want)) == list(want.values())
+        assert len(asked) == 4 * self.NODES
+        # with no wait for routing and no retry left it is an error at once
+        left[0] = 10 ** 6
+        hasty = StorageClient("hasty", fab.routing, messenger,
+                              retry=RetryOptions(max_retries=0,
+                                                 routing_wait_s=0.0))
+        with pytest.raises(FsError):
+            hasty.query_last_chunks(fab.chain_ids[0], list(want))
+
+    def test_a_close_batch_is_one_sweep(self, monkeypatch):
+        """Through the meta store's hook: eight closes in one batch_close
+        are four storage requests, and every length is the precise one."""
+        from tpu3fs.meta.store import BatchCloseItem
+
+        fab = self._fab()
+        fio = fab.file_client()
+        items, sizes = [], []
+        for i in range(8):
+            res = fab.meta.create(f"/ls{i}", flags=OpenFlags.WRITE
+                                  | OpenFlags.CREATE | OpenFlags.TRUNC,
+                                  client_id="c")
+            n = 1 + i * 1500
+            fio.write(res.inode, 0, bytes([i]) * n)
+            sizes.append(n)
+            items.append(BatchCloseItem(res.inode.id, res.session_id,
+                                        client_id="c", wrote=1))
+        sent = []
+        inner = fab.send
+
+        def send(node_id, method, payload):
+            sent.append(method)
+            return inner(node_id, method, payload)
+
+        monkeypatch.setattr(fab, "send", send)
+        out = fab.meta.batch_close(items)
+        assert [o.length for o in out] == sizes
+        assert sent == ["query_last_chunks"] * self.NODES
+
+
 class TestCodecBuckets:
     """No encode program is built on a later request's path: the first
     dispatch prepares every bucket (counted where jit keeps its programs,

@@ -181,11 +181,12 @@ class TestOpenCloseSessions:
     def test_file_length_hook_wins(self):
         store = MetaStore(
             MemKVEngine(), ChainAllocator(1, [1]),
-            file_length_hook=lambda inode: 777,
+            file_length_hook=lambda inodes: [777] * len(inodes),
         )
         res = store.create("/f", flags=OpenFlags.WRITE, client_id="c")
         inode = store.close(res.inode.id, res.session_id, length_hint=5)
         assert inode.length == 777
+        assert store.sync(res.inode.id, length_hint=9).length == 777
 
 
 class TestRemoveGc:
@@ -432,6 +433,190 @@ class TestBatchClose:
         import pytest as _pytest
         with _pytest.raises(FsError):
             fab.meta.close(items[5].inode_id, items[5].session_id)
+
+
+class TestLengthHookAndTruncate:
+    """What a batch pays storage for: ONE length-hook call a transaction
+    chunk for the files that passed, and no truncate round for an inode
+    the create itself made."""
+
+    def _mk(self, fail=()):
+        from tpu3fs.kv.mem import MemKVEngine
+        from tpu3fs.utils.result import Status
+
+        calls, truncs, fail = [], [], set(fail)
+
+        def lengths(inodes):
+            calls.append([ino.id for ino in inodes])
+            return [FsError(Status(Code.TARGET_OFFLINE, "no shard"))
+                    if ino.id in fail else 1000 + ino.id for ino in inodes]
+
+        store = MetaStore(
+            MemKVEngine(), ChainAllocator(1, [101]),
+            file_length_hook=lengths,
+            truncate_hook=lambda ino, ln: truncs.append((ino.id, ln)))
+        return store, calls, truncs, fail
+
+    def _open(self, store, n, prefix="/h"):
+        from tpu3fs.meta.store import BatchCloseItem
+
+        items = []
+        for i in range(n):
+            res = store.create(f"{prefix}{i}", flags=OpenFlags.WRITE,
+                               client_id="c")
+            items.append(BatchCloseItem(res.inode.id, res.session_id,
+                                        client_id="c", wrote=1))
+        return items
+
+    def test_one_hook_call_a_chunk_with_all_passing_files(self):
+        from tpu3fs.meta.store import BatchCloseItem
+
+        store, calls, _, _ = self._mk()
+        items = self._open(store, 70)
+        items.insert(3, BatchCloseItem(999999, "nope"))       # fails a check
+        out = store.batch_close(items)
+        assert [len(c) for c in calls] == [63, 7]     # 64 + 7 less the bad
+        assert calls[0] + calls[1] == [
+            it.inode_id for it in items if it.inode_id != 999999]
+        assert isinstance(out[3], FsError)
+        assert all(o.length == 1000 + o.id for o in out
+                   if not isinstance(o, FsError))
+        assert store._closed_files._value == 70
+        assert store.list_sessions() == []
+
+    def test_a_failed_length_keeps_session_and_old_length(self):
+        store, calls, _, fail = self._mk()
+        items = self._open(store, 5)
+        bad = items[2]
+        store.sync(bad.inode_id)                 # old length: 1000 + id
+        fail.add(bad.inode_id)
+        out = store.batch_close(items)
+        assert isinstance(out[2], FsError) \
+            and out[2].code == Code.TARGET_OFFLINE
+        assert [s.session_id for s in store.list_sessions()] == [
+            bad.session_id]
+        assert store.stat("/h2").length == 1000 + bad.inode_id
+        for i in (0, 1, 3, 4):
+            assert out[i].length == 1000 + items[i].inode_id
+        # the item is whole: it closes once storage answers
+        fail.clear()
+        assert not isinstance(store.batch_close([bad])[0], FsError)
+        assert store.list_sessions() == []
+
+    def test_close_and_sync_raise_the_length_error(self):
+        store, _, _, fail = self._mk()
+        (it,) = self._open(store, 1)
+        fail.add(it.inode_id)
+        with pytest.raises(FsError) as ei:
+            store.close(it.inode_id, it.session_id)
+        assert code_of(ei) == Code.TARGET_OFFLINE
+        with pytest.raises(FsError):
+            store.sync(it.inode_id)
+        assert len(store.list_sessions()) == 1
+        fail.clear()
+        assert store.close(it.inode_id, it.session_id).length \
+            == 1000 + it.inode_id
+
+    def test_a_replayed_request_makes_no_storage_call(self):
+        store, calls, _, _ = self._mk()
+        items = self._open(store, 3)
+        for k, it in enumerate(items):
+            it.request_id = f"r{k}"
+        first = store.batch_close(items)
+        assert len(calls) == 1
+        again = store.batch_close(items)
+        assert len(calls) == 1                       # answered from the cache
+        assert [a.length for a in again] == [f.length for f in first]
+        it = items[0]
+        assert store.close(it.inode_id, it.session_id, client_id="c",
+                           request_id="r0").length == first[0].length
+        assert len(calls) == 1
+        # a replay beside a fresh close: the hook sees the fresh file alone
+        (fresh,) = self._open(store, 1, prefix="/g")
+        store.batch_close([items[1], fresh])
+        assert calls[-1] == [fresh.inode_id]
+
+    def test_two_closes_of_one_file_share_the_inode(self):
+        from tpu3fs.kv.mem import MemKVEngine
+        from tpu3fs.meta.store import BatchCloseItem
+
+        store = MetaStore(MemKVEngine())             # hints, no hook
+        a = store.create("/two", flags=OpenFlags.WRITE, client_id="a")
+        b = store.open("/two", flags=OpenFlags.WRITE, client_id="b")
+        out = store.batch_close([
+            BatchCloseItem(a.inode.id, a.session_id, 100, wrote=1),
+            BatchCloseItem(a.inode.id, b.session_id, 50, wrote=1)])
+        assert [o.length for o in out] == [100, 100]
+        assert store.stat("/two").length == 100
+        assert store.list_sessions() == []
+
+    @pytest.mark.parametrize("batched", [False, True])
+    def test_trunc_truncates_what_existed_not_what_it_made(self, batched):
+        from tpu3fs.meta.store import BatchCreateItem
+
+        store, _, truncs, _ = self._mk()
+        flags = OpenFlags.WRITE | OpenFlags.CREATE | OpenFlags.TRUNC
+        old = store.create("/old").inode
+        if batched:
+            out = store.batch_create([
+                BatchCreateItem("/old", flags=flags, client_id="c"),
+                BatchCreateItem("/new", flags=flags, client_id="c"),
+                BatchCreateItem("/nodir/x", flags=flags)])
+            assert isinstance(out[2], FsError)
+            assert out[0].inode.id == old.id and out[1].session_id
+        else:
+            assert store.create("/old", flags=flags).inode.id == old.id
+            store.create("/new", flags=flags)
+        assert truncs == [(old.id, 0)]
+        assert store._create_truncated._value == 1
+        assert store._create_truncate_skipped._value == 1
+        # a re-create over the path just made and open(TRUNC) keep theirs
+        new = store.stat("/new")
+        store.create("/new", flags=flags)
+        store.open("/new", flags=OpenFlags.WRITE | OpenFlags.TRUNC)
+        assert truncs[1:] == [(new.id, 0), (new.id, 0)]
+        # without TRUNC nothing is sent or counted either way
+        store.create("/plain", flags=OpenFlags.WRITE)
+        assert len(truncs) == 3
+        assert store._create_truncate_skipped._value == 1
+
+    def test_a_retried_create_transaction_still_knows_what_it_made(self):
+        """A KV conflict reruns the create; the attempt that commits says
+        whether the inode is new."""
+        from tpu3fs.meta.store import BatchCreateItem
+        from tpu3fs.utils.result import Status
+
+        store, _, truncs, _ = self._mk()
+        flags = OpenFlags.WRITE | OpenFlags.CREATE | OpenFlags.TRUNC
+        inner_create = store._create_in_txn
+        seen = []
+
+        def noting(txn, path, *a):
+            res = inner_create(txn, path, *a)
+            seen.append(res[0].inode.id)
+            return res
+
+        store._create_in_txn = noting
+        eng, inner_txn, commits = store.engine, store.engine.transaction, []
+
+        def transaction():
+            txn = inner_txn()
+            commit = txn.commit
+
+            def flaky_commit():
+                if len(seen) == 1 and not commits:     # the create's own
+                    commits.append(1)
+                    raise FsError(Status(Code.KV_CONFLICT, "injected"))
+                return commit()
+
+            txn.commit = flaky_commit
+            return txn
+
+        eng.transaction = transaction
+        out = store.batch_create([BatchCreateItem("/r", flags=flags)])
+        assert len(seen) == 2 and seen[0] != seen[1]
+        assert out[0].inode.id == seen[1] and truncs == []
+        assert store.stat("/r").id == seen[1]
 
 
 class TestBatchSetAttr:

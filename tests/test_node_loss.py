@@ -502,3 +502,66 @@ def test_the_length_sweep_of_a_close_rides_out_the_detection(cluster):
 
     with pytest.raises(FsError):
         impatient.query_last_chunk(cluster.chain_ids[0], 800)
+
+
+def _sweep_files(cluster, client) -> dict:
+    """file id -> its (last index, length): three blocks, one block, one
+    whose tail shard (3) sits on the victim, a full stripe, none at all."""
+    chain = cluster.chain_ids[0]
+    want = {810: (2, BLOCK), 811: (0, BLOCK), 812: (0, 3 * S + 17),
+            813: (0, CHUNK), 814: (-1, 0)}
+    for fid, (idx, n) in want.items():
+        for i in range(idx + 1):
+            assert client.write_stripe(chain, ChunkId(fid, i),
+                                       payload(fid + i, n),
+                                       chunk_size=CHUNK).ok
+    return want
+
+
+def test_a_batched_sweep_asks_each_node_once_over_sockets(cluster):
+    client = cluster.storage_client(retry=RetryOptions(**FAST))
+    want = _sweep_files(cluster, client)
+    chain = cluster.chain_ids[0]
+    sent = []
+    inner = client._messenger
+
+    def counting(node_id, method, payload):
+        sent.append((method, node_id))
+        return inner(node_id, method, payload)
+
+    counting.parallel_fanout = True         # side by side, as served
+    client._messenger = counting
+    assert client.query_last_chunks(chain, list(want)) == list(want.values())
+    assert sorted(sent) == [("query_last_chunks", n) for n in (10, 11, 12, 13)]
+    assert [client.query_last_chunk(chain, f) for f in want] == list(
+        want.values())
+
+
+def test_a_batched_sweep_rides_out_the_detection(cluster):
+    """One node down with routing still SERVING: the whole sweep waits for
+    mgmtd's verdict and then settles every file precisely — the file whose
+    tail shard the victim held too; without the wait it raises. Never a
+    short length."""
+    from tpu3fs.utils.result import FsError
+
+    client = cluster.storage_client(retry=RetryOptions(
+        routing_wait_s=30.0, **FAST))
+    want = _sweep_files(cluster, client)
+    chain = cluster.chain_ids[0]
+    cluster.stop_node(VICTIM)
+    out = {}
+    th = threading.Thread(target=lambda: out.update(
+        got=client.query_last_chunks(chain, list(want))))
+    th.start()
+    time.sleep(0.6)
+    assert th.is_alive(), "the sweep failed or answered short"
+    cluster.declare_dead(VICTIM)
+    th.join(20)
+    assert out["got"] == list(want.values())
+    impatient = cluster.storage_client(retry=RetryOptions(
+        routing_wait_s=0.0, **FAST))
+    cluster.restart_empty(VICTIM)
+    cluster.recover()
+    cluster.stop_node(VICTIM)
+    with pytest.raises(FsError):
+        impatient.query_last_chunks(chain, list(want))

@@ -129,7 +129,7 @@ class MetaApp(TwoPhaseApplication):
         table = routing.chain_tables.get(table_id)
         chains = table.chain_ids if table else [1]
         hooks = dict(
-            file_length_hook=lambda ino: self._file_client().file_length(ino),
+            file_length_hook=lambda inos: self._file_client().file_lengths(inos),
             truncate_hook=lambda ino, ln: self._file_client().truncate_chunks(ino, ln),
             space_hook=self._cluster_space,
             default_chunk_size=self.config.get("chunk_size"),

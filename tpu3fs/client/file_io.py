@@ -9,7 +9,7 @@ Also provides the precise-length callback used by meta close/fsync
 
 from __future__ import annotations
 
-from typing import Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from tpu3fs.analytics import spans as _spans
 from tpu3fs.client.storage_client import StorageClient
@@ -588,15 +588,35 @@ class FileIoClient:
 
     def file_length(self, inode: Inode) -> int:
         """Precise length: max over chains of last chunk end (FileHelper)."""
-        layout = inode.layout
-        if layout is None:
-            return 0
-        best = 0
-        for chain_id in set(layout.chains):
-            idx, length = self._storage.query_last_chunk(chain_id, inode.id)
-            if idx >= 0:
-                best = max(best, idx * layout.chunk_size + length)
-        return best
+        got = self.file_lengths([inode])[0]
+        if isinstance(got, FsError):
+            raise got
+        return got
+
+    def file_lengths(self, inodes: List[Inode]) -> List[object]:
+        """file_length of MANY files: one length sweep a chain
+        (StorageClient.query_last_chunks) over the files whose layout names
+        it -> a length or an FsError an inode, in order. A chain's error
+        lands on exactly the inodes that lie on that chain."""
+        by_chain: Dict[int, List[int]] = {}
+        for i, inode in enumerate(inodes):
+            if inode.layout is not None:
+                for chain_id in set(inode.layout.chains):
+                    by_chain.setdefault(chain_id, []).append(i)
+        out: List[object] = [0] * len(inodes)
+        for chain_id, idxs in by_chain.items():
+            try:
+                got = self._storage.query_last_chunks(
+                    chain_id, [inodes[i].id for i in idxs])
+            except FsError as e:
+                got = [e] * len(idxs)
+            for i, last in zip(idxs, got):
+                if isinstance(last, FsError):
+                    out[i] = last
+                elif last[0] >= 0 and not isinstance(out[i], FsError):
+                    out[i] = max(out[i], last[0]
+                                 * inodes[i].layout.chunk_size + last[1])
+        return out
 
     def remove_chunks(self, inode: Inode) -> None:
         if self._prefetch is not None:

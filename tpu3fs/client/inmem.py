@@ -98,14 +98,18 @@ class StorageClientInMem:
     def query_last_chunk(self, chain_id: int, file_id: int
                          ) -> Tuple[int, int]:
         """(last index, last chunk's byte length); (-1, 0) when empty."""
+        return self.query_last_chunks(chain_id, [file_id])[0]
+
+    def query_last_chunks(self, chain_id: int, file_ids: List[int]
+                          ) -> List[Tuple[int, int]]:
+        """query_last_chunk for many files of one chain, in order."""
         with self._mu:
-            idxs = [ck[1] for (c, ck) in self._chunks
-                    if c == chain_id and ck[0] == file_id]
-            if not idxs:
-                return -1, 0
-            last = max(idxs)
-            buf = self._chunks[(chain_id, (file_id, last))]
-            return last, len(buf)
+            last = {f: -1 for f in file_ids}
+            for (c, (f, idx)) in self._chunks:
+                if c == chain_id and f in last and idx > last[f]:
+                    last[f] = idx
+            return [(last[f], len(self._chunks[(chain_id, (f, last[f]))]))
+                    if last[f] >= 0 else (-1, 0) for f in file_ids]
 
     def remove_file_chunks(self, chain_id: int, file_id: int) -> int:
         with self._mu:

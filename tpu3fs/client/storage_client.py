@@ -197,6 +197,9 @@ class StorageClient:
         self._routing_wait_ms = DistributionRecorder("client.routing_wait_ms")
         self._ec_parity_rmw = CounterRecorder("ec.parity_rmw")
         self._ec_rmw_fallback = CounterRecorder("ec.parity_rmw_fallback")
+        # storage requests sent by length sweeps (query_last_chunks): the
+        # meta service's closes are their only caller
+        self._length_rpcs = CounterRecorder("meta.close.length_rpcs")
         # partial writes at a chunk's offset 0: those that rode the stripe
         # batch (nothing was there) vs those sent to the RMW ladder
         self._ec_head_batched = CounterRecorder("ec.head_partial_batched")
@@ -2246,6 +2249,20 @@ class StorageClient:
         actually answered. Retry ladder with per-replica failover covers
         the just-killed-but-still-SERVING heartbeat window and transient
         no-serving windows during failover."""
+        return self.query_last_chunks(chain_id, [file_id])[0]
+
+    def query_last_chunks(self, chain_id: int,
+                          file_ids: List[int]) -> List[Tuple[int, int]]:
+        """query_last_chunk for MANY files of one chain, answers in the
+        order asked: ONE sweep settles a close batch. Its policy is
+        query_last_chunk's, word for word, and holds for the batch as a
+        whole — a sweep that fails fails for every file of it. On an EC
+        chain a sweep sends one request to every distinct node that hosts
+        a SERVING target (the node answers for ALL its local targets of
+        the chain), side by side; on a CR chain one request to one
+        replica."""
+        if not file_ids:
+            return []
         last_err: Optional[FsError] = None
         attempt = 0           # attempts spent; a wait for routing is none
         t_first = time.monotonic()
@@ -2261,10 +2278,8 @@ class StorageClient:
                 # mgmtd's verdict like a put does (_await_routing): a
                 # close inside the detection window settles, it does not
                 # fail
-                best = (-1, 0)
                 failed: Optional[FsError] = None
-                queried = 0
-                unreachable = other = 0
+                serving: Dict[int, int] = {}  # node -> SERVING targets on it
                 for t in chain.targets:
                     if t.public_state != PublicTargetState.SERVING:
                         continue
@@ -2276,21 +2291,22 @@ class StorageClient:
                             Code.TARGET_OFFLINE,
                             f"no route to target {t.target_id}"))
                         continue
-                    try:
-                        got = self._messenger(
-                            node.node_id, "query_last_chunk",
-                            (chain_id, file_id))
-                    except FsError as e:
-                        failed = e
-                        if e.code in UNREACHABLE_CODES:
-                            unreachable += 1
+                    serving[node.node_id] = serving.get(node.node_id, 0) + 1
+                got = self._ask_last_chunks(list(serving), chain_id, file_ids)
+                best: List[Tuple[int, int]] = [(-1, 0)] * len(file_ids)
+                # a node asked once answers for every target it hosts, so
+                # it counts for that many in queried / unreachable
+                queried = unreachable = other = 0
+                for (node_id, n), reply in zip(serving.items(), got):
+                    if isinstance(reply, FsError):
+                        failed = reply
+                        if reply.code in UNREACHABLE_CODES:
+                            unreachable += n
                         else:
-                            other += 1
+                            other += n
                         continue
-                    queried += 1
-                    if got[0] > best[0] or (
-                            got[0] == best[0] and got[1] > best[1]):
-                        best = tuple(got)
+                    queried += n
+                    best = [max(b, tuple(g)) for b, g in zip(best, reply)]
                 if failed is None and queried > 0:
                     return best
                 # zero targets answered, or a partial sweep: UNAVAILABLE
@@ -2310,14 +2326,12 @@ class StorageClient:
                     node = routing.node_of_target(t.target_id)
                     if node is None:
                         continue
-                    try:
-                        return self._messenger(
-                            node.node_id, "query_last_chunk",
-                            (chain_id, file_id))
-                    except FsError as e:
-                        last_err = e
-                        answered = True
-                        continue
+                    reply = self._ask_last_chunks(
+                        [node.node_id], chain_id, file_ids)[0]
+                    if not isinstance(reply, FsError):
+                        return [tuple(g) for g in reply]
+                    last_err = reply
+                    answered = True
                 if not answered and last_err is None:
                     # zero serving replicas right now (failover window):
                     # that means UNAVAILABLE, not empty — retry then raise
@@ -2331,3 +2345,21 @@ class StorageClient:
                 self._sleep(attempt)
             attempt += 1
         raise last_err
+
+    def _ask_last_chunks(self, node_ids: List[int], chain_id: int,
+                         file_ids: List[int]) -> List[object]:
+        """One length request a node, side by side -> a node's answers (a
+        pair a file, in order) or the FsError its call raised."""
+        out: List[object] = [None] * len(node_ids)
+
+        def _ask(item) -> None:
+            i, node_id = item
+            try:
+                out[i] = self._messenger(
+                    node_id, "query_last_chunks", (chain_id, file_ids))
+            except FsError as e:
+                out[i] = e
+
+        self._length_rpcs.add(len(node_ids))
+        self._fan_out(_ask, list(enumerate(node_ids)))
+        return out

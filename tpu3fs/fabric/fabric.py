@@ -140,7 +140,7 @@ class Fabric:
         self.meta = MetaStore(
             self.kv,
             ChainAllocator(1, self.chain_ids),
-            file_length_hook=self._file_length,
+            file_length_hook=self._file_lengths,
             truncate_hook=self._truncate_chunks,
             space_hook=self._cluster_space,
             default_chunk_size=self.cfg.chunk_size,
@@ -341,6 +341,8 @@ class Fabric:
             return svc.remove_file_chunks(*payload)
         if method == "query_last_chunk":
             return svc.query_last_chunk(*payload)
+        if method == "query_last_chunks":
+            return svc.query_last_chunks(*payload)
         if method == "truncate_file_chunks":
             return svc.truncate_file_chunks(*payload)
         if method == "space_info":
@@ -364,8 +366,8 @@ class Fabric:
     def file_client(self, **kw) -> FileIoClient:
         return FileIoClient(self.storage_client(**kw))
 
-    def _file_length(self, inode) -> int:
-        return self.file_client().file_length(inode)
+    def _file_lengths(self, inodes) -> list:
+        return self.file_client().file_lengths(inodes)
 
     def _truncate_chunks(self, inode, length: int) -> None:
         self.file_client().truncate_chunks(inode, length)

@@ -120,6 +120,13 @@ class RetryConfig:
     backoff_max_s: float = 0.1
 
 
+#: what with_transaction runs the body again for; a body that catches
+#: FsError item by item (the meta store's batches) lets these through
+RETRYABLE_CODES = frozenset({
+    Code.KV_CONFLICT, Code.KV_TXN_TOO_OLD, Code.KV_RETRYABLE,
+    Code.KV_NOT_PRIMARY, Code.KV_MAYBE_COMMITTED})
+
+
 def with_transaction(
     engine: IKVEngine,
     fn: Callable[[ITransaction], T],
@@ -172,9 +179,7 @@ def _with_transaction_untraced(
             # commit_unknown_result, which its default retry loop DOES
             # retry; the meta layer's Idempotent records / existence checks
             # carry the same at-least-once burden as in the reference.
-            if e.code not in (Code.KV_CONFLICT, Code.KV_TXN_TOO_OLD,
-                              Code.KV_RETRYABLE, Code.KV_NOT_PRIMARY,
-                              Code.KV_MAYBE_COMMITTED):
+            if e.code not in RETRYABLE_CODES:
                 raise
             attempt += 1
             if attempt > retry.max_retries:

@@ -23,9 +23,14 @@ import threading
 import time
 import uuid
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
-from tpu3fs.kv.kv import IKVEngine, ITransaction, with_transaction
+from tpu3fs.kv.kv import (
+    RETRYABLE_CODES,
+    IKVEngine,
+    ITransaction,
+    with_transaction,
+)
 from tpu3fs.meta.types import (
     Acl,
     DirEntry,
@@ -177,7 +182,8 @@ class MetaStore:
         engine: IKVEngine,
         chain_allocator: Optional[ChainAllocator] = None,
         *,
-        file_length_hook: Optional[Callable[[Inode], int]] = None,
+        file_length_hook: Optional[
+            Callable[[List[Inode]], List[object]]] = None,
         truncate_hook: Optional[Callable[[Inode, int], None]] = None,
         space_hook: Optional[Callable[[], Tuple[int, int]]] = None,
         default_chunk_size: int = 1 << 20,
@@ -189,8 +195,9 @@ class MetaStore:
         # optional structured meta event stream (ref src/meta/event/Event.cc)
         self._events = event_log
         self._chains = chain_allocator or ChainAllocator(1, [1])
-        # queries storage for the real last-chunk length on close/fsync
-        # (ref FileHelper.cc queryLastChunk)
+        # queries storage for the real last-chunk lengths on close/fsync
+        # (ref FileHelper.cc queryLastChunk): the files of one batch in,
+        # a length or an FsError a file out, in order
         self._file_length_hook = file_length_hook
         # trims/removes storage chunks past the new EOF (ref: meta truncate
         # goes through the storage client in the reference too)
@@ -201,6 +208,15 @@ class MetaStore:
         self._space_hook = space_hook
         self._default_chunk_size = default_chunk_size
         self._default_stripe = default_stripe
+        from tpu3fs.monitor.recorder import CounterRecorder
+
+        # what a batch pays storage for (docs/observability.md): files whose
+        # close settled, and O_TRUNC creates that sent / were spared the
+        # truncate round (an inode this call made holds no chunk)
+        self._closed_files = CounterRecorder("meta.close.files")
+        self._create_truncated = CounterRecorder("meta.create.truncated")
+        self._create_truncate_skipped = CounterRecorder(
+            "meta.create.truncate_skipped")
         self._ensure_root()
 
     @property
@@ -492,12 +508,12 @@ class MetaStore:
         gets allocator striping."""
         layout = self._resolve_create_layout(chunk_size, stripe, layout)
 
-        def op(txn: ITransaction) -> OpenResult:
+        def op(txn: ITransaction) -> Tuple[OpenResult, bool]:
             return self._create_in_txn(txn, path, user, perm, flags,
                                        client_id, layout)
 
-        result = with_transaction(self._engine, op)
-        self._maybe_truncate_chunks(result, flags)
+        result, created = with_transaction(self._engine, op)
+        self._maybe_truncate_chunks(result, flags, created=created)
         self._emit("create", path, inode_id=result.inode.id, uid=user.uid)
         return result
 
@@ -529,14 +545,18 @@ class MetaStore:
         flags: int,
         client_id: str,
         layout: Layout,
-    ) -> OpenResult:
+    ) -> Tuple[OpenResult, bool]:
+        """-> (the open, whether THIS attempt made the inode). A retried
+        transaction runs this again and allocates a fresh id, so True
+        always means an inode id no chunk can carry."""
         parent, name, existing = self._walk(txn, path, user)
         if name is None:
             raise _err(Code.META_IS_DIRECTORY, "/")
         if existing is not None:
             if flags & OpenFlags.EXCL:
                 raise _err(Code.META_EXISTS, path)
-            return self._do_open(txn, existing, user, flags, client_id)
+            return self._do_open(txn, existing, user, flags,
+                                 client_id), False
         self._check_dir_writable(parent, user)
         inode = Inode.new_file(
             self._ids.allocate(), Acl(user.uid, user.gid, perm), layout
@@ -549,7 +569,7 @@ class MetaStore:
         if flags & OpenFlags.WRITE:
             session_id = self._add_session(txn, inode.id, client_id,
                                            user.uid)
-        return OpenResult(inode, session_id)
+        return OpenResult(inode, session_id), True
 
     def batch_create(
         self,
@@ -594,11 +614,14 @@ class MetaStore:
 
             for i, res in with_transaction(self._engine, op):
                 results[i] = res
-        for it, res in zip(items, results):
-            if isinstance(res, OpenResult):
-                self._maybe_truncate_chunks(res, it.flags)
-                self._emit("create", it.path, inode_id=res.inode.id,
-                           uid=user.uid)
+        for i, it in enumerate(items):
+            if isinstance(results[i], FsError):
+                continue
+            results[i], created = results[i]
+            self._maybe_truncate_chunks(results[i], it.flags,
+                                        created=created)
+            self._emit("create", it.path, inode_id=results[i].inode.id,
+                       uid=user.uid)
         return results
 
     def open(
@@ -619,15 +642,22 @@ class MetaStore:
         self._maybe_truncate_chunks(result, flags)
         return result
 
-    def _maybe_truncate_chunks(self, result: "OpenResult", flags: int) -> None:
+    def _maybe_truncate_chunks(self, result: "OpenResult", flags: int, *,
+                               created: bool = False) -> None:
         # O_TRUNC reclaims existing chunks through storage, outside the KV
-        # transaction (storage truncate is idempotent, so a meta retry is safe)
+        # transaction (storage truncate is idempotent, so a meta retry is
+        # safe). An inode the create itself just made has none: ids are
+        # monotonic and never reused (InodeIdAllocator), so no round is sent
         if (
             flags & OpenFlags.TRUNC
             and self._truncate_hook is not None
             and result.inode.is_file()
         ):
+            if created:
+                self._create_truncate_skipped.add()
+                return
             self._truncate_hook(result.inode, 0)
+            self._create_truncated.add()
 
     def _do_open(
         self, txn: ITransaction, inode: Inode, user: User, flags: int, client_id: str
@@ -684,80 +714,107 @@ class MetaStore:
         mtime only moves if the session wrote (wrote=True, or unspecified
         with a length hint present) — a read-only open+close must not look
         like a modification."""
+        item = BatchCloseItem(
+            inode_id, session_id,
+            -1 if length_hint is None else length_hint, client_id,
+            request_id, -1 if wrote is None else int(wrote))
+        (res,), settled = with_transaction(
+            self._engine, lambda txn: self._close_in_txn(txn, [item], user))
+        if isinstance(res, FsError):
+            raise res
+        self._closed_files.add(settled)
+        return res
 
-        def op(txn: ITransaction) -> Inode:
-            return self._close_in_txn(
-                txn, inode_id, session_id, length_hint=length_hint,
-                client_id=client_id, request_id=request_id, wrote=wrote,
-                user=user)
+    def _close_in_txn(self, txn: ITransaction,
+                      items: List["BatchCloseItem"],
+                      user: Optional[User]) -> Tuple[List[object], int]:
+        """The closes of one transaction -> (an Inode or an FsError an
+        item, files settled) (ref BatchOperation.cc:750 batches exactly
+        these inode settles into one transaction)."""
+        # ORDER MATTERS, item by item: every read/permission check and the
+        # (RPC-backed) length hook run BEFORE the first mutation, so an item
+        # whose check or whose length failed leaves zero buffered writes in
+        # the shared transaction — a failed item must not half-commit
+        # (session gone, length unsettled) and its batch-mates settle.
+        out: List[object] = [None] * len(items)
+        inodes: Dict[int, Inode] = {}   # two closes of one file share it
+        passed = []                     # (item index, session key, cache key)
+        for i, it in enumerate(items):
+            try:
+                got = self._close_checked(txn, it, user, inodes)
+            except FsError as e:
+                if e.code in RETRYABLE_CODES:
+                    raise        # the transaction's, not the item's
+                got = e
+            if isinstance(got, tuple):
+                passed.append((i, *got))
+            else:
+                out[i] = got     # a replayed request's Inode, or the error
+        # ONE length query for the files that passed: a replay and a failed
+        # item make no storage call
+        failed: Dict[int, FsError] = {}
+        files = [ino for ino in inodes.values() if ino.is_file()]
+        if files and self._file_length_hook is not None:
+            for ino, got in zip(files, self._file_length_hook(files)):
+                if isinstance(got, FsError):
+                    failed[ino.id] = got
+                else:
+                    ino.length = got
+        # -- mutations (nothing below may raise) -----------------------------
+        settled = 0
+        for i, skey, ckey in passed:
+            it = items[i]
+            inode = out[i] = failed.get(it.inode_id) or inodes[it.inode_id]
+            if isinstance(inode, FsError):
+                continue
+            if it.session_id:
+                txn.clear(skey)
+            if inode.is_file():
+                if (self._file_length_hook is None
+                        and it.length_hint >= 0):
+                    inode.length = max(inode.length, it.length_hint)
+                if it.wrote > 0 or (it.wrote < 0 and it.length_hint >= 0):
+                    inode.mtime = time.time()
+                self._store_inode(txn, inode)
+                settled += 1
+            if it.request_id:
+                txn.set(ckey, serialize(inode))
+        return out, settled
 
-        return with_transaction(self._engine, op)
-
-    def _close_in_txn(
-        self,
-        txn: ITransaction,
-        inode_id: int,
-        session_id: str,
-        *,
-        length_hint: Optional[int] = None,
-        client_id: str = "",
-        request_id: str = "",
-        wrote: Optional[bool] = None,
-        user: Optional[User] = None,
-    ) -> Inode:
-        """One close inside an already-open transaction — shared by close()
-        and batch_close() (ref BatchOperation.cc:750 batches exactly these
-        inode settles into one transaction)."""
-        # ORDER MATTERS for batch_close: every read/permission check and
-        # the (possibly RPC-backed, possibly raising) length hook run
-        # BEFORE the first mutation, so a per-item FsError caught by the
-        # batch leaves zero buffered writes for that item in the shared
-        # transaction — a failed item must not half-commit (session gone,
-        # length unsettled).
+    def _close_checked(self, txn: ITransaction, it: "BatchCloseItem",
+                       user: Optional[User], inodes: Dict[int, Inode]):
+        """The reads and permission checks of one close -> the cached Inode
+        of a replayed request, else (session key, idempotency key) with the
+        inode loaded into ``inodes``; raises the item's FsError."""
         # the cache key is scoped to the caller's identity in auth mode:
         # a replay of another client's (client_id, request_id) by a
         # different user misses and must pass authorization below
-        ckey = idempotent_key(client_id, request_id,
+        ckey = idempotent_key(it.client_id, it.request_id,
                               None if user is None else user.uid)
-        if request_id:
+        if it.request_id:
             cached = txn.get(ckey)
             if cached is not None:
                 return deserialize(cached, Inode)
-        inode = self._load_inode(txn, inode_id)
+        inode = inodes.get(it.inode_id) or self._load_inode(txn, it.inode_id)
         if inode is None:
-            raise _err(Code.META_NOT_FOUND, str(inode_id))
-        skey = session_key(inode_id, session_id)
-        if session_id:
+            raise _err(Code.META_NOT_FOUND, str(it.inode_id))
+        skey = session_key(it.inode_id, it.session_id)
+        if it.session_id:
             raw = txn.get(skey)
             if raw is None:
-                raise _err(Code.META_NO_SESSION, session_id)
+                raise _err(Code.META_NO_SESSION, it.session_id)
             if user is not None:
                 # the session is the capability granted at open: closing
                 # authorizes against its owner, not the live ACL (a chmod
                 # between open and close must not wedge the session)
                 sess = deserialize(raw, FileSession)
                 if not (user.is_root or sess.uid == user.uid):
-                    raise _err(Code.META_NO_PERMISSION, session_id)
+                    raise _err(Code.META_NO_PERMISSION, it.session_id)
         elif user is not None and not inode.acl.check_user(user, PERM_W):
             # sessionless length settle falls back to the ACL
-            raise _err(Code.META_NO_PERMISSION, str(inode_id))
-        store_inode = False
-        if inode.is_file():
-            if self._file_length_hook is not None:
-                inode.length = self._file_length_hook(inode)  # may raise
-            elif length_hint is not None:
-                inode.length = max(inode.length, length_hint)
-            if wrote or (wrote is None and length_hint is not None):
-                inode.mtime = time.time()
-            store_inode = True
-        # -- mutations (nothing above may raise past here) -------------------
-        if session_id:
-            txn.clear(skey)
-        if store_inode:
-            self._store_inode(txn, inode)
-        if request_id:
-            txn.set(ckey, serialize(inode))
-        return inode
+            raise _err(Code.META_NO_PERMISSION, str(it.inode_id))
+        inodes[it.inode_id] = inode
+        return skey, ckey
 
     def batch_close(
         self,
@@ -769,31 +826,20 @@ class MetaStore:
         """Settle MANY write sessions' lengths in O(len/txn_batch) KV
         transactions instead of one per file (ref src/meta/store/ops/
         BatchOperation.cc:750 — batched inode updates behind the
-        Distributor). Per-item failures (missing inode/session, permission)
-        come back as FsError entries without failing their batch-mates;
-        a KV conflict retries the whole chunk via with_transaction."""
-        results: List[object] = [None] * len(items)
+        Distributor), with ONE storage length query a transaction for all
+        its files. Per-item failures (missing inode/session, permission, a
+        length that storage could not give) come back as FsError entries
+        without failing their batch-mates; a KV conflict retries the whole
+        chunk via with_transaction."""
+        results: List[object] = []
         for base in range(0, len(items), txn_batch):
-            chunk = list(enumerate(items[base:base + txn_batch], start=base))
-
-            def op(txn: ITransaction, _chunk=chunk):
-                out = []
-                for i, it in _chunk:
-                    try:
-                        out.append((i, self._close_in_txn(
-                            txn, it.inode_id, it.session_id,
-                            length_hint=(it.length_hint
-                                         if it.length_hint >= 0 else None),
-                            client_id=it.client_id,
-                            request_id=it.request_id,
-                            wrote=(None if it.wrote < 0 else bool(it.wrote)),
-                            user=user)))
-                    except FsError as e:
-                        out.append((i, e))
-                return out
-
-            for i, res in with_transaction(self._engine, op):
-                results[i] = res
+            chunk = items[base:base + txn_batch]
+            out, settled = with_transaction(
+                self._engine,
+                lambda txn, _chunk=chunk: self._close_in_txn(
+                    txn, _chunk, user))
+            results.extend(out)
+            self._closed_files.add(settled)
         return results
 
     def sync(self, inode_id: int, *, length_hint: Optional[int] = None,
@@ -817,7 +863,10 @@ class MetaStore:
                     raise _err(Code.META_NO_PERMISSION, str(inode_id))
             if inode.is_file():
                 if self._file_length_hook is not None:
-                    inode.length = self._file_length_hook(inode)
+                    got = self._file_length_hook([inode])[0]
+                    if isinstance(got, FsError):
+                        raise got
+                    inode.length = got
                 elif length_hint is not None and length_hint > inode.length:
                     inode.length = length_hint
                 inode.length_hint_ver += 1

@@ -41,6 +41,7 @@ CLASSIFICATION: Dict[Tuple[str, str], str] = {
     ("StorageSerde", "removeChunk"): MUTATING,
     ("StorageSerde", "removeFileChunks"): MUTATING,
     ("StorageSerde", "queryLastChunk"): IDEMPOTENT,
+    ("StorageSerde", "queryLastChunks"): IDEMPOTENT,
     ("StorageSerde", "truncateChunks"): MUTATING,
     ("StorageSerde", "spaceInfo"): IDEMPOTENT,
     ("StorageSerde", "batchRead"): IDEMPOTENT,

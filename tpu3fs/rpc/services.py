@@ -88,6 +88,12 @@ class FileChunksReq:
 
 
 @dataclass
+class FileChunksBatchReq:
+    chain_id: int
+    file_ids: List[int] = field(default_factory=list)
+
+
+@dataclass
 class TruncateChunksReq:
     chain_id: int
     file_id: int
@@ -145,6 +151,11 @@ class IntReply:
 class PairReply:
     a: int = 0
     b: int = 0
+
+
+@dataclass
+class PairListReply:
+    pairs: List[List[int]] = field(default_factory=list)
 
 
 @dataclass
@@ -347,6 +358,11 @@ def bind_storage_service(server: RpcServer, svc: StorageService) -> None:
     # section; craq.StorageService.chain_encode)
     s.method(22, "chainEncodeWrite", BatchShardWriteReq, BatchWriteRsp,
              _batch_write(svc.chain_encode), bulk=True)
+    # a close batch's length sweep: queryLastChunk for many files of one
+    # chain in one request a node (method 8 stays for what speaks it)
+    s.method(23, "queryLastChunks", FileChunksBatchReq, PairListReply,
+             lambda r: PairListReply([list(p) for p in svc.query_last_chunks(
+                 r.chain_id, r.file_ids)]))
     server.add_service(s)
 
 
@@ -1017,6 +1033,10 @@ class RpcMessenger:
         if method == "query_last_chunk":
             r = c.call(addr, sid, 8, FileChunksReq(*payload), PairReply)
             return r.a, r.b
+        if method == "query_last_chunks":
+            rsp = c.call(addr, sid, 23, FileChunksBatchReq(*payload),
+                         PairListReply)
+            return [tuple(p) for p in rsp.pairs]
         if method == "truncate_file_chunks":
             return c.call(addr, sid, 9, TruncateChunksReq(*payload), IntReply).value
         if method == "space_info":

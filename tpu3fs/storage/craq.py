@@ -2669,43 +2669,45 @@ class StorageService:
         in-chunk length contribution is j*S + shard_len (0 for parity shards
         and empty data shards); the client maxes contributions over targets
         to recover the precise logical length."""
+        return self.query_last_chunks(chain_id, [file_id])[0]
+
+    def query_last_chunks(self, chain_id: int,
+                          file_ids: List[int]) -> List[Tuple[int, int]]:
+        """query_last_chunk for MANY files of one chain in one request (a
+        close batch's length sweep asks each node once), answers in the
+        order asked. One prefix scan a file a local target: the engine
+        filters the prefix in C, so F scans are cheaper than one pass over
+        the whole inventory, whose every meta would cross into Python."""
         chain = self._chain(chain_id)
-        if chain.is_ec:
-            # a node may host SEVERAL shards of one EC chain: max the
-            # contribution over every local target, not just the first
-            best = (-1, 0)
-            for t in chain.targets:
-                if t.target_id not in self._targets:
-                    continue
-                target = self._targets[t.target_id]
-                metas = [m for m in target.engine.query(
-                    ChunkId.file_prefix(file_id)) if m.committed_ver > 0]
-                if not metas:
-                    continue
-                last = max(metas, key=lambda m: m.chunk_id.index)
-                shard = chain.shard_index(t.target_id)
-                if last.aux > 0:
-                    # exact: every shard stores the stripe's logical length
-                    # (ShardWriteReq.logical_len -> engine aux), so even a
-                    # parity-only node reports the precise contribution
-                    contrib = last.aux
-                else:
-                    contrib = (0 if shard >= chain.ec_k or last.length == 0
-                               else shard * target.chunk_size + last.length)
-                got = (last.chunk_id.index, contrib)
-                if got[0] > best[0] or (got[0] == best[0] and got[1] > best[1]):
-                    best = got
-            return best
-        for t in chain.targets:
-            if t.target_id in self._targets:
-                target = self._targets[t.target_id]
-                metas = target.engine.query(ChunkId.file_prefix(file_id))
-                metas = [m for m in metas if m.committed_ver > 0]
-                if not metas:
-                    return -1, 0
-                last = max(metas, key=lambda m: m.chunk_id.index)
+        local = [t for t in chain.targets if t.target_id in self._targets]
+        if not chain.is_ec:
+            local = local[:1]
+        return [self._last_chunk(chain, local, fid) for fid in file_ids]
+
+    def _last_chunk(self, chain, local, file_id: int) -> Tuple[int, int]:
+        # a node may host SEVERAL shards of one EC chain: max the
+        # contribution over every local target, not just the first
+        best = (-1, 0)
+        for t in local:
+            target = self._targets[t.target_id]
+            metas = [m for m in target.engine.query(
+                ChunkId.file_prefix(file_id)) if m.committed_ver > 0]
+            if not metas:
+                continue
+            last = max(metas, key=lambda m: m.chunk_id.index)
+            if not chain.is_ec:
                 return last.chunk_id.index, last.length
-        return -1, 0
+            shard = chain.shard_index(t.target_id)
+            if last.aux > 0:
+                # exact: every shard stores the stripe's logical length
+                # (ShardWriteReq.logical_len -> engine aux), so even a
+                # parity-only node reports the precise contribution
+                contrib = last.aux
+            else:
+                contrib = (0 if shard >= chain.ec_k or last.length == 0
+                           else shard * target.chunk_size + last.length)
+            best = max(best, (last.chunk_id.index, contrib))
+        return best
 
     def remove_file_chunks(self, chain_id: int, file_id: int) -> int:
         """Remove all chunks of a file on the local target and forward down

@@ -46,6 +46,7 @@ ENFORCEMENT: Dict[Tuple[str, str], str] = {
     ("StorageSerde", "removeChunk"): IOPS,
     ("StorageSerde", "removeFileChunks"): IOPS,
     ("StorageSerde", "queryLastChunk"): IOPS,
+    ("StorageSerde", "queryLastChunks"): IOPS,
     ("StorageSerde", "truncateChunks"): IOPS,
     ("StorageSerde", "spaceInfo"): EXEMPT,
     ("StorageSerde", "batchRead"): BYTES,
