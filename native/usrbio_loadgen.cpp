@@ -213,8 +213,15 @@ int main(int argc, char** argv) {
   if (!make_symlink(iov.path.substr(strlen("/dev/shm/")),
                     virt + "/iovs/" + tag))
     return die("iov register symlink");
+  // io_depth (hf3fs_iorcreate): matched batches of <depth> where the file
+  // divides into them; else "up to <depth> after a short wait", so that a
+  // short last batch is served (N > 0 would hold it for SQEs that never
+  // come)
+  size_t blocks_in_file = file_bytes / block;
+  int io_depth = blocks_in_file % depth == 0 ? int(depth) : -int(depth);
   if (!make_symlink(ring.name + "?entries=" + std::to_string(depth) +
-                        "&rw=r&prio=1&iov=" + tag,
+                        "&rw=r&prio=1&depth=" + std::to_string(io_depth) +
+                        "&iov=" + tag,
                     virt + "/iors/" + tag))
     return die("ring register symlink");
 

@@ -341,6 +341,96 @@ class TestReadIntoBoundaries:
         assert bytes(dest[2 * cs:2 * cs + 100]) == b"\x5a" * 100
 
 
+class TestBatchReadInto:
+    """batch_read_into: many (inode, offset, size, dest) as ONE
+    StorageClient.batch_read; it equals read() range by range, on
+    replication and on an EC chain, and a range that fails fails alone."""
+
+    CS = 4096
+
+    def _fab(self, kind):
+        if kind == "ec":
+            return Fabric(SystemSetupConfig(
+                num_storage_nodes=4, num_chains=1, chunk_size=12 << 10,
+                ec_k=3, ec_m=1)), 12 << 10
+        return Fabric(SystemSetupConfig(
+            num_storage_nodes=4, num_chains=4, chunk_size=self.CS)), self.CS
+
+    @pytest.mark.parametrize("kind", ["cr", "ec"])
+    def test_equals_read_range_by_range(self, kind):
+        fab, cs = self._fab(kind)
+        rng = np.random.default_rng(5)
+        a = rng.integers(0, 256, 3 * cs + cs // 2, dtype=np.uint8).tobytes()
+        b = rng.integers(0, 256, cs + 17, dtype=np.uint8).tobytes()
+        ia = _file_with_data(fab, "/a", a)
+        ib = _file_with_data(fab, "/b", b)
+        fio = fab.file_client()
+        ranges = [(ia, 0, 64), (ib, 0, 64), (ia, cs - 3, 7),
+                  (ia, 2 * cs - 100, cs + 200), (ib, cs, 4096),
+                  (ia, len(a) - 10, 4096), (ia, len(a), 16), (ib, 5, 0),
+                  (ia, 100, 3 * cs)]
+        calls = []
+        inner = fio.storage.batch_read
+        fio.storage.batch_read = lambda reqs: (calls.append(len(reqs)),
+                                               inner(reqs))[1]
+        dests = [memoryview(bytearray(b"\xEE" * size))
+                 for _, _, size in ranges]
+        got = fio.batch_read_into(
+            [(inode, off, size, dest)
+             for (inode, off, size), dest in zip(ranges, dests)])
+        fio.storage.batch_read = inner
+        assert len(calls) == 1          # ONE batch for all of them
+        for (inode, off, size), dest, n in zip(ranges, dests, got):
+            want = bytes(fio.read(inode, off, size))
+            assert n == len(want), (off, size)
+            assert bytes(dest[:n]) == want, (off, size)
+            assert bytes(dest[n:]) == b"\xEE" * (size - n)   # untouched
+        fab.close()
+
+    def test_holes_zero_fill_and_an_untracked_empty_file_is_eof(self):
+        from tpu3fs.meta.store import OpenFlags
+
+        fab, cs = self._fab("cr")
+        fio = fab.file_client()
+        res = fab.meta.create("/holes", flags=OpenFlags.WRITE,
+                              client_id="t")
+        fio.write(res.inode, 2 * cs, b"\x5a" * 100)
+        holes = fab.meta.close(res.inode.id, res.session_id,
+                               length_hint=2 * cs + 100, wrote=True)
+        empty = fab.meta.create("/empty", flags=OpenFlags.WRITE,
+                                client_id="t").inode
+        d1, d2 = (memoryview(bytearray(b"\xEE" * 3 * cs)) for _ in range(2))
+        got = fio.batch_read_into([(holes, 0, 3 * cs, d1),
+                                   (empty, 0, 3 * cs, d2)])
+        assert got == [2 * cs + 100, 0]
+        assert bytes(d1[:2 * cs]) == b"\x00" * (2 * cs)
+        assert bytes(d1[2 * cs:2 * cs + 100]) == b"\x5a" * 100
+        fab.close()
+
+    def test_a_range_that_fails_fails_alone(self):
+        from dataclasses import replace
+
+        from tpu3fs.utils.result import FsError
+
+        fab, cs = self._fab("cr")
+        data = bytes(range(256)) * 64
+        inode = _file_with_data(fab, "/ok", data)
+        # a layout that names a chain routing does not know
+        lost = replace(inode, layout=replace(
+            inode.layout, chains=[99999] * len(inode.layout.chains)))
+        fio = fab.file_client()
+        d = [memoryview(bytearray(64)) for _ in range(3)]
+        got = fio.batch_read_into([(inode, 0, 64, d[0]), (lost, 0, 64, d[1]),
+                                   (inode, cs + 1, 64, d[2])])
+        assert got[0] == 64 and got[2] == 64
+        assert isinstance(got[1], FsError)
+        assert bytes(d[0]) == data[:64]
+        assert bytes(d[2]) == data[cs + 1:cs + 65]
+        with pytest.raises(FsError):   # read_into is its one-element case
+            fio.read_into(lost, 0, 64, d[1])
+        fab.close()
+
+
 class TestEcFirstClassWrites:
     """EC as a first-class layout through the normal write path: delta-
     parity RMW for sub-stripe writes, inline degraded decode in batched

@@ -3,7 +3,8 @@
 The tier-1 command collects ``tests/`` only, so this module brings in the
 tests (and fixtures) of every module under ``perfbench/tests`` except
 ``test_cells.py``: the contract of the result line, the plain reference,
-the span and counter readers, the proxies, ``Cluster.stop``. A change to
+the span and counter readers, the proxies, ``Cluster.stop``, the
+small-I/O cell's driver on an in-process fabric. A change to
 the program that breaks what the benchmark reads of it then fails here,
 on the CPU, before a chip run does. ``test_cells.py`` stays out: every
 case of it boots a whole cluster and runs a window (minutes);
@@ -36,6 +37,7 @@ MODULES = (
     "test_reference",
     "test_span_ms",
     "test_trace",
+    "test_uring_pieces",
 )
 
 pytest.register_assert_rewrite(

@@ -1085,6 +1085,53 @@ class TestPins:
         assert "module @jit__decode_device" in texts[0].splitlines()[0]
         assert texts[0] == texts[1]
 
+    def test_a_ring_drain_is_one_op_with_its_four_stages(self, tracer,
+                                                          tmp_path):
+        """The benchmark's `sp.uring.*` readers find a drain of a
+        file-mode ring by the op `usrbio.ring_batch` and its stages
+        `drain`, `stat`, `read`, `complete`; and the drain's reads are ONE
+        `client.batch_read` beneath ONE `fio.batch_read_into`."""
+        from tpu3fs.fabric.fabric import Fabric, SystemSetupConfig
+        from tpu3fs.meta.store import OpenFlags
+        from tpu3fs.usrbio import UsrbioAgent, UsrbioClient
+
+        tracer.configure(service="cl", node=0, directory=str(tmp_path),
+                         sample_rate=1.0)
+        fab = Fabric(SystemSetupConfig(num_storage_nodes=2, num_chains=2,
+                                       num_replicas=2, chunk_size=4096))
+        fio = fab.file_client()
+        res = fab.meta.create("/f", flags=OpenFlags.WRITE, client_id="t")
+        fio.write(res.inode, 0, b"x" * 20000)
+        fab.meta.close(res.inode.id, res.session_id, length_hint=20000,
+                       wrote=True)
+        agent = UsrbioAgent(fab.meta, fio)
+        client = UsrbioClient(agent)
+        iov = client.iovcreate(1 << 16)
+        ring = client.iorcreate(16, [iov], io_depth=8)
+        fd = client.reg_fd("/f")
+        for i in range(8):
+            client.prep_io(ring, iov, i * 512, 512, fd, i * 2000, read=True,
+                           userdata=i)
+        client.submit_ios(ring)
+        assert len(client.wait_for_ios(ring, 8, timeout=10)) == 8
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+        agent.stop()
+        rows = _rows(tracer)
+        roots = [r for r in rows
+                 if r["op"] == "usrbio.ring_batch" and not r["stage"]]
+        assert len(roots) == 1 and roots[0]["nbytes"] == 8 * 512
+        mine = [r for r in rows if r["trace_id"] == roots[0]["trace_id"]]
+        stages = {r["stage"]: r for r in mine
+                  if r["op"] == "usrbio.ring_batch" and r["stage"]}
+        assert set(stages) == {"drain", "stat", "read", "complete"}
+        assert stages["drain"]["nbytes"] == 8      # SQEs: a count
+        assert stages["complete"]["nbytes"] == 8   # CQEs: a count
+        assert stages["stat"]["nbytes"] == 1       # distinct inodes
+        ops = [r["op"] for r in mine if not r["stage"]]
+        assert ops.count("fio.batch_read_into") == 1
+        assert ops.count("client.batch_read") == 1
+
     def test_the_profiler_switch_is_where_the_tracer_looks(self):
         """(f) a jaxlib that moves TraceMe.is_enabled fails here and does
         not silently end all capture."""

@@ -251,6 +251,302 @@ class TestReadInto:
         assert n2 == len(payload) and bytes(buf2) == payload
 
 
+# -- the batched drain ---------------------------------------------------------
+
+CS = 4096          # the cluster fixture's chunk size
+LEN_A = 3 * CS + 1500
+
+
+def _lay_files(fab):
+    """/a: 3.4 chunks of bytes over both chains; /h: chunk 0, a hole where
+    chunk 1 would be, a short chunk 2."""
+    from tpu3fs.meta.store import OpenFlags
+
+    fio = fab.file_client()
+    rng = np.random.default_rng(7)
+    a = rng.integers(0, 256, LEN_A, dtype=np.uint8).tobytes()
+    res = fab.meta.create("/a", flags=OpenFlags.WRITE, client_id="t")
+    fio.write(res.inode, 0, a)
+    fab.meta.close(res.inode.id, res.session_id, length_hint=len(a),
+                   wrote=True)
+    res = fab.meta.create("/h", flags=OpenFlags.WRITE, client_id="t")
+    fio.write(res.inode, 0, b"H" * CS)
+    fio.write(res.inode, 2 * CS, b"T" * 100)
+    fab.meta.close(res.inode.id, res.session_id, length_hint=2 * CS + 100,
+                   wrote=True)
+
+
+#: name -> [(file or a bad SQE's kind, file offset, length)]: one drain
+DRAINS = {
+    "mixed_files": [("/a", 0, 512), ("/h", 0, 512), ("/a", 5000, 512),
+                    ("/h", 2 * CS, 64), ("/a", 9000, 100)],
+    "chunk_and_stripe_boundaries": [
+        ("/a", CS - 96, 200),            # chunk 0 -> 1: the other chain
+        ("/a", 2 * CS - 200, 400),       # chunk 1 -> 2: back to the first
+        ("/a", 100, 3 * CS),             # four chunks of one range
+    ],
+    "across_eof": [("/a", LEN_A - 100, CS), ("/a", LEN_A, 64),
+                   ("/a", 0, 64)],
+    "hole": [("/h", CS, CS), ("/h", CS - 10, 30), ("/h", 2 * CS - 8, 200)],
+    "a_bad_sqe_fails_alone": [("/a", 0, 256), ("unknown_fd", 0, 256),
+                              ("/a", 256, 256), ("iov_overflow", 0, 256),
+                              ("/h", 0, 256)],
+}
+
+
+class TestBatchedDrain:
+    """A drain of a read ring is ONE batch, held to the serial reference:
+    the same (fd, offset, length, slot) answered one by one through
+    FileIoClient.read give the same CQE results and the same bytes."""
+
+    SLOT = 4 * CS
+
+    def _submit(self, client, ring, iov, fds, sqes):
+        for i, (what, off, n) in enumerate(sqes):
+            fd, slot = fds.get(what, 9999), i * self.SLOT
+            if what == "iov_overflow":
+                fd, slot = fds["/a"], iov.size - n + 1
+            client.prep_io(ring, iov, slot, n, fd, off, read=True,
+                           userdata=1000 + i)
+        client.submit_ios(ring)
+
+    def _reference(self, fab, sqes):
+        fio = fab.file_client()
+        want = []
+        for what, off, n in sqes:
+            if what == "unknown_fd":
+                want.append((-int(Code.META_NOT_FOUND), b""))
+            elif what == "iov_overflow":
+                want.append((-int(Code.INVALID_ARG), b""))
+            else:
+                data = bytes(fio.read(fab.meta.stat(what), off, n))
+                want.append((len(data), data))
+        return want
+
+    @pytest.mark.parametrize("case", sorted(DRAINS))
+    def test_a_drain_equals_the_serial_reads(self, cluster, case):
+        fab, agent, client = cluster
+        _lay_files(fab)
+        sqes = DRAINS[case]
+        iov = client.iovcreate(len(sqes) * self.SLOT)
+        ring = client.iorcreate(16, [iov], io_depth=len(sqes))
+        fds = {p: client.reg_fd(p) for p in ("/a", "/h")}
+        calls = {"stat": 0, "read": 0}
+        inner_stat, inner_read = agent._meta.batch_stat, \
+            agent._fio.storage.batch_read
+
+        def batch_stat(ids):
+            calls["stat"] += 1
+            return inner_stat(ids)
+
+        def batch_read(reqs):
+            calls["read"] += 1
+            return inner_read(reqs)
+
+        agent._meta.batch_stat = batch_stat
+        agent._fio.storage.batch_read = batch_read
+        try:
+            iov.write(0, b"\xEE" * iov.size)
+            self._submit(client, ring, iov, fds, sqes)
+            done = dict((ud, res) for res, ud in
+                        client.wait_for_ios(ring, len(sqes), timeout=10))
+        finally:
+            agent._meta.batch_stat = inner_stat
+            agent._fio.storage.batch_read = inner_read
+        want = self._reference(fab, sqes)
+        assert sorted(done) == [1000 + i for i in range(len(sqes))]
+        for i, (res, data) in enumerate(want):
+            assert done[1000 + i] == res, (case, i)
+            if res > 0:
+                assert iov.read(i * self.SLOT, res) == data, (case, i)
+        # one batch_stat and one batch_read for the whole drain
+        assert calls == {"stat": 1, "read": 1}
+        assert agent.totals["batches"] == 1
+        assert agent.totals["sqes"] == len(sqes)
+        assert agent.totals["sqe_errors"] == sum(r < 0 for r, _ in want)
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+    def test_a_batch_after_a_write_reads_the_new_bytes(self, cluster):
+        fab, agent, client = cluster
+        _lay_files(fab)
+        iov = client.iovcreate(8 * CS)
+        ring = client.iorcreate(8, [iov], io_depth=3)
+        fd = client.reg_fd("/a", write=True)
+        # ONE drain: a read, a write over part of its range, the read again
+        iov.write(0, b"\x00" * iov.size)
+        iov.write(CS, b"new!" * 64)
+        client.prep_io(ring, iov, 0, 512, fd, CS - 100, read=True, userdata=1)
+        client.prep_io(ring, iov, CS, 256, fd, CS - 50, read=False,
+                       userdata=2)
+        client.prep_io(ring, iov, 2 * CS, 512, fd, CS - 100, read=True,
+                       userdata=3)
+        client.submit_ios(ring)
+        done = dict((ud, res) for res, ud in
+                    client.wait_for_ios(ring, 3, timeout=10))
+        assert done == {1: 512, 2: 256, 3: 512}
+        old, new = iov.read(0, 512), iov.read(2 * CS, 512)
+        assert new[50:306] == b"new!" * 64 and old[50:306] != new[50:306]
+        assert new[:50] == old[:50] and new[306:] == old[306:]
+        # and a later batch through a fresh reader agrees
+        data = bytes(fab.file_client().read(fab.meta.stat("/a"), CS - 100,
+                                            512))
+        assert data == new
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+
+class TestIoDepth:
+    """hf3fs_iorcreate's io_depth through the agent: 0 serves at once,
+    N > 0 holds a drain until N are queued, N < 0 serves a short batch
+    after the wait."""
+
+    def _ring(self, cluster, entries, io_depth):
+        fab, agent, client = cluster
+        _lay_files(fab)
+        iov = client.iovcreate(entries * 64)
+        ring = client.iorcreate(entries, [iov], io_depth=io_depth)
+        return agent, client, iov, ring, client.reg_fd("/a")
+
+    def _prep(self, client, ring, iov, fd, lo, hi):
+        for i in range(lo, hi):
+            client.prep_io(ring, iov, (i % ring.entries) * 64, 64, fd,
+                           i * 64, read=True, userdata=i)
+        client.submit_ios(ring)
+
+    def test_zero_serves_what_is_there_at_once(self, cluster):
+        agent, client, iov, ring, fd = self._ring(cluster, 16, 0)
+        self._prep(client, ring, iov, fd, 0, 3)
+        done = client.wait_for_ios(ring, 3, timeout=5)
+        assert sorted(ud for _, ud in done) == [0, 1, 2]
+        assert agent.totals["short_drains"] == 0
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+    def test_n_holds_a_drain_until_n_are_queued(self, cluster):
+        agent, client, iov, ring, fd = self._ring(cluster, 16, 8)
+        self._prep(client, ring, iov, fd, 0, 7)
+        assert client.wait_for_ios(ring, 1, timeout=0.4) == []
+        assert agent.totals["batches"] == 0
+        self._prep(client, ring, iov, fd, 7, 8)
+        done = client.wait_for_ios(ring, 8, timeout=5)
+        assert sorted(ud for _, ud in done) == list(range(8))
+        assert all(res == 64 for res, _ in done)
+        # sixteen queued at once are two drains of eight, none short
+        self._prep(client, ring, iov, fd, 8, 24)
+        done = client.wait_for_ios(ring, 16, timeout=5)
+        assert sorted(ud for _, ud in done) == list(range(8, 24))
+        assert agent.totals["batches"] == 3
+        assert agent.totals["sqes"] == 24
+        assert agent.totals["short_drains"] == 0
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+    def test_negative_n_serves_a_short_batch_after_the_wait(self, cluster):
+        agent, client, iov, ring, fd = self._ring(cluster, 32, -8)
+        self._prep(client, ring, iov, fd, 0, 3)
+        done = client.wait_for_ios(ring, 3, timeout=5)
+        assert sorted(ud for _, ud in done) == [0, 1, 2]
+        assert agent.totals["batches"] == 1
+        # twenty at once: no drain holds more than eight
+        self._prep(client, ring, iov, fd, 3, 23)
+        done = client.wait_for_ios(ring, 20, timeout=5)
+        assert sorted(ud for _, ud in done) == list(range(3, 23))
+        assert agent.totals["batches"] >= 1 + 3
+        assert agent.totals["short_drains"] == 0   # counted for N > 0 only
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+    def test_a_depth_the_ring_can_never_fill_is_refused(self, cluster):
+        fab, agent, client = cluster
+        iov = client.iovcreate(4096)
+        with pytest.raises(FsError) as ei:
+            client.iorcreate(8, [iov], io_depth=9)
+        assert ei.value.code == Code.INVALID_ARG
+        client.iovdestroy(iov)
+
+    def test_one_semaphore_post_a_batch(self, cluster):
+        import time
+
+        agent, client, iov, ring, fd = self._ring(cluster, 16, 8)
+        self._prep(client, ring, iov, fd, 0, 8)
+        deadline = time.time() + 5
+        while ring._counters()[3] < 8 and time.time() < deadline:
+            time.sleep(0.01)
+        assert ring._counters()[3] == 8
+        posts = 0
+        while ring.complete_sem.wait(timeout=0.05):
+            posts += 1
+        assert posts == 1
+        assert len(ring.reap()) == 8
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+    def test_depth_reaches_the_agent_through_the_3fs_virt_target(self):
+        from tpu3fs.fuse.ops import VIRT_DIR, FuseOps
+
+        fab = Fabric()
+        fio = fab.file_client()
+        agent = UsrbioAgent(fab.meta, fio)
+        ops = FuseOps(fab.meta, fio, agent)
+        iov = Iov(1 << 12, create=True)
+        rings = [IoRing(16, create=True) for _ in range(2)]
+        try:
+            ops.symlink(iov.name, f"/{VIRT_DIR}/iovs/v0")
+            ops.symlink(f"{rings[0].name}?entries=16&rw=r&prio=1&depth=4"
+                        f"&iov=v0", f"/{VIRT_DIR}/iors/deep")
+            ops.symlink(f"{rings[1].name}?entries=16&rw=r&prio=1&iov=v0",
+                        f"/{VIRT_DIR}/iors/old")
+            assert agent._rings[rings[0].name].io_depth == 4
+            assert agent._rings[rings[1].name].io_depth == 0
+        finally:
+            ops.destroy()
+            for r in rings:
+                r.close(unlink=True)
+            iov.close(unlink=True)
+
+
+class TestWriteRingsUnchanged:
+    def test_each_write_gets_its_cqe_in_ring_order(self, cluster):
+        fab, agent, client = cluster
+        iov = client.iovcreate(1 << 16)
+        ring = client.iorcreate(16, [iov], for_read=False)
+        fd = client.reg_fd("/w", write=True)
+        for i in range(6):
+            iov.write(i * 1000, bytes([65 + i]) * 1000)
+            client.prep_io(ring, iov, i * 1000, 1000, fd, i * 1000,
+                           read=False, userdata=i)
+        client.submit_ios(ring)
+        done = client.wait_for_ios(ring, 6, timeout=10)
+        assert [ud for _, ud in done] == list(range(6))
+        assert all(res == 1000 for res, _ in done)
+        client.dereg_fd(fd, length_hint=6000)
+        data = bytes(fab.file_client().read(fab.meta.stat("/w"), 0, 6000))
+        assert data == b"".join(bytes([65 + i]) * 1000 for i in range(6))
+        client.iordestroy(ring)
+        client.iovdestroy(iov)
+
+
+class TestPushCqes:
+    def test_many_cqes_one_header_write_one_post(self):
+        ring = IoRing(8, create=True)
+        try:
+            for i in range(5):
+                ring.prep_io(0, 1, 0, 1, read=True, userdata=i)
+            sqes = ring.drain_sqes(limit=3)
+            assert [q.userdata for q in sqes] == [0, 1, 2]
+            assert ring.pending_sqes() == 2
+            ring.push_cqes([(7, q.userdata) for q in sqes])
+            ring.push_cqes([])                      # posts nothing
+            assert ring.complete_sem.wait(timeout=0.05)
+            assert not ring.complete_sem.wait(timeout=0.05)
+            assert ring.reap() == [(7, 0), (7, 1), (7, 2)]
+            ring.push_cqe(9, 3, stamps=5)
+            assert ring.reap(with_stamps=True) == [(9, 3, 5)]
+        finally:
+            ring.close(unlink=True)
+
+
 # -- ring ABI v2 --------------------------------------------------------------
 
 
@@ -606,6 +902,40 @@ class TestRingTransport:
             [bytes(r.data) for r in got]
         assert not any(m2._usrbio_rings.values())
         sc2.close()
+        sc.close()
+
+    def test_a_node_batch_of_256_small_reads_rides_the_ring(self,
+                                                            ring_cluster):
+        """What a drain of 1024 4-KiB reads sends a node: 256 sub-chunk
+        reads in ONE ring call whose reply fits the reply region — never
+        silently by socket — each range exact."""
+        from tpu3fs.client.storage_client import ReadReq
+        from tpu3fs.storage.types import ChunkId
+
+        sc, messenger = _mk_client(ring_cluster, "rc-256")
+        chain = ring_cluster["chain_id"]
+        rng = np.random.default_rng(3)
+        chunks = [rng.integers(0, 256, 4096, dtype=np.uint8).tobytes()
+                  for _ in range(16)]
+        assert all(r.ok for r in sc.batch_write(
+            [(chain, ChunkId(9, i), 0, c) for i, c in enumerate(chunks)],
+            chunk_size=4096))
+        rings = {k: v for k, v in messenger._usrbio_rings.items()
+                 if v is not None}
+        assert rings
+        before = {k: v._next_ud for k, v in rings.items()}
+        reqs = [ReadReq(chain, ChunkId(9, i % 16), (i * 37) % 3072, 1024)
+                for i in range(512)]
+        got = sc.batch_read(reqs)
+        for req, r in zip(reqs, got):
+            assert r.ok and bytes(r.data) == chunks[req.chunk_id.index][
+                req.offset:req.offset + 1024]
+        # one ring call a node group that got reads, and the same rings
+        # still stand: nothing fell back to the sockets
+        after = {k: messenger._usrbio_rings.get(k) for k in rings}
+        assert all(after[k] is rings[k] for k in rings)
+        calls = sum(rings[k]._next_ud - before[k] for k in rings)
+        assert 1 <= calls <= len(rings)
         sc.close()
 
     def test_a_traced_ring_hop_carries_the_server_s_stamps(self,

@@ -82,7 +82,8 @@ HOT_PATH: List[Tuple[str, List[str]]] = [
     ("tpu3fs/storage/ec_resync.py",
      ["_gather_batched", "_install_batch", "_rebuild_batch"]),
     ("tpu3fs/client/file_io.py",
-     ["read_into", "_batch_read_files_direct", "_fetch_window",
+     ["read_into", "_batch_read_into", "_batch_read_files_direct",
+      "_fetch_window",
       # write path: user-buffer gather into per-chunk views
       "write", "batch_write_files", "_byte_view", "_flush_cr"]),
     # the dataload batch-assembly hot loop: records must be sliced out of

@@ -454,59 +454,85 @@ class FileIoClient:
     def read_into(self, inode: Inode, offset: int, size: int,
                   dest) -> int:
         """Read a byte range DIRECTLY into a caller-owned buffer (memoryview
-        over registered shm): chunk replies are written at their slots with
-        no intermediate assembly, and the chunk ops ride ONE node-grouped
-        batch_read — the USRBIO zero-copy read path (the reference
-        RDMA-WRITEs results into the user's registered iov,
-        StorageOperator.cc:176-226). Returns bytes filled (short at EOF);
-        holes and short chunks zero-fill their slots."""
-        layout = inode.layout
-        assert layout is not None
-        if inode.length:
-            size = max(0, min(size, inode.length - offset))
-        if size == 0:
-            return 0
-        with _spans.root_span("fio.read_into", nbytes=size):
-            return self._read_into(inode, layout, offset, size, dest)
+        over registered shm): batch_read_into's one-element case. Returns
+        bytes filled (short at EOF); raises what the range failed with."""
+        got = self.batch_read_into([(inode, offset, size, dest)])[0]
+        if isinstance(got, FsError):
+            raise got
+        return got
 
-    def _read_into(self, inode: Inode, layout: Layout, offset: int,
-                   size: int, dest) -> int:
+    def batch_read_into(
+        self, files: List[Tuple[Inode, int, int, object]]
+    ) -> List[object]:
+        """Read many (inode, offset, size, dest) ranges DIRECTLY into
+        caller-owned buffers (memoryviews over registered shm) as ONE
+        node-grouped StorageClient.batch_read: every chunk reply is
+        written at its slot of its range's ``dest`` with no intermediate
+        assembly — the USRBIO zero-copy read path (the reference
+        RDMA-WRITEs results into the user's registered iov,
+        StorageOperator.cc:176-226), for a whole ring drain at once.
+        -> per range the bytes filled (short at EOF, clamped by its own
+        inode's length; holes and short chunks zero-fill their slots), or
+        the FsError that range failed with: a bad range fails alone."""
+        with _spans.root_span("fio.batch_read_into") as sp:
+            out = self._batch_read_into(files)
+            if sp is not None:
+                sp.nbytes = sum(n for n in out if isinstance(n, int))
+            return out
+
+    def _batch_read_into(self, files) -> List[object]:
         from tpu3fs.client.storage_client import ReadReq
 
         pf = self._prefetch
-        if pf is not None:
-            hit = pf.lookup(inode.id, offset, size)
-            if hit is not None:
-                dest[:size] = hit
-                pf.record_read(inode, offset, size)
-                return size
-        segs = self._split(layout, offset, size)
-        reqs = [
-            ReadReq(chain_id, ChunkId(inode.id, idx), in_off, n,
-                    chunk_size=layout.chunk_size)
-            for idx, chain_id, in_off, n in segs
-        ]
-        replies = self._storage.batch_read(reqs)
-        pos = 0
-        any_data = False
-        for (idx, chain_id, in_off, n), reply in zip(segs, replies):
-            slot = dest[pos:pos + n]
+        out: List[object] = [0] * len(files)
+        reqs: List[ReadReq] = []
+        slots: List[Tuple[int, int, int]] = []  # (file, pos in dest, n)
+        asked: set = set()                      # files with a wire read
+        for i, (inode, offset, size, dest) in enumerate(files):
+            layout = inode.layout
+            assert layout is not None
+            if inode.length:
+                size = max(0, min(size, inode.length - offset))
+            if size == 0:
+                continue
+            out[i] = size
+            if pf is not None:
+                hit = pf.lookup(inode.id, offset, size)
+                if hit is not None:
+                    dest[:size] = hit
+                    continue
+            asked.add(i)
+            pos = 0
+            for idx, chain_id, in_off, n in self._split(layout, offset, size):
+                reqs.append(ReadReq(chain_id, ChunkId(inode.id, idx), in_off,
+                                    n, chunk_size=layout.chunk_size))
+                slots.append((i, pos, n))
+                pos += n
+        replies = self._storage.batch_read(reqs) if reqs else []
+        got_data: set = set()
+        for (i, pos, n), reply in zip(slots, replies):
+            if isinstance(out[i], FsError):
+                continue
             if reply.code == Code.CHUNK_NOT_FOUND:
-                slot[:] = b"\x00" * n           # hole
+                data = b""                          # hole
             elif not reply.ok:
-                raise FsError(Status(reply.code))
+                out[i] = FsError(Status(reply.code))
+                continue
             else:
-                any_data = True
-                got = reply.data[:n]
-                slot[:len(got)] = got
-                if len(got) < n:
-                    slot[len(got):] = b"\x00" * (n - len(got))
-            pos += n
-        if not any_data and inode.length == 0:
-            return 0
-        if pf is not None:
-            pf.record_read(inode, offset, size)
-        return size
+                got_data.add(i)
+                data = reply.data[:n]
+            dest = files[i][3]
+            dest[pos:pos + len(data)] = data
+            if len(data) < n:
+                dest[pos + len(data):pos + n] = b"\x00" * (n - len(data))
+        for i, (inode, offset, _, _) in enumerate(files):
+            if isinstance(out[i], FsError) or not out[i]:
+                continue
+            if inode.length == 0 and i in asked and i not in got_data:
+                out[i] = 0   # untracked length, no chunk at all: true EOF
+            elif pf is not None:
+                pf.record_read(inode, offset, out[i])
+        return out
 
     def batch_read_files(
         self, files: List[Tuple[Inode, int, int]]

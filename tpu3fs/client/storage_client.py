@@ -510,15 +510,22 @@ class StorageClient:
             self._channels.release(channel)
 
     # -- reads ----------------------------------------------------------------
-    def _pick_targets(self, chain: ChainInfo,
-                      routing: RoutingInfo) -> List[int]:
+    def _pick_targets(self, chain: ChainInfo, routing: RoutingInfo,
+                      memo: Optional[Tuple[dict, dict]] = None) -> List[int]:
         """Serving targets of ``chain`` in read order; ``routing`` is the
-        snapshot the caller resolved the chain from."""
-        serving = [
-            t.target_id
-            for t in chain.targets
-            if t.public_state == PublicTargetState.SERVING
-        ]
+        snapshot the caller resolved the chain from. ``memo`` is a batch's
+        own (serving targets a chain, suspect verdict a target): a batch of
+        a thousand reads asks routing and the health registry once a chain
+        and once a target, and still draws its replica a request."""
+        serving = memo[0].get(chain.chain_id) if memo is not None else None
+        if serving is None:
+            serving = [
+                t.target_id
+                for t in chain.targets
+                if t.public_state == PublicTargetState.SERVING
+            ]
+            if memo is not None:
+                memo[0][chain.chain_id] = serving
         if not serving:
             return []
         mode = self._selection
@@ -538,10 +545,16 @@ class StorageClient:
         # observation instead of after a 60s heartbeat timeout. Stable:
         # the selection mode's order is preserved within each class.
         if self._retry.health_reorder and len(order) > 1:
+            verdicts = memo[1] if memo is not None else {}
+
             def _suspect(tid: int) -> bool:
-                node = routing.node_of_target(tid)
-                return (node is not None
+                got = verdicts.get(tid)
+                if got is None:
+                    node = routing.node_of_target(tid)
+                    got = verdicts[tid] = (
+                        node is not None
                         and self._health.suspect(node.node_id))
+                return got
 
             order.sort(key=_suspect)
         return order
@@ -681,6 +694,8 @@ class StorageClient:
         wire: List[Tuple[int, ReadReq]] = []   # (node_id, wire op)
         tags: List[Tuple] = []                 # ("cr", i) | ("ec", i, j)
         ec_specs: Dict[int, dict] = {}
+        memo: Tuple[dict, dict] = ({}, {})
+        node_ids: Dict[int, Optional[int]] = {}   # target -> its node
         with _spans.span("client.batch_read", "plan"):
             for i, req in enumerate(reqs):
                 chain = routing.chains.get(req.chain_id)
@@ -704,17 +719,21 @@ class StorageClient:
                         tags.append(("ec", i, j))
                         wire.append((node_id, rr))
                     continue
-                targets = self._pick_targets(chain, routing)
+                targets = self._pick_targets(chain, routing, memo)
                 if not targets:
                     replies[i] = ReadReply(Code.TARGET_OFFLINE)
                     continue
                 target_id = req.target_id or targets[0]
-                node = routing.node_of_target(target_id)
-                if node is None:
+                if target_id not in node_ids:
+                    node = routing.node_of_target(target_id)
+                    node_ids[target_id] = (None if node is None
+                                           else node.node_id)
+                node_id = node_ids[target_id]
+                if node_id is None:
                     replies[i] = ReadReply(Code.TARGET_NOT_FOUND)
                     continue
                 tags.append(("cr", i))
-                wire.append((node.node_id, ReadReq(
+                wire.append((node_id, ReadReq(
                     req.chain_id, req.chunk_id, req.offset, req.length,
                     target_id
                 )))

@@ -455,7 +455,9 @@ class FuseOps:
             self._virt[kind][name] = f"{fs_path}?rw={rw}&fd={fd}"
             return
         else:
-            # target = "<ring-shm-name>?entries=N&rw=r|w&prio=P&iov=<names,>"
+            # target = "<ring-shm-name>?entries=N&rw=r|w&prio=P&depth=D
+            # &iov=<names,>"; depth is hf3fs_iorcreate's io_depth, absent
+            # = 0 (an older client's target stands)
             ring_name, _, qs = target.partition("?")
             params = dict(
                 kv.split("=", 1) for kv in qs.split("&") if "=" in kv
@@ -468,6 +470,7 @@ class FuseOps:
                 iovs,
                 for_read=params.get("rw", "r") == "r",
                 priority=int(params.get("prio", "1")),
+                io_depth=int(params.get("depth", "0")),
             )
         self._virt[kind][name] = target
 

@@ -701,10 +701,14 @@ class RpcMessenger:
         is a memoryview over the transport's receive buffer, which stays
         alive exactly as long as the views do. Consumers that retain
         replies beyond the request must copy (bytes(data))."""
-        if not segs:
-            return replies
-        return [replace(rp, data=seg) if len(seg) else rp
-                for rp, seg in zip(replies, segs)]
+        if segs:
+            # the replies were parsed for this call alone: set the field in
+            # place (dataclasses.replace costs a constructor a reply, and a
+            # ring drain brings a thousand)
+            for rp, seg in zip(replies, segs):
+                if len(seg):
+                    rp.data = seg
+        return replies
 
     def _stripe_spans(self, reqs) -> List[Tuple[int, int]]:
         """Split one node group into contiguous stripe spans. Groups below

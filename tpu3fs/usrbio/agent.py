@@ -3,25 +3,46 @@
 The FUSE-daemon half of the reference (src/fuse/IovTable.h:10-39 iov
 registration; src/fuse/FuseClients.cc:150,218 — watch threads poll submit
 semaphores, ioRingWorkers run IoRing::process; src/fuse/PioV.cc splits ring
-entries into chunk IOs). Here the agent owns Meta/Storage clients and worker
-threads: each ring gets a dedicated worker (the reference multiplexes rings
-over 3 priority-lane semaphores, IoRing.h:259-264; with a worker per ring
-priorities never contend, so the ring's priority is recorded but does not
-schedule), SQEs are translated to chunk reads/writes through FileIoClient,
-and data moves directly between the chunk store and the client's registered
-shm buffer.
+entries into chunk IOs and sends them as batches). Here the agent owns
+Meta/Storage clients and worker threads: each ring gets a dedicated worker
+(the reference multiplexes rings over 3 priority-lane semaphores,
+IoRing.h:259-264; with a worker per ring the ring's priority is recorded
+but does not schedule). A worker serves its ring one DRAIN at a time, and
+how many SQEs make a drain is the ring's ``io_depth`` (hf3fs_iorcreate):
+0 whatever is queued, N > 0 exactly N, N < 0 up to -N after a short wait.
+The reads of a drain are ONE batch — one ``batch_stat`` of the distinct
+inodes, one node-grouped ``FileIoClient.batch_read_into`` whose replies
+land in the SQEs' Iov windows, all CQEs pushed with one wake-up — and an
+SQE that is wrong fails alone with its negative code; writes run one by
+one in ring order, a write between two reads splitting the batch so that
+a read never overtakes it. Across rings the agent bounds the DRAINS being
+served at once (``max_concurrent_batches``), not the I/Os inside them.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
+import time
 from typing import Dict, List, Optional
 
+from tpu3fs.analytics import spans as _spans
 from tpu3fs.client.file_io import FileIoClient
 from tpu3fs.meta.store import MetaStore, OpenFlags
-from tpu3fs.meta.types import Inode
-from tpu3fs.usrbio.ring import Iov, IoRing, reap_stale_shm
+from tpu3fs.usrbio.ring import SQE_FLAG_READ, Iov, IoRing, reap_stale_shm
 from tpu3fs.utils.result import Code, FsError, Status
+
+OP = "usrbio.ring_batch"
+
+#: how long a ring of ``io_depth`` < 0 waits for its batch to fill before
+#: it serves what is there
+BATCH_WAIT_S = 0.002
+
+
+def _scope_key(sqe):
+    """What of an SQE decides the scopes its I/O runs under: the reads of
+    a drain that share it ride one batch."""
+    return sqe.flags & ~SQE_FLAG_READ, sqe.token
 
 
 def _sqe_scopes(sqe):
@@ -32,7 +53,6 @@ def _sqe_scopes(sqe):
     attributed and shed exactly as if the client had spoken sockets."""
     import contextlib
 
-    from tpu3fs.analytics import spans as _spans
     from tpu3fs.qos.core import class_from_flags, tagged
     from tpu3fs.rpc import deadline as _deadline
     from tpu3fs.tenant import identity as _tenant_id
@@ -57,9 +77,10 @@ def _sqe_scopes(sqe):
 
 
 class _RingState:
-    def __init__(self, ring: IoRing, iovs: List[Iov]):
+    def __init__(self, ring: IoRing, iovs: List[Iov], io_depth: int = 0):
         self.ring = ring
         self.iovs = iovs
+        self.io_depth = io_depth
         self.worker: Optional[threading.Thread] = None
         self.running = True
         # set when deregister gives up joining a busy worker: the worker
@@ -72,7 +93,7 @@ class UsrbioAgent:
 
     def __init__(self, meta: MetaStore, file_client: FileIoClient,
                  client_id: str = "usrbio-agent", *,
-                 max_concurrent_ios: int = 64):
+                 max_concurrent_batches: int = 64):
         self._meta = meta
         self._fio = file_client
         self._client_id = client_id
@@ -81,14 +102,35 @@ class UsrbioAgent:
         self._next_fd = 100
         self._rings: Dict[str, _RingState] = {}
         self._lock = threading.Lock()
-        # host-wide IO throttle across ALL rings (the reference bounds
+        # host-wide throttle across ALL rings (the reference bounds
         # in-flight usrbio IO with semaphores per priority lane,
-        # IoRing.h:259-264): one misbehaving client with a deep ring
-        # cannot monopolize the storage backend
+        # IoRing.h:259-264). ONE rule: it bounds the DRAINS being served
+        # at once, whatever they hold — a ring has one drain in flight, a
+        # drain is one batch at the storage client, and how much a batch
+        # may ask of the backend is the storage client's own striping
+        from tpu3fs.monitor.recorder import CounterRecorder
         from tpu3fs.utils.executor import ConcurrencyLimiter
 
-        self._io_limiter = ConcurrencyLimiter("usrbio-io",
-                                              max_concurrent_ios)
+        self._batch_limiter = ConcurrencyLimiter("usrbio-batch",
+                                                 max_concurrent_batches)
+        # file-mode rings (docs/observability.md): SQEs served, drains,
+        # drains of an io_depth > 0 ring that held fewer than its depth,
+        # CQEs that carried a negative code. The recorders reset a
+        # collection window; `totals` keeps the lifetime counts
+        self._rec = {
+            "sqes": CounterRecorder("usrbio.sqes"),
+            "batches": CounterRecorder("usrbio.batches"),
+            "short_drains": CounterRecorder("usrbio.short_drains"),
+            "sqe_errors": CounterRecorder("usrbio.sqe_errors"),
+        }
+        self.totals = dict.fromkeys(self._rec, 0)
+
+    def _count(self, **counts: int) -> None:
+        with self._lock:
+            for key, n in counts.items():
+                if n:
+                    self._rec[key].add(n)
+                    self.totals[key] += n
 
     # -- control plane (the reference's ClientAgent service, fbs/lib) --------
     def open(self, path: str, *, write: bool = False) -> int:
@@ -125,10 +167,19 @@ class UsrbioAgent:
         return Iov(size, name=name, create=False)
 
     def register_ring(self, name: str, entries: int, iovs: List[Iov],
-                      *, for_read: bool = True, priority: int = 1) -> None:
+                      *, for_read: bool = True, priority: int = 1,
+                      io_depth: int = 0) -> None:
+        """``io_depth`` as hf3fs_iorcreate gives it: 0 serve what is queued
+        as soon as it is there; N > 0 serve the ring in batches of exactly
+        N (the caller owes the N: fewer stay queued); N < 0 up to -N a
+        batch, served after BATCH_WAIT_S whatever is there."""
+        if io_depth > entries:
+            raise FsError(Status(
+                Code.INVALID_ARG,
+                f"io_depth {io_depth} can never fill a ring of {entries}"))
         ring = IoRing(entries, name=name, create=False, for_read=for_read,
-                      priority=priority)
-        state = _RingState(ring, iovs)
+                      io_depth=io_depth, priority=priority)
+        state = _RingState(ring, iovs, io_depth)
         t = threading.Thread(
             target=self._ring_worker, args=(state,), daemon=True,
             name=f"usrbio-{name}",
@@ -161,12 +212,13 @@ class UsrbioAgent:
             while state.running:
                 if not ring.submit_sem.wait(timeout=0.5):
                     continue
-                if not state.running:
-                    return
-                for sqe in ring.drain_sqes():
-                    with self._io_limiter, _sqe_scopes(sqe):
-                        result = self._process_sqe(state, sqe)
-                    ring.push_cqe(result, sqe.userdata)
+                while state.running:
+                    t0 = time.perf_counter()
+                    sqes = self._drain(state)
+                    if not sqes:
+                        break
+                    with self._batch_limiter:
+                        self._serve_drain(state, sqes, t0)
         except (ValueError, FsError):
             # ring mmap closed under us during deregistration (ValueError)
             # or the header tore (USRBIO_TORN_RING): exit quietly — the
@@ -176,28 +228,139 @@ class UsrbioAgent:
             if state.close_on_exit:
                 state.ring.close()
 
-    def _process_sqe(self, state: _RingState, sqe) -> int:
-        """-> bytes moved, or negative Code on failure."""
+    def _drain(self, state: _RingState) -> list:
+        """The ring's next drain by its ``io_depth``, or [] when none is
+        due yet (the worker goes back to the submit semaphore)."""
+        ring, depth = state.ring, state.io_depth
+        if depth == 0:
+            return ring.drain_sqes()
+        if depth > 0:
+            if ring.pending_sqes() < depth:
+                return []
+            return ring.drain_sqes(limit=depth)
+        if not ring.pending_sqes():
+            return []
+        deadline = time.perf_counter() + BATCH_WAIT_S
+        while state.running and ring.pending_sqes() < -depth:
+            left = deadline - time.perf_counter()
+            if left <= 0:
+                break
+            ring.submit_sem.wait(timeout=left)
+        return ring.drain_sqes(limit=-depth)
+
+    def _serve_drain(self, state: _RingState, sqes: list,
+                     t0: float) -> None:
+        """One drain as one root op ``usrbio.ring_batch`` (``nbytes`` =
+        bytes moved; stages ``drain``, ``stat``, ``read``, ``complete``):
+        runs of reads as one batch each, writes one by one where the ring
+        has them."""
+        t1 = time.perf_counter()
+        ctx = _spans.open_op(OP, live=False)
+        if ctx is not None:
+            ctx.ts = _spans.wall_of_perf(t0)
+            _spans.add_span_at(ctx, OP, "drain", t0, t1 - t0,
+                               nbytes=len(sqes))
+        # counted before the CQEs go out: whoever reaps a batch finds it
+        # in the totals
+        self._count(batches=1,
+                    short_drains=int(len(sqes) < state.io_depth))
+        moved = 0
+        try:
+            with _spans.trace_scope(ctx):
+                for done in self._serve_steps(state, sqes):
+                    self._count(sqes=len(done),
+                                sqe_errors=sum(r < 0 for r, _ in done))
+                    with _spans.span(OP, "complete", nbytes=len(done)):
+                        state.ring.push_cqes(done)
+                    moved += sum(r for r, _ in done if r > 0)
+        finally:
+            _spans.close_op(ctx, OP, t0, time.perf_counter() - t0,
+                            nbytes=moved)
+
+    def _serve_steps(self, state: _RingState, sqes: list):
+        """The drain in ring order -> the (result, userdata) completions of
+        each step: a run of consecutive reads is one step, a write is a
+        step of its own (its CQE goes out when it is done, as ever)."""
+        for is_read, run in itertools.groupby(sqes, key=lambda q: q.is_read):
+            if is_read:
+                yield self._serve_reads(state, list(run))
+                continue
+            for sqe in run:
+                with _sqe_scopes(sqe):
+                    result = self._process_write(state, sqe)
+                yield [(result, sqe.userdata)]
+
+    def _check_sqe(self, state: _RingState, sqe):
+        """-> (fd entry, iov) of a well-formed SQE, or its negative code."""
         entry = self._fds.get(sqe.fd)
         if entry is None:
             return -int(Code.META_NOT_FOUND)
-        inode = entry[0]
         if sqe.iov_id >= len(state.iovs):
             return -int(Code.INVALID_ARG)
         iov = state.iovs[sqe.iov_id]
         if sqe.iov_offset + sqe.length > iov.size:
             return -int(Code.INVALID_ARG)
+        return entry, iov
+
+    def _serve_reads(self, state: _RingState, run: list) -> list:
+        """A run of read SQEs as ONE batch (one a distinct request scope:
+        class bits and token, in practice one): the lengths of the run's
+        distinct inodes refreshed by one ``batch_stat`` so that EOF
+        clamping sees recent writes, then one
+        ``FileIoClient.batch_read_into`` whose replies land directly in
+        the registered shm windows — no assembly buffer, no iov copy. An
+        SQE that is wrong (unknown fd, window outside its Iov, a storage
+        error on its range) gets its own negative code and its neighbours
+        are served."""
+        results: List[Optional[int]] = [None] * len(run)
+        groups: Dict[tuple, list] = {}
+        for i, sqe in enumerate(run):
+            ok = self._check_sqe(state, sqe)
+            if isinstance(ok, int):
+                results[i] = ok
+            else:
+                groups.setdefault(_scope_key(sqe), []).append((i, *ok))
+        for members in groups.values():
+            with _sqe_scopes(run[members[0][0]]):
+                try:
+                    got = self._read_batch(run, members)
+                except FsError as e:
+                    got = [-int(e.code)] * len(members)
+                except Exception:
+                    # transport/storage faults must surface as CQE errors,
+                    # never kill the ring worker (clients would block
+                    # forever)
+                    got = [-int(Code.INTERNAL)] * len(members)
+            for (i, _, _), res in zip(members, got):
+                results[i] = res
+        return [(res, sqe.userdata) for res, sqe in zip(results, run)]
+
+    def _read_batch(self, run: list, members: list) -> List[int]:
+        """members: [(index into run, fd entry, iov)] -> bytes moved or a
+        negative code, a member."""
+        inodes = {entry[0].id: entry[0] for _, entry, _ in members}
+        with _spans.span(OP, "stat", nbytes=len(inodes)):
+            ids = list(inodes)
+            for ino, fresh in zip(ids, self._meta.batch_stat(ids)):
+                if fresh is not None:
+                    inodes[ino] = fresh
+        files = []
+        for i, entry, iov in members:
+            sqe = run[i]
+            files.append((inodes[entry[0].id], sqe.file_offset, sqe.length,
+                          iov.view(sqe.iov_offset, sqe.length)))
+        with _spans.span(OP, "read", nbytes=sum(f[2] for f in files)):
+            got = self._fio.batch_read_into(files)
+        return [g if isinstance(g, int) else -int(g.code) for g in got]
+
+    def _process_write(self, state: _RingState, sqe) -> int:
+        """-> bytes written, or negative Code on failure."""
+        ok = self._check_sqe(state, sqe)
+        if isinstance(ok, int):
+            return ok
+        entry, iov = ok
+        inode = entry[0]
         try:
-            if sqe.is_read:
-                # refresh length so EOF clamping sees recent writes
-                fresh = self._meta.batch_stat([inode.id])[0]
-                src = fresh if fresh is not None else inode
-                # replies land directly in the registered shm window — no
-                # assembly buffer, no iov copy (round-2 weak: zero-copy
-                # reads into usrbio iovs)
-                return self._fio.read_into(
-                    src, sqe.file_offset, sqe.length,
-                    iov.view(sqe.iov_offset, sqe.length))
             data = iov.read(sqe.iov_offset, sqe.length)
             # flag before issuing so a close_fd racing this write still
             # sees the session as written
@@ -208,8 +371,6 @@ class UsrbioAgent:
         except FsError as e:
             return -int(e.code)
         except Exception:
-            # transport/storage faults must surface as a CQE error, never
-            # kill the ring worker (clients would block forever)
             return -int(Code.INTERNAL)
 
     def reap_stale(self, *, iov_max_age_s: float = 3600.0) -> list:

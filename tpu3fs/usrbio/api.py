@@ -13,6 +13,10 @@ Mirrors src/lib/api/hf3fs_usrbio.h:71-165:
 The shm segments + named semaphores are the real cross-process transport;
 the control handshake (registration) goes to the agent, playing the role of
 the reference's magic-symlink protocol in the FUSE virtual directory.
+``io_depth`` travels in that handshake and is the agent's batching rule
+for the ring (0 at once, N > 0 batches of exactly N, N < 0 up to -N after
+a short wait: docs/usrbio.md); a drain's reads are served as one batch and
+their CQEs arrive together, so ``wait_for_ios`` wakes once a batch.
 """
 
 from __future__ import annotations
@@ -45,9 +49,17 @@ class UsrbioClient:
                       io_depth=io_depth, priority=priority)
         # registration handshake: agent maps the same shm by name
         agent_iovs = [self._agent.register_iov(v.name, v.size) for v in iovs]
-        self._agent.register_ring(
-            ring.name, entries, agent_iovs, for_read=for_read, priority=priority
-        )
+        try:
+            self._agent.register_ring(
+                ring.name, entries, agent_iovs, for_read=for_read,
+                priority=priority, io_depth=io_depth,
+            )
+        except BaseException:
+            # a refused registration leaves no /dev/shm entry behind
+            for v in agent_iovs:
+                v.close()
+            ring.close(unlink=True)
+            raise
         self._ring_iovs[ring.name] = iovs
         return ring
 

@@ -48,6 +48,7 @@ _HDR = struct.Struct("<IIQQQQII")
 # token_len, reserved, token[156]
 _SQE = struct.Struct("<QQQQQiIHHQIHH156s")
 _CQE = struct.Struct("<qQQ")               # result, userdata, reserved
+_U64 = struct.Struct("<Q")
 MAGIC = 0x3F5B10
 VERSION = 2
 
@@ -193,9 +194,13 @@ class IoRing:
     Single-producer SQ (the client), single-consumer agent; monotonically
     increasing head/tail counters, slot = counter % entries. ``priority``
     selects which of the agent's priority lanes serves this ring (ref
-    IoRing.h:259-264's three submit semaphores). The creating process
-    stamps its pid into the header so an agent-side reaper can collect
-    segments whose owner died without deregistering.
+    IoRing.h:259-264's three submit semaphores). ``io_depth`` is
+    hf3fs_iorcreate's: how the agent batches the ring (0 serve what is
+    there at once; N > 0 batches of exactly N, the caller owes the N;
+    N < 0 up to -N a batch after a short wait) — it travels in the
+    registration handshake, not in the shm header (docs/usrbio_abi.md).
+    The creating process stamps its pid into the header so an agent-side
+    reaper can collect segments whose owner died without deregistering.
     """
 
     def __init__(
@@ -243,9 +248,8 @@ class IoRing:
         return struct.unpack_from("<I", self.buf, 44)[0]
 
     def _counters(self):
-        magic, entries, sq_h, sq_t, cq_h, cq_t, version, _ = _HDR.unpack(
-            self.buf[: _HDR.size]
-        )
+        magic, entries, sq_h, sq_t, cq_h, cq_t, _, _ = _HDR.unpack_from(
+            self.buf)
         if magic != MAGIC or entries != self.entries:
             # torn/overwritten header: surface as a typed USRBIO error so
             # neither side trusts garbage counters (a crashed writer or a
@@ -258,8 +262,7 @@ class IoRing:
 
     def _set_counter(self, index: int, value: int) -> None:
         # counters sit at offsets 8, 16, 24, 32 (8-byte aligned: atomic store)
-        off = 8 + index * 8
-        self.buf[off : off + 8] = struct.pack("<Q", value)
+        _U64.pack_into(self.buf, 8 + index * 8, value)
 
     # -- client side ---------------------------------------------------------
     def prep_io(
@@ -327,7 +330,8 @@ class IoRing:
             return -1
         slot = sq_t % self.entries
         off = self._sq_base + slot * SQE_SIZE
-        self.buf[off : off + SQE_SIZE] = _SQE.pack(
+        _SQE.pack_into(
+            self.buf, off,
             iov_offset, length, file_offset, rsp_offset, rsp_cap, fd,
             flags, service_id, method_id, userdata, iov_id,
             len(tok), 0, tok,
@@ -359,8 +363,7 @@ class IoRing:
         while cq_h < cq_t:
             slot = cq_h % self.entries
             off = self._cq_base + slot * CQE_SIZE
-            result, userdata, stamps = _CQE.unpack(
-                self.buf[off : off + CQE_SIZE])
+            result, userdata, stamps = _CQE.unpack_from(self.buf, off)
             out.append((result, userdata, stamps) if with_stamps
                        else (result, userdata))
             cq_h += 1
@@ -368,25 +371,44 @@ class IoRing:
         return out
 
     # -- agent side ----------------------------------------------------------
-    def drain_sqes(self):
-        """Consume all pending SQEs; returns list of Sqe."""
+    def pending_sqes(self) -> int:
+        """SQEs submitted and not yet drained (the agent's look before it
+        decides whether a batch is due)."""
         sq_h, sq_t, _, _ = self._counters()
+        return sq_t - sq_h
+
+    def drain_sqes(self, limit: Optional[int] = None):
+        """Consume pending SQEs, all of them or the first ``limit``;
+        returns list of Sqe."""
+        sq_h, sq_t, _, _ = self._counters()
+        if limit is not None:
+            sq_t = min(sq_t, sq_h + limit)
         out = []
         while sq_h < sq_t:
             slot = sq_h % self.entries
             off = self._sq_base + slot * SQE_SIZE
-            vals = _SQE.unpack(self.buf[off : off + SQE_SIZE])
-            out.append(Sqe(*vals))
+            out.append(Sqe(*_SQE.unpack_from(self.buf, off)))
             sq_h += 1
         self._set_counter(0, sq_h)  # sq_head
         return out
 
     def push_cqe(self, result: int, userdata: int, stamps: int = 0) -> None:
-        _, _, cq_h, cq_t = self._counters()
-        slot = cq_t % self.entries
-        off = self._cq_base + slot * CQE_SIZE
-        self.buf[off : off + CQE_SIZE] = _CQE.pack(result, userdata, stamps)
-        self._set_counter(3, cq_t + 1)  # cq_tail
+        self.push_cqes([(result, userdata, stamps)])
+
+    def push_cqes(self, cqes) -> None:
+        """Post many completions — (result, userdata[, stamps]) each — as
+        ONE batch: every CQE is written, then ``cq_tail`` moves once and
+        the completion semaphore is posted once, so a waiter for N results
+        wakes once a batch, not once a CQE. An empty batch posts nothing."""
+        if not cqes:
+            return
+        _, _, _, cq_t = self._counters()
+        for cqe in cqes:
+            off = self._cq_base + (cq_t % self.entries) * CQE_SIZE
+            _CQE.pack_into(self.buf, off, cqe[0], cqe[1],
+                           cqe[2] if len(cqe) > 2 else 0)
+            cq_t += 1
+        self._set_counter(3, cq_t)  # cq_tail
         self.complete_sem.post()
 
     def close(self, unlink: Optional[bool] = None) -> None:
