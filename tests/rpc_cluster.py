@@ -27,11 +27,13 @@ FILE_ID = 4242
 
 class RpcCluster:
     def __init__(self, *, replicas: int, chains: int, size: int,
-                 ec: tuple = (), nodes: int = 0):
+                 ec: tuple = (), nodes: int = 0, usrbio: bool = False):
         """ec=(k, m) makes every chain an RS(k, m) group of k+m targets
         (target i holds shard i; `replicas` is then ignored) whose engine
         chunk size is the shard size. `nodes` overrides how many storage
-        services there are (shard j of chain c on node (c + j) % nodes)."""
+        services there are (shard j of chain c on node (c + j) % nodes).
+        `usrbio` has every storage service host the USRBIO control service
+        and ring agent, so a client's messenger rides shm rings."""
         self.mgmtd = Mgmtd(1, MemKVEngine())
         self.mgmtd.extend_lease()
         mgmtd_server = RpcServer()
@@ -54,6 +56,7 @@ class RpcCluster:
         self.chain_ids = [900_001 + i for i in range(chains)]
         node_states: dict = {n: {} for n in node_ids}
         self.services = []
+        self.usrbio_hosts = []
         self.node_server = {}
         self.mclis = {}
         svc_by_node = self.svc_by_node = {}
@@ -67,6 +70,15 @@ class RpcCluster:
                                            self.shared_client))
             server = RpcServer()
             bind_storage_service(server, svc)
+            if usrbio:
+                from tpu3fs.usrbio.server import (
+                    UsrbioRpcHost,
+                    bind_usrbio_service,
+                )
+
+                host = UsrbioRpcHost(server)
+                bind_usrbio_service(server, host)
+                self.usrbio_hosts.append(host)
             server.start()
             self.mgmtd.register_node(node_id, NodeType.STORAGE,
                                      host=server.host, port=server.port)
@@ -187,6 +199,12 @@ class RpcCluster:
                 workers[id(svc)].run_once()
 
     def close(self) -> None:
+        for host in self.usrbio_hosts:
+            host.stop()
+        for svc in self.services:
+            # chain-forward messengers grew rings of their own: unlink
+            # now, not at interpreter exit
+            svc._messenger.close_rings()
         self.shared_client.close()
         for s in self.servers:
             s.stop()

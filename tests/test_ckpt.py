@@ -559,3 +559,61 @@ class TestExtensionDtypes:
                 "n": jax.ShapeDtypeStruct((), np.int32)}
         assert mgr.restore(5, like=like)["w"].tobytes() == \
             np.asarray(w).tobytes()
+
+
+class TestRestoreOverSockets:
+    def test_restore_of_node_groups_over_a_frame_is_batched(
+            self, monkeypatch):
+        """A restore asks for every shard file in ONE batched read: node
+        groups of 5 MiB where a frame and a ring's buffer are 1 MiB (the
+        cells' sizes over 64). The spans are cut to what their carrier
+        returns, so the tree comes back bit for bit and no chunk goes
+        through the single-op ladder (docs/readpath.md)."""
+        from rpc_cluster import RpcCluster
+        from tpu3fs.client.file_io import FileIoClient
+        from tpu3fs.client.storage_client import RetryOptions
+        from tpu3fs.kv import MemKVEngine
+        from tpu3fs.meta.store import ChainAllocator, MetaStore
+        from tpu3fs.rpc import net, services
+
+        chunk = 16 << 10
+        monkeypatch.setattr(net, "MAX_PACKET", 1 << 20)
+        monkeypatch.setattr(services, "USRBIO_IOV_BYTES", 1 << 20)
+        monkeypatch.setattr(services.RpcMessenger, "_stripe_min_bytes",
+                            64 << 10)
+        cluster = RpcCluster(replicas=3, chains=4, size=chunk, nodes=4)
+        client = cluster.storage_client(
+            retry=RetryOptions(backoff_base_s=0.001))
+        fio = FileIoClient(client)
+        kv = MemKVEngine()
+        meta = MetaStore(kv, ChainAllocator(1, cluster.chain_ids),
+                         file_length_hook=fio.file_lengths,
+                         truncate_hook=fio.truncate_chunks,
+                         default_chunk_size=chunk, default_stripe=4)
+        groups = []
+        pipelined = client._messenger.batch_read_pipelined
+        monkeypatch.setattr(
+            client._messenger, "batch_read_pipelined",
+            lambda g: groups.append(g) or pipelined(g))
+        try:
+            rng = np.random.default_rng(35)
+            tree = {f"w{i}": rng.standard_normal((1280, 1024)).astype(
+                np.float32) for i in range(4)}        # 4 x 5 MiB
+            tree["step"] = np.int64(35)
+            mgr = CheckpointManager(meta, fio, kv=kv)
+            mgr.save(tree, 35)
+            groups.clear()
+            out = mgr.restore(35)
+            largest = max(sum(r.length for r in reqs)
+                          for batch in groups for _, reqs in batch)
+            assert largest > 4 * net.MAX_PACKET
+            assert sorted(out) == sorted(tree)
+            for name, leaf in tree.items():
+                got = np.asarray(out[name])
+                assert got.dtype == leaf.dtype and got.shape == leaf.shape
+                assert got.tobytes() == leaf.tobytes()
+            assert client._read_ladder_ops._value == 0
+        finally:
+            fio.close()
+            client.close()
+            cluster.close()

@@ -470,3 +470,275 @@ class TestNativeClassBits:
             client.close()
             server.stop()
             mgmtd_server.stop()
+
+
+# -- the read span plan: cut to what a frame and a ring can carry -------------
+
+SCALE = 16                       # the cells' sizes over 16, as CHUNK is
+FRAME = (64 << 20) // SCALE      # net.MAX_PACKET, services.USRBIO_IOV_BYTES
+STRIPE_MIN = (4 << 20) // SCALE  # services.READ_STRIPE_MIN_BYTES
+
+
+def _parent_spans(sizes, stripes, min_bytes):
+    """The plan before spans were bounded by bytes: at most `stripes`
+    equal stripes, whatever the group's bytes."""
+    n = len(sizes)
+    est = sum(sizes)
+    if n <= 1 or stripes <= 1 or est < 2 * min_bytes:
+        return [(0, n)]
+    k = min(stripes, n, max(1, est // min_bytes))
+    base, rem = divmod(n, k)
+    spans, lo = [], 0
+    for i in range(k):
+        hi = lo + base + (1 if i < rem else 0)
+        spans.append((lo, hi))
+        lo = hi
+    return spans
+
+
+@pytest.fixture
+def small_frames(monkeypatch):
+    """A frame, a ring's buffer and the stripe minimum at a sixteenth, so
+    that 64-KiB chunks stand to them as the cells' 1-MiB chunks do."""
+    from tpu3fs.rpc import net, services
+
+    monkeypatch.setattr(net, "MAX_PACKET", FRAME)
+    monkeypatch.setattr(services, "USRBIO_IOV_BYTES", FRAME)
+    monkeypatch.setattr(services.RpcMessenger, "_stripe_min_bytes",
+                        STRIPE_MIN)
+
+
+class TestReadSpanPlan:
+    """A batched read's node groups become spans their carrier can
+    answer, READ_STRIPES of a node in flight (docs/readpath.md)."""
+
+    OPS = 1340    # a train_moonlight_cr3 restore's chunks
+
+    def _cluster(self, carrier, ops):
+        """4 nodes, 4 CR-3 chains, `ops` chunks striped over the chains
+        -> (cluster, client, reqs, payloads)."""
+        from rpc_cluster import FILE_ID, RpcCluster
+        from tpu3fs.client.storage_client import ReadReq, RetryOptions
+        from tpu3fs.storage.types import ChunkId
+
+        cluster = RpcCluster(replicas=3, chains=4, size=CHUNK, nodes=4,
+                             usrbio=carrier == "ring")
+        client = cluster.storage_client(
+            retry=RetryOptions(backoff_base_s=0.001))
+        payloads = [bytes([i % 251 + 1]) * CHUNK for i in range(ops)]
+        ids = [(cluster.chain_ids[i % 4], ChunkId(FILE_ID, i))
+               for i in range(ops)]
+        for lo in range(0, ops, 128):
+            assert all(r.ok for r in client.batch_write(
+                [(chain, cid, 0, p) for (chain, cid), p in
+                 zip(ids[lo:lo + 128], payloads[lo:lo + 128])],
+                chunk_size=CHUNK))
+        reqs = [ReadReq(chain, cid, 0, CHUNK) for chain, cid in ids]
+        return cluster, client, reqs, payloads
+
+    @staticmethod
+    def _close(cluster, client):
+        client.close()
+        client._messenger.close_rings()
+        cluster.close()
+
+    @pytest.mark.parametrize("carrier", ["socket", "ring"])
+    def test_groups_over_four_frames_come_back_batched(
+            self, small_frames, monkeypatch, carrier):
+        """Node groups of about 335 chunks, 21 MiB where a frame is 4:
+        every op OK with its bytes and none through the single-op ladder
+        (all 1340 of them before the spans were bounded); a ring carries
+        every span, none falls to a socket."""
+        from tpu3fs.client.storage_client import StorageClient
+
+        cluster, client, reqs, payloads = self._cluster(carrier, self.OPS)
+        ladder, sockets = [], []
+        single = StorageClient._read_chunk_op
+        monkeypatch.setattr(
+            StorageClient, "_read_chunk_op",
+            lambda self, *a, **k: ladder.append(a) or single(
+                self, *a, **k))
+        rpc = client._messenger._client
+        start_call = rpc.start_call
+        monkeypatch.setattr(
+            rpc, "start_call",
+            lambda *a, **k: sockets.append(a) or start_call(*a, **k))
+        try:
+            groups = []
+            pipelined = client._messenger.batch_read_pipelined
+            monkeypatch.setattr(
+                client._messenger, "batch_read_pipelined",
+                lambda g: groups.extend(g) or pipelined(g))
+            replies = client.batch_read(reqs)
+            assert len(groups) == 4
+            assert all(len(ops) * CHUNK > 4 * FRAME for _, ops in groups)
+            assert all(r.ok for r in replies)
+            assert [bytes(r.data) for r in replies] == payloads
+            assert ladder == []
+            assert client._read_ladder_ops._value == 0
+            if carrier == "ring":
+                assert sockets == []
+                assert all(ring is not None for ring in
+                           client._messenger._usrbio_rings.values())
+            else:
+                assert len(sockets) > 4 * 4
+            del replies
+        finally:
+            self._close(cluster, client)
+
+    # (ops of 64 KiB but for `big`, which is one op of 2 MiB in the
+    # middle; the cap is 1 MiB: 15 ops of 64 KiB + 160 B fit, 16 do not)
+    @pytest.mark.parametrize("ops,big,want", [
+        (1, None, "parent"),            # one op
+        (7, None, "parent"),            # under 2 x the stripe minimum
+        (40, None, "parent"),           # 4 stripes under the cap
+        (60, None, "parent"),           # 4 stripes of 15: just under
+        (61, None, [(0, 15), (15, 30), (30, 45), (45, 60), (60, 61)]),
+        (335, None, [(lo, min(lo + 15, 335)) for lo in range(0, 335, 15)]),
+        (21, 10, [(0, 10), (10, 11), (11, 21)]),   # an op over the cap
+    ], ids=["one-op", "under-2x-min", "4-under-cap", "just-under-cap",
+            "just-over-cap", "restore-group", "op-over-cap"])
+    def test_span_list(self, ops, big, want):
+        from tpu3fs.rpc.services import RpcMessenger
+        from tpu3fs.storage.craq import ReadReq
+        from tpu3fs.storage.types import ChunkId
+
+        messenger = RpcMessenger(lambda: None)
+        messenger._stripe_min_bytes = STRIPE_MIN
+        cap = 1 << 20
+        sizes = [2 << 20 if i == big else CHUNK for i in range(ops)]
+        reqs = [ReadReq(1, ChunkId(1, i), 0, n)
+                for i, n in enumerate(sizes)]
+        parent = _parent_spans(sizes, 4, STRIPE_MIN)
+        if want == "parent":
+            want = parent
+        assert messenger._stripe_spans(reqs, cap) == want
+        assert messenger._stripe_spans(reqs) == parent    # no cap: as ever
+        # read-to-end stands at the chunk size
+        to_end = [ReadReq(1, ChunkId(1, i), 0, -1, chunk_size=n)
+                  for i, n in enumerate(sizes)]
+        assert messenger._stripe_spans(to_end, cap) == want
+        for lo, hi in want:
+            assert hi - lo == 1 \
+                or messenger._read_rsp_est(reqs[lo:hi]) <= cap
+
+    def test_the_cap_follows_the_frame_and_the_ring(self, small_frames):
+        """Nothing a user sets: MAX_PACKET, the ring's registered buffer
+        and READ_STRIPES decide it."""
+        from types import SimpleNamespace
+
+        from tpu3fs.rpc.services import READ_STRIPES, RpcMessenger
+        from tpu3fs.usrbio.transport import RSP_CTRL_BYTES
+
+        messenger = RpcMessenger(lambda: None)
+        on_socket = messenger._read_span_cap(None)
+        assert FRAME - FRAME // 32 < on_socket < FRAME
+
+        def ring(size):
+            return SimpleNamespace(iov=SimpleNamespace(size=size))
+
+        on_ring = messenger._read_span_cap(ring(FRAME))
+        # READ_STRIPES reply regions with their requests, and one more
+        # being turned over, fit the buffer side by side
+        assert (READ_STRIPES + 1) * (on_ring + RSP_CTRL_BYTES) < FRAME
+        assert on_ring > FRAME // (READ_STRIPES + 2)
+        # a span that misses the ring goes on a socket: a larger buffer
+        # does not lift the cap past a frame
+        assert messenger._read_span_cap(ring(64 * FRAME)) == on_socket
+
+    @pytest.mark.parametrize("carrier", ["socket", "ring"])
+    def test_at_most_read_stripes_of_a_node_in_flight(
+            self, small_frames, monkeypatch, carrier):
+        from tpu3fs.rpc.services import READ_STRIPES
+
+        cluster, client, reqs, payloads = self._cluster(carrier, self.OPS)
+        messenger = client._messenger
+        flying, peak, spans = {}, {}, {}
+        start, finish = (messenger._start_read_span,
+                         messenger._finish_read_span)
+
+        def started(node_id, plan, span):
+            flying[node_id] = flying.get(node_id, 0) + 1
+            peak[node_id] = max(peak.get(node_id, 0), flying[node_id])
+            spans[node_id] = spans.get(node_id, 0) + 1
+            return start(node_id, plan, span)
+
+        def finished(node_id, p, span, detach):
+            try:
+                return finish(node_id, p, span, detach)
+            finally:
+                flying[node_id] -= 1
+
+        monkeypatch.setattr(messenger, "_start_read_span", started)
+        monkeypatch.setattr(messenger, "_finish_read_span", finished)
+        try:
+            replies = client.batch_read(reqs)
+            assert [bytes(r.data) for r in replies] == payloads
+            assert len(peak) == 4
+            # every node's group needs more spans than the window holds
+            assert all(n > READ_STRIPES for n in spans.values())
+            assert set(peak.values()) == {READ_STRIPES}
+            assert set(flying.values()) == {0}
+            del replies
+        finally:
+            self._close(cluster, client)
+
+    def test_a_dead_node_s_spans_still_reach_the_ladder(self, monkeypatch):
+        """A span that fails for a real reason sends its ops down
+        read_chunk, and client.read_ladder_ops counts them."""
+        cluster, client, reqs, payloads = self._cluster("socket", 96)
+        groups = []
+        pipelined = client._messenger.batch_read_pipelined
+        monkeypatch.setattr(
+            client._messenger, "batch_read_pipelined",
+            lambda g: groups.extend(g) or pipelined(g))
+        try:
+            dead = 10
+            cluster.stop_node(dead)
+            replies = client.batch_read(reqs)
+            lost = [len(ops) for node_id, ops in groups
+                    if node_id == dead]
+            assert lost and lost[0] > 0
+            assert client._read_ladder_ops._value == lost[0]
+            assert all(r.ok for r in replies)
+            assert [bytes(r.data) for r in replies] == payloads
+        finally:
+            self._close(cluster, client)
+
+    def test_an_ec_load_keeps_the_ring(self, monkeypatch):
+        """An EC shard read names no size on the wire and is estimated at
+        1 MiB: a load of 64 blocks is 112 shard reads a node, four
+        stripes of 28 MiB by the estimate where the ring's buffer is 16.
+        Bounded by the estimate every span rides the ring (the old plan's
+        stripes were refused by it and went over sockets, silently)."""
+        from rpc_cluster import FILE_ID, RpcCluster
+        from tpu3fs.client.storage_client import ReadReq, RetryOptions
+        from tpu3fs.rpc import services
+        from tpu3fs.storage.types import ChunkId
+
+        monkeypatch.setattr(services, "USRBIO_IOV_BYTES", 16 << 20)
+        cluster = RpcCluster(replicas=0, chains=1, size=CHUNK, ec=(12, 4),
+                             nodes=4, usrbio=True)
+        client = cluster.storage_client(
+            retry=RetryOptions(backoff_base_s=0.001))
+        chain = cluster.chain_ids[0]
+        block = bytes(range(256)) * 144          # 36 KiB: shards 0..6
+        try:
+            for i in range(64):
+                assert client.write_stripe(chain, ChunkId(FILE_ID, i), block,
+                                           chunk_size=CHUNK).ok
+            reqs = [ReadReq(chain, ChunkId(FILE_ID, i), 0, len(block),
+                            chunk_size=CHUNK) for i in range(64)]
+            assert all(r.ok for r in client.batch_read(reqs[:2]))  # rings up
+            sockets = []
+            rpc = client._messenger._client
+            start_call = rpc.start_call
+            monkeypatch.setattr(
+                rpc, "start_call",
+                lambda *a, **k: sockets.append(a) or start_call(*a, **k))
+            replies = client.batch_read(reqs)
+            assert all(r.ok and bytes(r.data) == block for r in replies)
+            assert sockets == []
+            del replies
+        finally:
+            self._close(cluster, client)

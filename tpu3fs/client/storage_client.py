@@ -204,6 +204,9 @@ class StorageClient:
         # batch (nothing was there) vs those sent to the RMW ladder
         self._ec_head_batched = CounterRecorder("ec.head_partial_batched")
         self._ec_head_ladder = CounterRecorder("ec.head_partial_ladder")
+        # ops a batched read handed to the single-op ladder (read_chunk):
+        # their batched reply was not OK. 0 in a healthy cluster
+        self._read_ladder_ops = CounterRecorder("client.read_ladder_ops")
         self._ec_encode_gibps = ValueRecorder("ec.encode_gibps")
         # pipelined chain encode (TPU3FS_EC_CHAIN_ENCODE=1): stripes
         # staged through the chain relay vs stripes that fell back to the
@@ -756,6 +759,7 @@ class StorageClient:
                     chain = routing.chains.get(reqs[i].chain_id)
                     if chain is None or chain.is_ec:
                         continue  # unknown after the repoll above: final
+                    self._read_ladder_ops.add()
                     replies[i] = self.read_chunk(
                         reqs[i].chain_id, reqs[i].chunk_id, reqs[i].offset,
                         reqs[i].length

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
@@ -44,6 +45,7 @@ from tpu3fs.mgmtd.types import (
 )
 from tpu3fs.migration.types import MigrationJob, MoveSpec
 from tpu3fs.monitor.recorder import CounterRecorder
+from tpu3fs.rpc import net as _net
 from tpu3fs.rpc.net import RpcClient, RpcServer, ServiceDef
 from tpu3fs.storage.craq import (
     ReadReply,
@@ -373,6 +375,10 @@ def bind_storage_service(server: RpcServer, svc: StorageService) -> None:
 #: parallel instead of serializing on one socket. Why 4 MiB: sub-MiB
 #: stripes cost more in per-RPC serde/GIL than they win in parallelism,
 #: so only multi-MiB node groups (ckpt restore, large batch loads) split.
+#: A group whose stripes would not fit what their carrier can return (a
+#: frame of ``MAX_PACKET``, a share of the ring's buffer: _read_span_cap)
+#: becomes as many spans as its bytes need, ``READ_STRIPES`` of them in
+#: flight a node (docs/readpath.md).
 READ_STRIPES = 4
 READ_STRIPE_MIN_BYTES = 4 << 20
 #: write-side twins of the read striping plan (payload-weighted: a write's
@@ -391,6 +397,8 @@ USRBIO_WRITE_STRIPES = 1
 #: one same-host node (docs/usrbio.md)
 USRBIO_ENTRIES = 128
 USRBIO_IOV_BYTES = 64 << 20
+#: control slack a read op adds to its reply estimate (_read_rsp_est)
+_READ_OP_SLACK = 160
 
 
 class _RingPending:
@@ -401,6 +409,19 @@ class _RingPending:
     def __init__(self, ring, pending):
         self.ring = ring
         self.pending = pending
+
+
+class _ReadPlan:
+    """One node group of a pipelined batch read: where its spans go and
+    which are still to be issued."""
+
+    __slots__ = ("addr", "ring", "spans", "region")
+
+    def __init__(self, addr, ring, spans, region):
+        self.addr = addr
+        self.ring = ring        # None once a span fell back to sockets
+        self.spans = spans      # deque of (lo, hi) not yet on the wire
+        self.region = region    # one reply-region size for all, or 0
 
 
 class RpcMessenger:
@@ -602,12 +623,32 @@ class RpcMessenger:
         return out
 
     @staticmethod
-    def _read_rsp_est(reqs) -> int:
+    def _read_op_est(r) -> int:
+        """One read's reply data: the requested bytes (chunk size stands
+        in for read-to-end)."""
+        return r.length if r.length >= 0 else (r.chunk_size or (1 << 20))
+
+    @classmethod
+    def _read_rsp_est(cls, reqs) -> int:
         """Reply-region data estimate for read-ish ops: requested bytes
-        (chunk size stands in for read-to-end) + per-op control slack."""
-        return sum(
-            r.length if r.length >= 0 else (r.chunk_size or (1 << 20))
-            for r in reqs) + 160 * len(reqs)
+        + per-op control slack."""
+        return sum(map(cls._read_op_est, reqs)) + _READ_OP_SLACK * len(reqs)
+
+    def _read_span_cap(self, ring) -> int:
+        """The most a read span's reply estimate (_read_rsp_est) may be on
+        the carrier it is offered to. A socket reply is one frame of
+        MAX_PACKET, less the frame's own envelope (the share the native
+        server leaves for it). A ring reply is a region of the ring's
+        registered buffer: a node keeps ``_stripes`` regions with their
+        requests in flight while one more is turned over, and a span that
+        misses the ring is sent on a socket, so the frame bounds it too."""
+        cap = _net.MAX_PACKET - _net.MAX_PACKET // 64
+        if ring is not None:
+            from tpu3fs.usrbio.transport import RSP_CTRL_BYTES
+
+            share = ring.iov.size // (max(1, self._stripes) + 1)
+            cap = min(cap, share - share // 32 - RSP_CTRL_BYTES)
+        return cap
 
     def _ring_dispatch(self, ring, method: str, payload):
         """One messenger method over the ring — same reply semantics as
@@ -696,124 +737,176 @@ class RpcMessenger:
             self.health.observe(node_id, time.monotonic() - t0, ok=True)
 
     @staticmethod
-    def _attach_read_segs(replies, segs):
+    def _attach_read_segs(replies, segs, detach: bool = False):
         """Re-attach bulk segments as reply data — ZERO-COPY: each .data
         is a memoryview over the transport's receive buffer, which stays
         alive exactly as long as the views do. Consumers that retain
-        replies beyond the request must copy (bytes(data))."""
+        replies beyond the request must copy (bytes(data)). ``detach``
+        copies here instead: the buffer is a ring region that the next
+        span of the same node needs back."""
         if segs:
             # the replies were parsed for this call alone: set the field in
             # place (dataclasses.replace costs a constructor a reply, and a
             # ring drain brings a thousand)
             for rp, seg in zip(replies, segs):
-                if len(seg):
+                if not len(seg):
+                    continue
+                if detach:
+                    rp.data = bytes(seg)  # copy-ok: the region goes back
+                else:
                     rp.data = seg
         return replies
 
-    def _stripe_spans(self, reqs) -> List[Tuple[int, int]]:
+    def _stripe_spans(self, reqs, cap: int = 0) -> List[Tuple[int, int]]:
         """Split one node group into contiguous stripe spans. Groups below
         2x the stripe threshold stay whole (a tiny stripe pays more in
-        per-RPC overhead than it wins in parallelism)."""
+        per-RPC overhead than it wins in parallelism). No span's reply
+        estimate passes ``cap`` (_read_span_cap; 0 = unbounded): where the
+        ``_stripes`` equal stripes would, the group is cut by bytes into
+        as many spans as it needs, and an op larger than the cap is a
+        span of its own."""
         n = len(reqs)
-        if n <= 1 or self._stripes <= 1:
-            return [(0, n)]
-        est = sum(
-            r.length if r.length >= 0 else (r.chunk_size or (1 << 20))
-            for r in reqs)
-        if est < 2 * self._stripe_min_bytes:
-            return [(0, n)]
-        k = min(self._stripes, n,
-                max(1, est // self._stripe_min_bytes))
+        sizes = [self._read_op_est(r) for r in reqs]
+        est = sum(sizes)
+        k = 1
+        if n > 1 and self._stripes > 1 and est >= 2 * self._stripe_min_bytes:
+            k = min(self._stripes, n,
+                    max(1, est // self._stripe_min_bytes))
         base, rem = divmod(n, k)
         spans, lo = [], 0
         for i in range(k):
             hi = lo + base + (1 if i < rem else 0)
             spans.append((lo, hi))
             lo = hi
+        slack = _READ_OP_SLACK
+        if not cap or est + slack * n <= cap or all(
+                sum(sizes[lo:hi]) + slack * (hi - lo) <= cap
+                for lo, hi in spans):
+            return spans
+        spans, lo, acc = [], 0, 0
+        for i, size in enumerate(sizes):
+            if i > lo and acc + size + slack > cap:
+                spans.append((lo, i))
+                lo, acc = i, 0
+            acc += size + slack
+        spans.append((lo, n))
         return spans
+
+    def _start_read_span(self, node_id: int, plan: _ReadPlan, span):
+        """Put one span's BatchRead on the wire -> what to collect: a
+        pending call, a _RingPending or the FsError that stopped it."""
+        if plan.ring is not None:
+            # same-host: the stripe rides the shm ring (the agent
+            # dispatches stripes concurrently, so the socket pipelining
+            # shape is preserved)
+            try:
+                return _RingPending(plan.ring, plan.ring.start(
+                    STORAGE_SERVICE_ID, 11, BatchReadReq(span),
+                    BatchReadRsp, bulk_iovs=(),
+                    rsp_data_est=max(plan.region,
+                                     self._read_rsp_est(span))))
+            except FsError as e:
+                plan.ring = self._ring_fallback(node_id, plan.ring, e)
+        try:
+            return self._client.start_call(
+                plan.addr, STORAGE_SERVICE_ID, 11, BatchReadReq(span),
+                BatchReadRsp, bulk_iovs=())
+        except FsError as e:
+            return e
+
+    def _finish_read_span(self, node_id: int, p, span, detach: bool):
+        """Collect one span -> its replies with their data attached.
+        ``detach``: a ring reply gives its region back before this
+        returns (nothing here outlives the call that could hold it)."""
+        if not isinstance(p, _RingPending):
+            rsp, segs = self._client.finish_call(p)
+            return self._attach_read_segs(rsp.replies, segs)
+        try:
+            rsp, segs = p.ring.finish(p.pending)
+        except FsError as e:
+            # ring died mid-call: replay THIS span over a socket so
+            # callers never see a new failure mode from the fast path
+            self._ring_fallback(node_id, p.ring, e)
+            rsp, segs = self._client.call_bulk(
+                self._addr(node_id), STORAGE_SERVICE_ID, 11,
+                BatchReadReq(span), BatchReadRsp, bulk_iovs=())
+            return self._attach_read_segs(rsp.replies, segs)
+        return self._attach_read_segs(rsp.replies, segs, detach)
 
     def batch_read_pipelined(self, groups):
         """Striped, pipelined batch-read fan-out: `groups` is
-        [(node_id, [ReadReq, ...])]. Every group is split into stripes
-        (each a BatchRead RPC on its own pooled connection), ALL requests
-        are issued before any reply is collected — so the last node's
-        stripes are on the wire while the first node is still reading —
-        then replies are collected in issue order. -> per-group reply
-        lists aligned with the input reqs; ops a stripe failed for carry
-        the transport error code as their reply."""
-        pend = []     # (group idx, span lo, span hi,
-        #                pending | _RingPending | FsError)
+        [(node_id, [ReadReq, ...])]. Every group is split into spans
+        (each a BatchRead RPC on its own pooled connection, or an SQE of
+        the node's ring) that their carrier can answer (_read_span_cap).
+        The first ``_stripes`` spans of EVERY group are issued before any
+        reply is collected — so the last node's stripes are on the wire
+        while the first node is still reading — then replies are
+        collected in issue order, and a group with more spans issues its
+        next one as its oldest is collected: at most ``_stripes`` of a
+        node in flight. -> per-group reply lists aligned with the input
+        reqs; ops a span failed for carry the transport error code as
+        their reply."""
+        pend = deque()  # (group idx, span lo, span hi,
+        #                  pending | _RingPending | FsError, issue time)
         results = [[None] * len(reqs) for _, reqs in groups]
-        c = self._client
+        window = max(1, self._stripes)
+        plans: Dict[int, _ReadPlan] = {}   # by group idx
+
+        def issue(gi, stamp):
+            node_id, reqs = groups[gi]
+            lo, hi = plans[gi].spans.popleft()
+            p = self._start_read_span(node_id, plans[gi], reqs[lo:hi])
+            pend.append((gi, lo, hi, p, stamp))
+
         for gi, (node_id, reqs) in enumerate(groups):
             try:
                 addr = self._addr(node_id)
             except FsError as e:
-                pend.append((gi, 0, len(reqs), e))
+                pend.append((gi, 0, len(reqs), e, None))
                 continue
             ring = self._ring_for(node_id)
-            for lo, hi in self._stripe_spans(reqs):
-                span = reqs[lo:hi]
-                if ring is not None:
-                    # same-host: the stripe rides the shm ring (the
-                    # agent dispatches stripes concurrently, so the
-                    # socket pipelining shape is preserved)
-                    try:
-                        pend.append((gi, lo, hi, _RingPending(
-                            ring, ring.start(
-                                STORAGE_SERVICE_ID, 11,
-                                BatchReadReq(span), BatchReadRsp,
-                                bulk_iovs=(),
-                                rsp_data_est=self._read_rsp_est(span)))))
-                        continue
-                    except FsError as e:
-                        ring = self._ring_fallback(node_id, ring, e)
-                try:
-                    pend.append((gi, lo, hi, c.start_call(
-                        addr, STORAGE_SERVICE_ID, 11,
-                        BatchReadReq(span), BatchReadRsp,
-                        bulk_iovs=())))
-                except FsError as e:
-                    pend.append((gi, lo, hi, e))
-        t_issue = time.monotonic()
-        for gi, lo, hi, p in pend:
-            node_id = groups[gi][0]
+            cap = self._read_span_cap(ring)
+            spans = deque(self._stripe_spans(reqs, cap))
+            # a group that has to turn its ring regions over takes them
+            # all of one size, the cap's: the region a collected span
+            # gives back then holds the next span whatever its bytes
+            region = cap if len(spans) > window else 0
+            plans[gi] = _ReadPlan(addr, ring, spans, region)
+            for _ in range(min(window, len(spans))):
+                issue(gi, None)
+        # the first round's replies (no stamp) are timed from here, all of
+        # them on the wire; a span issued later from its own start
+        t_first = time.monotonic()
+        while pend:
+            gi, lo, hi, p, t_issue = pend.popleft()
+            node_id, reqs = groups[gi]
+            plan = plans.get(gi)
+            more = plan is not None and bool(plan.spans)
+            err = None
             if isinstance(p, FsError):
                 err = p
-                self._observe(node_id, t_issue, err=err)
             else:
                 try:
-                    if isinstance(p, _RingPending):
-                        try:
-                            rsp, segs = p.ring.finish(p.pending)
-                        except FsError as e:
-                            # ring died mid-call: replay THIS span over a
-                            # socket so callers never see a new failure
-                            # mode from the fast path
-                            self._ring_fallback(node_id, p.ring, e)
-                            rsp, segs = c.call_bulk(
-                                self._addr(node_id), STORAGE_SERVICE_ID,
-                                11, BatchReadReq(groups[gi][1][lo:hi]),
-                                BatchReadRsp, bulk_iovs=())
-                    else:
-                        rsp, segs = c.finish_call(p)
-                    self._observe(node_id, t_issue)
-                    replies = self._attach_read_segs(rsp.replies, segs)
+                    replies = self._finish_read_span(
+                        node_id, p, reqs[lo:hi], detach=more)
                     results[gi][lo:lo + len(replies)] = replies
-                    continue
                 except FsError as e:
                     err = e
-                    self._observe(node_id, t_issue, err=err)
-            # envelope-level sheds (native gates, dispatch admission)
-            # carry their retry-after hint only in the message: surface
-            # it in the typed field so ladders/hedging honor it
-            from tpu3fs.qos.core import retry_after_ms_of
+            self._observe(node_id, t_issue or t_first, err=err)
+            if err is not None:
+                # envelope-level sheds (native gates, dispatch admission)
+                # carry their retry-after hint only in the message:
+                # surface it in the typed field so ladders/hedging honor
+                # it
+                from tpu3fs.qos.core import retry_after_ms_of
 
-            hint = retry_after_ms_of(err.status.message)
-            for i in range(lo, min(hi, len(results[gi]))):
-                if results[gi][i] is None:
-                    results[gi][i] = ReadReply(err.code,
-                                               retry_after_ms=hint)
+                hint = retry_after_ms_of(err.status.message)
+                for i in range(lo, min(hi, len(results[gi]))):
+                    if results[gi][i] is None:
+                        results[gi][i] = ReadReply(err.code,
+                                                   retry_after_ms=hint)
+            if more:
+                issue(gi, time.monotonic())
         for out in results:
             for i, r in enumerate(out):
                 if r is None:  # short reply list from a confused server

@@ -269,6 +269,9 @@ def parse_reply(region: memoryview, total: int):
 # -- shm arena ----------------------------------------------------------------
 
 _ALIGN = 64
+#: what RingClient.start puts on a reply region besides the caller's data
+#: estimate: the header and room for the status message and control payload
+RSP_CTRL_BYTES = RSP_HDR.size + 4096
 
 
 class _ShmArena:
@@ -415,16 +418,19 @@ class RingClient:
                 f"envelope token exceeds SQE field ({len(token)} chars)"))
         payload = serialize(req, req_type or type(req))
         req_size = request_size(payload, bulk_iovs)
-        rsp_cap = RSP_HDR.size + 4096 + int(rsp_data_est)
-        req_off = self._arena.alloc(req_size)
-        if req_off is None:
-            raise FsError(Status(Code.USRBIO_RING_FULL,
-                                 f"iov arena exhausted ({req_size}B req)"))
+        rsp_cap = RSP_CTRL_BYTES + int(rsp_data_est)
+        # the reply region first: a caller that turns regions of one size
+        # over (RpcMessenger.batch_read_pipelined) finds the one it just
+        # gave back whole, not a request's length short
         rsp_off = self._arena.alloc(rsp_cap)
         if rsp_off is None:
-            self._arena.free(req_off, req_size)
             raise FsError(Status(Code.USRBIO_RING_FULL,
                                  f"iov arena exhausted ({rsp_cap}B rsp)"))
+        req_off = self._arena.alloc(req_size)
+        if req_off is None:
+            self._arena.free(rsp_off, rsp_cap)
+            raise FsError(Status(Code.USRBIO_RING_FULL,
+                                 f"iov arena exhausted ({req_size}B req)"))
         try:
             stage_request(self.iov, req_off, payload, bulk_iovs)
             with self._sq_lock:
