@@ -1020,7 +1020,8 @@ class TestProfiledCapture:
         assert spans.CAPTURE_MAX_ROWS >= 1 << 19
 
     def test_the_file_sink_keeps_its_schema(self, tracer, tmp_path):
-        """The span files carry no per-process clock or thread column."""
+        """The span files carry no per-process clock or thread column
+        (`cpu_us`, a duration, they do: TestCpuColumn)."""
         tracer.configure(service="t", node=1, directory=str(tmp_path),
                          sample_rate=0.0, slow_op_ms=10_000)
         with spans.root_span("client.op", force=True):
@@ -1053,6 +1054,241 @@ class TestProfiledCapture:
             rows[("inner", "stage")]["span_id"]
         tree = assemble.TraceTree("t", list(rows.values()))
         assert [r["op"] for r in tree.leaf_rows()] == ["leaf"]
+
+
+def _captured_by(fn):
+    """Rows (dicts) of what `fn` emits under a context captured as a
+    profiled trace would be, on a tracer of the test's own."""
+    tracer = spans.Tracer()
+    old, spans._TRACER = spans._TRACER, tracer
+    try:
+        ctx = spans.TraceContext("t" * 16, "r" * 16, profiled=True)
+        ctx.root = True
+        with spans.trace_scope(ctx):
+            fn(ctx)
+        tracer.finish_op(ctx, "outer", time.time(), 0.0)
+    finally:
+        spans._TRACER = old
+    return assemble.rows_of_captured(tracer.captured())
+
+
+def _burn(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+class TestCpuColumn:
+    """`cpu_us`: the emitting thread's CPU time over a span, from
+    time.thread_time_ns at the span's two ends; -1 = not measured. The
+    clock is a dear system call on the chip host, so it is read only by
+    a span that no span above it on the same thread already covers (a
+    thread's outermost op span, a pool worker's hop) and by the stages
+    that ask (`span(..., cpu=True)`)."""
+
+    @pytest.mark.parametrize("body, lo, hi", [
+        (lambda: _burn(0.03), 0.8, 1.0001),       # on the CPU throughout
+        (lambda: time.sleep(0.05), 0.0, 0.1),     # off it throughout
+    ], ids=["busy", "sleeping"])
+    def test_a_span_s_cpu_follows_what_its_thread_did(self, body, lo, hi):
+        # a busy worker beside the test can take the core away for a
+        # slice: the best of a few tries is what the clock reads
+        shares = []
+        for _ in range(5):
+            def block(ctx):
+                with spans.span("op", "stage", cpu=True):
+                    with spans.span("op", "plain"):
+                        body()
+
+            rows = {r["stage"]: r for r in _captured_by(block)}
+            assert rows["plain"]["cpu_us"] == -1     # it did not ask
+            row = rows["stage"]
+            assert row["cpu_us"] >= 0
+            shares.append(row["cpu_us"] / row["dur_us"])
+            if lo <= shares[-1] <= hi:
+                break
+        assert lo <= shares[-1] <= hi, shares
+
+    def test_live_ops_carry_it(self):
+        def block(ctx):
+            with spans.root_span("inner.root"):
+                _burn(0.005)
+            sp = spans.open_op("inner.open")
+            t0 = time.perf_counter()
+            _burn(0.005)
+            spans.close_op(sp, "inner.open", t0, time.perf_counter() - t0)
+
+            with spans.root_span("op.above"):
+                with spans.root_span("op.beneath"):   # covered: no read
+                    _burn(0.002)
+
+        rows = {r["op"]: r for r in _captured_by(block)}
+        for op in ("inner.root", "inner.open"):
+            assert 4000 <= rows[op]["cpu_us"] <= rows[op]["dur_us"] + 200, \
+                rows[op]
+        assert rows["outer"]["cpu_us"] == -1     # emitted by finish_op
+        assert rows["op.above"]["cpu_us"] >= 1900
+        assert rows["op.beneath"]["cpu_us"] == -1
+
+    @staticmethod
+    def _hop(ctx):
+        hop = spans.Hop.start()
+        _burn(0.002)                  # issue
+        hop.issued(10)
+        _burn(0.001)                  # between issue and the wait
+        hop.waiting()
+        time.sleep(0.02)              # collect: off the CPU
+        hop.decoding()
+        _burn(0.003)                  # decode
+        hop.collected("rpc.client.9.9", server=(0.004, 0.006))
+
+    def test_a_hop_no_span_above_covers_is_read_whole(self):
+        rows = _captured_by(self._hop)
+        stages = {r["stage"]: r for r in rows if r["op"] == "rpc.client"}
+        assert set(stages) == {"issue", "collect", "server_wait",
+                               "server_run", "wire", "decode"}
+        assert all(r["cpu_us"] == -1 for r in stages.values())
+        assert stages["collect"]["dur_us"] >= 20_000
+        (op,) = [r for r in rows if r["op"] == "rpc.client.9.9"]
+        assert 5900 <= op["cpu_us"] <= 7500          # 2 + 1 + ~0 + 3 ms
+
+    def test_a_hop_beneath_an_op_of_its_thread_reads_no_clock(
+            self, monkeypatch):
+        reads = []
+        real = time.thread_time_ns
+
+        def block(ctx):
+            with spans.root_span("client.op"):
+                monkeypatch.setattr(time, "thread_time_ns",
+                                    lambda: reads.append(1) or real())
+                self._hop(ctx)
+                monkeypatch.setattr(time, "thread_time_ns", real)
+
+        rows = _captured_by(block)
+        assert reads == []
+        (op,) = [r for r in rows if r["op"] == "rpc.client.9.9"]
+        assert op["cpu_us"] == -1
+        (above,) = [r for r in rows if r["op"] == "client.op"]
+        assert above["cpu_us"] >= 5900            # the hop's work is in it
+
+    def test_a_pool_worker_s_hop_beneath_an_op_is_read_whole(self):
+        def block(ctx):
+            with spans.root_span("client.op"):
+                inner = spans.current_trace()
+
+                def work():
+                    with spans.trace_scope(inner):
+                        self._hop(ctx)
+
+                t = threading.Thread(target=work)
+                t.start()
+                t.join(10)
+                assert not t.is_alive()
+
+        rows = _captured_by(block)
+        (op,) = [r for r in rows if r["op"] == "rpc.client.9.9"]
+        (above,) = [r for r in rows if r["op"] == "client.op"]
+        assert op["tid"] != above["tid"]
+        assert 5900 <= op["cpu_us"] <= 7500
+        assert above["cpu_us"] < 2000             # it only waited
+
+    @pytest.mark.parametrize("emit", [
+        lambda ctx: spans.add_span(ctx, "op", "late", time.time(), 0.001),
+        lambda ctx: spans.add_span_at(ctx, "op", "late",
+                                      time.perf_counter(), 0.001),
+        lambda ctx: spans.add_span_multi([ctx], "op", "late", time.time(),
+                                         0.001),
+        lambda ctx: spans.add_op("late.op", time.perf_counter(), 0.001),
+    ], ids=["add_span", "add_span_at", "add_span_multi", "add_op"])
+    def test_rows_measured_after_the_fact_read_not_measured(self, emit):
+        rows = _captured_by(emit)
+        assert len(rows) == 2
+        assert all(r["cpu_us"] == -1 for r in rows)
+
+    def test_an_op_closed_by_another_thread_reads_not_measured(self):
+        def block(ctx):
+            sp = spans.open_op("handed.over")
+            t0 = time.perf_counter()
+            t = threading.Thread(target=spans.close_op, args=(
+                sp, "handed.over", t0, 0.001))
+            t.start()
+            t.join(10)
+            assert not t.is_alive()
+
+        rows = {r["op"]: r for r in _captured_by(block)}
+        assert rows["handed.over"]["cpu_us"] == -1
+
+    def test_an_untraced_op_never_reads_the_cpu_clock(self, tracer,
+                                                      monkeypatch):
+        calls = []
+        real = time.thread_time_ns
+        monkeypatch.setattr(time, "thread_time_ns",
+                            lambda: calls.append(1) or real())
+        assert not spans.profiler_active()
+        with spans.root_span("op.any") as ctx:
+            assert ctx is None
+            with spans.span("op.any", "stage"):
+                assert spans.Hop.start() is None
+            spans.add_op("op.late", time.perf_counter(), 0.001)
+            sp = spans.open_op("op.open")
+            spans.close_op(sp, "op.open", time.perf_counter(), 0.0)
+        assert calls == []
+        # the counter itself sees a traced op's two reads, and none of a
+        # stage that does not ask
+        def block(ctx):
+            with spans.root_span("op"):
+                with spans.span("op", "s"):
+                    pass
+
+        _captured_by(block)
+        assert len(calls) == 2
+
+    def test_the_span_files_carry_it_and_older_files_load_without(
+            self, tracer, tmp_path):
+        new, old = tmp_path / "new", tmp_path / "old"
+        tracer.configure(service="t", node=1, directory=str(new),
+                         sample_rate=1.0)
+        with spans.root_span("client.op"):
+            with spans.span("client.op", "stage"):
+                _burn(0.002)
+            spans.add_span(spans.current_trace(), "client.op", "late",
+                           time.time(), 0.001)
+        written = _rows(tracer)
+        assert {"cpu_us"} <= set(written[0])
+        assert not {"t_perf", "tid"} & set(written[0])
+        from tpu3fs.analytics.trace import write_records
+
+        old.mkdir()
+        write_records(str(old / "spans-1.00000"), [
+            {k: v for k, v in r.items() if k != "cpu_us"} for r in written])
+        for d, reads in ((new, lambda r: r["cpu_us"]),
+                         (old, lambda r: -1.0)):
+            rows = {(r["op"], r["stage"]): r
+                    for r in assemble.load_spans([str(d)])}
+            assert len(rows) == 3
+            got = {k: r["cpu_us"] for k, r in rows.items()}
+            want = {(r["op"], r["stage"]): reads(r) for r in written}
+            assert got == want
+        assert want[("client.op", "stage")] == -1.0    # the old file's
+        assert rows[("client.op", "late")]["cpu_us"] == -1.0
+
+    def test_the_rendered_tree_says_cpu_beside_the_wall_where_known(self):
+        ev = spans.SpanEvent
+        rows = [
+            ev(trace_id="x" * 16, span_id="r" * 16, op="client.op", ts=1.0,
+               dur_us=9000.0, cpu_us=1250.0).__dict__,
+            ev(trace_id="x" * 16, span_id="a" * 16, parent_id="r" * 16,
+               op="rpc.client", stage="wire", ts=1.0,
+               dur_us=7000.0).__dict__,
+            {"trace_id": "x" * 16, "span_id": "b" * 16,        # an older
+             "parent_id": "r" * 16, "op": "storage.update",    # file's row
+             "stage": "commit", "ts": 1.0, "dur_us": 500.0},
+        ]
+        text = assemble.format_trace(assemble.assemble_traces(rows)["x" * 16])
+        by_name = {ln.split()[0]: ln for ln in text.splitlines()[1:4]}
+        assert "9.000 ms cpu     1.250 ms" in by_name["client.op"]
+        assert "cpu" not in by_name["rpc.client/wire"]
+        assert "cpu" not in by_name["storage.update/commit"]
 
 
 class TestPins:
@@ -1125,6 +1361,12 @@ class TestPins:
         stages = {r["stage"]: r for r in mine
                   if r["op"] == "usrbio.ring_batch" and r["stage"]}
         assert set(stages) == {"drain", "stat", "read", "complete"}
+        # the op is opened before the SQEs are unpacked and closed by the
+        # same worker: its CPU is measured, the back-dated `drain` is not
+        assert 0 <= roots[0]["cpu_us"] <= roots[0]["dur_us"] + 200
+        assert stages["drain"]["cpu_us"] == -1
+        assert stages["complete"]["cpu_us"] >= 0   # the stage that asks
+        assert stages["stat"]["cpu_us"] == stages["read"]["cpu_us"] == -1
         assert stages["drain"]["nbytes"] == 8      # SQEs: a count
         assert stages["complete"]["nbytes"] == 8   # CQEs: a count
         assert stages["stat"]["nbytes"] == 1       # distinct inodes

@@ -8,8 +8,12 @@ boundaries: a server op parents to the client's rpc span carried on the
 envelope), and derives the two operator views:
 
 - ``format_trace``: one trace as an indented tree with per-span wall
-  times and a STAGE COVERAGE line — the fraction of the root
-  (client-observed) latency that attributed spans account for.
+  times — and, where the row has it, the emitting thread's CPU time
+  beside the wall: a span that is slow with its CPU near its wall was
+  slow computing, one with little CPU was slow waiting (for a lock, the
+  GIL, a socket or another process alike) — and a STAGE COVERAGE line:
+  the fraction of the root (client-observed) latency that attributed
+  spans account for.
   Coverage takes the tree's LEAVES only: a span with spans beneath it,
   and the container stages (``collect``, ``forward``: they hold another
   process's whole pipeline), would double count.
@@ -50,9 +54,13 @@ def span_files(paths: Iterable[str]) -> List[str]:
 
 
 def load_spans(paths: Iterable[str]) -> List[dict]:
+    """Every row of the span files; a file written before the ``cpu_us``
+    column reads as not measured (-1)."""
     rows: List[dict] = []
     for path in span_files(paths):
-        rows.extend(read_records(path))
+        for row in read_records(path):
+            row.setdefault("cpu_us", -1.0)
+            rows.append(row)
     return rows
 
 
@@ -272,7 +280,9 @@ def _fmt_row(r: dict) -> str:
         extra += f" code={r['code']}"
     if r.get("slow"):
         extra += " SLOW"
-    return f"{name:<34s} {r.get('dur_us', 0.0) / 1e3:9.3f} ms" \
+    cpu_us = r.get("cpu_us", -1.0)
+    cpu = f" cpu {cpu_us / 1e3:9.3f} ms" if cpu_us >= 0 else ""
+    return f"{name:<34s} {r.get('dur_us', 0.0) / 1e3:9.3f} ms{cpu}" \
            f"  [{where}]{extra}"
 
 

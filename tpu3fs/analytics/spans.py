@@ -40,6 +40,27 @@ per-trace trees. Overhead discipline: with no tracer configured and no
 profiler session the only cost on any hot path is one ContextVar read
 returning None (nested sites) or that plus one ``is_enabled()`` call (root
 op sites).
+
+Three clocks, each read only where a span starts or ends and only when the
+op is traced: ``time.time()`` gives a row its ``ts`` (the one clock other
+processes share), ``time.perf_counter()`` its ``dur_us`` and ``t_perf``
+(this process's, the clock the profiler's trace is tied to), and
+``time.thread_time_ns()`` its ``cpu_us`` — how long the EMITTING THREAD
+was on a CPU between the span's start and end. A thread that waits — for
+the GIL, a lock, a socket, a semaphore, the device — is off the CPU alike,
+so ``dur_us - cpu_us`` of a span that makes no blocking call is what it
+queued for the interpreter. The clock is per thread: a row whose start and
+end were not read by one thread, or that was measured after the fact, or
+that is derived from another process's stamps, carries ``cpu_us`` -1.
+It is also a real system call where the other two are not, and on the chip
+host a dear one (6 us a read in a loop, about 20 inside a busy client, in
+10-ms ticks: docs/observability.md "Overhead"), so it is read only where a
+reading adds something: by a span that no span above it on the same
+thread already covers (``TraceContext.cover``) — the outermost op span of
+a thread (``root_span``, ``open_op``/``close_op``) and a hop on a pool
+worker's thread — and by a stage whose site asks (``span(..., cpu=True)``:
+a stage a metric names). What runs beneath a reading on its thread is
+inside it; every other row reads -1.
 """
 
 from __future__ import annotations
@@ -87,13 +108,18 @@ class SpanEvent:
     tenant: str = ""       # owning tenant (op spans; tpu3fs/tenant)
     sampled: bool = False
     slow: bool = False     # flushed by the slow-op/forced path
+    # the emitting thread's CPU time over the span (time.thread_time_ns at
+    # both ends: a thread's outermost op span, a pool worker's hop, a stage
+    # that asks); -1 = not measured
+    cpu_us: float = -1.0
     # in-memory only (the profiled sink; never written to the span files):
     t_perf: float = 0.0    # start on time.perf_counter (this process's)
     tid: int = 0           # emitting thread's ident
 
 
 # the two fields the span files do not carry (a per-process clock and a
-# per-process thread id mean nothing to another process's reader)
+# per-process thread id mean nothing to another process's reader; cpu_us is
+# a duration like dur_us and is a column of the files)
 _MEMORY_ONLY = ("t_perf", "tid")
 # a captured row is a plain tuple in this field order: a window of a
 # traced cell holds some hundred thousand rows, and a tuple of shared
@@ -145,11 +171,13 @@ class TraceContext:
     """
 
     __slots__ = ("trace_id", "span_id", "parent_id", "sampled", "slow",
-                 "events", "profiled", "root", "nbytes", "ts", "mark")
+                 "events", "profiled", "root", "nbytes", "ts", "mark",
+                 "cpu0", "cover")
 
     def __init__(self, trace_id: str, span_id: str, parent_id: str = "",
                  sampled: bool = False, slow: bool = False,
-                 events: Optional[list] = None, profiled: bool = False):
+                 events: Optional[list] = None, profiled: bool = False,
+                 cover: int = 0):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
@@ -163,6 +191,11 @@ class TraceContext:
         self.nbytes = 0        # payload bytes, where known only at the end
         self.ts = 0.0          # wall-clock start
         self.mark = None       # the open profiler annotation
+        self.cpu0 = None       # (thread ident, thread_time_ns) at the start
+        # ident of the thread whose CPU a span above this one is reading
+        # (0 = none): what runs on that thread beneath it is inside that
+        # reading and need not read the clock again
+        self.cover = cover
         # list.append is GIL-atomic: overlap-forward helper threads and
         # worker threads may append concurrently with the op thread
         self.events: List[SpanEvent] = events if events is not None else []
@@ -172,7 +205,7 @@ class TraceContext:
         accumulator: one flush decision covers the whole op)."""
         return TraceContext(self.trace_id, _new_id(), self.span_id,
                             self.sampled, self.slow, self.events,
-                            self.profiled)
+                            self.profiled, self.cover)
 
     # -- envelope carriage -------------------------------------------------
     def to_wire(self) -> str:
@@ -419,7 +452,8 @@ class Tracer:
     def end_op(self, ctx: TraceContext, op: str, ts: float, dur_s: float,
                *, code: int = 0, nbytes: int = 0,
                tclass: str = "", tenant: str = "",
-               t_perf: Optional[float] = None) -> None:
+               t_perf: Optional[float] = None,
+               cpu_us: float = -1.0) -> None:
         """Append the op span for a NESTED op (the flush decision belongs
         to whichever op owns the accumulator — the process root). An
         empty tenant resolves from the ambient scope, so every op span
@@ -431,18 +465,20 @@ class Tracer:
             parent_id=ctx.parent_id, service=self.service, node=self.node,
             op=op, stage="", ts=ts, dur_us=dur_s * 1e6, code=code,
             nbytes=nbytes, tclass=tclass, tenant=tenant,
-            sampled=ctx.sampled,
+            sampled=ctx.sampled, cpu_us=cpu_us,
             t_perf=perf_of_wall(ts) if t_perf is None else t_perf,
             tid=threading.get_ident()))
 
     def finish_op(self, ctx: TraceContext, op: str, ts: float,
                   dur_s: float, *, code: int = 0, nbytes: int = 0,
                   tclass: str = "", tenant: str = "",
-                  t_perf: Optional[float] = None) -> None:
+                  t_perf: Optional[float] = None,
+                  cpu_us: float = -1.0) -> None:
         """Emit the op span and make the flush-or-drop decision for every
         event the op accumulated in this process."""
         self.end_op(ctx, op, ts, dur_s, code=code, nbytes=nbytes,
-                    tclass=tclass, tenant=tenant, t_perf=t_perf)
+                    tclass=tclass, tenant=tenant, t_perf=t_perf,
+                    cpu_us=cpu_us)
         is_slow = ctx.slow or dur_s * 1e6 >= self.slow_op_us
         if is_slow and self._slow_hooks:
             for hook in self._slow_hooks:
@@ -545,11 +581,13 @@ def round_traces() -> Tuple[TraceContext, ...]:
 def add_span(ctx: Optional[TraceContext], op: str, stage: str, ts: float,
              dur_s: float, *, code: int = 0, nbytes: int = 0,
              t_perf: Optional[float] = None,
-             span_id: Optional[str] = None) -> None:
+             span_id: Optional[str] = None, cpu_us: float = -1.0) -> None:
     """Append one already-measured stage span to a context (no-op on
     None): the storage pipeline measures its stage/forward/commit walls
     anyway — tracing reuses those numbers instead of re-clocking. Stages
-    measured after the fact are rows only, never profiler annotations."""
+    measured after the fact are rows only, never profiler annotations,
+    and carry no CPU time (``cpu_us`` -1) unless the emitter read the
+    thread's CPU clock at both ends itself (``span``, ``Hop``)."""
     if ctx is None:
         return
     t = _TRACER
@@ -557,7 +595,7 @@ def add_span(ctx: Optional[TraceContext], op: str, stage: str, ts: float,
         trace_id=ctx.trace_id, span_id=span_id or _new_id(),
         parent_id=ctx.span_id, service=t.service, node=t.node, op=op,
         stage=stage, ts=ts, dur_us=dur_s * 1e6, code=code, nbytes=nbytes,
-        sampled=ctx.sampled,
+        sampled=ctx.sampled, cpu_us=cpu_us,
         t_perf=perf_of_wall(ts) if t_perf is None else t_perf,
         tid=threading.get_ident()))
 
@@ -592,15 +630,28 @@ class Hop:
     reconstructed as "now minus duration": the anchor conversion needs
     starts that are exact. The server's two stages tile from the end of
     ``issue``, where the request left this side; ``wire`` ends where the
-    reply is in."""
+    reply is in.
 
-    __slots__ = ("ctx", "ts", "t0", "t_issued")
+    Clocks: ``ts`` is ``time.time()`` at the start; every stage's start
+    and duration come from ``time.perf_counter()``. The hop's op span
+    carries ``cpu_us`` (``time.thread_time_ns()`` in ``__init__`` and
+    ``collected``) only where no span above the hop reads this thread's
+    CPU (``TraceContext.cover``: a pool worker's hop); beneath such a
+    span the reading is there already, and the hops one thread pipelines
+    overlap, so their own readings would count one another's work. The
+    stages carry -1."""
+
+    __slots__ = ("ctx", "ts", "t0", "t_issued", "t_wait", "t_decode",
+                 "tid", "c0")
 
     def __init__(self, parent: TraceContext):
         self.ctx = parent.child()
+        self.tid = threading.get_ident()
         self.ts = time.time()
-        self.t0 = time.perf_counter()
-        self.t_issued = self.t0
+        self.t0 = self.t_issued = self.t_wait = time.perf_counter()
+        self.c0 = (time.thread_time_ns() if parent.cover != self.tid
+                   else None)
+        self.t_decode: Optional[float] = None
 
     @classmethod
     def start(cls) -> Optional["Hop"]:
@@ -616,17 +667,30 @@ class Hop:
                  t_perf=t_perf)
 
     def issued(self, nbytes: int = 0) -> None:
-        self.t_issued = time.perf_counter()
+        """The request is on the wire; the wait for its reply begins here
+        unless ``waiting`` says later (a caller that issues several hops
+        before it collects the first)."""
+        self.t_issued = self.t_wait = time.perf_counter()
         self._add("issue", self.t0, self.t_issued - self.t0, nbytes)
 
-    def collected(self, op: str, t_wait: float, *, code: int = 0,
-                  server: Optional[Tuple[float, float]] = None,
-                  t_decode: Optional[float] = None) -> None:
-        """The reply is in and, where ``t_decode`` says when that began,
-        decoded: ``t_wait`` is perf_counter when the wait for the reply
-        began, ``server`` the (wait, run) seconds of the server's own
+    def waiting(self) -> None:
+        """The wait for the reply begins (``collect``'s start)."""
+        self.t_wait = time.perf_counter()
+
+    def decoding(self) -> None:
+        """The reply is in and its decode begins (``collect``'s end)."""
+        self.t_decode = time.perf_counter()
+
+    def collected(self, op: str, *, code: int = 0,
+                  server: Optional[Tuple[float, float]] = None) -> None:
+        """The reply is in and, where ``decoding`` said when that began,
+        decoded: ``server`` is the (wait, run) seconds of the server's own
         stamps where the reply carried them. Closes the hop."""
+        cpu_us = -1.0
+        if self.c0 is not None and threading.get_ident() == self.tid:
+            cpu_us = (time.thread_time_ns() - self.c0) / 1e3
         now = time.perf_counter()
+        t_wait, t_decode = self.t_wait, self.t_decode
         got = now if t_decode is None else t_decode
         self._add("collect", t_wait, got - t_wait)
         if server is not None:
@@ -639,7 +703,7 @@ class Hop:
         if t_decode is not None:
             self._add("decode", t_decode, now - t_decode)
         _TRACER.end_op(self.ctx, op, self.ts, now - self.t0, code=code,
-                       t_perf=self.t0)
+                       t_perf=self.t0, cpu_us=cpu_us)
 
 
 def _mark(ctx: TraceContext, op: str, stage: str = ""):
@@ -655,13 +719,18 @@ def _mark(ctx: TraceContext, op: str, stage: str = ""):
 
 
 @contextlib.contextmanager
-def span(op: str, stage: str, *, nbytes: int = 0):
+def span(op: str, stage: str, *, nbytes: int = 0, cpu: bool = False):
     """Clock a block as a stage span under the current context (no-op —
     not even a clock read — when untraced). What the block calls parents
     to the stage, so a tree's leaves are what is attributed and a stage
     that holds other spans is seen to (``TraceTree.coverage``). A block
     that learns its payload only at the end sets ``nbytes`` on
-    ``current_trace()``, which inside the block is the stage's own."""
+    ``current_trace()``, which inside the block is the stage's own.
+    Clocks: ``ts`` from ``time.time()``, ``t_perf`` and ``dur_us`` from
+    ``time.perf_counter()``; with ``cpu`` (a stage whose CPU a metric
+    reads, covered or not: the clock is a system call) ``cpu_us`` from
+    ``time.thread_time_ns()`` — the calling thread's CPU time inside the
+    block, whatever other threads did for it — and -1 without."""
     ctx = _trace_var.get()
     if ctx is None:
         yield None
@@ -670,16 +739,33 @@ def span(op: str, stage: str, *, nbytes: int = 0):
     mark = _mark(ctx, op, stage)
     ts = time.time()
     t0 = time.perf_counter()
+    c0 = None
+    if cpu:
+        c0 = time.thread_time_ns()
+        inner.cover = threading.get_ident()
     token = _trace_var.set(inner)
     try:
         yield ctx
     finally:
+        cpu_us = (time.thread_time_ns() - c0) / 1e3 if cpu else -1.0
         dur = time.perf_counter() - t0
         _trace_var.reset(token)
         if mark is not None:
             mark.__exit__(None, None, None)
         add_span(ctx, op, stage, ts, dur, nbytes=nbytes or inner.nbytes,
-                 t_perf=t0, span_id=inner.span_id)
+                 t_perf=t0, span_id=inner.span_id, cpu_us=cpu_us)
+
+
+def _join_or_start(force: bool) -> Optional[TraceContext]:
+    """The context of a new op span: a child of the current trace, or the
+    root of a trace of its own (None when nothing captures)."""
+    outer = _trace_var.get()
+    if outer is not None:
+        return outer.child()
+    ctx = _TRACER.start_trace(force=force)
+    if ctx is not None:
+        ctx.root = True
+    return ctx
 
 
 def open_op(op: str, *, force: bool = False,
@@ -689,18 +775,21 @@ def open_op(op: str, *, force: bool = False,
     the span too (``close_op``). Joins the current trace as a child op
     when one is active, otherwise head-starts a trace (sampling or a
     profiler session decide; None when neither captures). ``live`` False
-    is for an op that is already over: it gets no profiler annotation."""
-    outer = _trace_var.get()
-    if outer is not None:
-        ctx = outer.child()
-    else:
-        ctx = _TRACER.start_trace(force=force)
-        if ctx is None:
-            return None
-        ctx.root = True
+    is for an op whose start the caller back-dates: it gets no profiler
+    annotation. The context keeps the wall-clock start (``ts``) and,
+    unless a span above it already reads this thread's CPU (``cover``),
+    the thread's CPU clock (``cpu0``): the op's ``cpu_us`` counts from
+    here, wherever the caller puts its start."""
+    ctx = _join_or_start(force)
+    if ctx is None:
+        return None
     if live:
         ctx.mark = _mark(ctx, op)
     ctx.ts = time.time()
+    tid = threading.get_ident()
+    if ctx.cover != tid:
+        ctx.cover = tid
+        ctx.cpu0 = (tid, time.thread_time_ns())
     return ctx
 
 
@@ -708,22 +797,27 @@ def close_op(ctx: Optional[TraceContext], op: str, t_perf: float,
              dur_s: float, *, code: int = 0, nbytes: int = 0) -> None:
     """Emit the op span of an ``open_op`` context from the caller's clock
     reads (start on perf_counter, seconds); the root op flushes or drops
-    everything the op accumulated (incl. slow-op capture)."""
+    everything the op accumulated (incl. slow-op capture). ``cpu_us`` is
+    the calling thread's CPU time since ``open_op`` where that read the
+    clock, and -1 where it did not or another thread opened the op."""
     if ctx is None:
         return
+    cpu_us = -1.0
+    if ctx.cpu0 is not None and ctx.cpu0[0] == threading.get_ident():
+        cpu_us = (time.thread_time_ns() - ctx.cpu0[1]) / 1e3
     if ctx.mark is not None:
         ctx.mark.__exit__(None, None, None)
         ctx.mark = None
     emit = _TRACER.finish_op if ctx.root else _TRACER.end_op
     emit(ctx, op, ctx.ts, dur_s, code=code, nbytes=nbytes or ctx.nbytes,
-         t_perf=t_perf)
+         t_perf=t_perf, cpu_us=cpu_us)
 
 
 def add_op(op: str, t_perf: float, dur_s: float, *, nbytes: int = 0) -> None:
     """An already-measured op with nothing beneath it (a consumer's wait a
     recorder clocked): one row under the current trace, or a trace of its
-    own. No annotation: it is over when it is known."""
-    ctx = open_op(op, live=False)
+    own. No annotation and no CPU time: it is over when it is known."""
+    ctx = _join_or_start(False)
     if ctx is not None:
         ctx.ts = wall_of_perf(t_perf)
         close_op(ctx, op, t_perf, dur_s, nbytes=nbytes)
@@ -738,7 +832,9 @@ def root_span(op: str, *, nbytes: int = 0, force: bool = False,
     envelope stamping downstream, flush-or-drop at exit (incl. slow-op
     capture). Yields the op's context or None; a caller that learns the
     payload size only at the end sets ``ctx.nbytes`` before leaving.
-    ``code`` is what a block that does not raise records (-1 if it does)."""
+    ``code`` is what a block that does not raise records (-1 if it does).
+    Clocks: ``open_op``'s (``ts``; the thread's CPU where this is the
+    outermost op span of its thread) and perf_counter here."""
     ctx = open_op(op, force=force)
     if ctx is None:
         yield None

@@ -969,11 +969,11 @@ class NativeRpcClient:
         return hop, (msg.encode() if msg else None)
 
     @staticmethod
-    def _trace_finish(hop, service_id, method_id, t_wait, status) -> None:
+    def _trace_finish(hop, service_id, method_id, status) -> None:
         """The reply is in (the native reply carries no server stamps:
         issue and collect only)."""
         if hop is not None:
-            hop.collected(f"rpc.client.{service_id}.{method_id}", t_wait,
+            hop.collected(f"rpc.client.{service_id}.{method_id}",
                           code=status if status != int(Code.OK) else 0)
 
     def call_bulk(
@@ -1032,8 +1032,8 @@ class NativeRpcClient:
             if conn.lock.locked():
                 conn.lock.release()
         # one native call sends and receives: the whole of it is the wait
-        self._trace_finish(hop, service_id, method_id,
-                           hop.t0 if hop is not None else 0.0, status.value)
+        # (a hop that never said `waiting` waits from its start)
+        self._trace_finish(hop, service_id, method_id, status.value)
         return self._unmarshal_reply(status, rsp_ptr, rsp_len, bulk_ptr,
                                      bulk_off, bulk_len, has_bulk, msg_ptr,
                                      rsp_type)
@@ -1087,9 +1087,8 @@ class NativeRpcClient:
     def finish_call(self, pending):
         """Collect the reply of a start_call -> (rsp, segments|None)."""
         addr, conn, rsp_type, service_id, method_id, hop = pending
-        import time as _time
-
-        t_wait = _time.perf_counter() if hop is not None else 0.0
+        if hop is not None:
+            hop.waiting()
         status = ctypes.c_int64(0)
         rsp_ptr = ctypes.POINTER(ctypes.c_uint8)()
         rsp_len = ctypes.c_size_t(0)
@@ -1113,7 +1112,7 @@ class NativeRpcClient:
         finally:
             if conn.lock.locked():
                 conn.lock.release()
-        self._trace_finish(hop, service_id, method_id, t_wait, status.value)
+        self._trace_finish(hop, service_id, method_id, status.value)
         return self._unmarshal_reply(status, rsp_ptr, rsp_len, bulk_ptr,
                                      bulk_off, bulk_len, has_bulk, msg_ptr,
                                      rsp_type)

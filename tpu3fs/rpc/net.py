@@ -797,7 +797,8 @@ class RpcClient:
     def finish_call(self, pending):
         """Collect the reply of a start_call -> (rsp, reply_segments|None)."""
         addr, conn, pkt, rsp_type, hop = pending
-        t_wait = time.perf_counter() if hop is not None else 0.0
+        if hop is not None:
+            hop.waiting()
         try:
             try:
                 reply, reply_bulk = _recv_packet(conn.sock)
@@ -818,7 +819,7 @@ class RpcClient:
         finally:
             if conn.lock.locked():
                 conn.lock.release()
-        server, t_decode = None, 0.0
+        server = None
         if hop is not None:
             # the server's stamps share the server's monotonic clock, so
             # their differences are valid cross-process: the hop's
@@ -831,16 +832,17 @@ class RpcClient:
                     >= rts.server_receive > 0:
                 server = (rts.server_run_start - rts.server_receive,
                           rts.server_run_end - rts.server_run_start)
-            t_decode = time.perf_counter()
         op = f"rpc.client.{pkt.service_id}.{pkt.method_id}"
         if reply.status != int(Code.OK):
             if hop is not None:
-                hop.collected(op, t_wait, code=reply.status, server=server)
+                hop.collected(op, code=reply.status, server=server)
             raise FsError(Status(Code(reply.status), reply.message))
+        if hop is not None:
+            hop.decoding()
         reply.timestamps.client_done = time.monotonic()
         rsp = deserialize(reply.payload, rsp_type)
         if hop is not None:
-            hop.collected(op, t_wait, server=server, t_decode=t_decode)
+            hop.collected(op, server=server)
         return rsp, reply_bulk
 
     def close(self) -> None:

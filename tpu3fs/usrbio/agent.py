@@ -214,11 +214,11 @@ class UsrbioAgent:
                     continue
                 while state.running:
                     t0 = time.perf_counter()
-                    sqes = self._drain(state)
-                    if not sqes:
+                    limit = self._due(state)
+                    if not limit:
                         break
                     with self._batch_limiter:
-                        self._serve_drain(state, sqes, t0)
+                        self._serve_drain(state, limit, t0)
         except (ValueError, FsError):
             # ring mmap closed under us during deregistration (ValueError)
             # or the header tore (USRBIO_TORN_RING): exit quietly — the
@@ -228,34 +228,37 @@ class UsrbioAgent:
             if state.close_on_exit:
                 state.ring.close()
 
-    def _drain(self, state: _RingState) -> list:
-        """The ring's next drain by its ``io_depth``, or [] when none is
-        due yet (the worker goes back to the submit semaphore)."""
+    def _due(self, state: _RingState) -> int:
+        """How many SQEs the ring's next drain takes by its ``io_depth``,
+        or 0 when none is due yet (the worker goes back to the submit
+        semaphore)."""
         ring, depth = state.ring, state.io_depth
+        pending = ring.pending_sqes()
         if depth == 0:
-            return ring.drain_sqes()
+            return pending
         if depth > 0:
-            if ring.pending_sqes() < depth:
-                return []
-            return ring.drain_sqes(limit=depth)
-        if not ring.pending_sqes():
-            return []
+            return depth if pending >= depth else 0
+        if not pending:
+            return 0
         deadline = time.perf_counter() + BATCH_WAIT_S
         while state.running and ring.pending_sqes() < -depth:
             left = deadline - time.perf_counter()
             if left <= 0:
                 break
             ring.submit_sem.wait(timeout=left)
-        return ring.drain_sqes(limit=-depth)
+        return -depth
 
-    def _serve_drain(self, state: _RingState, sqes: list,
+    def _serve_drain(self, state: _RingState, limit: int,
                      t0: float) -> None:
         """One drain as one root op ``usrbio.ring_batch`` (``nbytes`` =
         bytes moved; stages ``drain``, ``stat``, ``read``, ``complete``):
         runs of reads as one batch each, writes one by one where the ring
-        has them."""
-        t1 = time.perf_counter()
+        has them. The op starts at ``t0``, where the worker began to look
+        for a drain; it is opened before the SQEs are unpacked, so its
+        ``cpu_us`` holds the unpack."""
         ctx = _spans.open_op(OP, live=False)
+        sqes = state.ring.drain_sqes(limit=limit)
+        t1 = time.perf_counter()
         if ctx is not None:
             ctx.ts = _spans.wall_of_perf(t0)
             _spans.add_span_at(ctx, OP, "drain", t0, t1 - t0,
@@ -270,7 +273,10 @@ class UsrbioAgent:
                 for done in self._serve_steps(state, sqes):
                     self._count(sqes=len(done),
                                 sqe_errors=sum(r < 0 for r, _ in done))
-                    with _spans.span(OP, "complete", nbytes=len(done)):
+                    # CPU-only (pack_into, one header write, one post): its
+                    # wall less its CPU is the queue for the interpreter
+                    with _spans.span(OP, "complete", nbytes=len(done),
+                                     cpu=True):
                         state.ring.push_cqes(done)
                     moved += sum(r for r, _ in done if r > 0)
         finally:

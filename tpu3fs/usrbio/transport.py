@@ -470,7 +470,8 @@ class RingClient:
         ``_abandoned`` and are reclaimed when the late CQE lands, never
         freed under an agent that may still be reading/writing them."""
         hop = pending.hop
-        t_wait = time.perf_counter() if hop is not None else 0.0
+        if hop is not None:
+            hop.waiting()
         try:
             result, stamps = self._await(pending.userdata,
                                          deadline_s=deadline_s)
@@ -479,7 +480,8 @@ class RingClient:
             raise
         self._arena.free(pending.req_off, pending.req_size)
         # what follows is this side's: the reply out of shm into objects
-        t_decode = time.perf_counter() if hop is not None else 0.0
+        if hop is not None:
+            hop.decoding()
         if result < 0:
             self._arena.free(pending.rsp_off, pending.rsp_cap)
             try:
@@ -500,8 +502,7 @@ class RingClient:
         server = unpack_stamps(stamps) if hop is not None else None
         if status != int(Code.OK):
             if hop is not None:
-                hop.collected("rpc.client.ring", t_wait, code=status,
-                              server=server, t_decode=t_decode)
+                hop.collected("rpc.client.ring", code=status, server=server)
             try:
                 code = Code(status)
             except ValueError:
@@ -512,8 +513,7 @@ class RingClient:
             raise FsError(Status(code, message))
         rsp = deserialize(payload, pending.rsp_type)
         if hop is not None:
-            hop.collected("rpc.client.ring", t_wait, server=server,
-                          t_decode=t_decode)
+            hop.collected("rpc.client.ring", server=server)
         return rsp, bulk
 
     def call(self, service_id: int, method_id: int, req, rsp_type, *,
