@@ -1462,7 +1462,7 @@ class TestCodecBuckets:
 
     def test_the_first_dispatch_builds_every_bucket(self, codec,
                                                     monkeypatch):
-        assert codec._encode_buckets() == [1, 2, 4, 8, 16]
+        assert codec._buckets(K + M) == [1, 2, 4, 8, 16]
         assert codec._encode_dev._cache_size() == 0
         codec.encode_batch(np.ones((3, K, 64), dtype=np.uint8))
         assert codec._encode_dev._cache_size() == 5
@@ -1477,14 +1477,15 @@ class TestCodecBuckets:
         assert built == 5
         out, sizes = self._encode(codec, data, monkeypatch)
         assert codec._encode_dev._cache_size() == built
-        assert all(s in codec._encode_buckets() for s in sizes)
+        assert all(s in codec._buckets(K + M) for s in sizes)
         assert sum(sizes) < 2 * b + 1 and len(sizes) == -(-b // self.STEP)
         assert np.array_equal(out[:, K:], codec.rs.encode_host(data))
         assert np.array_equal(out[:, :K], data)
 
     def test_only_the_encode_is_held_to_sixteen(self, codec):
-        """The rebuild's reconstruct and the CRC keep the bound by bytes:
-        their dispatches return a fraction of what an encode's does."""
+        """The CRC keeps the bound by bytes: its dispatches return a
+        fraction of what an encode's does (the reconstruct is held to
+        sixteen too: TestDecodeBuckets)."""
         assert codec._device_step(K + 1) > self.STEP
         assert codec._device_step(1) > self.STEP
         big = np.random.default_rng(7).integers(
@@ -1514,4 +1515,82 @@ class TestCodecBuckets:
         assert crcs.shape == (3, K + M)
         assert codec._prepared
         assert codec._encode_dev._cache_size() == \
-            len(codec._encode_buckets())
+            len(codec._buckets(K + M))
+
+
+class TestDecodeBuckets:
+    """A device decode goes out in buckets of at most sixteen stripes, and
+    the first device decode of a lost count builds every one of them, so
+    none is built on a later request's path. The CPU backend stands in for
+    the device (apply_operand takes its einsum form)."""
+
+    STEP = 16
+    KD, MD, SD = 4, 2, 64
+
+    @pytest.fixture(scope="class")
+    def codec(self):
+        from tpu3fs.ops import stripe
+
+        codec = stripe.StripeCodec(self.KD, self.MD, self.SD)
+        codec._use_host = lambda: False
+        return codec
+
+    def _stripes(self, b, seed):
+        """(B, k+m, S) shards, encoded by the host kernels."""
+        data = np.random.default_rng(seed).integers(
+            0, 256, (b, self.KD, self.SD), dtype=np.uint8)
+        parity = get_codec(self.KD, self.MD, self.SD).rs.encode_host(data)
+        return np.concatenate([data, parity], axis=1)
+
+    def test_the_first_decode_of_a_lost_count_builds_every_bucket(
+            self, codec):
+        assert codec._buckets(self.KD + 1) == [1, 2, 4, 8, 16]
+        assert codec._decode_dev._cache_size() == 0
+        shards = self._stripes(1, 0)
+        present, lost = (0, 2, 3, 4), (1,)
+        codec.reconstruct_batch(present, lost, shards[:, list(present)])
+        assert codec._decode_dev._cache_size() == 5
+        # a second lost count is a program set of its own, built once
+        present, lost = (2, 3, 4, 5), (0, 1)
+        for _ in range(2):
+            codec.reconstruct_batch(present, lost, shards[:, list(present)])
+        assert codec._decode_dev._cache_size() == 10
+
+    @pytest.mark.parametrize("b", range(1, 41))
+    def test_a_decode_of_any_size_builds_nothing_new(self, codec, b,
+                                                     monkeypatch):
+        present, lost = (0, 1, 3, 5), (2, 4)
+        shards = self._stripes(b, b)
+        surv = shards[:, list(present)]
+        codec.reconstruct_batch(present, lost, surv[:1])   # prepares
+        built = codec._decode_dev._cache_size()
+        sizes = []
+        inner = codec._decode_dev
+
+        def counted(matrix, part):
+            sizes.append(part.shape[0])
+            return inner(matrix, part)
+
+        monkeypatch.setattr(codec, "_decode_dev", counted)
+        got = codec.reconstruct_batch(present, lost, surv)
+        monkeypatch.undo()
+        assert codec._decode_dev._cache_size() == built
+        assert all(s in codec._buckets(self.KD + len(lost)) for s in sizes)
+        assert len(sizes) == -(-b // self.STEP)
+        host = get_codec(self.KD, self.MD, self.SD)
+        assert host._use_host()
+        assert np.array_equal(got, host.reconstruct_batch(present, lost,
+                                                          surv))
+        assert np.array_equal(got, shards[:, list(lost)])
+
+    def test_a_host_codec_builds_nothing(self):
+        from tpu3fs.ops import stripe
+
+        codec = stripe.StripeCodec(self.KD, self.MD, self.SD)
+        shards = self._stripes(20, 3)
+        present, lost = (0, 2, 3, 4), (1,)
+        got = codec.reconstruct_batch(present, lost,
+                                      shards[:, list(present)])
+        assert np.array_equal(got, shards[:, [1]])
+        assert codec._decode_dev._cache_size() == 0
+        assert not codec._prepared

@@ -9,6 +9,7 @@ back)."""
 
 import threading
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -16,10 +17,10 @@ import pytest
 from perfbench.lib import reference as ref
 from perfbench.lib import reference_decode as refdec
 from tests.rpc_cluster import RpcCluster
-from tpu3fs.client.storage_client import RetryOptions
+from tpu3fs.client.storage_client import RetryOptions, StorageClient
 from tpu3fs.mgmtd.types import PublicTargetState
 from tpu3fs.ops.stripe import get_codec
-from tpu3fs.storage.craq import ReadReq, ShardWriteReq
+from tpu3fs.storage.craq import ReadReply, ReadReq, ShardWriteReq
 from tpu3fs.storage.types import ChunkId
 from tpu3fs.utils.result import Code
 
@@ -565,3 +566,132 @@ def test_a_batched_sweep_rides_out_the_detection(cluster):
     cluster.stop_node(VICTIM)
     with pytest.raises(FsError):
         impatient.query_last_chunks(chain, list(want))
+
+
+# -- a batch's degraded stripes decode together, one call a loss pattern --
+
+def _degraded_stripe(k, m, S, seed, *, L=None, offset=0, length=None,
+                     lost=(1,), tag=True, mixed=False, missing=False):
+    """(spec, shard replies, the stripe's bytes) for one stripe that went
+    degraded: its shards stored trimmed as a put leaves them (views, as a
+    transport hands them over), the ``lost`` ones not answering. ``tag``:
+    replies carry the stored logical length; ``mixed``: half the shards
+    are at a newer version, so no version holds k; ``missing``: the
+    stripe is absent on every shard."""
+    L = k * S if L is None else L
+    length = L - offset if length is None else length
+    data = np.random.default_rng([31, seed]).integers(
+        1, 256, L, dtype=np.uint8)
+    padded = np.zeros((1, k, S), dtype=np.uint8)
+    padded.reshape(-1)[:L] = data
+    parity = get_codec(k, m, S).rs.encode_host(padded)[0]
+    replies = {}
+    for j in range(k + m):
+        stored = (data[j * S:min((j + 1) * S, L)] if j < k
+                  else parity[j - k]).tobytes()
+        if missing:
+            replies[j] = ReadReply(Code.CHUNK_NOT_FOUND)
+        elif j in lost:
+            replies[j] = ReadReply(Code.TARGET_OFFLINE)
+        else:
+            ver = 7 + (mixed and j >= (k + m) // 2)
+            replies[j] = ReadReply(Code.OK, data=memoryview(stored),
+                                   commit_ver=ver,
+                                   logical_len=L if tag else 0)
+    j0 = offset // S
+    j1 = (offset + length - 1) // S + 1
+    spec = {"chain": SimpleNamespace(target_of_shard=lambda j: None),
+            "k": k, "m": m, "S": S, "j0": j0, "j1": j1, "offset": offset,
+            "length": length, "wire": {}}
+    return spec, replies, data.tobytes()
+
+
+def _cases(k, m, S):
+    """Each case: keyword sets for _degraded_stripe, one a stripe."""
+    full = [dict(lost=(1,)), dict(lost=(1,))]
+    return {
+        "shared_pattern": [dict(lost=(1,)) for _ in range(5)],
+        "two_patterns": full + [dict(lost=(0,)), dict(lost=(0,))],
+        "no_lost_in_range": full + [dict(lost=(k - 1,), length=S)],
+        "partial_ranges": full + [
+            dict(offset=S // 2 + 3, length=2 * S),
+            dict(offset=S + 1, length=S - 2),
+            dict(offset=3, length=k * S - 5, lost=(0,))],
+        "short_last_shard": [
+            dict(L=(k - 1) * S + 17, lost=(k - 1,), tag=False),
+            dict(L=(k - 1) * S + 17, lost=(0,), tag=False),
+            dict(L=S + 5, lost=(0,), tag=False)],
+        "mixed_versions": full + [dict(mixed=True)],
+        "every_shard_missing": full + [dict(missing=True)],
+    }
+
+
+CODECS = [(K, M, 512), (3, 1, 64)]
+LADDER = ReadReply(Code.CHUNK_NOT_COMMIT)
+
+
+def _through_the_batch(stripes, monkeypatch):
+    """What _degraded_round answers for these stripes, nothing left to
+    fetch; a stripe with no decodable version goes down the ladder."""
+    client = StorageClient("t", lambda: None, lambda *a: None)
+    ladder = []
+    monkeypatch.setattr(client, "_issue_wire_reads", lambda wire: [])
+    monkeypatch.setattr(client, "read_stripe",
+                        lambda *a, **kw: ladder.append(a) or LADDER)
+    specs = {i: spec for i, (spec, _, _) in enumerate(stripes)}
+    have = {i: dict(rs) for i, (_, rs, _) in enumerate(stripes)}
+    reqs = [ReadReq(1, ChunkId(90, i), spec["offset"], spec["length"],
+                    chunk_size=spec["k"] * spec["S"])
+            for i, spec in specs.items()]
+    replies = [None] * len(stripes)
+    client._degraded_round(reqs, replies, specs, have, None,
+                           list(specs))
+    return client, replies, ladder
+
+
+@pytest.mark.parametrize("case", list(_cases(1, 1, 1)))
+@pytest.mark.parametrize("k,m,S", CODECS, ids=lambda v: str(v))
+def test_a_batch_decodes_as_the_stripes_one_by_one(k, m, S, case,
+                                                   monkeypatch):
+    """Same bytes, commit_ver and logical_len as the single-stripe path
+    (and as the stripe holds), on the host kernels."""
+    stripes = [_degraded_stripe(k, m, S, n, **kw)
+               for n, kw in enumerate(_cases(k, m, S)[case])]
+    client, batch, ladder = _through_the_batch(stripes, monkeypatch)
+    for (spec, rs, data), got in zip(stripes, batch):
+        one = client._stripe_degraded(spec, rs) or LADDER
+        assert (got.code, bytes(got.data), got.commit_ver,
+                got.logical_len) == (one.code, bytes(one.data),
+                                     one.commit_ver, one.logical_len)
+        if got is LADDER or not got.ok:
+            continue
+        lo = spec["offset"]
+        assert bytes(got.data) == data[lo:lo + spec["length"]]
+        assert got.commit_ver == 7
+        if (spec["j0"], spec["j1"]) == (0, k) or rs[k].logical_len:
+            assert got.logical_len == len(data)
+    assert len(ladder) == (case == "mixed_versions")
+    ok = sum(r is not LADDER and r.ok for r in batch)
+    assert client._ec_degraded._value == ok
+
+
+def test_a_batch_makes_one_decode_a_loss_pattern(monkeypatch):
+    """Three stripes lose shard 1, two lose shard 0, one loses nothing in
+    its range: two reconstruct_batch calls, of three and of two."""
+    from tpu3fs.ops.stripe import StripeCodec
+
+    calls = []
+    inner = StripeCodec.reconstruct_batch
+
+    def counted(self, present_idx, lost_idx, present):
+        calls.append((tuple(lost_idx), present.shape[0]))
+        return inner(self, present_idx, lost_idx, present)
+
+    monkeypatch.setattr(StripeCodec, "reconstruct_batch", counted)
+    stripes = [_degraded_stripe(K, M, 512, n, lost=lost, length=length)
+               for n, (lost, length) in enumerate(
+                   [((1,), None)] * 3 + [((0,), None)] * 2
+                   + [((K - 1,), 512)])]
+    _client, batch, _ = _through_the_batch(stripes, monkeypatch)
+    assert all(r.ok for r in batch)
+    assert sorted(calls) == [((0,), 2), ((1,), 3)]

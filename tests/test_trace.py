@@ -1494,13 +1494,15 @@ class TestNodeLossSpans:
             recon = [r for r in tree.rows if r["op"] == "codec.reconstruct"
                      and not r["stage"] and r["span_id"] in {
                          k["span_id"] for k in _below(tree, stage)}]
-            # one stripe a dispatch: B = 1, k survivors of S bytes
-            assert len(recon) == stripes
-            for r in recon:
-                assert r["nbytes"] == self.K * self.S and r["code"] == 0
-                kids = {k["stage"]: k for k in tree.children[r["span_id"]]}
-                assert set(kids) == {"dispatch", "fetch"}
-                assert kids["dispatch"]["nbytes"] == 1
+            # one decode a loss pattern: both stripes of the batch lose
+            # shard 3, so ONE dispatch carries them, k survivors of S
+            # bytes a stripe
+            (r,) = recon
+            assert r["nbytes"] == stripes * self.K * self.S
+            assert r["code"] == 0
+            kids = {k["stage"]: k for k in tree.children[r["span_id"]]}
+            assert set(kids) == {"dispatch", "fetch"}
+            assert kids["dispatch"]["nbytes"] == stripes
 
     def test_a_put_s_await_routing_counts_the_shards_it_waited_for(
             self, lossy):

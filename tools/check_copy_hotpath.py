@@ -68,7 +68,8 @@ HOT_PATH: List[Tuple[str, List[str]]] = [
       # EC data plane: batched shard fetch, clean/degraded stripe
       # assembly (the degraded fill), delta-parity sub-stripe RMW
       "_issue_wire_reads", "_plan_stripe_read", "_stripe_clean",
-      "_stripe_degraded", "_finish_stripe_reads", "_write_stripe_rmw",
+      "_stripe_degraded", "_degraded_plan", "_decode_stripes",
+      "_finish_stripe_reads", "_write_stripe_rmw",
       # chain-encode planning: raw data shards go out as VIEWS of the
       # caller's stripe bytes (the whole client-CPU offload story)
       "_write_stripes_chain"]),

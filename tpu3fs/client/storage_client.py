@@ -1859,34 +1859,27 @@ class StorageClient:
 
     @staticmethod
     def _stripe_logical(spec: dict, replies: Dict[int, ReadReply],
-                        group: Optional[Dict[int, bytes]] = None,
-                        parts: Optional[Dict[int, bytes]] = None) -> int:
+                        rebuilt: Optional[dict] = None) -> int:
         """Logical (pre-padding) stripe length: exact from any shard's
         stored aux tag (ShardWriteReq.logical_len persisted by the
         server); full-cover reads without one infer it from stored shard
-        extents (decoded shards via trim_rebuilt_shard)."""
+        extents (``rebuilt``: each decoded covering shard -> its padded
+        row, via trim_rebuilt_shard)."""
         k, S, j0, j1 = spec["k"], spec["S"], spec["j0"], spec["j1"]
         logical = max(
             (r.logical_len for r in replies.values()
              if r is not None and r.ok and r.logical_len), default=0)
         if logical == 0 and (j0, j1) == (0, k):
-            if group is None:
-                logical = max(
-                    (j * S + len(replies[j].data) for j in range(j0, j1)
-                     if len(replies[j].data) > 0), default=0)
-            else:
-                from tpu3fs.ops.stripe import trim_rebuilt_shard
+            from tpu3fs.ops.stripe import trim_rebuilt_shard
 
-                lens = {j: len(group[j]) for j in group if j < k}
-                logical = max(
-                    (j * S + len(group[j]) for j in group
-                     if j < k and len(group[j]) > 0), default=0)
-                for j in range(j0, j1):
-                    if j in group or j >= k:
-                        continue
-                    trimmed = trim_rebuilt_shard(parts[j], j, lens, k, S)
-                    if len(trimmed) > 0:
-                        logical = max(logical, j * S + len(trimmed))
+            lens = {j: len(r.data) for j, r in replies.items()
+                    if r is not None and r.ok and j < k}
+            logical = max((j * S + n for j, n in lens.items() if n > 0),
+                          default=0)
+            for j, row in (rebuilt or {}).items():
+                trimmed = trim_rebuilt_shard(row.tobytes(), j, lens, k, S)
+                if len(trimmed) > 0:
+                    logical = max(logical, j * S + len(trimmed))
         return logical
 
     def _stripe_clean(self, spec: dict,
@@ -1913,24 +1906,34 @@ class StorageClient:
 
     def _stripe_degraded(self, spec: dict,
                          replies: Dict[int, ReadReply]) -> Optional[ReadReply]:
-        """Degraded decode over ALL fetched shards: group by committed
-        version, reconstruct the covering shards from the newest version
-        holding a k-quorum. CHUNK_NOT_FOUND when every shard is missing;
-        None when no version is decodable yet (mixed versions mid-write —
-        the caller's ladder retries)."""
-        from tpu3fs.ops.stripe import get_codec
+        """Degraded decode of ONE stripe over all its fetched shards (the
+        single-op ladder's): _degraded_plan, then _decode_stripes as a
+        group of one. batch_read's _degraded_round runs the same two over
+        every degraded stripe of a batch, one decode a loss pattern, so
+        the two paths cannot drift apart. CHUNK_NOT_FOUND when every shard
+        is missing; None when no version is decodable yet (mixed versions
+        mid-write — the caller's ladder retries)."""
+        plan = self._degraded_plan(spec, replies)
+        if not isinstance(plan, tuple):
+            return plan
+        return self._decode_stripes([(spec, plan)])[0]
 
-        k, m, S = spec["k"], spec["m"], spec["S"]
-        j0, j1 = spec["j0"], spec["j1"]
-        by_ver: Dict[int, Dict[int, bytes]] = defaultdict(dict)
+    @staticmethod
+    def _degraded_plan(spec: dict, replies: Dict[int, ReadReply]):
+        """Group a degraded stripe's replies by committed version and take
+        the newest version holding a k-quorum -> (ver, {shard: its reply
+        at ver}, present = the first k of those shards, lost = the
+        covering shards outside them), both tuples. A CHUNK_NOT_FOUND
+        reply when every shard is missing; None when no version is
+        decodable."""
+        k, j0, j1 = spec["k"], spec["j0"], spec["j1"]
+        by_ver: Dict[int, Dict[int, ReadReply]] = defaultdict(dict)
         all_missing = True
         for j, r in replies.items():
             if r is None:
                 continue
             if r.ok:
-                # the decode path pads/joins/ndarray-stacks shard
-                # payloads: materialize any zero-copy transport view once
-                by_ver[r.commit_ver][j] = bytes(r.data)  # copy-ok: decode input
+                by_ver[r.commit_ver][j] = r
                 all_missing = False
             elif r.code != Code.CHUNK_NOT_FOUND:
                 all_missing = False
@@ -1939,42 +1942,59 @@ class StorageClient:
         usable = [v for v, g in by_ver.items() if len(g) >= k]
         if not usable:
             return None
-        import numpy as np
-
         ver = max(usable)
         group = by_ver[ver]
-        present = sorted(group)[:k]
-        lost = [j for j in range(j0, j1) if j not in present]
-        surv = np.stack([
-            np.frombuffer(
-                group[j].ljust(S, b"\x00"), dtype=np.uint8)
-            for j in present
-        ])
-        codec = get_codec(k, m, S)
-        parts: Dict[int, bytes] = {
-            j: group[j].ljust(S, b"\x00") for j in present
-            if j0 <= j < j1
-        }
+        present = tuple(sorted(group)[:k])
+        lost = tuple(j for j in range(j0, j1) if j not in present)
+        return ver, group, present, lost
+
+    def _decode_stripes(self, items: List[tuple]) -> List[ReadReply]:
+        """Decode and assemble degraded stripes of ONE loss pattern:
+        ``items`` = [(spec, _degraded_plan's tuple)], every plan with the
+        same (k, m, S, present, lost). Each survivor is copied once, into
+        its row of the batch's (B, k, S) array (a short shard leaves
+        zeros behind it); ONE reconstruct_batch rebuilds the lost covering
+        shards of them all (none when no covering shard is lost); and
+        each stripe's range is assembled once, from those rows."""
+        import numpy as np
+
+        from tpu3fs.ops.stripe import get_codec
+
+        spec0, (_, _, present, lost) = items[0]
+        k, S = spec0["k"], spec0["S"]
+        surv = np.zeros((len(items), k, S), dtype=np.uint8)
+        for b, (_, (_, group, _, _)) in enumerate(items):
+            for row, j in enumerate(present):
+                view = np.frombuffer(group[j].data, dtype=np.uint8)
+                surv[b, row, :view.size] = view
+        rebuilt = None
         if lost:
-            rebuilt = codec.reconstruct_batch(present, lost, surv[None])[0]
-            for i, j in enumerate(lost):
-                parts[j] = rebuilt[i].tobytes()
-        whole = b"".join(  # copy-ok: range assembly of decoded shards
-            parts[j] for j in range(j0, j1))
-        lo = spec["offset"] - j0 * S
-        ok_replies = {j: r for j, r in replies.items()
-                      if r is not None and r.ok and r.commit_ver == ver}
-        return ReadReply(
-            Code.OK, data=whole[lo : lo + spec["length"]], commit_ver=ver,
-            logical_len=self._stripe_logical(spec, ok_replies, group, parts))
+            rebuilt = get_codec(k, spec0["m"], S).reconstruct_batch(
+                present, lost, surv)
+        slot = {j: row for row, j in enumerate(present)}
+        out: List[ReadReply] = []
+        for b, (spec, (ver, group, _, _)) in enumerate(items):
+            lo, hi = spec["offset"], spec["offset"] + spec["length"]
+            decoded = {j: rebuilt[b, i] for i, j in enumerate(lost)}
+            segs = []
+            for j in range(spec["j0"], spec["j1"]):
+                row = surv[b, slot[j]] if j in slot else decoded[j]
+                segs.append(row[max(lo - j * S, 0):min(hi - j * S, S)])
+            out.append(ReadReply(
+                Code.OK,
+                data=b"".join(segs),  # copy-ok: the range, assembled once
+                commit_ver=ver,
+                logical_len=self._stripe_logical(spec, group, decoded)))
+        return out
 
     def _finish_stripe_reads(self, reqs, replies, ec_specs,
                              shard_replies, routing) -> None:
         """Resolve every EC request of a batch from its first-round shard
         replies; stripes that did not assemble cleanly go DEGRADED
         together — the missing/failed shards of ALL of them fetch in one
-        more batched round (any k of k+m survive), decode inline, and the
-        detour is recorded (ec.degraded_read / ec.degraded_read_ms)."""
+        more batched round (any k of k+m survive), decode one dispatch a
+        loss pattern, and the detour is recorded per stripe
+        (ec.degraded_read / ec.degraded_read_ms)."""
         degraded: List[int] = []
         for i, spec in ec_specs.items():
             out = self._stripe_clean(spec, shard_replies[i])
@@ -2020,9 +2040,22 @@ class StorageClient:
         for (i, j), r in zip(tags, self._issue_wire_reads(wire)):
             shard_replies[i][j] = r
         dt_ms = (time.monotonic() - t0) * 1000.0
+        # one decode a loss pattern, over every stripe that shares it
+        outs: Dict[int, object] = {}
+        groups: Dict[tuple, List[int]] = defaultdict(list)
+        for i in degraded:
+            spec = ec_specs[i]
+            plan = outs[i] = self._degraded_plan(spec, shard_replies[i])
+            if isinstance(plan, tuple):
+                _ver, _group, present, lost = plan
+                groups[(spec["k"], spec["m"], spec["S"], present,
+                        lost)].append(i)
+        for idxs in groups.values():
+            outs.update(zip(idxs, self._decode_stripes(
+                [(ec_specs[i], outs[i]) for i in idxs])))
         decoded = 0
         for i in degraded:
-            out = self._stripe_degraded(ec_specs[i], shard_replies[i])
+            out = outs[i]
             if out is None:
                 # no decodable version in this snapshot (write/rebuild in
                 # flight): the single-op ladder retries with backoff

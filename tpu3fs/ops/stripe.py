@@ -58,14 +58,14 @@ def _bucket(b: int) -> int:
 # planes, 8x the bytes — so the device branches run a large batch as a
 # sequence of dispatches of at most this size.
 DEVICE_BATCH_BYTES = 128 << 20
-# ... and the most stripes of an ENCODE dispatch. Every power of two up
-# to it is a program the codec builds before it serves
-# (StripeCodec._prepare): 5 a codec. And on a v5e the round trip of one
-# encode stops scaling past 16 stripes of RS(12,4) S=87552 (PERF.md, PR
-# 31: 0.9 ms a stripe at 16, 3.6 at 32, 3.9 at 64 — the 45 MB and more
-# coming back), so a large batch goes faster as dispatches of 16. The
-# reconstruct and the CRC return a fraction of that and keep the bound
-# by bytes alone.
+# ... and the most stripes of an encode or a reconstruct dispatch. Every
+# power of two up to it is a program the codec builds before it serves
+# (StripeCodec._prepare): 5 for the encode, and 5 for each count of lost
+# shards the codec decodes. On a v5e the round trip of one encode stops
+# scaling past 16 stripes of RS(12,4) S=87552 (PERF.md, "The codec's
+# buckets": 0.9 ms a stripe at 16, 3.6 at 32, 3.9 at 64 — the 45 MB and
+# more coming back), so a large batch goes faster as dispatches of 16.
+# The CRC returns a fraction of that and keeps the bound by bytes alone.
 DEVICE_BATCH_ITEMS = 16
 
 
@@ -104,7 +104,8 @@ class StripeCodec:
         self._encode_dev = jax.jit(self._encode_device)
         self._crc_dev = jax.jit(self._crc.compute)
         self._decode_dev = jax.jit(self._decode_device)
-        self._prepared = False      # every encode bucket is built
+        # "encode", and each lost count: every bucket of it is built
+        self._prepared: set = set()
         self._prepare_lock = threading.Lock()
 
     def _use_host(self) -> bool:
@@ -136,30 +137,32 @@ class StripeCodec:
         n = max(1, DEVICE_BATCH_BYTES // (rows_per_item * self.shard_size))
         return 1 << (n.bit_length() - 1)
 
-    def _encode_buckets(self) -> List[int]:
-        """Every batch size an encode dispatch can have: the powers of two
-        up to DEVICE_BATCH_ITEMS, or to _device_step where that is less."""
-        step = min(self._device_step(self.k + self.m), DEVICE_BATCH_ITEMS)
+    def _buckets(self, rows_per_item: int) -> List[int]:
+        """Every batch size an encode or reconstruct dispatch can have
+        (``rows_per_item``: k+m, or k + lost): the powers of two up to
+        DEVICE_BATCH_ITEMS, or to _device_step where that is less."""
+        step = min(self._device_step(rows_per_item), DEVICE_BATCH_ITEMS)
         return [1 << i for i in range(step.bit_length())]
 
-    def _prepare(self) -> None:
-        """Build the encode program of every bucket, once a codec: XLA
-        compiles a program the first time it meets a shape, and which
-        batch sizes a put will bring is the traffic's to say (a KVCache
-        suffix of 1 to 16 blocks, a drain of the write-back tier, a
-        document of 128), so whichever encode comes first pays for all of
-        them — at start-up, in practice — and none compiles on a later
-        request's path. Each is run once on zeros: that is what fills
-        jit's own table."""
-        if self._prepared:
+    def _prepare(self, key, fn, rows_per_item: int) -> None:
+        """Build ``fn``'s program of every bucket, once a ``key`` ("encode",
+        or a decode's lost count): XLA compiles a program the first time
+        it meets a shape, and which batch sizes a put or a degraded read
+        will bring is the traffic's to say (a KVCache suffix of 1 to 16
+        blocks, a drain of the write-back tier, a document of 128, the
+        degraded stripes of a load), so whichever call comes first pays
+        for all of them — at start-up, in practice — and none compiles on
+        a later request's path. Each is run once on zeros: that is what
+        fills jit's own table."""
+        if key in self._prepared:
             return
         with self._prepare_lock:
-            if self._prepared:
+            if key in self._prepared:
                 return
-            for bp in self._encode_buckets():
-                jax.block_until_ready(self._encode_dev(np.zeros(
+            for bp in self._buckets(rows_per_item):
+                jax.block_until_ready(fn(np.zeros(
                     (bp, self.k, self.shard_size), dtype=np.uint8)))
-            self._prepared = True
+            self._prepared.add(key)
 
     def _device_map(self, fn, items: np.ndarray, step: int,
                     op: str = "codec.encode"):
@@ -232,9 +235,10 @@ class StripeCodec:
         with _spans.root_span("codec.encode", nbytes=b * k * s):
             shards = np.empty((b, k + self.m, s), dtype=np.uint8)
             crcs = np.empty((b, k + self.m), dtype=np.uint32)
-            self._prepare()
+            rows = self.k + self.m
+            self._prepare("encode", self._encode_dev, rows)
             for lo, n, (out_s, out_c) in self._device_map(
-                    self._encode_dev, data, self._encode_buckets()[-1]):
+                    self._encode_dev, data, self._buckets(rows)[-1]):
                 shards[lo:lo + n] = out_s[:n]
                 crcs[lo:lo + n] = out_c[:n]
         return shards, crcs
@@ -305,19 +309,28 @@ class StripeCodec:
         present: np.ndarray,
     ) -> np.ndarray:
         """(B, k, S) survivors at present_idx -> (B, len(lost), S) rebuilt.
-        The single-chip serving path; the pod-scale variant is
-        tpu3fs.parallel.rebuild.rebuild_lost_shard over a mesh (the same
-        RSCode decode matrix underneath)."""
+        The single-chip serving path — a batched read hands it every
+        degraded stripe of one loss pattern at once; the pod-scale variant
+        is tpu3fs.parallel.rebuild.rebuild_lost_shard over a mesh (the
+        same RSCode decode matrix underneath). On the device, B goes out
+        in bucketed dispatches of at most DEVICE_BATCH_ITEMS stripes, and
+        the first call of a lost count builds every bucket of it
+        (_prepare): host-mode codecs compile nothing."""
         if self._use_host():
             return self.rs.reconstruct_host(present_idx, lost_idx, present)
         b = present.shape[0]
         matrix = self.rs.decode_operand(present_idx, lost_idx)
         out = np.empty((b, len(lost_idx), self.shard_size), dtype=np.uint8)
+        rows = self.k + len(lost_idx)
+
+        def decode(part):
+            return self._decode_dev(matrix, part)
+
         with _spans.root_span("codec.reconstruct",
                               nbytes=b * self.k * self.shard_size):
+            self._prepare(len(lost_idx), decode, rows)
             for lo, n, rebuilt in self._device_map(
-                    lambda part: self._decode_dev(matrix, part), present,
-                    self._device_step(self.k + len(lost_idx)),
+                    decode, present, self._buckets(rows)[-1],
                     op="codec.reconstruct"):
                 out[lo:lo + n] = rebuilt[:n]
         return out
@@ -325,10 +338,12 @@ class StripeCodec:
     def _decode_device(self, matrix, present):
         """(8*lost, 8k) decode matrix x (Bp, k, S) survivors -> (Bp, lost,
         S): ONE jitted program per (lost count, batch bucket), named
-        ``decode_device`` as the encode's is ``encode_device``. The matrix
-        is an OPERAND (RSCode.decode_operand), so every loss pattern of one
-        shape shares the program; the kernel's lane padding and the slice
-        back to S are inside it, not dispatches of their own."""
+        ``decode_device`` as the encode's is ``encode_device``; the buckets
+        of a lost count are the powers of two up to DEVICE_BATCH_ITEMS,
+        all built by its first call. The matrix is an OPERAND
+        (RSCode.decode_operand), so every loss pattern of one shape shares
+        the program; the kernel's lane padding and the slice back to S are
+        inside it, not dispatches of their own."""
         return self.rs.apply_operand(matrix, present)
 
     def crc_batch(self, shards: np.ndarray) -> np.ndarray:
