@@ -4,7 +4,7 @@ The tier-1 command collects ``tests/`` only, so this module brings in the
 tests (and fixtures) of every module under ``perfbench/tests`` except
 ``test_cells.py``: the contract of the result line, the plain reference,
 the span, CPU and counter readers, the proxies, ``Cluster.stop``, the
-small-I/O cell's driver on an in-process fabric. A change to
+small-I/O and storage-bench cells' drivers on an in-process fabric. A change to
 the program that breaks what the benchmark reads of it then fails here,
 on the CPU, before a chip run does. ``test_cells.py`` stays out: every
 case of it boots a whole cluster and runs a window (minutes);
@@ -35,6 +35,7 @@ MODULES = (
     "test_readers",
     "test_rebuild_pieces",
     "test_reference",
+    "test_sb_pieces",
     "test_span_cpu",
     "test_span_ms",
     "test_trace",

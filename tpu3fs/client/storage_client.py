@@ -34,7 +34,7 @@ from tpu3fs.storage.craq import (
     UpdateReply,
     WriteReq,
 )
-from tpu3fs.storage.types import ChunkId, SpaceInfo
+from tpu3fs.storage.types import Checksum, ChunkId, SpaceInfo
 from tpu3fs.utils.result import Code, FsError, Status
 
 
@@ -207,6 +207,9 @@ class StorageClient:
         # ops a batched read handed to the single-op ladder (read_chunk):
         # their batched reply was not OK. 0 in a healthy cluster
         self._read_ladder_ops = CounterRecorder("client.read_ladder_ops")
+        # stripe rounds an injected fault (the cluster fault plane) met
+        # whose stripe was then retried to success: 0 without a fault plane
+        self._injected_retried = CounterRecorder("client.injected_retried")
         self._ec_encode_gibps = ValueRecorder("ec.encode_gibps")
         # pipelined chain encode (TPU3FS_EC_CHAIN_ENCODE=1): stripes
         # staged through the chain relay vs stripes that fell back to the
@@ -662,18 +665,26 @@ class StorageClient:
         return last
 
     def batch_read(
-        self, reqs: List[ReadReq]
+        self, reqs: List[ReadReq], *, with_checksum: bool = False
     ) -> List[ReadReply]:
         """Traced entry: see _batch_read_op. The root span head-samples a
         trace when none is active (tpu3fs/analytics/spans.py); sampled or
-        slow ops capture their whole cross-process stage breakdown."""
+        slow ops capture their whole cross-process stage breakdown.
+
+        ``with_checksum``: an EC range reply carries the CRC32C of its bytes
+        in ``checksum`` (``length`` = ``len(data)``), combined from the
+        servers' shard checksums (_range_checksum); a CR reply carries the
+        server's own either way. A range that does not start on a shard
+        boundary, or ends inside a shard's stored bytes, carries none
+        (``Checksum()``, length 0). Off, an EC reply carries none and the
+        read does no work for it."""
         from tpu3fs.analytics import spans as _spans
 
         with _spans.root_span("client.batch_read"), self._op_scope():
-            return self._batch_read_op(reqs)
+            return self._batch_read_op(reqs, with_checksum)
 
     def _batch_read_op(
-        self, reqs: List[ReadReq]
+        self, reqs: List[ReadReq], with_checksum: bool = False
     ) -> List[ReadReply]:
         """Group per node (ref groupOpsByNodeId) then issue node batches.
 
@@ -751,7 +762,8 @@ class StorageClient:
                     shard_replies[tag[1]][tag[2]] = r
             if ec_specs:
                 self._finish_stripe_reads(
-                    reqs, replies, ec_specs, shard_replies, routing)
+                    reqs, replies, ec_specs, shard_replies, routing,
+                    with_checksum)
             # fall back to the single-op retry ladder for failures (EC replies
             # already went through the degraded decode / read_stripe ladder)
             for i, r in enumerate(replies):
@@ -1093,6 +1105,7 @@ class StorageClient:
         done: set = set()     # shard indices STAGED at `ver`
         landed: set = set()   # shard indices COMMITTED at `ver`
         attempt = 0           # attempts spent; a wait for routing is none
+        injected = False      # an injected fault refused a shard
         t_first = time.monotonic()
         while attempt <= self._retry.max_retries:
             if attempt and self._deadline_expired():
@@ -1162,9 +1175,11 @@ class StorageClient:
                             self._ec_next_ver(max(reply.commit_ver, ver)))
                     elif Status(reply.code).retryable() or reply.code in (
                         Code.RPC_PEER_CLOSED, Code.RPC_CONNECT_FAILED,
+                        Code.FAULT_INJECTION,   # the fault plane's: transient
                     ):
                         last = reply
                         unreachable += reply.code in UNREACHABLE_CODES
+                        injected |= reply.code == Code.FAULT_INJECTION
                     else:
                         hard = reply
             if hard is not None:
@@ -1216,6 +1231,8 @@ class StorageClient:
                             r2 = UpdateReply(e.code, message=e.status.message)
                         if r2.ok:
                             landed.add(j)
+                        elif r2.code == Code.FAULT_INJECTION:
+                            injected = True   # the next attempt re-commits
                         elif r2.code in (Code.CHUNK_MISSING_UPDATE,
                                          Code.CHUNK_NOT_FOUND):
                             # our pending was displaced (e.g. by a concurrent
@@ -1227,6 +1244,8 @@ class StorageClient:
                 if landed >= full:
                     if len(full) < k + m:
                         self._ec_degraded_write.add()
+                    if injected:
+                        self._injected_retried.add()
                     return UpdateReply(Code.OK, update_ver=ver,
                                        commit_ver=ver)
                 last = UpdateReply(
@@ -1351,13 +1370,19 @@ class StorageClient:
                 routing, chain, [items[b] for b in part],
                 [vers[b] for b in part], chunk_size)
             for b, reply in zip(part, got):
-                if reply is None or not (reply.ok or need_absent[b]):
-                    # partial, or a conflict on a stripe that may be
-                    # replaced: the single-stripe ladder re-probes
+                injected = (reply is not None
+                            and reply.code == Code.FAULT_INJECTION)
+                if (reply is None or injected
+                        or not (reply.ok or need_absent[b])):
+                    # partial, refused by an injected fault, or a conflict
+                    # on a stripe that may be replaced: the single-stripe
+                    # ladder re-probes
                     cid, data = items[b]
                     reply = self.write_stripe(
                         chain_id, cid, data, chunk_size=chunk_size,
                         update_ver=vers[b])
+                    if injected and reply.ok:
+                        self._injected_retried.add()
                 elif not reply.ok:
                     reply = None    # committed since the probe: merge
                 out[b] = reply
@@ -1416,7 +1441,8 @@ class StorageClient:
         not pass (every writable shard staged, at least k, then every one
         committed) comes back as the CHUNK_STALE_UPDATE a stage met, or
         None where the rounds just did not fully land: the caller picks
-        its ladder.
+        its ladder — or the FAULT_INJECTION an injected fault refused one
+        of its shards with, where it did not land.
 
         The rounds are traced under write_stripe's stage names,
         ``client.write_stripe.stage_shards`` / ``.commit_shards`` (the
@@ -1492,6 +1518,7 @@ class StorageClient:
         # -- phase 1: stage every shard (pending only) -----------------------
         # merge AFTER the _send_shard_batches barrier: `acked[b] += 1`
         # from concurrent node threads would be a lost-update race
+        injected: Dict[int, UpdateReply] = {}   # stripes a fault refused
         with _spans.span("client.write_stripe", "stage_shards"):
             staged = self._send_shard_batches(by_node)
         for b, reply in staged:
@@ -1499,6 +1526,8 @@ class StorageClient:
                 acked[b] += 1
             elif reply.code == Code.CHUNK_STALE_UPDATE:
                 hard[b] = reply
+            elif reply.code == Code.FAULT_INJECTION:
+                injected[b] = reply
         # -- phase 2: commit fully-staged stripes ----------------------------
         # an overwrite only destroys the previous version HERE, and only
         # for stripes whose every writable shard holds the staged content;
@@ -1520,13 +1549,15 @@ class StorageClient:
         for b, reply in landed:
             if reply.ok:
                 committed[b] += 1
+            elif reply.code == Code.FAULT_INJECTION:
+                injected[b] = reply
         # strict rule: every writable shard staged AND committed
         ok = [b in full_staged and committed[b] == acked[b]
               for b in range(B)]
         if writable < k + m:
             self._ec_degraded_write.add(sum(ok))
         return [UpdateReply(Code.OK, update_ver=vers[b], commit_ver=vers[b])
-                if ok[b] else hard[b] for b in range(B)]
+                if ok[b] else hard[b] or injected.get(b) for b in range(B)]
 
     def _write_stripes_chain(
         self,
@@ -1988,13 +2019,15 @@ class StorageClient:
         return out
 
     def _finish_stripe_reads(self, reqs, replies, ec_specs,
-                             shard_replies, routing) -> None:
+                             shard_replies, routing,
+                             with_checksum: bool = False) -> None:
         """Resolve every EC request of a batch from its first-round shard
         replies; stripes that did not assemble cleanly go DEGRADED
         together — the missing/failed shards of ALL of them fetch in one
         more batched round (any k of k+m survive), decode one dispatch a
         loss pattern, and the detour is recorded per stripe
-        (ec.degraded_read / ec.degraded_read_ms)."""
+        (ec.degraded_read / ec.degraded_read_ms). ``with_checksum``: each
+        reply then carries its range's CRC32C (_range_checksum)."""
         degraded: List[int] = []
         for i, spec in ec_specs.items():
             out = self._stripe_clean(spec, shard_replies[i])
@@ -2002,24 +2035,65 @@ class StorageClient:
                 replies[i] = out
             else:
                 degraded.append(i)
-        if not degraded:
-            return
-        from tpu3fs.analytics import spans as _spans
+        if degraded:
+            from tpu3fs.analytics import spans as _spans
 
-        with _spans.span("client.batch_read", "degraded"):
-            self._degraded_round(reqs, replies, ec_specs, shard_replies,
-                                 routing, degraded)
+            with _spans.span("client.batch_read", "degraded"):
+                self._degraded_round(reqs, replies, ec_specs, shard_replies,
+                                     routing, degraded)
+        if with_checksum:
+            for i, spec in ec_specs.items():
+                if replies[i].ok:
+                    replies[i].checksum = self._range_checksum(
+                        spec, shard_replies[i], replies[i])
+
+    @staticmethod
+    def _range_checksum(spec: dict, shards: Dict[int, ReadReply],
+                        reply: ReadReply) -> Checksum:
+        """CRC32C of an EC range reply's bytes, combined shard by shard
+        (crc32c_combine): a shard that answered at the reply's version
+        gives its server checksum, extended over the zeros the range pads
+        it with; a shard the client rebuilt (degraded) gives the CRC of
+        its rebuilt bytes. Checksum() — none — for a range that does not
+        start on a shard boundary or ends inside a shard's stored bytes."""
+        from tpu3fs.ops.crc32c import crc32c, crc32c_combine, crc32c_zeros
+
+        S, lo = spec["S"], spec["offset"]
+        data = memoryview(reply.data)
+        if lo % S:
+            return Checksum()
+        crc = 0
+        for j in range(spec["j0"], spec["j1"]):
+            at = j * S - lo
+            seg = min(S, len(data) - at)
+            r = shards.get(j)
+            if (r is not None and r.ok and r.commit_ver == reply.commit_ver
+                    and r.checksum.length == len(r.data)):
+                n = len(r.data)
+                if n > seg:
+                    return Checksum()
+                part = crc32c_combine(r.checksum.value,
+                                      crc32c_zeros(seg - n), seg - n)
+            else:
+                part = crc32c(data[at:at + seg])
+            crc = crc32c_combine(crc, part, seg)
+        return Checksum(crc, len(data))
 
     def _degraded_round(self, reqs, replies, ec_specs, shard_replies,
                         routing, degraded: List[int]) -> None:
         """_finish_stripe_reads past its clean stripes: the second round
         and the decodes, under the stage ``client.batch_read.degraded``
-        (``nbytes`` = payload bytes the decodes answered with)."""
+        (``nbytes`` = payload bytes the decodes answered with). The second
+        round reads again every shard the first did not get, so a read an
+        injected fault refused (transient, as upstream's storage bench
+        has it) is retried here; its stripe, answered, counts on
+        client.injected_retried."""
         from tpu3fs.analytics import spans as _spans
 
         t0 = time.monotonic()
         wire: List[Tuple[int, ReadReq]] = []
         tags: List[Tuple[int, int]] = []
+        injected: set = set()   # stripes a fault refused a shard read of
         for i in degraded:
             spec = ec_specs[i]
             chain = spec["chain"]
@@ -2028,6 +2102,8 @@ class StorageClient:
                 r = have.get(j)
                 if r is not None and r.ok:
                     continue
+                if r is not None and r.code == Code.FAULT_INJECTION:
+                    injected.add(i)
                 t = chain.target_of_shard(j)
                 if t is None or not t.public_state.can_read:
                     continue
@@ -2070,6 +2146,8 @@ class StorageClient:
                 self._ec_degraded.add()
                 self._ec_degraded_ms.record(dt_ms)
                 decoded += len(out.data)
+                if i in injected:
+                    self._injected_retried.add()
         stage = _spans.current_trace()
         if stage is not None:
             stage.nbytes = decoded

@@ -269,3 +269,42 @@ class BatchCrc32c:
             if native_ec.available():
                 return native_ec.crc32c_batch(np.asarray(chunks))
         return self._jit(chunks)
+
+
+class CrcVerifier:
+    """Check rows that landed in HBM against the CRC32C they were read
+    with (``StorageClient.batch_read(..., with_checksum=True)``): ONE
+    device program a batch shape, ``crc_verify_device`` — BatchCrc32c of
+    every row, compared with its expected value on the chip, so only a
+    flag a row comes back. ``land`` is the whole step a TPU client takes:
+    host rows to the device, then the check, traced as the root op
+    ``crc.verify`` with stages ``land`` (device_put) and ``check`` (the
+    program and the fetch of its flags)."""
+
+    def __init__(self, size: int, block: int = 512):
+        self.size = size
+        self._crc = BatchCrc32c(size, block=block)
+        self._check = jax.jit(self.crc_verify_device)
+
+    def crc_verify_device(self, rows, expected):
+        """(B, size) uint8, (B,) uint32 -> (B,) bool: row b's CRC32C is
+        expected[b]."""
+        return self._crc.compute(rows) == expected
+
+    def check(self, rows, expected) -> np.ndarray:
+        """Rows already on the device -> a host bool a row."""
+        assert rows.ndim == 2 and rows.shape[1] == self.size, rows.shape
+        return np.asarray(self._check(
+            rows, np.asarray(expected, dtype=np.uint32)))
+
+    def land(self, rows: np.ndarray, expected, device):
+        """(B, size) host uint8 rows -> (the rows on ``device``, a host
+        bool a row: it landed with the CRC32C it was read with)."""
+        from tpu3fs.analytics import spans as _spans
+
+        with _spans.root_span("crc.verify", nbytes=rows.size):
+            with _spans.span("crc.verify", "land", nbytes=rows.size):
+                landed = jax.device_put(rows, device)
+            with _spans.span("crc.verify", "check", nbytes=rows.shape[0]):
+                ok = self.check(landed, expected)
+        return landed, ok
