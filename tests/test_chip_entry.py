@@ -129,7 +129,10 @@ def test_stripe_device_switch_refuses_a_non_tpu_backend(monkeypatch):
     assert StripeCodec(3, 1, 512)._use_host()
 
 
-@pytest.mark.parametrize("k,m,S", [(3, 1, 1024), (12, 4, 512), (2, 1, 64)])
+# (3, 1, 5632): a 4-MiB chunk's shard (S = 1 398 272) scaled by 1/256,
+# shard_size_of(16 KiB, 3): the same uneven split, padded to the 512-B grid
+@pytest.mark.parametrize("k,m,S", [(3, 1, 1024), (12, 4, 512), (2, 1, 64),
+                                   (3, 1, 5632)])
 def test_codec_device_branches_match_host_past_the_batch_bound(
         monkeypatch, k, m, S):
     from tpu3fs.ops import stripe

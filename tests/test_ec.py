@@ -1518,6 +1518,74 @@ class TestCodecBuckets:
             len(codec._buckets(K + M))
 
 
+class TestEncodeFetch:
+    """A device encode brings back the parity and the CRCs, never the data
+    shards its caller sent and still holds; the serving encode still
+    enters through StripeCodec.encode_batch, where the benchmark's watcher
+    counts the calls its encode roofline divides by and its parity
+    controls plant themselves."""
+
+    @pytest.mark.parametrize("k,m,chunk", [(3, 1, 4 << 20), (12, 4, 1 << 20)],
+                             ids=["rs3p1_4m", "rs12p4_1m"])
+    @pytest.mark.parametrize("bp", [1, 16])
+    def test_the_device_program_returns_no_data_shard(self, k, m, chunk, bp):
+        import jax
+
+        from tpu3fs.ops.stripe import StripeCodec
+
+        s = shard_size_of(chunk, k)
+        codec = StripeCodec(k, m, s)
+        parity, crcs = jax.eval_shape(
+            codec._encode_dev, jax.ShapeDtypeStruct((bp, k, s), np.uint8))
+        assert (parity.shape, parity.dtype) == ((bp, m, s), np.uint8)
+        assert (crcs.shape, crcs.dtype) == ((bp, k + m), np.uint32)
+
+    def test_the_serving_encode_passes_both_hooks(self, monkeypatch):
+        from perfbench.faults import kv_parity_zeroed
+        from tpu3fs.ops.stripe import StripeCodec
+
+        chunk, b = 1 << 14, 5
+        s = shard_size_of(chunk, K)
+        fab = ec_fabric(chains=1, chunk_size=chunk)
+        chain = fab.chain_ids[0]
+        client = fab.storage_client()
+        codec = get_codec(K, M, s)
+        monkeypatch.setattr(codec, "_host_mode", False)  # the CPU backend
+        calls = []
+        inner = StripeCodec.encode_batch
+
+        def encode_batch(self, data):
+            calls.append(int(data.shape[0]))
+            return inner(self, data)
+
+        monkeypatch.setattr(StripeCodec, "encode_batch", encode_batch)
+        rng = np.random.default_rng(43)
+
+        def put(ino):
+            items = [(ChunkId(ino, i),
+                      rng.integers(0, 256, chunk, dtype=np.uint8).tobytes())
+                     for i in range(b)]
+            assert all(r.ok for r in client.write_stripes(
+                chain, items, chunk_size=chunk))
+            return [(_stored(fab, chain, cid), body) for cid, body in items]
+
+        for stored, body in put(70):
+            rows = np.zeros((1, K, s), dtype=np.uint8)
+            rows.reshape(-1)[:chunk] = np.frombuffer(body, dtype=np.uint8)
+            want = codec.rs.encode_host(rows)[0]
+            assert b"".join(stored[j][0] for j in range(K))[:chunk] == body
+            assert [stored[K + j][0] for j in range(M)] == \
+                [want[j].tobytes() for j in range(M)]
+            assert any(want.reshape(-1))
+        assert calls == [b]
+
+        kv_parity_zeroed.plant(None)   # wraps the method the watcher wraps
+        for stored, body in put(71):
+            assert b"".join(stored[j][0] for j in range(K))[:chunk] == body
+            assert [stored[K + j][0] for j in range(M)] == [bytes(s)] * M
+        assert calls == [b, b]
+
+
 class TestDecodeBuckets:
     """A device decode goes out in buckets of at most sixteen stripes, and
     the first device decode of a lost count builds every one of them, so

@@ -61,11 +61,12 @@ DEVICE_BATCH_BYTES = 128 << 20
 # ... and the most stripes of an encode or a reconstruct dispatch. Every
 # power of two up to it is a program the codec builds before it serves
 # (StripeCodec._prepare): 5 for the encode, and 5 for each count of lost
-# shards the codec decodes. On a v5e the round trip of one encode stops
+# shards the codec decodes. On a v5e the round trip of one encode stopped
 # scaling past 16 stripes of RS(12,4) S=87552 (PERF.md, "The codec's
-# buckets": 0.9 ms a stripe at 16, 3.6 at 32, 3.9 at 64 — the 45 MB and
-# more coming back), so a large batch goes faster as dispatches of 16.
-# The CRC returns a fraction of that and keeps the bound by bytes alone.
+# buckets": 0.9 ms a stripe at 16, 3.6 at 32, 3.9 at 64), measured when
+# the encode program still returned all k+m shards; it now returns the
+# parity and the CRCs alone, and whether the bound should move with that
+# is unmeasured. The CRC keeps the bound by bytes alone.
 DEVICE_BATCH_ITEMS = 16
 
 
@@ -175,9 +176,11 @@ class StripeCodec:
         O(log B) programs per codec, reused forever. Zero rows encode to
         zero parity, so the pad rows are simply sliced off by the caller.
 
-        Traced per dispatch as two stages of ``op``: ``dispatch`` (pad,
-        host -> device, launch) and ``fetch`` (device_get: the wait for
-        the program and device -> host). Bytes make no sense for a
+        Traced per dispatch as two stages of ``op``: ``dispatch`` (pad
+        and launch) and ``fetch`` (device_get: the wait for the program
+        and device -> host; on the chip the launch returns before the
+        host -> device copy is done, so that copy ends inside ``fetch``
+        too). Bytes make no sense for a
         launch, so both stages' ``nbytes`` holds the COUNT of items
         (stripes, for an encode) the dispatch carries."""
         for lo in range(0, items.shape[0], step):
@@ -198,11 +201,14 @@ class StripeCodec:
     def encode_parity(self, data: np.ndarray
                       ) -> Tuple[np.ndarray, np.ndarray]:
         """(B, k, S) uint8 -> (parity (B, m, S), crcs (B, k+m) uint32) —
-        the serving-path shape: callers already hold the data-shard bytes,
-        so the (B, k+m, S) concatenation encode_batch builds would be a
-        multi-MiB copy just to throw away. Honors the same host/device
-        policy as encode_batch (TPU3FS_STRIPE_DEVICE=1 keeps the device
-        kernels for hosts whose accelerator is local enough to win)."""
+        the serving-path shape: callers already hold the data-shard bytes.
+        Honors the same host/device policy as encode_batch
+        (TPU3FS_STRIPE_DEVICE=1 keeps the device kernels for hosts whose
+        accelerator is local enough to win). On the device branch it goes
+        through encode_batch, which fetches only the parity and the CRCs
+        from the chip: every device encode a client serves passes that one
+        method, where the benchmark notes the encodes its roofline counts
+        and its controls plant a fault."""
         b, k, s = data.shape
         assert k == self.k and s == self.shard_size, (data.shape, self.k)
         if not self._use_host():
@@ -223,7 +229,10 @@ class StripeCodec:
 
     def encode_batch(self, data: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(B, k, S) uint8 -> (shards (B, k+m, S), crcs (B, k+m) uint32),
-        both materialized on host for the RPC layer."""
+        both materialized on host for the RPC layer. On the device branch
+        the chip sends back the parity rows and the CRCs alone
+        (_encode_device); the data half of ``shards`` is copied from
+        ``data`` on the host, which the caller already holds."""
         b, k, s = data.shape
         assert k == self.k and s == self.shard_size, (data.shape, self.k)
         if self._use_host():
@@ -237,22 +246,24 @@ class StripeCodec:
             crcs = np.empty((b, k + self.m), dtype=np.uint32)
             rows = self.k + self.m
             self._prepare("encode", self._encode_dev, rows)
-            for lo, n, (out_s, out_c) in self._device_map(
+            for lo, n, (out_p, out_c) in self._device_map(
                     self._encode_dev, data, self._buckets(rows)[-1]):
-                shards[lo:lo + n] = out_s[:n]
+                shards[lo:lo + n, k:] = out_p[:n]
                 crcs[lo:lo + n] = out_c[:n]
+            shards[:, :k] = data
         return shards, crcs
 
     def _encode_device(self, data):
-        """(Bp, k, S) -> (shards (Bp, k+m, S), crcs (Bp, k+m)): encode and
-        checksum as ONE jitted program per batch bucket."""
+        """(Bp, k, S) -> (parity (Bp, m, S), crcs (Bp, k+m)): encode and
+        checksum as ONE jitted program per batch bucket. The CRCs cover
+        all k+m rows, computed on the chip; the data rows themselves do
+        not come back, since every caller holds them."""
         bp = data.shape[0]
         n = self.k + self.m
-        shards = data
-        if self.m:
-            shards = jnp.concatenate([data, self.rs.encode(data)], axis=1)
+        parity = self.rs.encode(data) if self.m else data[:, :0]
+        shards = jnp.concatenate([data, parity], axis=1)
         crcs = self._crc.compute(shards.reshape(bp * n, self.shard_size))
-        return shards, crcs.reshape(bp, n)
+        return parity, crcs.reshape(bp, n)
 
     def delta_parity(self, j: int, delta) -> np.ndarray:
         """Parity-row deltas for a sub-stripe change on data shard j:
